@@ -1,9 +1,13 @@
 # CI-shape runner — the Docker-suite analog (the reference image built the
 # lib, ran nosetests + lua tests + mpirun end-to-end targets,
-# deploy/docker/Dockerfile:93-113). One command reproduces everything the
-# driver measures:
+# deploy/docker/Dockerfile:93-113).
 #
-#   make check          native build + tests + multi-chip dryrun + bench
+#   make check          lint + native build + tests + multi-chip dryrun +
+#                       the CPU drills (no bench: a CPU timing is not a
+#                       device metric)
+#   make smoke          chip_smoke.py: the main path on the TPU this
+#                       process holds, every phase checked against numpy;
+#                       fails without a TPU
 #   make lint           mvlint project-invariant static analysis (blocking
 #                       in CI; docs/static_analysis.md)
 #   make native         just the C++ layer (libmultiverso_tpu.so + C client)
@@ -31,7 +35,11 @@
 #                       answers, attribution table is non-empty
 #                       (docs/observability.md §13)
 #   make dryrun         multi-chip sharding compile+execute check (CPU mesh)
-#   make bench          the headline JSON line (real TPU when available)
+#   make bench          the in-process device legs, one JSON line; fails
+#                       without a TPU
+#   make wire-bench     wire micro-bench only: codec ratio, TCP RTT and
+#                       bandwidth, served KV Adds, coalesced vs per-frame
+#   make profile-bench  sampling-profiler overhead A/B on the dense pass
 #   make apply-bench    apply-path micro-bench only: fused vs per-message
 #                       A/B, batch-size sweep, shm vs TCP RTT/throughput
 #   make read-bench     read-path A/B only: Zipf hot-key Gets, primary vs
@@ -92,13 +100,17 @@ PYTHON ?= python
 CPU_ENV := JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 CHAOS_SEED ?= 7
 
-.PHONY: check lint chaos failover sharded replicas reshard metrics-smoke \
-	profile-smoke native test dryrun bench apply-bench read-bench tiered \
-	audit audit-bench autopilot autopilot-bench overload overload-bench \
-	chargeback query query-bench autotune autotune-bench clean
+.PHONY: check smoke lint chaos failover sharded replicas reshard \
+	metrics-smoke profile-smoke native test dryrun bench wire-bench \
+	profile-bench apply-bench read-bench tiered audit audit-bench autopilot \
+	autopilot-bench overload overload-bench chargeback query query-bench \
+	autotune autotune-bench clean
 
 check: lint native test dryrun profile-smoke tiered audit autopilot \
-	overload chargeback query autotune bench
+	overload chargeback query autotune
+
+smoke:
+	$(PYTHON) chip_smoke.py
 
 lint:
 	$(PYTHON) -m tools.mvlint
@@ -151,6 +163,12 @@ dryrun:
 
 bench:
 	$(PYTHON) bench.py
+
+wire-bench:
+	$(CPU_ENV) $(PYTHON) bench.py --wire-bench
+
+profile-bench:
+	$(PYTHON) bench.py --profile-bench
 
 apply-bench:
 	$(PYTHON) bench.py --apply-bench

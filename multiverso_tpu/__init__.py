@@ -10,6 +10,7 @@ and the allreduce path is ``psum``/host-collectives instead of MPI.
 Public surface (MV_* parity):
 
     init / shutdown / barrier
+    configure_compile_cache        (persistent compile cache placement)
     rank / size / num_workers / num_servers / worker_id / server_id
     worker_id_to_rank / server_id_to_rank / is_master_worker
     set_flag / parse_cmd_flags
@@ -49,6 +50,7 @@ def init(argv: Optional[Sequence[str]] = None, sync: Optional[bool] = None,
         set_flag("sync", sync)
     for key, value in flag_overrides.items():
         set_flag(key, value)
+    configure_compile_cache()
     remaining = Zoo.instance().start(argv)
     _configure_native_allocator()
     _configure_profiling()
@@ -56,6 +58,32 @@ def init(argv: Optional[Sequence[str]] = None, sync: Optional[bool] = None,
     _start_observability()
     _start_autotune()
     return remaining
+
+
+def configure_compile_cache() -> Optional[str]:
+    """Place JAX's persistent compile cache before the first compile and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    reads it on its own and nothing is done here; otherwise the cache
+    goes to ``<checkout>/.jax_cache``. The path is part of the cache key,
+    so it is fixed by the package's location: every process of a
+    checkout resolves the same one. A process pinned to the CPU
+    (``JAX_PLATFORMS=cpu``: the tests, shard children, remote clients)
+    gets no default: hashing every module for the cache made test files
+    8-12% slower here, almost none of their compiles reach the one
+    second JAX caches from, and XLA:CPU logs a machine-feature mismatch
+    on every load."""
+    import os
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    import jax
+    if jax.config.jax_platforms == "cpu":
+        return None
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 _metrics_logger = None
@@ -195,6 +223,8 @@ def _configure_native_allocator() -> None:
     import ctypes
     from multiverso_tpu.utils.quantization import _load_native
     lib = _load_native()
+    log.info("wire codec: %s", "native (native/libmultiverso_tpu.so)"
+             if lib is not None else "numpy (native library not built)")
     if lib is None or not hasattr(lib, "MVTPU_ConfigureAllocator"):
         return  # native lib absent or predates the configure export
     lib.MVTPU_ConfigureAllocator.restype = ctypes.c_int

@@ -18,10 +18,13 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
 * batch size is a multiple of the row group (bucket sizes are powers of
   two ≥ the group).
 
-Off-TPU (the virtual-CPU test mesh) the kernels run in interpreter mode.
+Interpret mode is the caller's explicit choice, made once from the
+platform of the devices that hold the table (:func:`interpret_for`): ``cpu``
+interprets (the test mesh), ``tpu`` compiles, anything else is an error.
 
-Optimization record (measured on the bench chip, v5e single-core, 1024-row
-x 128-col update on a 1M-row table, scan-slope timing):
+Optimization record (measured at PR 5 on a v5e, single core, 1024-row x
+128-col update on a 1M-row table, scan-slope timing; not re-measured on the
+current machine):
 
 * group-size sweep: 8→83us, 16→49us, 32→32us, 64→26.4us, 128→27.2us;
   256 exceeds the semaphore-flag memory (sflag 2KB). The 64-group asymptote
@@ -39,9 +42,8 @@ x 128-col update on a 1M-row table, scan-slope timing):
   contiguity to merge, so this is the v5e floor for this op shape.
 * descriptor coalescing (r3): sorted-unique ids do contain contiguous runs
   on zipf workloads, so a variant merges each all-consecutive 4-row segment
-  into ONE 4-row DMA (`_scatter_add_kernel_coalesced`, enable with
-  MVTPU_COALESCE=1). Measured on the bench chip (1M×128 table, 1024-id
-  batches, scan-slope): simple 27.2-27.3µs vs coalesced 36.5-39.6µs on BOTH
+  into ONE 4-row DMA. Measured (1M×128 table, 1024-id batches,
+  scan-slope): simple 27.2-27.3µs vs coalesced 36.5-39.6µs on BOTH
   sorted-zipf and sorted-uniform ids — a 34-45% LOSS. Two reasons, both
   structural: (a) zipf-1024-of-1M contiguity is only 13% of segments (the
   dense head of the distribution is ~100 ids; the tail is sparse), and
@@ -50,8 +52,7 @@ x 128-col update on a 1M-row table, scan-slope timing):
   possible descriptor saving is 96 × ~13ns ≈ 1.2µs even at 100%
   contiguity. Conclusion: on v5e the branch cost exceeds the descriptor
   cost by ~10×, so run-merging cannot win at 512B rows regardless of
-  workload; the simple kernel stays the default. The coalesced kernel is
-  kept default-off as the reproduction artifact for this record.
+  workload. The coalesced kernel was deleted; this paragraph is its record.
 """
 
 from __future__ import annotations
@@ -60,7 +61,6 @@ import functools
 import os
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,8 +73,16 @@ if ROW_GROUP <= 0 or ROW_GROUP & (ROW_GROUP - 1):
     raise ValueError(f"MVTPU_ROW_GROUP must be a power of two, got {ROW_GROUP}")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_for(platform: str) -> bool:
+    """Whether the row kernels interpret on ``platform`` — the platform of
+    the devices that hold the table, not the process's default backend."""
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise ValueError(
+        f"pallas row kernels run compiled on tpu and interpreted on cpu; "
+        f"the table lives on {platform!r}")
 
 
 def _gather_kernel(ids_ref, table_ref, out_ref, sems):
@@ -112,12 +120,13 @@ def _gather_call(table, ids, interpret):
     )(ids, table)
 
 
-def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+def gather_rows(table: jax.Array, ids: jax.Array, *,
+                interpret: bool) -> jax.Array:
     """``table[ids]`` via overlapped row DMAs. ids: int32, len % ROW_GROUP == 0."""
     if ids.shape[0] % ROW_GROUP:
         raise ValueError(
             f"gather_rows: batch {ids.shape[0]} not a multiple of {ROW_GROUP}")
-    return _gather_call(table, ids, not _on_tpu())
+    return _gather_call(table, ids, interpret)
 
 
 def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
@@ -179,124 +188,11 @@ def _scatter_add_call(table, ids, deltas, interpret):
     )(ids, deltas, table)
 
 
-def scatter_add_rows(table: jax.Array, ids: jax.Array,
-                     deltas: jax.Array) -> jax.Array:
+def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
+                     *, interpret: bool) -> jax.Array:
     """In-place ``table.at[ids].add(deltas)`` for unique live ids; the input
     table buffer is donated."""
     if ids.shape[0] % ROW_GROUP:
         raise ValueError(
             f"scatter_add_rows: batch {ids.shape[0]} not a multiple of {ROW_GROUP}")
-    if COALESCE:
-        if ROW_GROUP % SEG:
-            # n_segs would floor to 0 and the kernel would silently drop
-            # every update on the aliased table
-            raise ValueError(
-                f"MVTPU_COALESCE needs ROW_GROUP % {SEG} == 0, "
-                f"got {ROW_GROUP}")
-        return _scatter_add_coalesced_call(table, ids, deltas, not _on_tpu())
-    return _scatter_add_call(table, ids, deltas, not _on_tpu())
-
-
-# -- descriptor coalescing (VERDICT r2 task 8) --------------------------------
-# Sorted-unique ids on zipf workloads contain contiguous runs (the hot head
-# of the distribution is dense after sorting). Segment each group into
-# SEG-row segments; a segment whose ids are consecutive moves as ONE
-# SEG-row DMA instead of SEG single-row DMAs — fewer descriptors, and the
-# per-descriptor issue cost (~13ns on the scalar core) is the measured
-# floor of the simple kernel. Run flags are computed on-device (cheap XLA
-# elementwise) and ride the scalar-prefetch channel next to the ids.
-
-SEG = 4  # rows per coalescible segment
-
-COALESCE = os.environ.get("MVTPU_COALESCE", "0") == "1"
-
-
-def _seg_flags(ids: jax.Array) -> jax.Array:
-    """(batch//SEG,) int32: 1 where a segment's ids are consecutive."""
-    segs = ids.reshape(-1, SEG)
-    return jnp.all(jnp.diff(segs, axis=1) == 1, axis=1).astype(jnp.int32)
-
-
-def _scatter_add_kernel_coalesced(ids_ref, flags_ref, delta_ref, table_in_ref,
-                                  table_ref, scratch, read_sems, write_sems):
-    del table_in_ref  # aliased with table_ref; all access goes through out
-    g = pl.program_id(0)
-    base = g * ROW_GROUP
-    n_segs = ROW_GROUP // SEG
-
-    def seg_copy(s, dst_is_table, sems):
-        slot = s * SEG
-        rid0 = ids_ref[base + slot]
-        if dst_is_table:
-            return pltpu.make_async_copy(scratch.at[pl.ds(slot, SEG)],
-                                         table_ref.at[pl.ds(rid0, SEG)],
-                                         sems.at[slot])
-        return pltpu.make_async_copy(table_ref.at[pl.ds(rid0, SEG)],
-                                     scratch.at[pl.ds(slot, SEG)],
-                                     sems.at[slot])
-
-    def row_copy(k, dst_is_table, sems):
-        rid = ids_ref[base + k]
-        if dst_is_table:
-            return pltpu.make_async_copy(scratch.at[k], table_ref.at[rid],
-                                         sems.at[k])
-        return pltpu.make_async_copy(table_ref.at[rid], scratch.at[k],
-                                     sems.at[k])
-
-    def phase(dst_is_table, sems):
-        for s in range(n_segs):
-            flag = flags_ref[g * n_segs + s]
-
-            @pl.when(flag == 1)
-            def _():
-                seg_copy(s, dst_is_table, sems).start()
-
-            @pl.when(flag == 0)
-            def _():
-                for j in range(SEG):
-                    row_copy(s * SEG + j, dst_is_table, sems).start()
-        for s in range(n_segs):
-            flag = flags_ref[g * n_segs + s]
-
-            @pl.when(flag == 1)
-            def _():
-                seg_copy(s, dst_is_table, sems).wait()
-
-            @pl.when(flag == 0)
-            def _():
-                for j in range(SEG):
-                    row_copy(s * SEG + j, dst_is_table, sems).wait()
-
-    phase(False, read_sems)
-    scratch[:, :] = scratch[:, :] + delta_ref[:, :]
-    phase(True, write_sems)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=(0,))
-def _scatter_add_coalesced_call(table, ids, deltas, interpret):
-    batch = ids.shape[0]
-    cols = table.shape[1]
-    flags = _seg_flags(ids)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch // ROW_GROUP,),
-        in_specs=[
-            pl.BlockSpec((ROW_GROUP, cols), lambda g, ids, flags: (g, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((ROW_GROUP, cols), table.dtype),
-            pltpu.SemaphoreType.DMA((ROW_GROUP,)),
-            pltpu.SemaphoreType.DMA((ROW_GROUP,)),
-        ],
-    )
-    return pl.pallas_call(
-        _scatter_add_kernel_coalesced,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-        input_output_aliases={3: 0},  # ids, flags, deltas, table → table
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(ids, flags, deltas, table)
+    return _scatter_add_call(table, ids, deltas, interpret)

@@ -19,6 +19,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from multiverso_tpu import log
+
 
 def parse_mesh_shape(text: str) -> Optional[Tuple[int, ...]]:
     """Parse '2x4'-style mesh shape flags; empty → None (auto 1-D)."""
@@ -34,6 +36,14 @@ def build_mesh(devices: Optional[Sequence[jax.Device]] = None,
     devs = list(devices) if devices is not None else jax.devices()
     if shape is None:
         shape = (len(devs),) + (1,) * (len(axis_names) - 1)
+    want, have = int(np.prod(shape)), len(devs)
+    if want > have:
+        log.fatal("mesh shape %s needs %d devices, have %d",
+                  "x".join(map(str, shape)), want, have)
+    if want < have:
+        devs = devs[:want]
+        log.info("mesh shape %s takes the first %d of %d devices: %s",
+                 "x".join(map(str, shape)), want, have, devs)
     arr = np.array(devs).reshape(shape)
     return Mesh(arr, axis_names=tuple(axis_names))
 

@@ -92,11 +92,7 @@ def init_distributed_cpu(coordinator: str, world: int, rank: int) -> None:
     substitute collectives."""
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jax: option absent; single-host still works
-        log.info("multihost: jax_cpu_collectives_implementation "
-                 "unavailable; cross-process CPU collectives may fail")
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator, num_processes=world,
                                process_id=rank)
 
@@ -1304,7 +1300,6 @@ def spawn_lockstep_world(child_script: str, scenario: str, world: int = 2,
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{devices_per_proc}")
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("_MV_DRYRUN_CHILD", None)
     # children inherit our process group on purpose: a harness killed by
     # an outer SIGKILL orphans them (nothing can prevent that from in
     # here — a preexec PDEATHSIG hook was tried and deadlocks forked

@@ -180,7 +180,7 @@ class _QueryCompletion(_ReadCompletion):
     Reply_Query stamped with the append watermark. Idempotent like a
     read — no dedup entry; a replayed query just re-scores. The done
     counter is the query plane's zero-primary-dispatch proof
-    (BENCH_r13 mirrors BENCH_r07's read-tier bar on it)."""
+    (BENCH_r13 holds the read tier's bar on it)."""
 
     __slots__ = ()
 

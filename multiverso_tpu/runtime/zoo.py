@@ -322,7 +322,7 @@ class Zoo:
 
         DEVICE path: pass a ``jax.Array`` (or list of them — a model's
         leaves) and the reduction runs as ONE jitted tree-sum in HBM with
-        the result returned still on device — host RAM and PCIe/tunnel
+        the result returned still on device — host RAM and PCIe
         bandwidth never see the model (the reference's MA mode summed in
         host buffers, the round-3 verdict's 'aggregate is host-bound'
         item). Mixed host/device calls across workers in one round are

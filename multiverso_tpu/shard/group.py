@@ -12,11 +12,13 @@ parsing races), the parent then writes ``layout.json`` atomically, and
 every member serves it over the ``Control_Layout`` RPC — the manifest on
 disk doubles as the recovery record for a restarted shard.
 
-Local groups force ``JAX_PLATFORMS=cpu`` into the children (N shards
-sharing one host's accelerator would fight over it); production runs the
-same child module one-per-host with explicit ``--port`` and a shared
-``base_dir`` on network storage, or any orchestrator that can run
-``python -m multiverso_tpu.shard._child``.
+Local groups force ``JAX_PLATFORMS=cpu`` into the children, whatever the
+parent's environment says (a chip belongs to one process: N shards sharing
+one host's accelerator would fight over it, and a parent that has touched
+JAX already holds it); the platform is recorded in the group spec and the
+start-up log. Production runs the same child module one-per-host with
+explicit ``--port`` and a shared ``base_dir`` on network storage, or any
+orchestrator that can run ``python -m multiverso_tpu.shard._child``.
 """
 
 from __future__ import annotations
@@ -34,6 +36,21 @@ from multiverso_tpu import config, log
 from multiverso_tpu.shard.partition import plan_tables, validate_partitioner_flag
 from multiverso_tpu.shard.router import (LAYOUT_VERSION, ShardLayout,
                                          ShardedClient)
+
+# JAX platform of every child a local group (or a live reshard) starts
+CHILD_PLATFORM = "cpu"
+
+
+def child_env() -> Dict[str, str]:
+    """Environment for a locally spawned shard process: the repo on the
+    path and the platform written unconditionally, never inherited."""
+    env = dict(os.environ)
+    repo_root = os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = CHILD_PLATFORM
+    return env
+
 
 class ShardGroup:
     """Start and own a local group of shard-serving child processes."""
@@ -108,6 +125,7 @@ class ShardGroup:
                 "tables": self.entries,
                 "flags": self.flags,
                 "host": self.host,
+                "platform": CHILD_PLATFORM,
                 "wal_root": self.base_dir if self.durable else "",
                 "layout_path": self.layout_path}
         with open(self.spec_path, "w", encoding="utf-8") as f:
@@ -147,8 +165,9 @@ class ShardGroup:
                                 primary=self.endpoints[k]))
             for k in range(self.num_shards):
                 self._await_file(f"standby{k}.ready", k, deadline)
-        log.info("shard group up: %d shard(s) at %s%s%s", self.num_shards,
-                 self.endpoints, " (+warm standbys)" if self.standby else "",
+        log.info("shard group up: %d %s shard(s) at %s%s%s", self.num_shards,
+                 CHILD_PLATFORM, self.endpoints,
+                 " (+warm standbys)" if self.standby else "",
                  (f" (+{self.num_replicas} read replica(s)/shard)"
                   if self.num_replicas else ""))
         return self
@@ -168,20 +187,14 @@ class ShardGroup:
                 argv += ["--takeover"]
         else:
             argv += self._primary_extra.get(shard, [])
-        env = dict(os.environ)
-        repo_root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        # a local group multiplexes one host: the children run CPU tables
-        # (production shards get one accelerator-owning host each)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         role = ("standby" if standby
                 else f"replica{shard}.{replica_index}"
                 if replica_index is not None else "shard")
         name = role if replica_index is not None else f"{role}{shard}"
         logf = open(os.path.join(self.base_dir, f"{name}.log"), "ab")
         try:
-            return subprocess.Popen(argv, stdout=logf, stderr=logf, env=env)
+            return subprocess.Popen(argv, stdout=logf, stderr=logf,
+                                    env=child_env())
         finally:
             logf.close()  # the child holds its own fd
 
@@ -271,6 +284,7 @@ class ShardGroup:
                 "tables": manifest["tables"],
                 "flags": self.flags,
                 "host": self.host,
+                "platform": CHILD_PLATFORM,
                 "wal_root": self.base_dir if self.durable else "",
                 "layout_path": self.layout_path}
         tmp = spec_path + ".tmp"

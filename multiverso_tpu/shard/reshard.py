@@ -65,6 +65,7 @@ from multiverso_tpu import config, log
 from multiverso_tpu.dashboard import count
 from multiverso_tpu.obs.trace import flight_dump, hop
 from multiverso_tpu.runtime.message import MsgType, next_msg_id
+from multiverso_tpu.shard.group import CHILD_PLATFORM, child_env
 from multiverso_tpu.shard.partition import partitioner_from_spec
 
 MIGRATABLE_KINDS = ("array", "matrix")
@@ -413,6 +414,7 @@ class MigrationCoordinator:
         j = joiner["shard"]
         new_entries = plan.new_manifest["tables"]
         spec = {"shard": j, "host": self.group.host, "port": port,
+                "platform": CHILD_PLATFORM,
                 "flags": self.group.flags,
                 "wal_root": self.group.base_dir,
                 "wal_suffix": f"-join{plan.new_version}",
@@ -428,14 +430,10 @@ class MigrationCoordinator:
     def _spawn_joiner(self, paths: Dict[str, str]) -> subprocess.Popen:
         argv = [sys.executable, "-m", "multiverso_tpu.shard._child",
                 "--join", paths["spec"]]
-        env = dict(os.environ)
-        repo_root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")  # same rule as ShardGroup
         logf = open(paths["log"], "ab")
         try:
-            return subprocess.Popen(argv, stdout=logf, stderr=logf, env=env)
+            return subprocess.Popen(argv, stdout=logf, stderr=logf,
+                                    env=child_env())
         finally:
             logf.close()  # the child holds its own fd
 

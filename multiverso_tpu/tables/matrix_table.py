@@ -25,6 +25,7 @@ TPU-native re-design:
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -42,9 +43,6 @@ from multiverso_tpu.updaters import AddOption, GetOption, SGDUpdater, Updater, g
 from multiverso_tpu.utils import async_upload, next_pow2 as _next_pow2
 
 
-import functools
-
-
 @functools.partial(jax.jit, static_argnames=("bucket", "cols"))
 def _device_pad(values: jax.Array, bucket: int, cols: int) -> jax.Array:
     """(n, c) → (bucket, cols) zero-padded, entirely on device."""
@@ -52,11 +50,12 @@ def _device_pad(values: jax.Array, bucket: int, cols: int) -> jax.Array:
     return out.at[: values.shape[0], : values.shape[1]].set(values)
 
 
-def _use_pallas_scatter(backend: str, num_shards: int) -> bool:
+def _use_pallas_scatter(platform: str, num_shards: int) -> bool:
     """Pallas row-DMA scatter serves single-shard TPU tables only:
     pallas_call has no SPMD partitioning rule, so multi-device tables take
-    XLA's scatter (which partitions fine)."""
-    return backend == "tpu" and num_shards == 1
+    XLA's scatter (which partitions fine). ``platform`` is that of the
+    table mesh's devices."""
+    return platform == "tpu" and num_shards == 1
 
 
 class MatrixServer(ServerTable):
@@ -128,28 +127,42 @@ class MatrixServer(ServerTable):
         self._sign = -1.0 if isinstance(self.updater, SGDUpdater) else 1.0
         self._gather = jax.jit(lambda data, ids: data[ids])
         # device-out gets feed WORKER-thread jits (the word2vec fast
-        # path's compact training space): committed to ONE device so
-        # those jits are single-device programs — concurrent sharded
-        # executions from worker threads deadlock the CPU backend's
-        # collective rendezvous while the dispatcher runs its own sharded
-        # gather (the same decision, for the same reason, as
-        # ArrayServer._leaf_codec; scatters re-shard on the way back in)
+        # path's compact training space): committed to ONE device, the
+        # mesh's first, so those jits are single-device programs and every
+        # cross-shard collective stays on the dispatcher thread (the same
+        # decision as ArrayServer._leaf_codec; scatters re-shard on the
+        # way back in). Concurrent sharded executions from worker threads
+        # deadlock the CPU test mesh's rendezvous; on a real multi-chip
+        # mesh the effect is not measured.
         from jax.sharding import SingleDeviceSharding
-        _out_dev = SingleDeviceSharding(jax.devices()[0])
+        first_dev = self.mesh.devices.flat[0]
+        _out_dev = SingleDeviceSharding(first_dev)
         self._gather_out = lambda data, ids: jax.device_put(
             self._gather(data, ids), _out_dev)
-        self._pallas_scatter = _use_pallas_scatter(
-            jax.default_backend(), num_shards)
+        platform = first_dev.platform
+        self._pallas_scatter = _use_pallas_scatter(platform, num_shards)
+        # None where XLA's scatter serves the table
+        self._pallas_interpret: Optional[bool] = None
         if self._pallas_scatter:
-            from multiverso_tpu.ops.pallas_rows import scatter_add_rows
+            from multiverso_tpu.ops import pallas_rows
+            self._pallas_interpret = pallas_rows.interpret_for(platform)
             # unique-id contract: see process_add
-            self._scatter_add_raw = scatter_add_rows
-            self._scatter_add = scatter_add_rows
+            self._scatter_add_raw = functools.partial(
+                pallas_rows.scatter_add_rows,
+                interpret=self._pallas_interpret)
+            self._scatter_add = self._scatter_add_raw
+            why = "pallas row-DMA kernel, %s" % (
+                "interpreted" if self._pallas_interpret else "compiled")
         else:
             self._scatter_add_raw = lambda data, ids, delta: (
                 data.at[ids].add(delta))
             self._scatter_add = jax.jit(self._scatter_add_raw,
                                         donate_argnums=(0,))
+            why = "XLA scatter (%s)" % (
+                "pallas_call has no SPMD partitioning rule"
+                if platform == "tpu" else "the kernel compiles for tpu only")
+        log.info("MatrixTable %dx%d on %d %s device(s): row scatter = %s",
+                 self.num_row, self.num_col, num_shards, platform, why)
         self._row_update = self._make_row_update(self.updater)
 
     def _make_row_update(self, updater: Updater, jit: bool = True):

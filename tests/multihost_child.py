@@ -460,9 +460,8 @@ def run_ctrlperf(mv, np, rank: int, world: int) -> None:
     """Bound + record the lockstep control plane's per-op cost: a sync
     row add from EVERY rank (followers pay the full forward -> leader
     execute -> broadcast -> replay -> ack round trip). The 250ms median
-    bound is a broken-plane guard with a 50ms advisory print — measured
-    medians are ~3ms on a loaded CI host (recorded in bench.py's
-    multihost_ctrl_op_us)."""
+    bound is a broken-plane guard with a 50ms advisory print — medians
+    of ~3ms were seen on a loaded CI host."""
     import time
 
     mat = mv.create_table("matrix", num_row=64, num_col=8)
@@ -477,10 +476,9 @@ def run_ctrlperf(mv, np, rank: int, world: int) -> None:
             samples.append(time.perf_counter() - t0)
     med = sorted(samples)[len(samples) // 2]
     print(f"CTRL_OP_MEDIAN_US rank={rank} {med * 1e6:.1f}", flush=True)
-    # 250ms is a broken-control-plane bound, not a perf target: measured
-    # medians are ~3ms, but an oversubscribed CI host can stall a whole
-    # scheduling quantum mid-round-trip. Flag (don't fail) past 50ms —
-    # bench.py's multihost_ctrl_op_us records the real figure.
+    # 250ms is a broken-control-plane bound, not a perf target: medians
+    # of ~3ms were seen, but an oversubscribed CI host can stall a whole
+    # scheduling quantum mid-round-trip. Flag (don't fail) past 50ms.
     if med >= 0.05:
         print(f"CTRL_OP_SLOW rank={rank} median {med * 1e3:.2f}ms exceeds "
               "the 50ms advisory bound (loaded host?)", flush=True)

@@ -1,0 +1,46 @@
+"""chip_smoke.py's phases at a tiny size on the CPU — and the first test of
+the Pallas row kernel INSIDE the table path: on a one-device mesh with the
+scatter gate forced open, ``MatrixServer.add`` (duplicate ids), the device
+Add and one fused ``PSTrainer`` transaction run through the interpreted
+kernel and must match numpy. Tracing the interpreted kernel (a Python-unrolled
+loop of 4 x ROW_GROUP DMA ops) costs seconds per shape at the production group
+of 64, so the test runs it at a group of 8 and all three phases share one table
+shape and one id bucket. The row kernels' jits are cached by shape, not by
+group: 61 x 128 tables appear in no other test."""
+
+import pytest
+
+import multiverso_tpu as mv
+from multiverso_tpu import log
+from multiverso_tpu.ops import pallas_rows
+from multiverso_tpu.parallel import mesh as mesh_lib
+from multiverso_tpu.tables import matrix_table
+
+import chip_smoke
+
+
+def test_smoke_phases_through_the_interpreted_kernel(monkeypatch):
+    monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                        lambda platform, num_shards: num_shards == 1)
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    mv.init(mesh_shape="1", **chip_smoke._INIT_FLAGS)
+    assert mv.num_servers() == 1  # the first of the 8 virtual devices
+    interpreted = (True, True)
+
+    table, checks = chip_smoke.phase_kernels(60, 50, 48, interpreted)
+    assert checks["pallas_scatter"] and checks["interpret"] is True
+    assert "bare_kernels" in checks and checks["padded_cols"] == 128
+
+    trainer, w_in, checks = chip_smoke.phase_trainer(
+        60, 16, 64, 64, 2, 2, interpreted)
+    assert checks["untouched_rows_bit_equal"]
+    assert trainer.input_table._server_table._pallas_interpret is True
+
+    report = chip_smoke.phase_server(trainer.input_table, w_in, 32, 5, 4)
+    assert report["backends_initialized"] is False
+    assert report["query_ids_equal_numpy"]
+
+
+def test_mesh_shape_above_the_device_count_is_fatal():
+    with pytest.raises(log.FatalError, match="needs 16 devices, have 8"):
+        mesh_lib.build_mesh(shape=(16,))

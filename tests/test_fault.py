@@ -418,8 +418,10 @@ def test_server_killed_client_surfaces_clean_error():
     child = subprocess.Popen([sys.executable, child_script],
                              stdout=subprocess.PIPE, text=True, env=env)
     try:
-        line = child.stdout.readline().strip()
-        assert line.startswith("serving "), line
+        line = child.stdout.readline()
+        while line and not line.startswith("serving "):  # skip log lines
+            line = child.stdout.readline()
+        assert line, "server child died during startup"
         _, endpoint, table_id = line.split()
 
         mv.set_flag("reconnect_deadline_seconds", 2.0)

@@ -1,16 +1,25 @@
 """Pallas row-kernel tests (interpret mode on the CPU mesh).
 
-The TPU-compiled path is exercised by bench.py on hardware; these verify
-kernel semantics and the caller contracts (group-multiple batches, sentinel
-padding, unique live ids)."""
+The TPU-compiled path is exercised by chip_smoke.py on hardware; these
+verify kernel semantics and the caller contracts (group-multiple batches,
+sentinel padding, unique live ids). The kernel inside the table path is
+covered by tests/test_chip_smoke.py. The one-group tests share one table
+shape: tracing the interpreted kernel costs seconds per new shape."""
+
+import functools
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from multiverso_tpu.ops.pallas_rows import (ROW_GROUP, gather_rows,
-                                            scatter_add_rows)
+from multiverso_tpu.ops import pallas_rows
+from multiverso_tpu.ops.pallas_rows import ROW_GROUP
+
+gather_rows = functools.partial(pallas_rows.gather_rows, interpret=True)
+scatter_add_rows = functools.partial(pallas_rows.scatter_add_rows,
+                                     interpret=True)
+ROWS = 1024
 
 
 @pytest.fixture
@@ -19,8 +28,8 @@ def rng():
 
 
 def test_gather_matches_take(rng):
-    table = jnp.asarray(rng.normal(size=(512, 128)).astype(np.float32))
-    ids = jnp.asarray(rng.choice(512, ROW_GROUP, replace=False).astype(np.int32))
+    table = jnp.asarray(rng.normal(size=(ROWS, 128)).astype(np.float32))
+    ids = jnp.asarray(rng.choice(ROWS, ROW_GROUP, replace=False).astype(np.int32))
     out = gather_rows(table, ids)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(table)[np.asarray(ids)])
@@ -28,7 +37,7 @@ def test_gather_matches_take(rng):
 
 def test_gather_repeated_ids_allowed(rng):
     # reads may repeat rows freely
-    table = jnp.asarray(rng.normal(size=(64, 128)).astype(np.float32))
+    table = jnp.asarray(rng.normal(size=(ROWS, 128)).astype(np.float32))
     ids = jnp.asarray(np.array([3] * ROW_GROUP, np.int32))
     out = gather_rows(table, ids)
     np.testing.assert_allclose(np.asarray(out),
@@ -36,8 +45,8 @@ def test_gather_repeated_ids_allowed(rng):
 
 
 def test_scatter_add_unique_ids(rng):
-    table = jnp.asarray(rng.normal(size=(256, 128)).astype(np.float32))
-    ids = rng.choice(256, ROW_GROUP, replace=False).astype(np.int32)
+    table = jnp.asarray(rng.normal(size=(ROWS, 128)).astype(np.float32))
+    ids = rng.choice(ROWS, ROW_GROUP, replace=False).astype(np.int32)
     deltas = rng.normal(size=(ROW_GROUP, 128)).astype(np.float32)
     expect = np.asarray(table).copy()
     expect[ids] += deltas
@@ -49,7 +58,7 @@ def test_scatter_add_sentinel_padding(rng):
     """Pad slots aim at a sentinel row with zero deltas: live rows update,
     sentinel row is untouched (zero delta), matching the matrix-table
     bucket contract."""
-    rows, sentinel = 128, 100
+    rows, sentinel = ROWS, 100
     table = jnp.zeros((rows, 128), jnp.float32)
     live = np.array([5, 17], np.int32)
     ids = np.full(ROW_GROUP, sentinel, np.int32)
@@ -67,8 +76,8 @@ def test_scatter_add_sentinel_padding(rng):
 
 def test_multiple_groups(rng):
     batch = ROW_GROUP * 4
-    table = jnp.asarray(rng.normal(size=(1024, 128)).astype(np.float32))
-    ids = rng.choice(1024, batch, replace=False).astype(np.int32)
+    table = jnp.asarray(rng.normal(size=(ROWS, 128)).astype(np.float32))
+    ids = rng.choice(ROWS, batch, replace=False).astype(np.int32)
     deltas = rng.normal(size=(batch, 128)).astype(np.float32)
     expect = np.asarray(table).copy()
     expect[ids] += deltas
@@ -76,6 +85,13 @@ def test_multiple_groups(rng):
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-6)
     got = gather_rows(jnp.asarray(expect), jnp.asarray(ids))
     np.testing.assert_allclose(np.asarray(got), expect[ids], rtol=1e-6)
+
+
+def test_interpret_follows_the_tables_platform():
+    assert pallas_rows.interpret_for("cpu") is True
+    assert pallas_rows.interpret_for("tpu") is False
+    with pytest.raises(ValueError, match="gpu"):
+        pallas_rows.interpret_for("gpu")
 
 
 def test_pallas_scatter_gate_predicate():
@@ -98,33 +114,7 @@ def test_matrix_server_multi_shard_add_correct(mv_env):
     assert Zoo.instance().num_servers > 1  # the 8-device virtual mesh
     table = mv.create_table("matrix", 64, 16, np.float32)
     assert not table._server_table._pallas_scatter
+    assert table._server_table._pallas_interpret is None
     ids = np.array([1, 9, 42], np.int32)
     table.add(np.full((3, 16), 2.0, np.float32), row_ids=ids)
     np.testing.assert_allclose(table.get(ids), np.full((3, 16), 2.0))
-
-def test_coalesced_scatter_matches_simple(rng):
-    """The MVTPU_COALESCE variant (recorded as a measured LOSS in the
-    optimization record — kept as the reproduction artifact) must stay
-    numerically identical to the simple kernel."""
-    from multiverso_tpu.ops.pallas_rows import (ROW_GROUP, _scatter_add_call,
-                                                _scatter_add_coalesced_call,
-                                                _seg_flags)
-
-    rows, cols = 4096, 128
-    batch = 2 * ROW_GROUP
-    table = rng.normal(size=(rows, cols)).astype(np.float32)
-    # contiguous head (coalescible) + scattered tail + sentinel pads
-    live = np.unique(np.concatenate(
-        [np.arange(40), rng.choice(np.arange(64, rows - 1), 60,
-                                   replace=False)]))
-    pads = np.full(batch - len(live), rows - 1, np.int32)
-    ids = np.concatenate([np.sort(live).astype(np.int32), pads])
-    deltas = rng.normal(size=(batch, cols)).astype(np.float32)
-    deltas[len(live):] = 0.0
-    assert int(np.asarray(_seg_flags(jnp.asarray(ids))).sum()) > 0
-
-    simple = np.asarray(_scatter_add_call(
-        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas), True))
-    coal = np.asarray(_scatter_add_coalesced_call(
-        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas), True))
-    np.testing.assert_allclose(coal, simple, rtol=1e-6)
