@@ -14,16 +14,21 @@ records its duration distribution, not just the average), and point-in-time
 ``Gauge``\\ s. ``snapshot()`` serializes the whole registry for the stats
 RPC / metrics JSONL; ``render(format="prom")`` emits Prometheus text
 exposition. Metric catalog: ``docs/observability.md``.
+
+The op trace: while ``Dashboard.profile_annotations`` is on, every
+``monitor``/``span`` section and every ``obs.trace.hop`` also appends one
+:class:`OpRecord` to ``RING``, a fixed in-memory ring on the
+``time.perf_counter_ns`` clock; ``RING.window(t0, t1)`` cuts it to a
+stretch of that clock (``docs/observability.md`` §2.1).
 """
 
 from __future__ import annotations
 
+import itertools
 import re
 import threading
 import time
-from typing import Dict, Iterator, Optional
-
-from contextlib import contextmanager
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 try:  # profiler annotations are optional — pure-host use works without jax
     from jax.profiler import TraceAnnotation as _TraceAnnotation
@@ -432,29 +437,191 @@ class Dashboard:
                     + list(cls._gauges.values()))
         for obj in objs:
             obj.reset()
+        RING.reset()
 
 
-@contextmanager
-def monitor(name: str) -> Iterator[Monitor]:
+class OpRecord(NamedTuple):
+    """One stage of one op's passage through the program. ``seq`` is the
+    append order; ``id`` names a span (0 for a point) and ``parent`` the
+    span that caused this one (0 = none); times are
+    ``time.perf_counter_ns`` (one clock for every process of a Linux
+    host); ``cpu_ns`` is the thread's own CPU time over the span
+    (``time.thread_time_ns``: a first touch burns it, a wait does not)
+    for the sections that ask for it, else 0; ``op`` is the request's
+    ``req_id``, else its ``msg_id``; ``n`` counts rows, bytes or fused
+    messages, by stage."""
+
+    seq: int
+    id: int
+    parent: int
+    stage: str
+    start_ns: int
+    dur_ns: int
+    cpu_ns: int
+    op: int
+    n: int
+
+
+class OpRing:
+    """Fixed ring of op records, written only while
+    ``Dashboard.profile_annotations`` is on. Preallocated; an append
+    takes no lock (``next`` on a C counter is one step under the GIL and
+    each record lands in its own slot); the oldest record is overwritten
+    and ``window`` says when that cost it part of what was asked for."""
+
+    def __init__(self, size: int = 1 << 17) -> None:
+        if size < 1 or size & (size - 1):
+            raise ValueError("OpRing: size must be a power of two")
+        self._mask = size - 1
+        self.reset()
+
+    def reset(self) -> None:
+        self._slots: List[Optional[tuple]] = [None] * (self._mask + 1)
+        self._seq = itertools.count()
+
+    def append(self, span_id: int, parent: int, stage: str, start_ns: int,
+               dur_ns: int, cpu_ns: int, op: int, n: int) -> None:
+        seq = next(self._seq)
+        self._slots[seq & self._mask] = (seq, span_id, parent, stage,
+                                         start_ns, dur_ns, cpu_ns, op, n)
+
+    def point(self, stage: str, op: int) -> None:
+        """A point of an op's passage (``hop``), caused by the span the
+        calling thread is in."""
+        self.append(0, getattr(_op_tls, "span", 0), stage,
+                    time.perf_counter_ns(), 0, 0, op, 0)
+
+    def _kept(self) -> List[tuple]:
+        return sorted(r for r in list(self._slots) if r is not None)
+
+    @property
+    def overwritten(self) -> int:
+        """Records lost to the ring's size since the last reset."""
+        kept = self._kept()
+        return kept[-1][0] + 1 - len(kept) if kept else 0
+
+    def window(self, t0: float, t1: float) -> Tuple[List[OpRecord], bool]:
+        """The records that lie wholly between two ``time.perf_counter``
+        instants (seconds), in append order, and whether the ring
+        overwrote a record that may have lain there: records are
+        appended as their spans end, so everything lost ended no later
+        than the oldest record kept."""
+        lo, hi = int(t0 * 1e9), int(t1 * 1e9)
+        kept = self._kept()
+        lost = bool(kept) and kept[0][0] > 0 \
+            and kept[0][4] + kept[0][5] > lo
+        return ([OpRecord._make(r) for r in kept
+                 if r[4] >= lo and r[4] + r[5] <= hi], lost)
+
+
+RING = OpRing()
+_span_ids = itertools.count(1)
+_op_tls = threading.local()  # .span / .op: what the thread is inside
+
+
+class _Section:
+    """One timed same-thread section. Always: its duration goes to the
+    ``feeds`` it was given (``monitor``: the Monitor and Histogram of its
+    name). While ``Dashboard.profile_annotations`` is on it is also a
+    ``TraceAnnotation`` of its name and one span record in ``RING``,
+    child of the section the thread was in; sections and hops inside it
+    inherit its ``op`` unless they name their own. ``n`` may be set
+    until the section ends; ``id`` is 0 while the switch is off.
+
+    ``cpu`` asks for the thread's CPU time too. Only the few sections
+    whose question it answers take it (is a busy dispatcher computing or
+    waiting; is a buffer's fill a first touch or a wait): under gVisor,
+    where the chip's host runs, the thread CPU clock is a 5.8 us call
+    into the sandbox's kernel and ticks in 10 ms, so it is a sampling
+    estimate that means something over sums of a second or more."""
+
+    __slots__ = ("_name", "_feeds", "_op", "n", "id", "start_ns", "dur_ns",
+                 "_parent", "_outer_op", "_cpu", "_cpu0", "_ann")
+
+    def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
+                 cpu: bool) -> None:
+        self._name, self._feeds, self._op, self.n = name, feeds, op, n
+        self._cpu = cpu
+        self.id = 0
+
+    def __enter__(self) -> "_Section":
+        if Dashboard.profile_annotations:
+            tls = _op_tls
+            self._parent = getattr(tls, "span", 0)
+            self._outer_op = getattr(tls, "op", 0)
+            self.id = tls.span = next(_span_ids)
+            if self._op:
+                tls.op = self._op
+            else:
+                self._op = self._outer_op
+            self._ann = None
+            if _TraceAnnotation is not None:
+                self._ann = _TraceAnnotation(self._name)
+                self._ann.__enter__()
+            if self._cpu:
+                self._cpu0 = time.thread_time_ns()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur_ns = time.perf_counter_ns() - self.start_ns
+        if self.id:
+            cpu = time.thread_time_ns() - self._cpu0 if self._cpu else 0
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            _op_tls.span, _op_tls.op = self._parent, self._outer_op
+            RING.append(self.id, self._parent, self._name, self.start_ns,
+                        self.dur_ns, cpu, self._op, self.n)
+        if self._feeds is not None:
+            seconds = self.dur_ns * 1e-9
+            for unit in self._feeds:
+                unit.observe(seconds)
+        return False
+
+
+class _Off:
+    """What ``span`` hands out while the switch is off: nothing is timed
+    and ``n`` goes nowhere."""
+
+    __slots__ = ("n",)
+    id = 0
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_OFF = _Off()
+_monitor_feeds: Dict[str, tuple] = {}
+
+
+def monitor(name: str, op: int = 0, n: int = 0,
+            cpu: bool = False) -> _Section:
     """``MONITOR_BEGIN(name) ... MONITOR_END(name)`` as a context manager.
     The duration feeds BOTH the monitor (count/total/average) and the
     same-named histogram (p50/p95/p99) — every timed section gets a
-    distribution for free. Timing is a local on the caller's stack, so
-    overlapping scopes on any thread mix cannot corrupt each other."""
-    mon = Dashboard.get(name)
-    t0 = time.perf_counter()
-    ann = None
-    if Dashboard.profile_annotations and _TraceAnnotation is not None:
-        ann = _TraceAnnotation(name)
-        ann.__enter__()
-    try:
-        yield mon
-    finally:
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        dt = time.perf_counter() - t0
-        mon.observe(dt)
-        Dashboard.histogram(name).observe(dt)
+    distribution for free — and, while profiling is on, the op trace
+    (see :class:`_Section`). The two are resolved once per name: the
+    registry's lock stays off the hot path (``Dashboard.reset`` zeroes
+    objects in place, so the references stay live)."""
+    feeds = _monitor_feeds.get(name)
+    if feeds is None:
+        feeds = _monitor_feeds[name] = (Dashboard.get(name),
+                                        Dashboard.histogram(name))
+    return _Section(name, feeds, op, n, cpu)
+
+
+def span(name: str, op: int = 0, n: int = 0,
+         feeds: Optional[tuple] = None, cpu: bool = False):
+    """A section that exists only in the op trace: one predicate while
+    ``Dashboard.profile_annotations`` is off. ``feeds`` (objects with
+    ``observe(seconds)``) makes its duration an always-on series under
+    another name."""
+    if feeds is None and not Dashboard.profile_annotations:
+        return _OFF
+    return _Section(name, feeds, op, n, cpu)
 
 
 def count(name: str, n: int = 1) -> None:

@@ -28,6 +28,8 @@ import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
+from multiverso_tpu.dashboard import RING, Dashboard
+
 MAX_HOPS_PER_TRACE = 64
 
 # The tenant every untagged span (and unclaimed table) folds into — the
@@ -37,14 +39,12 @@ MAX_HOPS_PER_TRACE = 64
 DEFAULT_TENANT = "_default"
 
 # Loss counters at the store's bounds, cached Counter objects so the hot
-# path stays one dict hit (Dashboard import is deferred: dashboard.py
-# imports config which must not cycle back through obs at import time).
+# path stays one dict hit.
 _loss_counters: List[Any] = []
 
 
 def _bound_counters():
     if not _loss_counters:
-        from multiverso_tpu.dashboard import Dashboard
         _loss_counters.append(Dashboard.counter("TRACE_EVICTED"))
         _loss_counters.append(Dashboard.counter("TRACE_DROPPED_HOPS"))
     return _loss_counters
@@ -149,8 +149,14 @@ TRACES = TraceStore()
 
 
 def hop(req_id: int, stage: str) -> None:
-    """Append one hop to ``req_id``'s trace (no-op for req_id 0)."""
+    """Append one hop to ``req_id``'s trace (no-op for req_id 0); while
+    ``Dashboard.profile_annotations`` is on, also a point record in the
+    op trace's ring, on the ``perf_counter_ns`` clock."""
+    if not req_id:
+        return
     TRACES.hop(req_id, stage)
+    if Dashboard.profile_annotations:
+        RING.point(stage, req_id)
 
 
 def tag_tenant(req_id: int, tenant: str) -> None:

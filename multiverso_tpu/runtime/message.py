@@ -220,6 +220,10 @@ class Message:
     # onto derived requests. 0.0 ("legacy peer / no deadline") is never
     # refused. Replies don't carry it — by reply time the wait is over.
     deadline: float = 0.0
+    # time.perf_counter_ns() at Server.send: the dispatcher measures the
+    # queue wait from it (SERVER_QUEUE_WAIT_SECONDS). Local to the process,
+    # never on the wire; 0 = not queued, or its wait already observed.
+    enq_ns: int = 0
     data: List[Any] = field(default_factory=list)
 
     def create_reply(self) -> "Message":

@@ -37,7 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from multiverso_tpu import config, log
-from multiverso_tpu.dashboard import count, gauge_add, observe
+from multiverso_tpu.dashboard import count, gauge_add, observe, span
 from multiverso_tpu.obs.profiler import clear_wait, mark_wait
 from multiverso_tpu.obs.trace import flight_dump, hop
 from multiverso_tpu.runtime.message import Message, MsgType
@@ -140,6 +140,20 @@ def _send_metrics():
                                Dashboard.histogram("WIRE_FRAMES_PER_SYSCALL"),
                                Dashboard.gauge("SEND_QUEUE_BYTES"))
     return _send_metrics_cache
+
+
+_frame_decode_feeds = None
+
+
+def _decode_feeds():
+    """The operator's FRAME_DECODE_SECONDS series, fed by the
+    NET_FRAME_COPY section; resolved once like the send-path objects."""
+    global _frame_decode_feeds
+    if _frame_decode_feeds is None:
+        from multiverso_tpu.dashboard import Dashboard
+        _frame_decode_feeds = (
+            Dashboard.histogram("FRAME_DECODE_SECONDS"),)
+    return _frame_decode_feeds
 
 
 class _SendState:
@@ -743,11 +757,16 @@ class TcpNet:
                       version, _VERSION)
             raise _WireDesync("wire version mismatch")
         srcs_seen.add(src)
+        op = req_id or msg_id
         # the header's payload_len keeps the stream in sync even when the
         # payload is garbage: read it all, checksum, and only then parse
-        # blob structure out of it
-        payload = read(payload_len) if payload_len else b""
-        if zlib.crc32(payload) != crc:
+        # blob structure out of it. The spans start at the header's
+        # arrival: the wait for a header is the peer's time, not ours
+        with span("NET_FRAME_READ", op=op, n=payload_len, cpu=True):
+            payload = read(payload_len) if payload_len else b""
+        with span("NET_FRAME_CRC", op=op, n=payload_len):
+            intact = zlib.crc32(payload) == crc
+        if not intact:
             count("FRAME_CRC_REJECTS")
             log.error("net: CRC mismatch on %s frame from %d — "
                       "frame discarded (retransmit recovers it)",
@@ -756,20 +775,20 @@ class TcpNet:
             flight_dump("frame_crc_reject", src=src,
                         msg_type=int(mtype), req_id=req_id)
             return None
-        t0 = time.perf_counter()
         off = 0
         blobs = []
-        for _ in range(nblobs):
-            ndim, dt, nbytes = _BLOB.unpack_from(payload, off)
-            off += _BLOB.size
-            shape = struct.unpack_from(f"<{ndim}q", payload, off)
-            off += 8 * ndim
-            dtype = np.dtype(dt.decode().strip())
-            blobs.append(np.frombuffer(
-                payload, dtype=dtype, count=nbytes // dtype.itemsize,
-                offset=off).reshape(shape).copy())
-            off += nbytes
-        observe("FRAME_DECODE_SECONDS", time.perf_counter() - t0)
+        with span("NET_FRAME_COPY", op=op, n=payload_len,
+                  feeds=_decode_feeds(), cpu=True):
+            for _ in range(nblobs):
+                ndim, dt, nbytes = _BLOB.unpack_from(payload, off)
+                off += _BLOB.size
+                shape = struct.unpack_from(f"<{ndim}q", payload, off)
+                off += 8 * ndim
+                dtype = np.dtype(dt.decode().strip())
+                blobs.append(np.frombuffer(
+                    payload, dtype=dtype, count=nbytes // dtype.itemsize,
+                    offset=off).reshape(shape).copy())
+                off += nbytes
         hop(req_id, "net_recv")
         msg = Message(src=src, dst=dst, type=MsgType(mtype),
                       table_id=table_id, msg_id=msg_id,

@@ -50,7 +50,8 @@ import numpy as np
 
 from multiverso_tpu import config, log
 from multiverso_tpu import io as mv_io
-from multiverso_tpu.dashboard import Dashboard, count, gauge_set, observe
+from multiverso_tpu.dashboard import (Dashboard, count, gauge_set, observe,
+                                      span)
 from multiverso_tpu.fault.detector import LivenessDetector
 from multiverso_tpu.fault.inject import make_net
 from multiverso_tpu.fault.retry import (CircuitBreaker, RetryBudget,
@@ -112,18 +113,21 @@ class _NetCompletion:
 
     def _reply(self, msg_type: MsgType, payload: Any) -> None:
         t = self._template
-        msg = Message(src=t.dst, dst=t.src, type=msg_type,
-                      table_id=t.table_id, msg_id=t.msg_id, req_id=t.req_id,
-                      watermark=self._server.append_watermark(),
-                      data=wire.encode(payload, compress=self._compress))
-        self._server._dedup_store(t.req_id, msg)
-        hop(t.req_id, "reply_sent")
-        try:
-            self._server._net.send_via(self._conn, msg)
-        except OSError as exc:
-            log.error("remote: reply to worker %d failed: %r (the client "
-                      "recovers it via retransmit + the dedup cache)",
-                      t.src, exc)
+        with span("WIRE_REPLY", op=t.req_id or t.msg_id):
+            msg = Message(src=t.dst, dst=t.src, type=msg_type,
+                          table_id=t.table_id, msg_id=t.msg_id,
+                          req_id=t.req_id,
+                          watermark=self._server.append_watermark(),
+                          data=wire.encode(payload, compress=self._compress))
+            self._server._dedup_store(t.req_id, msg)
+            hop(t.req_id, "reply_sent")
+            try:
+                with span("NET_SEND") as sent:
+                    sent.n = self._server._net.send_via(self._conn, msg)
+            except OSError as exc:
+                log.error("remote: reply to worker %d failed: %r (the "
+                          "client recovers it via retransmit + the dedup "
+                          "cache)", t.src, exc)
 
     def done(self, result: Any) -> None:
         reply_type = (MsgType.Reply_Get
@@ -519,7 +523,8 @@ class RemoteServer:
             if msg is None:
                 return
             try:
-                self._handle(msg, compress)
+                with span("SERVE_HANDLE", op=msg.req_id or msg.msg_id):
+                    self._handle(msg, compress)
             except Exception as exc:  # noqa: BLE001 — keep serving
                 log.error("remote server: error on %s: %r", msg.type, exc)
                 _NetCompletion(self, msg._conn, msg, False).fail(exc)

@@ -25,7 +25,8 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from multiverso_tpu import config, log
-from multiverso_tpu.dashboard import count, gauge_set, monitor, observe
+from multiverso_tpu.dashboard import (RING, Dashboard, count, gauge_set,
+                                      monitor, observe, span)
 from multiverso_tpu.obs.profiler import clear_wait, mark_wait
 from multiverso_tpu.obs.trace import flight_dump, hop
 from multiverso_tpu.runtime.admission import (AdmissionGate, DeadlineExceeded,
@@ -45,7 +46,6 @@ def _apply_metrics():
     1µs latency default whose top edge it would overflow."""
     global _apply_metrics_cache
     if _apply_metrics_cache is None:
-        from multiverso_tpu.dashboard import Dashboard
         from multiverso_tpu.obs.metrics import log_bounds
         _apply_metrics_cache = (
             Dashboard.counter("APPLY_FUSED_CALLS"),
@@ -53,6 +53,7 @@ def _apply_metrics():
             Dashboard.histogram("APPLY_BATCH_ROWS",
                                 bounds=log_bounds(lowest=1.0)),
             Dashboard.gauge("SERVER_QUEUE_DEPTH"),
+            Dashboard.histogram("SERVER_QUEUE_WAIT_SECONDS"),
         )
     return _apply_metrics_cache
 
@@ -257,6 +258,7 @@ class Server:
 
     # -- client side -------------------------------------------------------
     def send(self, msg: Message) -> None:
+        msg.enq_ns = time.perf_counter_ns()
         self._queue.push(msg)
 
     # -- dispatcher --------------------------------------------------------
@@ -270,23 +272,26 @@ class Server:
             # the drain is "no work", everything after is dispatch cost
             _prev_wait = mark_wait("dispatcher_drain")
             try:
-                msgs = self._queue.pop_all()
+                with span("DISPATCHER_PARKED"):
+                    msgs = self._queue.pop_all()
             finally:
                 clear_wait(_prev_wait)
             if msgs is None:
                 return
-            # depth AFTER the drain = requests that arrived behind this
-            # wakeup's batch; sampled once per drain, not once per message
-            # (per-message sampling was pure hot-loop overhead)
-            queue_gauge.set(self._queue.size())
-            if self._lane_sort and len(msgs) > 1:
-                msgs = lane_order(msgs)
-            msgs = self._admit(msgs)
-            if fuse and len(msgs) > 1:
-                self._dispatch_batch(msgs)
-            else:
-                for msg in msgs:
-                    self._dispatch_guarded(msg)
+            with span("DISPATCHER_DRAIN", n=len(msgs), cpu=True):
+                # depth AFTER the drain = requests that arrived behind
+                # this wakeup's batch; sampled once per drain, not once
+                # per message (per-message sampling was pure hot-loop
+                # overhead)
+                queue_gauge.set(self._queue.size())
+                if self._lane_sort and len(msgs) > 1:
+                    msgs = lane_order(msgs)
+                msgs = self._admit(msgs)
+                if fuse and len(msgs) > 1:
+                    self._dispatch_batch(msgs)
+                else:
+                    for msg in msgs:
+                        self._dispatch_guarded(msg)
 
     def _admit(self, msgs: List[Message]) -> List[Message]:
         """Drain-time overload filter: drop expired-deadline work (its
@@ -318,9 +323,28 @@ class Server:
             admitted.append(msg)
         return admitted
 
+    @staticmethod
+    def _queue_waited(msg: Message, until_ns: int = 0) -> None:
+        """A Get's or Add's wait for the dispatcher, from ``send`` to the
+        moment its service begins (``until_ns``, else now), so the time
+        behind earlier messages of its own drain counts. Observed once:
+        a message a clock gate re-dispatches later waited at the gate
+        (SYNC_GATE_WAIT_SECONDS), not in the queue."""
+        enq_ns = msg.enq_ns
+        if not enq_ns or msg.type not in (MsgType.Request_Get,
+                                          MsgType.Request_Add):
+            return
+        msg.enq_ns = 0
+        waited = (until_ns or time.perf_counter_ns()) - enq_ns
+        _apply_metrics()[4].observe(waited * 1e-9)
+        if Dashboard.profile_annotations:
+            RING.append(0, 0, "SERVER_QUEUE_WAIT", enq_ns, waited, 0,
+                        msg.req_id or msg.msg_id, 0)
+
     def _dispatch_guarded(self, msg: Message) -> None:
+        self._queue_waited(msg)
         try:
-            with monitor("SERVER_DISPATCH_MSG"):
+            with monitor("SERVER_DISPATCH_MSG", op=msg.req_id or msg.msg_id):
                 self._dispatch(msg)
         except Exception as exc:  # keep the dispatcher alive; fail the waiter
             log.error("server dispatcher error on %s: %r", msg.type, exc)
@@ -384,12 +408,15 @@ class Server:
         if len(msgs) == 1:
             self._dispatch_guarded(msgs[0])
             return 1
+        began_ns = time.perf_counter_ns()  # the group's service: the merge too
         table = self._tables.get(table_id)
         merged = None
         if table is not None:
             try:
-                merged = table.merge_add_requests(
-                    [m.data[0] for m in msgs])
+                with span("TABLE_MERGE_ADDS", n=len(msgs),
+                          op=msgs[0].req_id or msgs[0].msg_id):
+                    merged = table.merge_add_requests(
+                        [m.data[0] for m in msgs])
             except Exception as exc:  # merge must never sink the batch
                 log.error("server: merge_add_requests failed on table %d "
                           "(%r); applying per message", table_id, exc)
@@ -413,11 +440,14 @@ class Server:
         # recovery replays the records individually, which sums to the
         # same state for the commutative Adds that merged at all
         for msg in msgs:
+            self._queue_waited(msg, began_ns)
             self._wal_append(msg)
             hop(msg.req_id, "apply_add")
-        fused_c, batched_c, rows_h, _g = _apply_metrics()
+        fused_c, batched_c, rows_h = _apply_metrics()[:3]
         try:
-            with monitor("SERVER_PROCESS_ADD_MSG"):
+            with monitor("SERVER_PROCESS_ADD_MSG",
+                         op=msgs[0].req_id or msgs[0].msg_id,
+                         n=len(msgs)) as fused:
                 self._apply_fused(table, request)
         except Exception as exc:
             # merge validated shapes, so this is rare; the contract that
@@ -436,6 +466,13 @@ class Server:
         fused_c.add(1)
         batched_c.add(len(msgs))
         rows_h.observe(rows)
+        if fused.id:
+            # each request the group answers carries the group's service
+            # span: its service time is the group's
+            for msg in msgs:
+                RING.append(0, fused.id, "APPLY_FUSED_ADD", fused.start_ns,
+                            fused.dur_ns, 0, msg.req_id or msg.msg_id,
+                            len(msgs))
         for msg in msgs:
             msg.data[-1].done(None)
         return consumed
@@ -466,7 +503,7 @@ class Server:
 
     @dispatcher_only
     def _process_add(self, msg: Message) -> None:
-        with monitor("SERVER_PROCESS_ADD_MSG"):
+        with monitor("SERVER_PROCESS_ADD_MSG", n=1):
             request, completion = msg.data
             self._wal_append(msg)
             hop(msg.req_id, "apply_add")

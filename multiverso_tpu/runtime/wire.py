@@ -36,8 +36,11 @@ def encode(obj: Any, compress: bool = False) -> List[np.ndarray]:
     """Structure -> [json-tree blob, ndarray blobs...]. Timed under the
     WIRE_ENCODE monitor (the reference instrumented exactly its serialize
     path, mpi_net.h:292)."""
-    with monitor("WIRE_ENCODE"):
-        return _encode(obj, compress)
+    with monitor("WIRE_ENCODE", cpu=True) as encoded:
+        blobs = _encode(obj, compress)
+        if encoded.id:
+            encoded.n = sum(b.nbytes for b in blobs)
+        return blobs
 
 
 def _encode(obj: Any, compress: bool) -> List[np.ndarray]:

@@ -17,28 +17,35 @@ callers written against the reference's API port 1:1.
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from typing import Any, Dict, Optional
 
 from multiverso_tpu import log
-from multiverso_tpu.dashboard import monitor
+from multiverso_tpu.dashboard import Dashboard, monitor, span
 from multiverso_tpu.runtime.message import Message, MsgType, next_msg_id
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.utils import Waiter
 
 
 class Completion:
-    """One outstanding request: a waiter plus its result slot."""
+    """One outstanding request: a waiter plus its result slot.
+    ``done_ns`` is ``time.perf_counter_ns()`` at ``done`` while the op
+    trace is on (else 0): ``WorkerTable.wait`` reads its wake latency
+    from it."""
 
-    __slots__ = ("_waiter", "result", "error")
+    __slots__ = ("_waiter", "result", "error", "done_ns")
 
     def __init__(self) -> None:
         self._waiter = Waiter(1)
         self.result: Any = None
         self.error: Optional[BaseException] = None
+        self.done_ns = 0
 
     def done(self, result: Any) -> None:
         self.result = result
+        if Dashboard.profile_annotations:
+            self.done_ns = time.perf_counter_ns()
         self._waiter.notify()
 
     def fail(self, error: BaseException) -> None:
@@ -117,7 +124,12 @@ class WorkerTable:
             request = self._pending_request.pop(msg_id, None)
         if completion is None:
             log.fatal("wait: unknown msg_id %d on table %d", msg_id, self.table_id)
-        raw = completion.wait()
+        with span("WORKER_WAIT", op=msg_id) as waited:
+            raw = completion.wait()
+            if waited.id and completion.done_ns > waited.start_ns:
+                # n: how long after done() the thread that slept here ran
+                # again (0 where the result was there before the wait)
+                waited.n = time.perf_counter_ns() - completion.done_ns
         if raw is None:
             return None
         return self.process_reply_get(raw, request)
@@ -253,7 +265,10 @@ class ServerTable:
                     out_shardings=NamedSharding(self.mesh,
                                                 PartitionSpec()))
             arr = self._replicate(arr)
-        return np.asarray(jax.device_get(arr))
+        with span("TABLE_HOST_READ", cpu=True) as read:
+            out = np.asarray(jax.device_get(arr))
+            read.n = out.nbytes
+        return out
 
     def merge_add_requests(self, requests):
         """Fuse a PREFIX of a drained group of Add requests into ONE

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu import log
-from multiverso_tpu.dashboard import monitor
+from multiverso_tpu.dashboard import monitor, span
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import ServerTable, WorkerTable
@@ -48,6 +48,12 @@ def _device_pad(values: jax.Array, bucket: int, cols: int) -> jax.Array:
     """(n, c) → (bucket, cols) zero-padded, entirely on device."""
     out = jnp.zeros((bucket, cols), values.dtype)
     return out.at[: values.shape[0], : values.shape[1]].set(values)
+
+
+def _row_gather(data: jax.Array, ids: jax.Array) -> jax.Array:
+    """The table's row Get; named so that its compiled module is
+    ``jit__row_gather`` in a trace."""
+    return data[ids]
 
 
 def _use_pallas_scatter(platform: str, num_shards: int) -> bool:
@@ -125,7 +131,7 @@ class MatrixServer(ServerTable):
         self._whole_update = _make_whole_update(self.updater)
         self._linear = type(self.updater) in (Updater, SGDUpdater)
         self._sign = -1.0 if isinstance(self.updater, SGDUpdater) else 1.0
-        self._gather = jax.jit(lambda data, ids: data[ids])
+        self._gather = jax.jit(_row_gather)
         # device-out gets feed WORKER-thread jits (the word2vec fast
         # path's compact training space): committed to ONE device, the
         # mesh's first, so those jits are single-device programs and every
@@ -269,6 +275,10 @@ class MatrixServer(ServerTable):
                 int(len(ids)), len(ids_list))
 
     def process_add(self, request):
+        with span("TABLE_PROCESS_ADD"):
+            return self._process_add(request)
+
+    def _process_add(self, request):
         if isinstance(request[0], str) and request[0] == "transact":
             return self._process_transact(request)
         if isinstance(request[0], str) and request[0] == "transact_named":
@@ -294,28 +304,30 @@ class MatrixServer(ServerTable):
                 scalars)
             touched: Optional[np.ndarray] = None
         else:
-            row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
-            self._check_row_range(row_ids, "add")
-            values = np.asarray(values, dtype=self.dtype).reshape(-1, self.num_col)
-            if len(row_ids) != len(values):
-                log.fatal("Matrix.add: %d ids but %d value rows", len(row_ids), len(values))
-            # unique ids: required by stateful updaters (one apply per row)
-            # and by the pallas scatter kernel's in-place row DMA contract;
-            # XLA's scatter-add handles duplicates natively, so the linear
-            # non-pallas path skips the host-side aggregation (fused
-            # micro-batches from the dispatcher concatenate without
-            # dedup for exactly this reason)
-            if not (self._linear and not self._pallas_scatter):
-                # lazy import: remote imports this module (worker proxies)
-                from multiverso_tpu.runtime.remote import \
-                    merge_duplicate_rows
-                row_ids, values = merge_duplicate_rows(row_ids, values)
-            ids_p, vals_p, _ = self._bucket_ids(row_ids, values)
-            if self._linear:
-                self.data = self._scatter_add(self.data, ids_p, self._sign * vals_p)
-            else:
-                self.data, self.states = self._row_update(
-                    self.data, self.states, ids_p, vals_p, worker, scalars)
+            with span("TABLE_ROW_PREP") as prep:
+                row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
+                self._check_row_range(row_ids, "add")
+                values = np.asarray(values, dtype=self.dtype).reshape(-1, self.num_col)
+                if len(row_ids) != len(values):
+                    log.fatal("Matrix.add: %d ids but %d value rows", len(row_ids), len(values))
+                # unique ids: required by stateful updaters (one apply per
+                # row) and by the pallas scatter kernel's in-place row DMA
+                # contract; XLA's scatter-add handles duplicates natively,
+                # so the linear non-pallas path skips the host-side
+                # aggregation (fused micro-batches from the dispatcher
+                # concatenate without dedup for exactly this reason)
+                if not (self._linear and not self._pallas_scatter):
+                    # lazy import: remote imports this module (worker proxies)
+                    from multiverso_tpu.runtime.remote import \
+                        merge_duplicate_rows
+                    row_ids, values = merge_duplicate_rows(row_ids, values)
+                ids_p, vals_p, prep.n = self._bucket_ids(row_ids, values)
+            with span("TABLE_ROW_LAUNCH"):
+                if self._linear:
+                    self.data = self._scatter_add(self.data, ids_p, self._sign * vals_p)
+                else:
+                    self.data, self.states = self._row_update(
+                        self.data, self.states, ids_p, vals_p, worker, scalars)
             touched = row_ids
         if self.is_sparse:
             with self._std_lock:
@@ -326,29 +338,32 @@ class MatrixServer(ServerTable):
 
     def _process_add_device(self, row_ids, values, option, worker,
                             scalars) -> None:
-        row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
-        n = len(row_ids)
-        if values.shape[0] != n:
-            log.fatal("Matrix.add(device): %d ids but %d value rows",
-                      n, values.shape[0])
-        from multiverso_tpu.ops.pallas_rows import ROW_GROUP
-        bucket = max(_next_pow2(n), ROW_GROUP)
-        ids_p = async_upload(np.concatenate(
-            [row_ids, np.full(bucket - n, self.sentinel_row, np.int32)]))
-        vals_p = _device_pad(values.astype(self.dtype), bucket,
-                             self.padded_cols)
-        # worker-thread kernels hand deltas back committed to ONE device
-        # (the gather_out contract); re-shard here — on the dispatcher
-        # thread, where cross-shard collectives are legal — or the
-        # scatter jit would reject the mixed device sets
-        vals_p = jax.device_put(
-            vals_p, mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
-        if self._linear:
-            self.data = self._scatter_add(self.data, ids_p,
-                                          self._sign * vals_p)
-        else:
-            self.data, self.states = self._row_update(
-                self.data, self.states, ids_p, vals_p, worker, scalars)
+        with span("TABLE_ROW_PREP") as prep:
+            row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
+            prep.n = n = len(row_ids)
+            if values.shape[0] != n:
+                log.fatal("Matrix.add(device): %d ids but %d value rows",
+                          n, values.shape[0])
+            from multiverso_tpu.ops.pallas_rows import ROW_GROUP
+            bucket = max(_next_pow2(n), ROW_GROUP)
+            ids_p = async_upload(np.concatenate(
+                [row_ids, np.full(bucket - n, self.sentinel_row, np.int32)]))
+        with span("TABLE_ROW_LAUNCH"):
+            vals_p = _device_pad(values.astype(self.dtype), bucket,
+                                 self.padded_cols)
+            # worker-thread kernels hand deltas back committed to ONE
+            # device (the gather_out contract); re-shard here — on the
+            # dispatcher thread, where cross-shard collectives are legal —
+            # or the scatter jit would reject the mixed device sets
+            vals_p = jax.device_put(
+                vals_p,
+                mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
+            if self._linear:
+                self.data = self._scatter_add(self.data, ids_p,
+                                              self._sign * vals_p)
+            else:
+                self.data, self.states = self._row_update(
+                    self.data, self.states, ids_p, vals_p, worker, scalars)
         if self.is_sparse:
             with self._std_lock:
                 live = row_ids[row_ids < self.num_row]
@@ -433,6 +448,10 @@ class MatrixServer(ServerTable):
         return option is not None and 0 <= option.worker_id < self.num_slots
 
     def process_get(self, request):
+        with span("TABLE_PROCESS_GET"):
+            return self._process_get(request)
+
+    def _process_get(self, request):
         device_out = False
         if len(request) == 3:  # in-process device-out form
             row_ids, option, device_out = request
@@ -444,14 +463,18 @@ class MatrixServer(ServerTable):
             # admin whole-table reads take the dense path
             out = self.updater.access(self.data)
             return self._host_read(out)[: self.num_row, : self.num_col]
-        row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
-        if not device_out:
-            # device gets may carry sentinel-aimed pad ids (the compact
-            # training space contract); host/wire gets may not
-            self._check_row_range(row_ids, "get")
-        ids_p, _, n = self._bucket_ids(row_ids, None, ensure_pad=device_out)
-        gathered = (self._gather_out if device_out
-                    else self._gather)(self.data, ids_p)
+        with span("TABLE_ROW_PREP") as prep:
+            row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
+            if not device_out:
+                # device gets may carry sentinel-aimed pad ids (the compact
+                # training space contract); host/wire gets may not
+                self._check_row_range(row_ids, "get")
+            ids_p, _, n = self._bucket_ids(row_ids, None,
+                                           ensure_pad=device_out)
+            prep.n = n
+        with span("TABLE_ROW_LAUNCH"):
+            gathered = (self._gather_out if device_out
+                        else self._gather)(self.data, ids_p)
         if self.is_sparse and self._is_worker(option):
             with self._std_lock:
                 self._up_to_date[option.worker_id, row_ids] = True

@@ -1,0 +1,376 @@
+"""The op trace: the ring that `monitor`, `span` and `hop` write while
+`Dashboard.profile_annotations` is on, the stages an op leaves in it on its
+way through the dispatcher, the table and the wire's server half, and the
+benchmark's readers of it (`benchmark/op_trace.py`, `benchmark/layers/`).
+
+Times here come from a CPU run: they check that spans nest, tile and carry
+the right counts, never how fast anything is."""
+
+import threading
+import time
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+import multiverso_tpu as mv
+from multiverso_tpu import dashboard
+from multiverso_tpu.dashboard import Dashboard, OpRing, monitor, span
+from multiverso_tpu.obs.trace import TRACES, hop
+from multiverso_tpu.runtime.message import Message, MsgType
+from multiverso_tpu.runtime.server import _ExecWaiter
+from multiverso_tpu.runtime.zoo import Zoo
+
+from benchmark import common, op_trace
+
+ROWS, COLS = 256, 128
+IDS = np.arange(32, dtype=np.int32)
+ONES = np.ones((len(IDS), COLS), np.float32)
+
+
+@pytest.fixture
+def tracing():
+    """The one switch, on for the test's body; `mv.init` sets it from the
+    flag of its name."""
+    mv.set_flag("profile_annotations", True)
+    Dashboard.profile_annotations = True
+    yield
+    Dashboard.profile_annotations = False
+
+
+def _table():
+    return mv.create_table("matrix", ROWS, COLS,
+                           init_value=np.zeros((ROWS, COLS), np.float32))
+
+
+def _run(t0, t1):
+    """What a per-layer reader is handed, as far as these readers look."""
+    return SimpleNamespace(window=(t0, t1))
+
+
+def _served_run(t0):
+    """The window from t0 to now, closed behind the dispatcher: a waiter
+    has its result before the dispatcher has left the spans around `done`,
+    and a no-op queued behind them returns after they have ended."""
+    Zoo.instance().server.run_serialized(lambda: None)
+    return _run(t0, time.perf_counter())
+
+
+def _metric(name, run):
+    return common.load_module("layers", name).read(run)
+
+
+def _hold_dispatcher(server):
+    """Block the dispatcher inside a Server_Execute until the returned
+    event is set: everything queued behind it lands in one drain."""
+    gate = threading.Event()
+    server.send(Message(src=-1, dst=-1, type=MsgType.Server_Execute,
+                        data=[lambda: gate.wait(30), _ExecWaiter()]))
+    time.sleep(0.05)  # let the dispatcher enter the gate
+    return gate
+
+
+def _inside(child, parent):
+    return (parent.start_ns <= child.start_ns
+            and child.start_ns + child.dur_ns
+            <= parent.start_ns + parent.dur_ns)
+
+
+def _chain(trace, op, stages):
+    """The op's records of `stages`, each the child of the one before."""
+    found = []
+    for stage in stages:
+        mine = [r for r in trace.spans(stage) if r.op == op]
+        assert len(mine) == 1, (stage, op, mine)
+        if found:
+            assert mine[0].parent == found[-1].id, stage
+            assert _inside(mine[0], found[-1]), stage
+        found.append(mine[0])
+    return found
+
+
+# -- (a) switch off ------------------------------------------------------------
+
+def test_switch_off_appends_nothing_and_monitors_keep_their_shape():
+    mv.init()
+    table = _table()
+    t0 = time.perf_counter()
+    table.add(ONES, row_ids=IDS)
+    table.get(IDS)
+    with span("NEVER_RECORDED", n=3) as off:
+        off.n = 4
+    assert off.id == 0
+    records, overwrote = dashboard.RING.window(t0, time.perf_counter())
+    assert records == [] and not overwrote
+    assert dashboard.RING.overwritten == 0
+    monitors = Dashboard.snapshot()["monitors"]
+    for name in ("SERVER_DISPATCH_MSG", "SERVER_PROCESS_ADD_MSG",
+                 "SERVER_PROCESS_GET_MSG", "WORKER_TABLE_SYNC_ADD",
+                 "WORKER_TABLE_SYNC_GET"):
+        assert set(monitors[name]) == {"count", "elapse_ms", "average_ms"}
+        assert monitors[name]["count"] >= 1
+    # the sections that exist only in the op trace register nothing
+    ring_only = ("TABLE_", "DISPATCHER_", "WORKER_WAIT", "NET_", "SERVE_",
+                 "WIRE_REPLY", "NEVER_")
+    assert not [m for m in monitors if m.startswith(ring_only)]
+    # the always-on addition: one queue wait a Get or Add
+    assert Dashboard.histogram("SERVER_QUEUE_WAIT_SECONDS").count == 2
+    mv.shutdown()
+
+
+def test_monitor_resolves_its_units_once_and_survives_reset():
+    with monitor("RESOLVED_ONCE"):
+        pass
+    feeds = dashboard._monitor_feeds["RESOLVED_ONCE"]
+    assert feeds == (Dashboard.get("RESOLVED_ONCE"),
+                     Dashboard.histogram("RESOLVED_ONCE"))
+    Dashboard.reset()  # zeroes in place: the resolved references stay live
+    with monitor("RESOLVED_ONCE"):
+        pass
+    assert dashboard._monitor_feeds["RESOLVED_ONCE"] is feeds
+    assert Dashboard.get("RESOLVED_ONCE").count == 1
+    assert Dashboard.histogram("RESOLVED_ONCE").count == 1
+
+
+# -- (b) the stages an op leaves, nested -----------------------------------------
+
+def test_in_process_pair_leaves_its_stages_nested(tracing):
+    mv.init()
+    table = _table()
+    table.add(ONES, row_ids=IDS)  # compile outside the window
+    table.get(IDS)
+    t0 = time.perf_counter()
+    add = table.add_async(ONES, row_ids=IDS)
+    table.wait(add)
+    # the Get's result comes only after its waiter has gone to sleep
+    gate = _hold_dispatcher(Zoo.instance().server)
+    get = table.get_async(IDS)
+    threading.Timer(0.05, gate.set).start()
+    table.wait_get(get, IDS)
+    run = _served_run(t0)
+    trace = op_trace.of(run)
+
+    chain = _chain(trace, add, ("SERVER_DISPATCH_MSG",
+                                "SERVER_PROCESS_ADD_MSG",
+                                "TABLE_PROCESS_ADD", "TABLE_ROW_PREP"))
+    assert chain[-1].n == len(IDS)
+    _chain(trace, add, ("TABLE_PROCESS_ADD", "TABLE_ROW_LAUNCH"))
+    chain = _chain(trace, get, ("SERVER_DISPATCH_MSG",
+                                "SERVER_PROCESS_GET_MSG",
+                                "TABLE_PROCESS_GET", "TABLE_HOST_READ"))
+    assert chain[-1].n >= len(IDS) * COLS * 4  # bytes of the padded bucket
+    _chain(trace, get, ("TABLE_PROCESS_GET", "TABLE_ROW_PREP"))
+    _chain(trace, get, ("TABLE_PROCESS_GET", "TABLE_ROW_LAUNCH"))
+    for op in (add, get):
+        wait, = [r for r in trace.spans("SERVER_QUEUE_WAIT") if r.op == op]
+        service, = [r for r in trace.spans("SERVER_DISPATCH_MSG")
+                    if r.op == op]
+        assert wait.start_ns + wait.dur_ns <= service.start_ns
+        drain, = [r for r in trace.spans("DISPATCHER_DRAIN")
+                  if r.id == service.parent]
+        assert drain.n == 1 and _inside(service, drain)
+        waited, = [r for r in trace.spans("WORKER_WAIT") if r.op == op]
+        assert 0 <= waited.n < waited.dur_ns
+    assert waited.n > 0  # the Get's: woken after done, before the span's end
+    assert trace.spans("DISPATCHER_PARKED")
+
+    # the readers of the cell without a wire see what is theirs
+    assert _metric("dispatch_queue_wait_ms", run) > 0
+    assert _metric("completion_wake_ms", run) > 0
+    assert 0 < _metric("dispatcher_busy_share", run) <= 100
+    assert 0 < _metric("dispatcher_cpu_share", run) <= 100
+    ops = trace.spans("TABLE_PROCESS_ADD") + trace.spans("TABLE_PROCESS_GET")
+    assert 0 < _metric("table_op_self_ms", run) \
+        < sum(r.dur_ns for r in ops) / len(ops) / 1e6
+    for wire_metric in ("wire_ingress_ms", "wire_reply_ms",
+                        "server_residence_ms"):
+        assert _metric(wire_metric, run) is None
+    mv.shutdown()
+
+
+def test_served_pair_tiles_its_residence(tracing):
+    mv.init(remote_workers=1)
+    table = _table()
+    client = mv.remote_connect(mv.serve("127.0.0.1:0"))
+    remote = client.table(table.table_id)
+    remote.add(ONES, row_ids=IDS)  # compile outside the window
+    remote.get(IDS)
+    t0 = time.perf_counter()
+    remote.add(ONES, row_ids=IDS)
+    remote.get(IDS)
+    run = _served_run(t0)
+    trace = op_trace.of(run)
+
+    requests = trace.requests()
+    assert len(requests) == 2
+    for q in requests:
+        for part in (q.ingress, q.queue_wait, q.service, q.reply):
+            assert part > 0
+        parts = q.ingress + q.queue_wait + q.service + q.reply
+        assert parts == pytest.approx(q.residence, rel=0.05)
+        _chain(trace, q.op, ("SERVE_HANDLE", "WIRE_DECODE"))
+    add, get = sorted(requests, key=lambda q: q.op)
+    _chain(trace, add.op, ("SERVER_DISPATCH_MSG", "SERVER_PROCESS_ADD_MSG",
+                           "WIRE_REPLY", "NET_SEND"))
+    chain = _chain(trace, get.op, (
+        "SERVER_DISPATCH_MSG", "SERVER_PROCESS_GET_MSG", "WIRE_REPLY",
+        "WIRE_ENCODE"))
+    assert chain[-1].n >= len(IDS) * COLS * 4  # the rows, encoded
+    _chain(trace, get.op, ("SERVER_PROCESS_GET_MSG", "TABLE_PROCESS_GET",
+                           "TABLE_HOST_READ"))
+    for q in requests:  # the receive thread, before the request's arrival
+        arrived = min(r.start_ns for r in trace.spans("net_recv")
+                      if r.op == q.op)
+        for stage in ("NET_FRAME_READ", "NET_FRAME_CRC", "NET_FRAME_COPY"):
+            first = min((r for r in trace.spans(stage) if r.op == q.op),
+                        key=lambda r: r.start_ns)
+            assert first.n > 0 and first.start_ns + first.dur_ns <= arrived
+    # points inherit the span they fell in
+    handle, = [r for r in trace.spans("SERVE_HANDLE") if r.op == get.op]
+    enqueue, = [r for r in trace.spans("dispatch_enqueue")
+                if r.op == get.op]
+    assert enqueue.parent == handle.id
+
+    # the readers of the cell with a wire, and how they close
+    ingress = _metric("wire_ingress_ms", run)
+    residence = _metric("server_residence_ms", run)
+    assert 0 < ingress < residence
+    assert 0 < _metric("wire_reply_ms", run) < residence
+    assert 0 < _metric("table_host_read_ms", run) < residence
+    assert _metric("dispatch_queue_wait_ms", run) < residence
+    client.close()
+    mv.shutdown()
+
+
+# -- (c) a fused apply ---------------------------------------------------------
+
+def test_fused_apply_gives_each_request_the_groups_span(tracing):
+    mv.init()
+    table = _table()
+    gate = _hold_dispatcher(Zoo.instance().server)
+    t0 = time.perf_counter()
+    handles = [table.add_async(ONES, row_ids=IDS) for _ in range(3)]
+    gate.set()
+    for h in handles:
+        table.wait(h)
+    trace = op_trace.of(_served_run(t0))
+
+    members = trace.spans("APPLY_FUSED_ADD")
+    assert sorted(m.op for m in members) == sorted(handles)
+    group, = [r for r in trace.spans("SERVER_PROCESS_ADD_MSG")
+              if r.id == members[0].parent]
+    assert group.n == 3
+    for m in members:
+        assert (m.parent, m.start_ns, m.dur_ns, m.n) == (
+            group.id, group.start_ns, group.dur_ns, 3)
+    # one apply for the three, its rows the three requests' together
+    apply, = [r for r in trace.spans("TABLE_PROCESS_ADD")
+              if r.parent == group.id]
+    prep, = [r for r in trace.children(apply.id)
+             if r.stage == "TABLE_ROW_PREP"]
+    assert prep.n == 3 * len(IDS)  # XLA's scatter takes duplicates as they are
+    merge, = trace.spans("TABLE_MERGE_ADDS")  # the concatenation before it
+    assert merge.n == 3 and merge.start_ns + merge.dur_ns <= group.start_ns
+    # each waited in the queue until the group's service began
+    for h in handles:
+        wait, = [r for r in trace.spans("SERVER_QUEUE_WAIT") if r.op == h]
+        assert wait.start_ns + wait.dur_ns <= merge.start_ns
+    np.testing.assert_array_equal(table.get(IDS), 3 * ONES)
+    mv.shutdown()
+
+
+# -- (d) the window cut and an overwritten ring -----------------------------------
+
+def test_window_returns_only_what_lies_inside_and_reports_overwrites():
+    ring = OpRing(8)
+    second = 1_000_000_000
+    for i in range(6):  # spans [i, i + 0.5] s
+        ring.append(i + 1, 0, "STAGE", i * second, second // 2, 0, i, 0)
+    records, overwrote = ring.window(0.9, 4.6)
+    assert [r.op for r in records] == [1, 2, 3, 4] and not overwrote
+    assert ring.overwritten == 0
+    for i in range(6, 12):
+        ring.append(i + 1, 0, "STAGE", i * second, second // 2, 0, i, 0)
+    assert ring.overwritten == 4  # spans 0-3 are gone; 4 is the oldest kept
+    records, overwrote = ring.window(2.0, 8.0)
+    assert [r.op for r in records] == [4, 5, 6, 7] and overwrote
+    records, overwrote = ring.window(4.6, 8.0)  # after the oldest kept ended
+    assert [r.op for r in records] == [5, 6, 7] and not overwrote
+    with pytest.raises(ValueError):
+        OpRing(12)
+
+
+def test_reader_fails_on_a_ring_that_overwrote_the_window(tracing,
+                                                          monkeypatch):
+    monkeypatch.setattr(dashboard, "RING", OpRing(8))
+    t0 = time.perf_counter()
+    for _ in range(20):
+        with span("TOO_MANY"):
+            pass
+    with pytest.raises(RuntimeError, match="overwrote"):
+        op_trace.of(_run(t0, time.perf_counter()))
+
+
+# -- (e) the queue wait the metric reads -------------------------------------------
+
+def test_queue_wait_metric_reads_an_injected_wait(tracing):
+    mv.init()
+    table = _table()
+    table.get(IDS)  # compile outside the window
+    Dashboard.reset()
+    gate = _hold_dispatcher(Zoo.instance().server)
+    t0 = time.perf_counter()
+    get = table.get_async(IDS)
+    time.sleep(0.2)
+    held_ms = (time.perf_counter() - t0) * 1e3
+    gate.set()
+    table.wait_get(get, IDS)
+    waited_ms = _metric("dispatch_queue_wait_ms", _served_run(t0))
+    assert held_ms - 1 <= waited_ms <= held_ms + 100
+    # the operator's series holds the same wait with the switch on or off
+    hist = Dashboard.histogram("SERVER_QUEUE_WAIT_SECONDS")
+    assert hist.count == 1
+    assert hist.max * 1e3 == pytest.approx(waited_ms, abs=1e-3)
+    mv.shutdown()
+
+
+# -- (f) CPU time tells a wait from work ---------------------------------------------
+
+def test_thread_cpu_time_of_a_sleeping_span_is_far_under_its_wall_time(
+        tracing):
+    t0 = time.perf_counter()
+    with span("SLEEPS", op=9, n=1, cpu=True):
+        time.sleep(0.05)
+    with span("SPINS", cpu=True):
+        end = time.perf_counter() + 0.05
+        while time.perf_counter() < end:
+            pass
+    with span("UNASKED"):  # the clock is read only where a section asks
+        pass
+    records, _ = dashboard.RING.window(t0, time.perf_counter())
+    sleeps, spins, unasked = [r for r in records if r.stage in (
+        "SLEEPS", "SPINS", "UNASKED")]
+    assert unasked.cpu_ns == 0
+    assert (sleeps.stage, sleeps.op, sleeps.n) == ("SLEEPS", 9, 1)
+    assert sleeps.dur_ns >= 50e6 and sleeps.cpu_ns < sleeps.dur_ns / 10
+    assert spins.cpu_ns > spins.dur_ns / 4  # a busy thread, even when shared
+
+
+# -- hops: a point in the ring, the TraceStore as it was --------------------------------
+
+def test_hop_adds_a_ring_point_and_keeps_the_trace_store_format(tracing):
+    TRACES.reset()
+    t0 = time.perf_counter()
+    before = time.time_ns()
+    with span("AROUND", op=77) as around:
+        hop(77, "client_send")
+        hop(0, "never")  # in-process messages carry no req_id
+    records, _ = dashboard.RING.window(t0, time.perf_counter())
+    point, section = [r for r in records if r.op == 77]
+    assert (point.stage, point.op, point.dur_ns, point.id) == (
+        "client_send", 77, 0, 0)
+    assert point.parent == around.id == section.id
+    assert _inside(point, section)
+    (stage, t_ns), = TRACES.export(8)[77]  # wall clock, [stage, t_ns] pairs
+    assert stage == "client_send" and before <= t_ns <= time.time_ns()
+    assert 0 not in TRACES.export(8)
