@@ -373,7 +373,7 @@ def run_mesh(devices, clock, sizes, with_server):
 
 FULL_SIZES = {
     # rows, cols, ids             (bench.py bench_matrix_table)
-    "kernels": (1_000_000, 50, 1024),
+    "kernels": (1_000_000, 50, 1000),
     # vocab, dim, batch_pairs, block_tokens, group, submissions
     "trainer": (100_000, 128, 32768, 8192, 64, 3),    # bench_ps_word2vec
     # rows per Add/Get, k, queries

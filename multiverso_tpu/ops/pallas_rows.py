@@ -15,8 +15,18 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
   (duplicates pre-combined); pad slots may repeat the sentinel because a
   zero delta leaves its bytes unchanged, so racing identical writes are
   benign.
-* batch size is a multiple of the row group (bucket sizes are powers of
-  two ≥ the group).
+* the number of ids is a multiple of the row group (bucket sizes are
+  powers of two ≥ the group).
+* for ``scatter_add_rows`` the ids may outnumber the delta's rows: the
+  DELTA sizes the grid (``ceil(rows / ROW_GROUP)`` steps, a static shape),
+  so the tail of a bucket is never read, moved or written. Only the slots
+  of the last group past the delta's end are still walked; the kernel
+  gives them a zero delta whatever the block holds there, so they must
+  aim at the sentinel (or at any row the call does not name).
+* ``sign`` (a static float of the table's updater: -1.0 for SGD) scales
+  the delta inside the kernel; the cast to the table's dtype and the pad
+  to its lane width are inside the same jitted program. A row Add is one
+  device program.
 
 Interpret mode is the caller's explicit choice, made once from the
 platform of the devices that hold the table (:func:`interpret_for`): ``cpu``
@@ -39,7 +49,8 @@ current machine):
   engine's own queueing. The simple kernel is kept.
 * remaining headroom would need fewer/larger descriptors (rows are 512B —
   per-descriptor cost dominates); with arbitrary row ids there is no
-  contiguity to merge, so this is the v5e floor for this op shape.
+  contiguity to merge. That floor is PER SLOT, and says nothing of which
+  slots a launch walks: see PR 25 below.
 * descriptor coalescing (r3): sorted-unique ids do contain contiguous runs
   on zipf workloads, so a variant merges each all-consecutive 4-row segment
   into ONE 4-row DMA. Measured (1M×128 table, 1024-id batches,
@@ -53,6 +64,18 @@ current machine):
   contiguity. Conclusion: on v5e the branch cost exceeds the descriptor
   cost by ~10×, so run-merging cannot win at 512B rows regardless of
   workload. The coalesced kernel was deleted; this paragraph is its record.
+* the grid follows the delta (PR 25, 2026-09-28, v5e, the benchmark's
+  `emb128.bulk-rows` cell: a device Add of 100,000 rows x 128 float32 into
+  a 10,000,000-row table, ids in a 131,072 bucket; device time of the
+  kernel's events in a 4 s traced window). Before, the grid came from the
+  ids: 2,048 steps, 3.351 ms a launch, 31,072 slots of it (486 whole
+  groups) sentinel pads, with a pad and a multiply program of 0.18 and
+  0.20 ms in front. Now 1,563 steps over the delta's 100,032 slots:
+  2.451 ms a launch, nothing in front. That is 24.5 ns a slot against
+  25.6: the pad slots cost MORE than live ones (the 64 descriptors of an
+  all-sentinel group write one row), so a quarter of the slots was 27% of
+  the time. The per-slot floor above stands; it is now asked of live slots
+  only (100,032 launched for 100,000 named).
 """
 
 from __future__ import annotations
@@ -61,6 +84,7 @@ import functools
 import os
 
 import jax
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -130,7 +154,7 @@ def gather_rows(table: jax.Array, ids: jax.Array, *,
 
 
 def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
-                        scratch, read_sems, write_sems):
+                        scratch, read_sems, write_sems, *, rows, sign):
     del table_in_ref  # aliased with table_ref; all access goes through out
     g = pl.program_id(0)
     base = g * ROW_GROUP
@@ -149,7 +173,15 @@ def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
         read_dma(k).start()
     for k in range(ROW_GROUP):
         read_dma(k).wait()
-    scratch[:, :] = scratch[:, :] + delta_ref[:, :]
+    delta = delta_ref[:, :]
+    if rows % ROW_GROUP:
+        # the last group hangs over the delta's end: that part of the block
+        # is unspecified (it may hold NaN), so select, never multiply
+        row = base + jax.lax.broadcasted_iota(jnp.int32, delta.shape, 0)
+        delta = jnp.where(row < rows, delta, 0.0)
+    if sign != 1.0:
+        delta = sign * delta
+    scratch[:, :] = scratch[:, :] + delta
     for k in range(ROW_GROUP):
         write_dma(k).start()
     # write-backs must land before the next grid step may read these rows
@@ -158,14 +190,25 @@ def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
         write_dma(k).wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",),
+def launched_slots(rows: int) -> int:
+    """Id slots a scatter-add of ``rows`` delta rows reads, adds and writes:
+    whole row groups."""
+    return pl.cdiv(rows, ROW_GROUP) * ROW_GROUP
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "sign"),
                    donate_argnums=(0,))
-def _scatter_add_call(table, ids, deltas, interpret):
-    batch = ids.shape[0]
+def _scatter_add_call(table, ids, deltas, interpret, sign=1.0):
+    rows = deltas.shape[0]
     cols = table.shape[1]
+    deltas = deltas.astype(table.dtype)
+    if deltas.shape[1] != cols:  # a table narrower than its lane padding
+        deltas = jnp.pad(deltas, ((0, 0), (0, cols - deltas.shape[1])))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(batch // ROW_GROUP,),
+        # the delta sizes the grid: id slots past its last group (the tail
+        # of a caller's bucket) are never read
+        grid=(pl.cdiv(rows, ROW_GROUP),),
         in_specs=[
             pl.BlockSpec((ROW_GROUP, cols), lambda g, ids: (g, 0),
                          memory_space=pltpu.VMEM),
@@ -179,7 +222,7 @@ def _scatter_add_call(table, ids, deltas, interpret):
         ],
     )
     return pl.pallas_call(
-        _scatter_add_kernel,
+        functools.partial(_scatter_add_kernel, rows=rows, sign=sign),
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         grid_spec=grid_spec,
         # operand order: ids (scalar prefetch), deltas, table → alias table
@@ -189,10 +232,18 @@ def _scatter_add_call(table, ids, deltas, interpret):
 
 
 def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
-                     *, interpret: bool) -> jax.Array:
-    """In-place ``table.at[ids].add(deltas)`` for unique live ids; the input
-    table buffer is donated."""
+                     *, interpret: bool, sign: float = 1.0) -> jax.Array:
+    """In-place ``table.at[ids[:n]].add(sign * deltas)`` for the ``n`` rows
+    of ``deltas`` and unique live ids; the input table buffer is donated.
+    ``ids`` may be longer than ``deltas``: the slots of the last row group
+    past ``n`` are read and written back unchanged, later ones not at all."""
     if ids.shape[0] % ROW_GROUP:
         raise ValueError(
             f"scatter_add_rows: batch {ids.shape[0]} not a multiple of {ROW_GROUP}")
-    return _scatter_add_call(table, ids, deltas, interpret)
+    if deltas.shape[0] > ids.shape[0]:
+        raise ValueError(
+            f"scatter_add_rows: {deltas.shape[0]} delta rows for "
+            f"{ids.shape[0]} id slots")
+    if not deltas.shape[0]:
+        return table
+    return _scatter_add_call(table, ids, deltas, interpret, sign)

@@ -50,6 +50,17 @@ def _device_pad(values: jax.Array, bucket: int, cols: int) -> jax.Array:
     return out.at[: values.shape[0], : values.shape[1]].set(values)
 
 
+def _xla_scatter_add(data: jax.Array, ids: jax.Array, deltas: jax.Array,
+                     *, sign: float = 1.0) -> jax.Array:
+    """XLA's scatter-add in the call shape of
+    ``pallas_rows.scatter_add_rows``: ``ids`` may be longer than ``deltas``
+    (a bucket; its tail is sliced off here) and the updater's sign is
+    applied inside the program."""
+    if sign != 1.0:
+        deltas = sign * deltas
+    return data.at[ids[: deltas.shape[0]]].add(deltas)
+
+
 def _row_gather(data: jax.Array, ids: jax.Array) -> jax.Array:
     """The table's row Get; named so that its compiled module is
     ``jit__row_gather`` in a trace."""
@@ -155,13 +166,13 @@ class MatrixServer(ServerTable):
             # unique-id contract: see process_add
             self._scatter_add_raw = functools.partial(
                 pallas_rows.scatter_add_rows,
-                interpret=self._pallas_interpret)
+                interpret=self._pallas_interpret, sign=self._sign)
             self._scatter_add = self._scatter_add_raw
             why = "pallas row-DMA kernel, %s" % (
                 "interpreted" if self._pallas_interpret else "compiled")
         else:
-            self._scatter_add_raw = lambda data, ids, delta: (
-                data.at[ids].add(delta))
+            self._scatter_add_raw = functools.partial(
+                _xla_scatter_add, sign=self._sign)
             self._scatter_add = jax.jit(self._scatter_add_raw,
                                         donate_argnums=(0,))
             why = "XLA scatter (%s)" % (
@@ -197,10 +208,10 @@ class MatrixServer(ServerTable):
         update. ``ids`` must be unique apart from sentinel pads with
         zero deltas."""
         if self._linear:
-            sign, scatter = self._sign, self._scatter_add_raw
+            scatter = self._scatter_add_raw
 
             def apply_linear(data, states, ids, delta, worker, scalars):
-                return scatter(data, ids, sign * delta), states
+                return scatter(data, ids, delta), states
 
             return apply_linear
         return self._make_row_update(self.updater, jit=False)
@@ -322,9 +333,10 @@ class MatrixServer(ServerTable):
                         merge_duplicate_rows
                     row_ids, values = merge_duplicate_rows(row_ids, values)
                 ids_p, vals_p, prep.n = self._bucket_ids(row_ids, values)
-            with span("TABLE_ROW_LAUNCH"):
+            with span("TABLE_ROW_LAUNCH") as launch:
+                launch.n = ids_p.shape[0]
                 if self._linear:
-                    self.data = self._scatter_add(self.data, ids_p, self._sign * vals_p)
+                    self.data = self._scatter_add(self.data, ids_p, vals_p)
                 else:
                     self.data, self.states = self._row_update(
                         self.data, self.states, ids_p, vals_p, worker, scalars)
@@ -336,6 +348,17 @@ class MatrixServer(ServerTable):
                 else:
                     self._up_to_date[:, touched] = False
 
+    def _bucket_delta(self, values: jax.Array, bucket: int) -> jax.Array:
+        """A device delta as XLA's programs take it: zero-padded to the id
+        bucket and the table's lanes, on the table's devices. Worker-thread
+        kernels hand deltas back committed to ONE device (the gather_out
+        contract); re-shard here — on the dispatcher thread, where
+        cross-shard collectives are legal — or the jit would reject the
+        mixed device sets."""
+        return jax.device_put(
+            _device_pad(values.astype(self.dtype), bucket, self.padded_cols),
+            mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
+
     def _process_add_device(self, row_ids, values, option, worker,
                             scalars) -> None:
         with span("TABLE_ROW_PREP") as prep:
@@ -344,26 +367,22 @@ class MatrixServer(ServerTable):
             if values.shape[0] != n:
                 log.fatal("Matrix.add(device): %d ids but %d value rows",
                           n, values.shape[0])
-            from multiverso_tpu.ops.pallas_rows import ROW_GROUP
+            from multiverso_tpu.ops.pallas_rows import (ROW_GROUP,
+                                                        launched_slots)
             bucket = max(_next_pow2(n), ROW_GROUP)
             ids_p = async_upload(np.concatenate(
                 [row_ids, np.full(bucket - n, self.sentinel_row, np.int32)]))
-        with span("TABLE_ROW_LAUNCH"):
-            vals_p = _device_pad(values.astype(self.dtype), bucket,
-                                 self.padded_cols)
-            # worker-thread kernels hand deltas back committed to ONE
-            # device (the gather_out contract); re-shard here — on the
-            # dispatcher thread, where cross-shard collectives are legal —
-            # or the scatter jit would reject the mixed device sets
-            vals_p = jax.device_put(
-                vals_p,
-                mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
+        with span("TABLE_ROW_LAUNCH") as launch:
+            # the pallas kernel takes the delta as it came and walks its row
+            # groups, not the bucket's: one device program an Add
+            if not (self._linear and self._pallas_scatter):
+                values = self._bucket_delta(values, bucket)
+            launch.n = launched_slots(values.shape[0])
             if self._linear:
-                self.data = self._scatter_add(self.data, ids_p,
-                                              self._sign * vals_p)
+                self.data = self._scatter_add(self.data, ids_p, values)
             else:
                 self.data, self.states = self._row_update(
-                    self.data, self.states, ids_p, vals_p, worker, scalars)
+                    self.data, self.states, ids_p, values, worker, scalars)
         if self.is_sparse:
             with self._std_lock:
                 live = row_ids[row_ids < self.num_row]
@@ -472,7 +491,8 @@ class MatrixServer(ServerTable):
             ids_p, _, n = self._bucket_ids(row_ids, None,
                                            ensure_pad=device_out)
             prep.n = n
-        with span("TABLE_ROW_LAUNCH"):
+        with span("TABLE_ROW_LAUNCH") as launch:
+            launch.n = ids_p.shape[0]
             gathered = (self._gather_out if device_out
                         else self._gather)(self.data, ids_p)
         if self.is_sparse and self._is_worker(option):

@@ -1,6 +1,8 @@
 """Tier-b MatrixTable tests: whole/row Get-Add, duplicate rows, sparse
 staleness tracking (reference: test_matrix_table.cpp, src/table/matrix.cpp)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -231,3 +233,94 @@ def test_named_transact_refused_on_gated_server(sync_env):
     mv.register_program("test.gated_pair", lambda d, s: (d, s, None))
     with pytest.raises(mv.log.FatalError):
         a.transact_device_async("test.gated_pair", [b])
+
+
+# -- the device Add: one program, sized by the delta -------------------------
+
+_compiles = []  # every backend compile of this process, as JAX reports them
+
+
+def _on_jax_event(event, duration, **_):
+    if event.endswith("backend_compile_duration"):
+        _compiles.append(duration)
+
+
+@pytest.fixture
+def compile_count():
+    """JAX's own compile events (what `window_compiles.rows` counts)."""
+    import jax.monitoring
+    if not _compiles:
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+        _compiles.append(0.0)  # registered once a process
+    return lambda: len(_compiles)
+
+
+@pytest.mark.parametrize("updater", ["", "sgd"])
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_device_add_of_an_odd_row_count(kernel, updater, monkeypatch,
+                                        compile_count):
+    """`add_device_async` of 21 rows (no power of two, no multiple of the
+    row group) of a delta narrower than the table's lanes: equal to numpy
+    to the bit with the plain and the SGD updater, the same shape compiles
+    on its first Add only, and TABLE_ROW_LAUNCH's `n` is the slots the
+    launch covers: the delta's row groups where the (interpreted) kernel
+    serves, the bucket where XLA's scatter does. 83 x 100 tables appear in
+    no other test: the kernel's jit is cached by shape, not by group."""
+    import jax
+
+    from multiverso_tpu import dashboard
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.tables import matrix_table
+
+    rows, cols, n = 83, 100, 21
+    if kernel == "pallas":
+        monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                            lambda platform, num_shards: num_shards == 1)
+        monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+        mv.init(mesh_shape="1")
+        slots = 24
+    else:
+        mv.init()
+        slots = 64  # the id bucket: max(next_pow2(21), the row group)
+    monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
+    rng = np.random.default_rng(25)
+    mirror = rng.standard_normal((rows, cols)).astype(np.float32)
+    table = mv.create_table("matrix", rows, cols, np.float32,
+                            updater_type=updater, init_value=mirror)
+    assert table._server_table._pallas_scatter == (kernel == "pallas")
+    ids = rng.choice(rows, n, replace=False).astype(np.int32)
+    vals = rng.standard_normal((n, cols)).astype(np.float32)
+    dev_vals = jax.device_put(vals)
+
+    t0, compiled = time.perf_counter(), []
+    for _ in range(3):
+        before = compile_count()
+        table.wait(table.add_device_async(dev_vals, ids))
+        jax.block_until_ready(table._server_table.data)
+        compiled.append(compile_count() - before)
+        mirror[ids] += -vals if updater == "sgd" else vals
+    records, _ = dashboard.RING.window(t0, time.perf_counter())
+
+    np.testing.assert_array_equal(table.get(), mirror)
+    # the kernel's Add is ONE program; XLA's keeps its pad and re-shard
+    assert compiled[1:] == [0, 0] and compiled[0] >= 1, compiled
+    if kernel == "pallas":
+        assert compiled[0] == 1, compiled
+    launched = [r.n for r in records if r.stage == "TABLE_ROW_LAUNCH"]
+    named = [r.n for r in records if r.stage == "TABLE_ROW_PREP"]
+    assert launched == [slots] * 3 and named == [n] * 3, (launched, named)
+
+
+def test_xla_scatter_add_takes_the_kernels_call_shape():
+    """The XLA branch's scatter-add slices a longer id bucket to the delta's
+    rows and applies the sign inside its program, as the kernel does."""
+    import jax.numpy as jnp
+
+    from multiverso_tpu.tables.matrix_table import _xla_scatter_add
+
+    data = jnp.zeros((8, 4), jnp.float32)
+    ids = jnp.asarray([3, 5, 1, 1], jnp.int32)  # the tail names a live row
+    out = np.asarray(_xla_scatter_add(data, ids, jnp.ones((2, 4)), sign=-1.0))
+    expect = np.zeros((8, 4), np.float32)
+    expect[[3, 5]] = -1.0
+    np.testing.assert_array_equal(out, expect)

@@ -87,6 +87,43 @@ def test_multiple_groups(rng):
     np.testing.assert_allclose(np.asarray(got), expect[ids], rtol=1e-6)
 
 
+@pytest.mark.parametrize("sign", [1.0, -1.0])
+@pytest.mark.parametrize("n", [4 * ROW_GROUP, 2 * ROW_GROUP,
+                               2 * ROW_GROUP + 5, 1])
+def test_scatter_add_grid_follows_the_delta(rng, n, sign):
+    """ids may outnumber the delta's rows (a bucket): the rows the delta
+    names get ``sign * delta`` to the bit, and nothing else changes: not
+    the sentinel row behind the last group's tail, and not the LIVE rows
+    that later slots name, which a kernel walking the ids would touch."""
+    bucket, sentinel = 4 * ROW_GROUP, ROWS - 1
+    start = rng.normal(size=(ROWS, 128)).astype(np.float32)
+    named = rng.choice(sentinel, bucket, replace=False).astype(np.int32)
+    ids = named.copy()
+    # the last launched group's tail reads and writes back its rows (the
+    # server aims it at the sentinel); the groups after it name live rows
+    launched = pallas_rows.launched_slots(n)
+    assert launched == -(-n // ROW_GROUP) * ROW_GROUP
+    ids[n:launched] = sentinel
+    deltas = rng.normal(size=(n, 128)).astype(np.float32)
+    out = np.asarray(scatter_add_rows(
+        jnp.asarray(start), jnp.asarray(ids), jnp.asarray(deltas), sign=sign))
+    expect = start.copy()
+    expect[named[:n]] += np.float32(sign) * deltas
+    np.testing.assert_array_equal(out[named[:n]], expect[named[:n]])
+    np.testing.assert_array_equal(out[sentinel], start[sentinel])
+    np.testing.assert_array_equal(out[named[n:]], start[named[n:]])
+    np.testing.assert_array_equal(out, expect)
+
+
+def test_scatter_add_refuses_more_delta_rows_than_ids(rng):
+    table = jnp.zeros((ROWS, 128), jnp.float32)
+    ids = jnp.zeros(ROW_GROUP, jnp.int32)
+    with pytest.raises(ValueError, match="delta rows"):
+        scatter_add_rows(table, ids, jnp.zeros((ROW_GROUP + 1, 128)))
+    # no rows, no launch
+    assert scatter_add_rows(table, ids, jnp.zeros((0, 128))) is table
+
+
 def test_interpret_follows_the_tables_platform():
     assert pallas_rows.interpret_for("cpu") is True
     assert pallas_rows.interpret_for("tpu") is False
