@@ -8,7 +8,10 @@ points (``mv.init`` -> ``create_table`` -> updater -> dispatcher;
 
 1. kernels   1,000,000 x 50 float32 MatrixTable: the Pallas row gather and
              scatter-add on its device state, then Add (duplicate ids),
-             device Add and Get through the dispatcher, against numpy.
+             device Add and Get through the dispatcher, against numpy; then
+             the same on 300,000 x 300 (rows of three lane tiles), so a
+             width that stops working on the chip fails here. Each reports
+             which program served its row launches.
 2. trainer   word2vec PSTrainer, 100,000 x 128, three submissions of
              64 x 8,192 Zipf tokens through the fused device transaction.
 3. server    ``mv.serve`` on the trainer's tables; ONE child process (pinned
@@ -104,6 +107,11 @@ def phase_kernels(rows, cols, n_ids, expect_scatter, seed=0):
     import multiverso_tpu as mv
     from multiverso_tpu.ops import pallas_rows
 
+    from multiverso_tpu.dashboard import Dashboard
+
+    launch_counters = [f"ROW_LAUNCH_{path}_{op}" for op in ("ADD", "GET")
+                       for path in ("PALLAS", "XLA")]
+    launched = {n: Dashboard.counter_value(n) for n in launch_counters}
     rng = np.random.default_rng(seed)
     mirror = rng.standard_normal((rows, cols)).astype(np.float32)
     table = mv.create_table("matrix", rows, cols, np.float32,
@@ -158,6 +166,13 @@ def phase_kernels(rows, cols, n_ids, expect_scatter, seed=0):
     np.testing.assert_array_equal(table.get(others), mirror[others])
     checks["table_ops"] = (f"add {len(dup_ids)} ids ({n_ids // 4} "
                            f"duplicates), add_device {n_ids}, get")
+    # which program served the row launches: both Adds the kernel's where
+    # the table says it uses it, every Get XLA's gather
+    checks["row_launches"] = {n: Dashboard.counter_value(n) - was
+                              for n, was in launched.items()}
+    served = "PALLAS" if pallas else "XLA"
+    assert checks["row_launches"][f"ROW_LAUNCH_{served}_ADD"] == 2, checks
+    assert checks["row_launches"]["ROW_LAUNCH_XLA_GET"] == 3, checks
     return table, checks
 
 
@@ -356,6 +371,10 @@ def run_mesh(devices, clock, sizes, with_server):
     report("kernels", t0, checks)
 
     t0 = time.perf_counter()
+    _, checks = phase_kernels(*sizes["kernels_wide"], expect_scatter=expect)
+    report("kernels_wide", t0, checks)
+
+    t0 = time.perf_counter()
     trainer, w_in, checks = phase_trainer(*sizes["trainer"],
                                           expect_scatter=expect)
     if devices > 1:
@@ -374,6 +393,8 @@ def run_mesh(devices, clock, sizes, with_server):
 FULL_SIZES = {
     # rows, cols, ids             (bench.py bench_matrix_table)
     "kernels": (1_000_000, 50, 1000),
+    # the word-embedding width: three lane tiles a row (384 lanes)
+    "kernels_wide": (300_000, 300, 1000),
     # vocab, dim, batch_pairs, block_tokens, group, submissions
     "trainer": (100_000, 128, 32768, 8192, 64, 3),    # bench_ps_word2vec
     # rows per Add/Get, k, queries

@@ -449,7 +449,10 @@ class OpRecord(NamedTuple):
     (``time.thread_time_ns``: a first touch burns it, a wait does not)
     for the sections that ask for it, else 0; ``op`` is the request's
     ``req_id``, else its ``msg_id``; ``n`` counts rows, bytes or fused
-    messages, by stage."""
+    messages, by stage. A row launch (``TABLE_ROW_LAUNCH``) also says
+    which program served it (``path``: ``pallas`` or ``xla``), the DMA
+    descriptors it issues and the bytes of table rows it moves; every
+    other stage leaves the three empty."""
 
     seq: int
     id: int
@@ -460,6 +463,9 @@ class OpRecord(NamedTuple):
     cpu_ns: int
     op: int
     n: int
+    path: str = ""
+    descriptors: int = 0
+    bytes: int = 0
 
 
 class OpRing:
@@ -480,10 +486,12 @@ class OpRing:
         self._seq = itertools.count()
 
     def append(self, span_id: int, parent: int, stage: str, start_ns: int,
-               dur_ns: int, cpu_ns: int, op: int, n: int) -> None:
+               dur_ns: int, cpu_ns: int, op: int, n: int, path: str = "",
+               descriptors: int = 0, bytes: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
-                                         start_ns, dur_ns, cpu_ns, op, n)
+                                         start_ns, dur_ns, cpu_ns, op, n,
+                                         path, descriptors, bytes)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -536,13 +544,15 @@ class _Section:
     estimate that means something over sums of a second or more."""
 
     __slots__ = ("_name", "_feeds", "_op", "n", "id", "start_ns", "dur_ns",
-                 "_parent", "_outer_op", "_cpu", "_cpu0", "_ann")
+                 "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
+                 "path", "descriptors", "bytes")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
         self._name, self._feeds, self._op, self.n = name, feeds, op, n
         self._cpu = cpu
         self.id = 0
+        self.path, self.descriptors, self.bytes = "", 0, 0
 
     def __enter__(self) -> "_Section":
         if Dashboard.profile_annotations:
@@ -571,7 +581,8 @@ class _Section:
                 self._ann.__exit__(None, None, None)
             _op_tls.span, _op_tls.op = self._parent, self._outer_op
             RING.append(self.id, self._parent, self._name, self.start_ns,
-                        self.dur_ns, cpu, self._op, self.n)
+                        self.dur_ns, cpu, self._op, self.n, self.path,
+                        self.descriptors, self.bytes)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -581,9 +592,9 @@ class _Section:
 
 class _Off:
     """What ``span`` hands out while the switch is off: nothing is timed
-    and ``n`` goes nowhere."""
+    and what a section would carry goes nowhere."""
 
-    __slots__ = ("n",)
+    __slots__ = ("n", "path", "descriptors", "bytes")
     id = 0
 
     def __enter__(self) -> "_Off":

@@ -24,9 +24,22 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
   gives them a zero delta whatever the block holds there, so they must
   aim at the sentinel (or at any row the call does not name).
 * ``sign`` (a static float of the table's updater: -1.0 for SGD) scales
-  the delta inside the kernel; the cast to the table's dtype and the pad
-  to its lane width are inside the same jitted program. A row Add is one
-  device program.
+  the delta inside the kernel; the cast to the table's dtype is inside the
+  same jitted program. A delta narrower than the table's lanes (300 columns
+  into 384) is taken as it is: its block is as wide as the delta and the
+  kernel adds the last lane tile under a static lane mask. XLA would pad
+  it in a pass of its own (it will not fuse the pad into the copy that
+  turns a column-major 100,000 x 300 device array row-major: 0.45 ms each
+  on the v5e, my chip run, PR 26). A row Add is one device program.
+* the table's width is any whole number of 128-lane tiles, ``T``. A table
+  of one tile is taken as it is: a row is 512 contiguous bytes. A wider
+  float32 table lies in HBM as (8, 128) tiles, ``T`` of them side by side
+  for every 8 rows, so a row is ``T`` runs of 512 bytes, 4 KB apart; the
+  kernels see it as ``(rows / 8, T, 8, 128)`` (:func:`_tile_view`, a
+  bitcast to XLA: no byte moves, the table's layout is what it was) and
+  move a row with ONE strided descriptor of ``T x 512`` bytes. Its row
+  count must then be a multiple of 8 (the caller pads; the tiles are
+  there in HBM either way).
 
 Interpret mode is the caller's explicit choice, made once from the
 platform of the devices that hold the table (:func:`interpret_for`): ``cpu``
@@ -76,6 +89,24 @@ current machine):
   all-sentinel group write one row), so a quarter of the slots was 27% of
   the time. The per-slot floor above stands; it is now asked of live slots
   only (100,032 launched for 100,000 named).
+* rows wider than one lane tile (PR 26). What Mosaic said to each form,
+  compiled for a described v5e (libtpu 0.0.34), 3,000,008 x 384 float32:
+  a one-row slice of the table as XLA holds it, ``(1, 384)`` or ``(1, 128)``
+  of ``memref<3000008x384xf32, #tpu.tiled<(8,128),[3,1]>>``: "Slice shape
+  along dimension 0 must be aligned to tiling (8), but is 1" (at 128 lanes
+  the same slice passes: an (8, 128)-tiled array one tile wide is
+  row-major). A table re-laid as ``(rows x T, 128)`` or as ``T`` planes
+  would compile, and would cost every reader of ``[row, column]``
+  (``get_device``, dense ops, updaters, checkpoints) a copy of the table.
+  The tile view needs neither: ``(rows / 8, T, 8, 128)`` is the same bytes,
+  XLA lowers the reshape and transpose on both sides of the call to
+  ``bitcast`` (0 bytes of temporaries, the table still aliased in place),
+  and Mosaic takes ``[rid >> 3, :, rid & 7, :]`` either as ``T``
+  descriptors of ``(1, 128)`` or as one of ``(T, 1, 128)``. The second is
+  what runs: on the v5e, 100,000 rows of 384 lanes, 8.16 ms a launch as
+  three descriptors a row, 3.86 as one with ``//`` and ``%``, 2.85 with
+  the shift and the mask (28 ns a slot; 24.5 at one tile); 256 and 512
+  lanes cost what 384 do. PERF.md, Findings, PR 26.
 """
 
 from __future__ import annotations
@@ -109,14 +140,88 @@ def interpret_for(platform: str) -> bool:
         f"the table lives on {platform!r}")
 
 
+LANES = 128     # one lane tile
+SUBLANES = 8    # rows of one (8, 128) tile of 32-bit values
+# VMEM a grid step of the scatter-add holds: the delta block, which the
+# pipeline double-buffers, and the scratch the rows are read into, each
+# ROW_GROUP x lanes values. The budget keeps that under half of the
+# 16 MiB a v5e kernel may use by default: 10,922 float32 lanes at a
+# group of 64. A wider table takes XLA's scatter (`fits_vmem` is part of
+# the table's gate), it does not fail in the compiler.
+VMEM_BUDGET_BYTES = 8 << 20
+
+
+def fits_vmem(lanes: int, itemsize: int) -> bool:
+    return 3 * ROW_GROUP * lanes * itemsize <= VMEM_BUDGET_BYTES
+
+
+def lane_tiles(table) -> int:
+    if table.shape[1] % LANES:
+        raise ValueError(
+            f"pallas row kernels take tables of whole {LANES}-lane tiles; "
+            f"this one has {table.shape[1]} columns")
+    return table.shape[1] // LANES
+
+
+def _tile_view(table: jax.Array) -> jax.Array:
+    """``(rows, T x 128)`` as its (8, 128) tiles lie in HBM:
+    ``(rows / 8, T, 8, 128)``. XLA makes this a bitcast on the TPU (the
+    minor two dimensions are one tile), so the kernel works on the table's
+    own buffer. A table of one tile is already row-major and is returned as
+    it is."""
+    tiles = lane_tiles(table)
+    if tiles == 1:
+        return table
+    if table.shape[0] % SUBLANES:
+        raise ValueError(
+            f"a table of {tiles} lane tiles needs a multiple of {SUBLANES} "
+            f"rows, got {table.shape[0]}")
+    return table.reshape(table.shape[0] // SUBLANES, SUBLANES, tiles,
+                         LANES).transpose(0, 2, 1, 3)
+
+
+def _row_view(view: jax.Array) -> jax.Array:
+    """The inverse of :func:`_tile_view`."""
+    if view.ndim == 2:
+        return view
+    blocks, tiles = view.shape[:2]
+    return view.transpose(0, 2, 1, 3).reshape(blocks * SUBLANES,
+                                              tiles * LANES)
+
+
+def _row_of(table_ref, rid):
+    """The HBM ref of table row ``rid``: 512 contiguous bytes of a one-tile
+    table, else the row's ``T`` runs of 512 bytes in the tile view, for one
+    strided descriptor."""
+    if len(table_ref.shape) == 2:
+        return table_ref.at[rid]
+    # ids are never negative: a shift and a mask, where // and % would
+    # spend a dozen scalar instructions a descriptor on the sign
+    return table_ref.at[rid >> (SUBLANES.bit_length() - 1), :,
+                        pl.ds(rid & (SUBLANES - 1), 1), :]
+
+
+def _slot_of(block_ref, k):
+    """Slot ``k`` of a VMEM row block: ``(ROW_GROUP, 128)`` for one tile,
+    ``(T, ROW_GROUP, 128)`` (lane tile major, so that each tile of the
+    block is a plain 2-D array to the vector unit) for more."""
+    if len(block_ref.shape) == 2:
+        return block_ref.at[k]
+    return block_ref.at[:, pl.ds(k, 1), :]
+
+
+def _block_shape(tiles: int):
+    return (ROW_GROUP, LANES) if tiles == 1 else (tiles, ROW_GROUP, LANES)
+
+
 def _gather_kernel(ids_ref, table_ref, out_ref, sems):
     g = pl.program_id(0)
     base = g * ROW_GROUP
 
     def row_dma(k):
         rid = ids_ref[base + k]
-        return pltpu.make_async_copy(table_ref.at[rid], out_ref.at[k],
-                                     sems.at[k])
+        return pltpu.make_async_copy(_row_of(table_ref, rid),
+                                     _slot_of(out_ref, k), sems.at[k])
 
     for k in range(ROW_GROUP):
         row_dma(k).start()
@@ -127,21 +232,28 @@ def _gather_kernel(ids_ref, table_ref, out_ref, sems):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gather_call(table, ids, interpret):
     batch = ids.shape[0]
-    cols = table.shape[1]
+    tiles = lane_tiles(table)
+    if tiles == 1:
+        out_shape, out_map = (batch, LANES), lambda g, ids: (g, 0)
+    else:  # lane tile major; put back in row order below
+        out_shape, out_map = (tiles, batch, LANES), lambda g, ids: (0, g, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(batch // ROW_GROUP,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ROW_GROUP, cols), lambda g, ids: (g, 0),
+        out_specs=pl.BlockSpec(_block_shape(tiles), out_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.SemaphoreType.DMA((ROW_GROUP,))],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
-        out_shape=jax.ShapeDtypeStruct((batch, cols), table.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, table.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(ids, table)
+    )(ids, _tile_view(table))
+    if tiles == 1:
+        return out
+    return out.transpose(1, 0, 2).reshape(batch, tiles * LANES)
 
 
 def gather_rows(table: jax.Array, ids: jax.Array, *,
@@ -161,12 +273,13 @@ def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
 
     def read_dma(k):
         rid = ids_ref[base + k]
-        return pltpu.make_async_copy(table_ref.at[rid], scratch.at[k],
-                                     read_sems.at[k])
+        return pltpu.make_async_copy(_row_of(table_ref, rid),
+                                     _slot_of(scratch, k), read_sems.at[k])
 
     def write_dma(k):
         rid = ids_ref[base + k]
-        return pltpu.make_async_copy(scratch.at[k], table_ref.at[rid],
+        return pltpu.make_async_copy(_slot_of(scratch, k),
+                                     _row_of(table_ref, rid),
                                      write_sems.at[k])
 
     for k in range(ROW_GROUP):
@@ -181,7 +294,15 @@ def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
         delta = jnp.where(row < rows, delta, 0.0)
     if sign != 1.0:
         delta = sign * delta
-    scratch[:, :] = scratch[:, :] + delta
+    # the delta is as wide as the caller's columns: the lanes past them
+    # (the last tile's padding) are written back as they were read
+    width = delta.shape[1]
+    if len(scratch.shape) == 2:
+        scratch[:, :width] = scratch[:, :width] + delta
+    else:
+        for t in range(pl.cdiv(width, LANES)):
+            lo, w = t * LANES, min(LANES, width - t * LANES)
+            scratch[t, :, :w] = scratch[t, :, :w] + delta[:, lo:lo + w]
     for k in range(ROW_GROUP):
         write_dma(k).start()
     # write-backs must land before the next grid step may read these rows
@@ -199,36 +320,37 @@ def launched_slots(rows: int) -> int:
 @functools.partial(jax.jit, static_argnames=("interpret", "sign"),
                    donate_argnums=(0,))
 def _scatter_add_call(table, ids, deltas, interpret, sign=1.0):
-    rows = deltas.shape[0]
-    cols = table.shape[1]
+    rows, width = deltas.shape
+    tiles = lane_tiles(table)
     deltas = deltas.astype(table.dtype)
-    if deltas.shape[1] != cols:  # a table narrower than its lane padding
-        deltas = jnp.pad(deltas, ((0, 0), (0, cols - deltas.shape[1])))
+    view = _tile_view(table)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         # the delta sizes the grid: id slots past its last group (the tail
         # of a caller's bucket) are never read
         grid=(pl.cdiv(rows, ROW_GROUP),),
         in_specs=[
-            pl.BlockSpec((ROW_GROUP, cols), lambda g, ids: (g, 0),
+            # as wide as the delta itself (a block may span a whole
+            # dimension whatever its size): no pad program in front
+            pl.BlockSpec((ROW_GROUP, width), lambda g, ids: (g, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((ROW_GROUP, cols), table.dtype),
+            pltpu.VMEM(_block_shape(tiles), table.dtype),
             pltpu.SemaphoreType.DMA((ROW_GROUP,)),
             pltpu.SemaphoreType.DMA((ROW_GROUP,)),
         ],
     )
-    return pl.pallas_call(
+    return _row_view(pl.pallas_call(
         functools.partial(_scatter_add_kernel, rows=rows, sign=sign),
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
         grid_spec=grid_spec,
         # operand order: ids (scalar prefetch), deltas, table → alias table
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(ids, deltas, table)
+    )(ids, deltas, view))
 
 
 def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
@@ -244,6 +366,10 @@ def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
         raise ValueError(
             f"scatter_add_rows: {deltas.shape[0]} delta rows for "
             f"{ids.shape[0]} id slots")
+    if deltas.shape[1] > table.shape[1]:
+        raise ValueError(
+            f"scatter_add_rows: deltas of {deltas.shape[1]} columns for a "
+            f"table of {table.shape[1]}")
     if not deltas.shape[0]:
         return table
     return _scatter_add_call(table, ids, deltas, interpret, sign)

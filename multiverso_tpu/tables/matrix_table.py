@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu import log
-from multiverso_tpu.dashboard import monitor, span
+from multiverso_tpu.dashboard import Dashboard, monitor, span
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import ServerTable, WorkerTable
@@ -67,12 +67,19 @@ def _row_gather(data: jax.Array, ids: jax.Array) -> jax.Array:
     return data[ids]
 
 
-def _use_pallas_scatter(platform: str, num_shards: int) -> bool:
+def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
+                        itemsize: int = 4) -> bool:
     """Pallas row-DMA scatter serves single-shard TPU tables only:
     pallas_call has no SPMD partitioning rule, so multi-device tables take
     XLA's scatter (which partitions fine). ``platform`` is that of the
-    table mesh's devices."""
-    return platform == "tpu" and num_shards == 1
+    table mesh's devices. Any number of lane tiles goes (a row wider than
+    one tile is one strided descriptor, ``ops/pallas_rows``) up to the
+    width whose row group still fits the kernel's VMEM; past it XLA's
+    scatter serves, and the table's creation line and every launch record
+    say which."""
+    from multiverso_tpu.ops.pallas_rows import fits_vmem
+    return (platform == "tpu" and num_shards == 1
+            and fits_vmem(lanes, itemsize))
 
 
 class MatrixServer(ServerTable):
@@ -100,6 +107,13 @@ class MatrixServer(ServerTable):
         # unlocks the Pallas row-DMA scatter path (ops/pallas_rows), which
         # is ~8x faster than XLA's serialized scatter for row Adds.
         self.padded_cols = mesh_lib.pad_to_multiple(self.num_col, 128)
+        if num_shards == 1 and self.padded_cols > 128:
+            # the row kernel reaches a row of several lane tiles through
+            # the table's (8, 128) tiles: whole tiles of rows (HBM holds
+            # them anyway; the extra rows are scratch like the sentinel).
+            # Decided from the shape alone: the gate below imports Pallas,
+            # seconds that belong beside the upload, not before it
+            self.padded_rows = mesh_lib.pad_to_multiple(self.padded_rows, 8)
 
         sharding = mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0)
         init = np.zeros((self.padded_rows, self.padded_cols), dtype=self.dtype)
@@ -157,7 +171,8 @@ class MatrixServer(ServerTable):
         self._gather_out = lambda data, ids: jax.device_put(
             self._gather(data, ids), _out_dev)
         platform = first_dev.platform
-        self._pallas_scatter = _use_pallas_scatter(platform, num_shards)
+        self._pallas_scatter = _use_pallas_scatter(
+            platform, num_shards, self.padded_cols, self.dtype.itemsize)
         # None where XLA's scatter serves the table
         self._pallas_interpret: Optional[bool] = None
         if self._pallas_scatter:
@@ -176,10 +191,19 @@ class MatrixServer(ServerTable):
             self._scatter_add = jax.jit(self._scatter_add_raw,
                                         donate_argnums=(0,))
             why = "XLA scatter (%s)" % (
-                "pallas_call has no SPMD partitioning rule"
-                if platform == "tpu" else "the kernel compiles for tpu only")
+                "the kernel compiles for tpu only" if platform != "tpu"
+                else "pallas_call has no SPMD partitioning rule"
+                if num_shards > 1
+                else "a row group of %d lanes is past the kernel's VMEM"
+                % self.padded_cols)
         log.info("MatrixTable %dx%d on %d %s device(s): row scatter = %s",
                  self.num_row, self.num_col, num_shards, platform, why)
+        # always on: which program served each row launch, by op
+        self._launch_counters = {
+            ("add", "pallas"): Dashboard.counter("ROW_LAUNCH_PALLAS_ADD"),
+            ("add", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_ADD"),
+            ("get", "pallas"): Dashboard.counter("ROW_LAUNCH_PALLAS_GET"),
+            ("get", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_GET")}
         self._row_update = self._make_row_update(self.updater)
 
     def _make_row_update(self, updater: Updater, jit: bool = True):
@@ -217,6 +241,23 @@ class MatrixServer(ServerTable):
         return self._make_row_update(self.updater, jit=False)
 
     # -- helpers -----------------------------------------------------------
+    def _note_launch(self, launch, op: str, slots: int,
+                     pallas: bool) -> None:
+        """What a row launch did, on its TABLE_ROW_LAUNCH record and the
+        always-on counters: ``n`` id slots, the program that served them
+        (``pallas`` or ``xla``), the DMA descriptors the kernel issues for
+        them (a read and a write a slot for an Add; XLA's programs issue
+        their own, not counted: 0) and the bytes of table rows moved, at
+        the table's lane width."""
+        path = "pallas" if pallas else "xla"
+        self._launch_counters[op, path].add()
+        moves = 2 if op == "add" else 1
+        launch.n = slots
+        launch.path = path
+        launch.descriptors = moves * slots if pallas else 0
+        launch.bytes = (moves * slots * self.padded_cols
+                        * self.dtype.itemsize)
+
     def _bucket_ids(self, ids: np.ndarray, values: Optional[np.ndarray],
                     ensure_pad: bool = False
                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], int]:
@@ -334,7 +375,8 @@ class MatrixServer(ServerTable):
                     row_ids, values = merge_duplicate_rows(row_ids, values)
                 ids_p, vals_p, prep.n = self._bucket_ids(row_ids, values)
             with span("TABLE_ROW_LAUNCH") as launch:
-                launch.n = ids_p.shape[0]
+                self._note_launch(launch, "add", ids_p.shape[0],
+                                  self._linear and self._pallas_scatter)
                 if self._linear:
                     self.data = self._scatter_add(self.data, ids_p, vals_p)
                 else:
@@ -375,9 +417,11 @@ class MatrixServer(ServerTable):
         with span("TABLE_ROW_LAUNCH") as launch:
             # the pallas kernel takes the delta as it came and walks its row
             # groups, not the bucket's: one device program an Add
-            if not (self._linear and self._pallas_scatter):
+            pallas = self._linear and self._pallas_scatter
+            if not pallas:
                 values = self._bucket_delta(values, bucket)
-            launch.n = launched_slots(values.shape[0])
+            self._note_launch(launch, "add",
+                              launched_slots(values.shape[0]), pallas)
             if self._linear:
                 self.data = self._scatter_add(self.data, ids_p, values)
             else:
@@ -492,7 +536,7 @@ class MatrixServer(ServerTable):
                                            ensure_pad=device_out)
             prep.n = n
         with span("TABLE_ROW_LAUNCH") as launch:
-            launch.n = ids_p.shape[0]
+            self._note_launch(launch, "get", ids_p.shape[0], False)
             gathered = (self._gather_out if device_out
                         else self._gather)(self.data, ids_p)
         if self.is_sparse and self._is_worker(option):

@@ -21,7 +21,7 @@ import chip_smoke
 
 def test_smoke_phases_through_the_interpreted_kernel(monkeypatch):
     monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
-                        lambda platform, num_shards: num_shards == 1)
+                        lambda platform, num_shards, *width: num_shards == 1)
     monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
     mv.init(mesh_shape="1", **chip_smoke._INIT_FLAGS)
     assert mv.num_servers() == 1  # the first of the 8 virtual devices
@@ -30,6 +30,15 @@ def test_smoke_phases_through_the_interpreted_kernel(monkeypatch):
     table, checks = chip_smoke.phase_kernels(60, 50, 48, interpreted)
     assert checks["pallas_scatter"] and checks["interpret"] is True
     assert "bare_kernels" in checks and checks["padded_cols"] == 128
+    assert checks["row_launches"] == {
+        "ROW_LAUNCH_PALLAS_ADD": 2, "ROW_LAUNCH_XLA_ADD": 0,
+        "ROW_LAUNCH_PALLAS_GET": 0, "ROW_LAUNCH_XLA_GET": 3}
+
+    # rows of three lane tiles: the same phase on a 300-column table
+    _, checks = chip_smoke.phase_kernels(60, 300, 48, interpreted)
+    assert checks["pallas_scatter"] and checks["padded_cols"] == 384
+    assert "bare_kernels" in checks
+    assert checks["row_launches"]["ROW_LAUNCH_PALLAS_ADD"] == 2
 
     trainer, w_in, checks = chip_smoke.phase_trainer(
         60, 16, 64, 64, 2, 2, interpreted)

@@ -275,7 +275,7 @@ def test_device_add_of_an_odd_row_count(kernel, updater, monkeypatch,
     rows, cols, n = 83, 100, 21
     if kernel == "pallas":
         monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
-                            lambda platform, num_shards: num_shards == 1)
+                            lambda platform, num_shards, *width: num_shards == 1)
         monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
         mv.init(mesh_shape="1")
         slots = 24
@@ -324,3 +324,125 @@ def test_xla_scatter_add_takes_the_kernels_call_shape():
     expect = np.zeros((8, 4), np.float32)
     expect[[3, 5]] = -1.0
     np.testing.assert_array_equal(out, expect)
+
+
+def test_word_embedding_table_pair_against_the_reference(monkeypatch):
+    """Two 2,000 x 300 float32 MatrixTables (three lane tiles a row) behind
+    one dispatcher, the interpreted row kernel serving their Adds: a
+    trainer's block through the worker API (Get both, Add both, Get both,
+    device and host forms), every element against the benchmark's plain
+    reference."""
+    import jax
+
+    from benchmark import common
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.tables import matrix_table
+
+    monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                        lambda platform, num_shards, *width: num_shards == 1)
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    # integers from a hash of (seed, table, row, column); imports nothing
+    # of the program
+    ref = common.load_module("reference", "w2v-googlenews-300")
+    rows, cols, n, seed = 2000, 300, 150, 26
+    mv.init(mesh_shape="1")
+    try:
+        rng = np.random.default_rng(seed)
+        tables, mirrors = [], []
+        for index in range(2):
+            init, _ = ref.init_table(rows, cols, seed, index)
+            tables.append(mv.create_table("matrix", rows, cols, np.float32,
+                                          init_value=init))
+            mirrors.append(ref.Mirror(cols, seed, index))
+            server = tables[-1]._server_table
+            assert server._pallas_scatter and server._pallas_interpret
+            assert server.padded_cols == 384 and server.padded_rows % 8 == 0
+            assert tables[-1].get_device().shape == (server.padded_rows, 384)
+        ids = [rng.choice(rows, n, replace=False).astype(np.int32)
+               for _ in tables]
+        deltas = [ref.delta_k(rng, n, cols) for _ in tables]
+        for mirror, i, dk in zip(mirrors, ids, deltas):
+            mirror.add_pool(i, dk)
+        everything = np.arange(rows, dtype=np.int32)
+        for table, mirror, i in zip(tables, mirrors, ids):
+            out = table.wait_device(table.get_device_async(i), i)
+            got = np.asarray(out)[:n, :cols]
+            assert ref.mismatches(got, mirror.rows_k(i, [0])) == 0
+        # the two tables differ: the hash takes the table's index
+        assert ref.mismatches(tables[0].get(), mirrors[1].rows_k(
+            everything, [0])) > rows * cols // 2
+        for table, i, dk in zip(tables, ids, deltas):
+            table.wait(table.add_device_async(
+                jax.device_put(ref.to_float(dk)), i))
+            table.add(ref.to_float(dk), row_ids=i)  # the host form, once
+        for table, mirror in zip(tables, mirrors):
+            assert ref.mismatches(
+                table.get(), mirror.rows_k(everything, [2])) == 0
+            # lanes past column 300 and the rows past the table stay zero
+            data = np.asarray(table.get_device())
+            assert not data[:, cols:].any() and not data[rows:].any()
+    finally:
+        mv.shutdown()
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
+    """Every TABLE_ROW_LAUNCH record says which program served it and
+    carries, beside `n` id slots, the descriptors issued and the bytes of
+    table rows moved; the always-on counters count launches by path and
+    op. 90 x 260 tables (three lane tiles) appear in no other test."""
+    import jax
+
+    from multiverso_tpu import dashboard
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.tables import matrix_table
+
+    rows, cols, lanes, n = 90, 260, 384, 21
+    if kernel == "pallas":
+        monkeypatch.setattr(
+            matrix_table, "_use_pallas_scatter",
+            lambda platform, num_shards, *width: num_shards == 1)
+        monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+        mv.init(mesh_shape="1")
+        # the delta's row groups of 8; the id bucket of a host op is 32
+        add_slots, bucket, path = 24, 32, "pallas"
+    else:
+        mv.init()
+        add_slots = bucket = 64  # max(next_pow2(21), the row group)
+        path = "xla"
+    try:
+        monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
+        counters = {name: dashboard.Dashboard.counter_value(name)
+                    for name in ("ROW_LAUNCH_PALLAS_ADD",
+                                 "ROW_LAUNCH_XLA_ADD", "ROW_LAUNCH_XLA_GET",
+                                 "ROW_LAUNCH_PALLAS_GET")}
+        table = mv.create_table("matrix", rows, cols, np.float32)
+        rng = np.random.default_rng(26)
+        ids = rng.choice(rows, n, replace=False).astype(np.int32)
+        vals = rng.standard_normal((n, cols)).astype(np.float32)
+        t0 = time.perf_counter()
+        table.wait(table.add_device_async(jax.device_put(vals), ids))
+        table.add(vals, row_ids=ids)
+        np.testing.assert_array_equal(table.get(ids), 2 * vals)
+        records, _ = dashboard.RING.window(t0, time.perf_counter())
+        launches = [r for r in records if r.stage == "TABLE_ROW_LAUNCH"]
+        assert [r.path for r in launches] == [path, path, "xla"]
+        device_add, host_add, get = launches
+        assert (device_add.n, host_add.n, get.n) == (add_slots, bucket, bucket)
+        for r, moves in ((device_add, 2), (host_add, 2), (get, 1)):
+            assert r.bytes == moves * r.n * lanes * 4
+            assert r.descriptors == (moves * r.n if r.path == "pallas" else 0)
+        if kernel == "pallas":  # one strided descriptor a row, 3 x 512 bytes
+            assert device_add.bytes // device_add.descriptors == 1536
+        # every other stage leaves the three empty
+        assert all((r.path, r.descriptors, r.bytes) == ("", 0, 0)
+                   for r in records if r.stage != "TABLE_ROW_LAUNCH")
+        grew = {name: dashboard.Dashboard.counter_value(name) - was
+                for name, was in counters.items()}
+        assert grew == {f"ROW_LAUNCH_{path.upper()}_ADD": 2,
+                        "ROW_LAUNCH_XLA_GET": 1,
+                        **{name: 0 for name in counters
+                           if name not in (f"ROW_LAUNCH_{path.upper()}_ADD",
+                                           "ROW_LAUNCH_XLA_GET")}}
+    finally:
+        mv.shutdown()

@@ -374,3 +374,107 @@ def test_hop_adds_a_ring_point_and_keeps_the_trace_store_format(tracing):
     (stage, t_ns), = TRACES.export(8)[77]  # wall clock, [stage, t_ns] pairs
     assert stage == "client_send" and before <= t_ns <= time.time_ns()
     assert 0 not in TRACES.export(8)
+
+
+# -- the row launch's path, descriptors and bytes, and their readers ----------
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_readers_of_the_row_launch_fields(kernel, tracing, monkeypatch):
+    """`pallas_row_share` and `row_descriptor_bytes` read the launch
+    records of the window: all of the Adds the kernel's and 1,536 bytes a
+    descriptor on a table of three lane tiles where the (interpreted)
+    kernel serves; 0% and nothing to read where XLA's scatter does. A
+    program whose records carry no path (the ring of the PR before) gives
+    None."""
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.tables import matrix_table
+
+    if kernel == "pallas":
+        monkeypatch.setattr(
+            matrix_table, "_use_pallas_scatter",
+            lambda platform, num_shards, *width: num_shards == 1)
+        monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+        mv.init(mesh_shape="1")
+    else:
+        mv.init()
+    # 72 x 300 tables appear in no other test
+    table = mv.create_table("matrix", 72, 300, np.float32)
+    t0 = time.perf_counter()
+    for _ in range(3):
+        table.add(np.ones((len(IDS), 300), np.float32), row_ids=IDS)
+        table.get(IDS)
+    run = _served_run(t0)
+    share = _metric("pallas_row_share", run)
+    carried = _metric("row_descriptor_bytes", run)
+    if kernel == "pallas":
+        assert share == 100.0 and carried == 1536.0
+    else:
+        assert share == 0.0 and carried is None
+    # the parent's records: the same window with the three fields gone
+    trace = op_trace.of(run)
+    bare = SimpleNamespace(
+        window=run.window,
+        _op_trace=op_trace.Trace(
+            [SimpleNamespace(**{k: v for k, v in r._asdict().items()
+                                if k not in ("path", "descriptors", "bytes")})
+             for r in trace.records], trace.t0_ns, trace.t1_ns))
+    assert _metric("pallas_row_share", bare) is None
+    assert _metric("row_descriptor_bytes", bare) is None
+    mv.shutdown()
+
+
+def _reduction(raw_ops):
+    """What `trace_reduce.Reduction` gives a reader, as far as the wide
+    row readers look."""
+    import re
+
+    from benchmark import trace_reduce
+
+    def ops_matching(pattern):
+        rx = re.compile(pattern)
+        return [(k, n, s) for k, (n, s) in raw_ops.items()
+                if rx.search(trace_reduce.short_name(k))]
+    return SimpleNamespace(raw_ops=raw_ops, ops_matching=ops_matching)
+
+
+def test_wide_row_readers_count_the_rows_named_at_300_columns():
+    """The scatter's and the gather's share of the roofline on a table of
+    three lane tiles, by hand: 10 Adds and 10 Gets of 100,000 rows of 300
+    float32 columns; event names as the v5e's trace gives them."""
+    scatter = ("%_scatter_add_call.1 = f32[375001,3,8,128]{3,2,1,0:T(8,128)} "
+               "custom-call(s32[131072]{0:T(1024)} %ids.1, "
+               "f32[100000,300]{1,0:T(8,128)} %copy.1, "
+               "f32[375001,3,8,128]{3,2,1,0:T(8,128)} %bitcast.1), "
+               "custom_call_target=\"tpu_custom_call\"")
+    gather = ("%fusion = f32[131072,384]{1,0:T(8,128)} fusion("
+              "f32[3000008,384]{1,0:T(8,128)} %data.1, "
+              "s32[131072]{0:T(1024)S(1)} %fusion.1), kind=kCustom")
+    other = ("%copy.1 = f32[100000,300]{1,0:T(8,128)} copy("
+             "f32[100000,300]{0,1:T(8,128)} %deltas.1)")
+    run = SimpleNamespace(
+        trace=_reduction({scatter: [10, 0.028], gather: [10, 0.020],
+                          other: [10, 0.004]}),
+        result={"add_rows": 1_000_000, "get_rows": 1_000_000,
+                "row_cols": 300},
+        peaks={"hbm_bytes_per_s": 819e9})
+    assert _metric("wide_scatter_device_ms", run) == pytest.approx(2.8)
+    assert _metric("wide_gather_device_ms", run) == pytest.approx(2.0)
+    # 3 x 1,000,000 x 300 x 4 B = 3.6 GB in 28 ms = 128.6 GB/s of 819
+    assert _metric("wide_scatter_roofline", run) == pytest.approx(
+        100 * 3.6e9 / 0.028 / 819e9)
+    # 2 x 1,000,000 x 300 x 4 B = 2.4 GB in 20 ms
+    assert _metric("wide_gather_roofline", run) == pytest.approx(
+        100 * 2.4e9 / 0.020 / 819e9)
+    # fewer slots in the trace than rows named: part of the work is missing
+    run.result["add_rows"] = run.result["get_rows"] = 1_400_000
+    with pytest.raises(ValueError, match="part of the work"):
+        _metric("wide_scatter_roofline", run)
+    with pytest.raises(ValueError, match="part of the work"):
+        _metric("wide_gather_roofline", run)
+    # no traced run, or a trace without the programs: nothing to read
+    run.trace = None
+    assert _metric("wide_scatter_roofline", run) is None
+    assert _metric("wide_gather_device_ms", run) is None
+    run.trace = _reduction({other: [10, 0.004]})
+    assert _metric("wide_gather_roofline", run) is None
+    assert _metric("wide_scatter_device_ms", run) is None

@@ -140,6 +140,15 @@ def test_pallas_scatter_gate_predicate():
     assert _use_pallas_scatter("tpu", 1)
     assert not _use_pallas_scatter("tpu", 8)
     assert not _use_pallas_scatter("cpu", 1)
+    # any number of lane tiles, float32 or narrower, up to the width whose
+    # row group (delta block twice, scratch once) still fits the VMEM budget
+    for lanes in (128, 256, 384, 512, 4096):
+        assert _use_pallas_scatter("tpu", 1, lanes, 4)
+        assert not _use_pallas_scatter("tpu", 4, lanes, 4)
+    widest = pallas_rows.VMEM_BUDGET_BYTES // (3 * ROW_GROUP * 4)
+    assert _use_pallas_scatter("tpu", 1, widest // 128 * 128, 4)
+    assert not _use_pallas_scatter("tpu", 1, widest // 128 * 128 + 128, 4)
+    assert _use_pallas_scatter("tpu", 1, widest // 128 * 128 + 128, 2)
 
 
 def test_matrix_server_multi_shard_add_correct(mv_env):
@@ -155,3 +164,47 @@ def test_matrix_server_multi_shard_add_correct(mv_env):
     ids = np.array([1, 9, 42], np.int32)
     table.add(np.full((3, 16), 2.0, np.float32), row_ids=ids)
     np.testing.assert_allclose(table.get(ids), np.full((3, 16), 2.0))
+
+
+@pytest.mark.parametrize("cols", [129, 256, 300, 384, 512])
+def test_row_kernels_at_widths_past_one_lane_tile(cols, rng, monkeypatch):
+    """A table of two, three or four lane tiles, rows reached through the
+    tile view: unique live ids, sentinel padding up to the id bucket, a
+    delta that ends inside the last row group (the masked tail) and inside
+    the last lane tile (129 and 300 columns), both signs; the rows no id
+    names, and the lanes past the delta's columns, keep their bytes. 43
+    delta rows in a bucket of 64 appear in no other test."""
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    lanes = -(-cols // 128) * 128
+    rows, n, bucket = 200, 43, 64  # 200 rows: 25 whole tiles of 8
+    sentinel = rows - 1
+    table = rng.integers(-99, 99, (rows, lanes)).astype(np.float32)
+    ids = rng.choice(sentinel, n, replace=False).astype(np.int32)
+    ids_p = jnp.asarray(np.concatenate(
+        [ids, np.full(bucket - n, sentinel, np.int32)]))
+    deltas = rng.integers(-9, 9, (n, cols)).astype(np.float32)
+
+    got = np.asarray(gather_rows(jnp.asarray(table), ids_p))
+    np.testing.assert_array_equal(got, table[np.asarray(ids_p)])
+    assert pallas_rows.launched_slots(n) == 48
+    for sign in (1.0, -1.0):
+        expect = table.copy()
+        expect[ids, :cols] += sign * deltas
+        out = scatter_add_rows(jnp.asarray(table), ids_p,
+                               jnp.asarray(deltas), sign=sign)
+        np.testing.assert_array_equal(np.asarray(out), expect)
+
+
+def test_wide_table_needs_whole_tiles_of_rows():
+    """The tile view is a reshape of whole (8, 128) tiles: a wide table
+    whose rows are no multiple of 8 is the caller's error (MatrixServer pads
+    its rows), and so is a delta wider than the table."""
+    ids = jnp.zeros(ROW_GROUP, jnp.int32)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        scatter_add_rows(jnp.zeros((12, 256)), ids, jnp.zeros((1, 256)))
+    with pytest.raises(ValueError, match="multiple of 8"):
+        gather_rows(jnp.zeros((12, 256)), ids)
+    with pytest.raises(ValueError, match="columns"):
+        scatter_add_rows(jnp.zeros((16, 256)), ids, jnp.zeros((1, 300)))
+    with pytest.raises(ValueError, match="lane tiles"):
+        gather_rows(jnp.zeros((16, 200)), ids)
