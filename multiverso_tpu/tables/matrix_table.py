@@ -16,7 +16,12 @@ TPU-native re-design:
   ``Partition`` bucketing loop is gone, XLA partitions the scatter.
 * Row-id batches are padded to power-of-two buckets aimed at a sentinel
   scratch row, so jit traces are reused across batch sizes and the MXU sees
-  static shapes.
+  static shapes. The work follows the rows named, not the bucket: an Add's
+  kernel walks the delta's row groups, and a Get gathers its ids rounded up
+  to a thirty-second of the bucket and 8 (``_live_slots``) and fills the
+  rest of its ``(bucket, padded_cols)`` result with one read of the sentinel
+  row, so a bucket size has at most 16 gather programs and callers see the
+  shape and the sentinel tail they always saw.
 * ``up_to_date`` staleness tracking is host-side metadata (numpy bools):
   it gates *what crosses the host boundary*, which is exactly the resource it
   existed to save; wire compression (SparseFilter) only ever mattered on a
@@ -61,10 +66,42 @@ def _xla_scatter_add(data: jax.Array, ids: jax.Array, deltas: jax.Array,
     return data.at[ids[: deltas.shape[0]]].add(deltas)
 
 
-def _row_gather(data: jax.Array, ids: jax.Array) -> jax.Array:
-    """The table's row Get; named so that its compiled module is
-    ``jit__row_gather`` in a trace."""
-    return data[ids]
+def _live_slots(n: int, bucket: int) -> int:
+    """Slots of a ``bucket`` that a Get of ``n`` ids gathers: ``n`` rounded
+    up to a step of a thirty-second of the bucket (at least 8), so that a
+    bucket size compiles at most 16 gather programs, not one an id count,
+    and at most 3% of it is left aimed at the sentinel (such a slot costs
+    the device three named rows'). Then one group of 8 more: XLA's TPU
+    gather takes its ids in tiles of 1,024 and tiles its rows by 128 where
+    the ids fill their last tile, by 256 where they leave most of it empty,
+    and the second form moves a row in 4.3 ns against 10.3 (PERF.md,
+    Findings, PR 27); a whole number of steps is a whole number of tiles
+    from a bucket of 32,768 up. A count within a step of the bucket gathers
+    the bucket."""
+    step = max(bucket // 32, 8)
+    return min(-(-n // step) * step + 8, bucket)
+
+
+def _row_gather(data: jax.Array, ids: jax.Array,
+                bucket: Optional[int] = None, sentinel: int = 0) -> jax.Array:
+    """The table's row Get: ``(bucket, lanes)`` whose first ``len(ids)``
+    slots are the rows ``ids`` names and whose every later slot is a copy
+    of row ``sentinel``, read once and broadcast (the gather follows the
+    ids, not the bucket). Ids that fill the bucket (or no bucket given)
+    leave the gather alone. Named, like its table parameter, so that the
+    compiled module is ``jit__row_gather`` in a trace and the gather a
+    fusion over ``%data``."""
+    rows = data[ids]
+    tail = (bucket or ids.shape[0]) - ids.shape[0]
+    if not tail:
+        return rows
+    return jnp.concatenate(
+        [rows, jnp.broadcast_to(data[sentinel], (tail, data.shape[1]))])
+
+
+# one jit for every table: the programs are keyed by shapes, bucket and
+# sentinel, and tables of one shape share them
+_row_gather_jit = jax.jit(_row_gather, static_argnames=("bucket", "sentinel"))
 
 
 def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
@@ -156,7 +193,8 @@ class MatrixServer(ServerTable):
         self._whole_update = _make_whole_update(self.updater)
         self._linear = type(self.updater) in (Updater, SGDUpdater)
         self._sign = -1.0 if isinstance(self.updater, SGDUpdater) else 1.0
-        self._gather = jax.jit(_row_gather)
+        self._gather = functools.partial(_row_gather_jit,
+                                         sentinel=self.sentinel_row)
         # device-out gets feed WORKER-thread jits (the word2vec fast
         # path's compact training space): committed to ONE device, the
         # mesh's first, so those jits are single-device programs and every
@@ -168,8 +206,8 @@ class MatrixServer(ServerTable):
         from jax.sharding import SingleDeviceSharding
         first_dev = self.mesh.devices.flat[0]
         _out_dev = SingleDeviceSharding(first_dev)
-        self._gather_out = lambda data, ids: jax.device_put(
-            self._gather(data, ids), _out_dev)
+        self._gather_out = lambda data, ids, bucket: jax.device_put(
+            self._gather(data, ids, bucket=bucket), _out_dev)
         platform = first_dev.platform
         self._pallas_scatter = _use_pallas_scatter(
             platform, num_shards, self.padded_cols, self.dtype.itemsize)
@@ -244,7 +282,8 @@ class MatrixServer(ServerTable):
     def _note_launch(self, launch, op: str, slots: int,
                      pallas: bool) -> None:
         """What a row launch did, on its TABLE_ROW_LAUNCH record and the
-        always-on counters: ``n`` id slots, the program that served them
+        always-on counters: ``n`` id slots (an Add's row groups, the slots
+        a Get gathers: not the bucket), the program that served them
         (``pallas`` or ``xla``), the DMA descriptors the kernel issues for
         them (a read and a write a slot for an Add; XLA's programs issue
         their own, not counted: 0) and the bytes of table rows moved, at
@@ -260,24 +299,29 @@ class MatrixServer(ServerTable):
 
     def _bucket_ids(self, ids: np.ndarray, values: Optional[np.ndarray],
                     ensure_pad: bool = False
-                    ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], int]:
-        """Pad (ids, values) to a power-of-two bucket aimed at the sentinel
-        scratch row so jit traces are shape-stable. ``ensure_pad`` keeps at
-        least one sentinel slot (device-out gets hand the bucket itself to
-        the caller as a compact training space; its masked ops need a
-        guaranteed non-live row)."""
+                    ) -> Tuple[jax.Array, Optional[jax.Array], int, int]:
+        """Pad ``ids`` with sentinel-aimed slots and upload them: ``(ids,
+        values, n, bucket)``. The bucket is the next power of two, so jit
+        traces are shape-stable. An Add (``values`` given) uploads the whole
+        bucket, the values zero-padded to it. A Get uploads the slots it
+        gathers, ``_live_slots`` of them: the rest of its bucket is filled on
+        the device, not fetched. ``ensure_pad`` keeps at least one sentinel
+        slot in the bucket (device-out gets hand the bucket itself to the
+        caller as a compact training space; its masked ops need a guaranteed
+        non-live row)."""
         n = len(ids)
         # min bucket = pallas ROW_GROUP (batch must be a group multiple)
         from multiverso_tpu.ops.pallas_rows import ROW_GROUP
         bucket = max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
-        pad = bucket - n
-        ids_p = np.concatenate([ids, np.full(pad, self.sentinel_row, dtype=ids.dtype)])
+        slots = bucket if values is not None else _live_slots(n, bucket)
+        ids_p = np.concatenate(
+            [ids, np.full(slots - n, self.sentinel_row, dtype=ids.dtype)])
         vals_p = None
         if values is not None:
             padded = np.zeros((bucket, self.padded_cols), dtype=values.dtype)
             padded[:n, : self.num_col] = values
             vals_p = async_upload(padded)
-        return async_upload(ids_p), vals_p, n
+        return async_upload(ids_p), vals_p, n, bucket
 
     # -- server ops --------------------------------------------------------
     def merge_add_requests(self, requests):
@@ -373,7 +417,7 @@ class MatrixServer(ServerTable):
                     from multiverso_tpu.runtime.remote import \
                         merge_duplicate_rows
                     row_ids, values = merge_duplicate_rows(row_ids, values)
-                ids_p, vals_p, prep.n = self._bucket_ids(row_ids, values)
+                ids_p, vals_p, prep.n, _ = self._bucket_ids(row_ids, values)
             with span("TABLE_ROW_LAUNCH") as launch:
                 self._note_launch(launch, "add", ids_p.shape[0],
                                   self._linear and self._pallas_scatter)
@@ -532,13 +576,14 @@ class MatrixServer(ServerTable):
                 # device gets may carry sentinel-aimed pad ids (the compact
                 # training space contract); host/wire gets may not
                 self._check_row_range(row_ids, "get")
-            ids_p, _, n = self._bucket_ids(row_ids, None,
-                                           ensure_pad=device_out)
+            ids_p, _, n, bucket = self._bucket_ids(row_ids, None,
+                                                   ensure_pad=device_out)
             prep.n = n
         with span("TABLE_ROW_LAUNCH") as launch:
+            # the slots gathered, not the bucket the result fills
             self._note_launch(launch, "get", ids_p.shape[0], False)
-            gathered = (self._gather_out if device_out
-                        else self._gather)(self.data, ids_p)
+            gathered = (self._gather_out if device_out else self._gather)(
+                self.data, ids_p, bucket=bucket)
         if self.is_sparse and self._is_worker(option):
             with self._std_lock:
                 self._up_to_date[option.worker_id, row_ids] = True
@@ -559,9 +604,9 @@ class MatrixServer(ServerTable):
         if len(stale) == self.num_row:
             return stale, self._host_read(
                 self.data)[: self.num_row, : self.num_col]
-        ids_p, _, n = self._bucket_ids(stale, None)
-        rows = self._host_read(
-            self._gather(self.data, ids_p))[:n, : self.num_col]
+        ids_p, _, n, bucket = self._bucket_ids(stale, None)
+        rows = self._host_read(self._gather(
+            self.data, ids_p, bucket=bucket))[:n, : self.num_col]
         return stale, rows
 
     def remote_spec(self):
@@ -760,9 +805,14 @@ class MatrixWorker(WorkerTable):
     def get_device_async(self, row_ids: np.ndarray,
                          option: Optional[GetOption] = None) -> int:
         """Async candidate-row pull that stays in HBM. The reply (via
-        ``wait_device``) is a ``(bucket, padded_cols)`` jax.Array whose
-        slots ``>= len(row_ids)`` are sentinel copies — usable directly as
-        a compact training space."""
+        ``wait_device``) is a ``(bucket, padded_cols)`` jax.Array, the
+        bucket the next power of two above ``len(row_ids)``, whose slots
+        ``>= len(row_ids)`` are sentinel copies (at least one) — usable
+        directly as a compact training space. The device gathers the rows
+        named, rounded up to a step of the bucket (``_live_slots``), and
+        fills the rest from one read of the sentinel row: the result's
+        shape follows the bucket alone, so a caller's own jit over it sees
+        one shape a bucket whatever the count of rows it names."""
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()

@@ -410,6 +410,7 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
         mv.init()
         add_slots = bucket = 64  # max(next_pow2(21), the row group)
         path = "xla"
+    gathered = 32  # a Get's 21 ids rounded up to its step of 8, and 8
     try:
         monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
         counters = {name: dashboard.Dashboard.counter_value(name)
@@ -428,7 +429,8 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
         launches = [r for r in records if r.stage == "TABLE_ROW_LAUNCH"]
         assert [r.path for r in launches] == [path, path, "xla"]
         device_add, host_add, get = launches
-        assert (device_add.n, host_add.n, get.n) == (add_slots, bucket, bucket)
+        assert (device_add.n, host_add.n, get.n) == (add_slots, bucket,
+                                                     gathered)
         for r, moves in ((device_add, 2), (host_add, 2), (get, 1)):
             assert r.bytes == moves * r.n * lanes * 4
             assert r.descriptors == (moves * r.n if r.path == "pallas" else 0)
@@ -446,3 +448,97 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
                                            "ROW_LAUNCH_XLA_GET")}}
     finally:
         mv.shutdown()
+
+
+@pytest.mark.parametrize("cols", [100, 300])
+@pytest.mark.parametrize("n", [1, 63, 64, 65, 1000, 1023, 1024, 1025, 3000])
+def test_row_get_contract_around_steps_and_buckets(mv_env, n, cols):
+    """A row Get on a one-tile and on a three-tile table (sharded over the
+    test mesh), id counts on both sides of a gather step and of a bucket:
+    the device-out result is `(bucket, padded_cols)`, its slots below `n`
+    numpy's rows, every slot from `n` up the sentinel row as the table
+    holds it (made non-zero here), at least one of them; sentinel ids that
+    the caller puts inside `row_ids` are served like any row; the host form
+    returns the `n` rows at the table's columns."""
+    from multiverso_tpu.tables.matrix_table import _live_slots
+    from multiverso_tpu.utils import next_pow2
+
+    rows = 3100
+    rng = np.random.default_rng(27 * n + cols)
+    init = rng.standard_normal((rows, cols)).astype(np.float32)
+    table = mv.create_table("matrix", rows, cols, np.float32, init_value=init)
+    server = table._server_table
+    lanes = server.padded_cols
+    assert lanes == (128 if cols == 100 else 384)
+    server.data = server.data.at[server.sentinel_row].set(
+        np.arange(1, lanes + 1, dtype=np.float32))
+    held = np.asarray(server.data)
+    ids = rng.choice(rows, n, replace=False).astype(np.int32)
+
+    out = table.wait_device(table.get_device_async(ids), ids)
+    bucket = max(next_pow2(n + 1), 64)
+    assert out.shape == (bucket, lanes) and out.dtype == np.float32
+    assert len(out.sharding.device_set) == 1
+    out = np.asarray(out)
+    np.testing.assert_array_equal(out[:n], held[ids])
+    np.testing.assert_array_equal(out[:n, :cols], init[ids])
+    assert bucket > n
+    np.testing.assert_array_equal(
+        out[n:], np.broadcast_to(held[server.sentinel_row], (bucket - n, lanes)))
+
+    # the worker proxy refuses ids past the table; the server's device-out
+    # form takes the caller's own sentinel pads (the trainers' contract)
+    mixed = ids.copy()
+    mixed[n // 2] = server.sentinel_row
+    direct = server.process_get((mixed, None, True))
+    assert direct.shape == (bucket, lanes)
+    np.testing.assert_array_equal(np.asarray(direct)[:n], held[mixed])
+    np.testing.assert_array_equal(np.asarray(direct)[n:], out[n:])
+
+    np.testing.assert_array_equal(table.get(ids), init[ids])
+    # what was gathered: the ids rounded up to a step (and 8), never the
+    # bucket's tail unless the ids reach into its last step
+    step = max(bucket // 32, 8)
+    live = _live_slots(n, bucket)
+    assert n <= live <= min(n + step + 7, bucket) and live % 8 == 0
+
+
+def test_row_get_compiles_a_step_not_an_id_count(mv_env):
+    """200 different id counts inside one 4,096-slot bucket compile at most
+    16 gather programs (a trainer names another count every block), each
+    Get equal to numpy's rows; ids that fill their bucket run the plain
+    gather, operation for operation."""
+    import functools
+
+    import jax
+
+    from multiverso_tpu.tables.matrix_table import _live_slots, _row_gather
+
+    rows, cols = 5000, 20
+    init = np.random.default_rng(27).standard_normal(
+        (rows, cols)).astype(np.float32)
+    table = mv.create_table("matrix", rows, cols, np.float32, init_value=init)
+    server = table._server_table
+    # one jit serves every table; 5,000 x 20 tables appear in no other test
+    gather = server._gather.func
+    before = gather._cache_size()
+    counts = np.unique(np.linspace(2049, 4096, 200).astype(int))
+    assert len(counts) == 200
+    lives = set()
+    for n in counts:
+        ids = np.arange(n, dtype=np.int32) + (n % 7)
+        np.testing.assert_array_equal(table.get(ids), init[ids])
+        lives.add(_live_slots(int(n), 4096))
+    assert len(lives) == 16 and max(lives) == 4096 and min(lives) == 2184
+    assert gather._cache_size() - before == 16
+    # a trainer's device-out Gets land in the same programs
+    ids = np.arange(3000, dtype=np.int32)
+    assert table.wait_device(
+        table.get_device_async(ids), ids).shape == (4096, 128)
+    assert gather._cache_size() - before == 16
+
+    data = jax.ShapeDtypeStruct((rows + 8, 128), np.float32)
+    full = jax.ShapeDtypeStruct((4096,), np.int32)
+    assert str(jax.make_jaxpr(functools.partial(
+        _row_gather, bucket=4096, sentinel=rows))(data, full)) == str(
+            jax.make_jaxpr(lambda data, ids: data[ids])(data, full))

@@ -71,3 +71,48 @@ def test_gather_rows_compiles(one_chip, lanes):
     # never a copy of the table: at most the result, once more in row order
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= 131_072 * lanes * 4 + (1 << 20))
+
+
+@pytest.mark.parametrize("rows,lanes", [(10_000_001, 128), (3_000_008, 384)])
+def test_row_get_gathers_the_ids_named_not_the_bucket(one_chip, rows, lanes):
+    """The table's row Get of 100,000 ids in their 131,072-slot bucket, at
+    the benchmark's two table shapes: the result is the bucket; exactly one
+    fusion gathers, over `%data` and an s32 id array (how
+    `benchmark/row_bytes.py` finds it in a trace), of at least the ids
+    named and under one step more; the fill pass is not such a fusion; the
+    only temporary is the gathered rows (no second copy of the result);
+    and the compiler tiles that gather's rows by 256, the form the chip ran
+    2.4 times as fast as the 128 it picks for a whole number of id tiles
+    (PERF.md, Findings, PR 27)."""
+    import re
+
+    from benchmark.row_bytes import GATHER_EVENT
+    from multiverso_tpu.tables.matrix_table import (_live_slots,
+                                                    _row_gather_jit)
+
+    named, bucket = 100_000, 131_072
+    live = _live_slots(named, bucket)
+    assert named <= live < named + bucket // 32
+    # the table's own jit: the module and its parameters keep the names a
+    # trace is read by
+    compiled = _row_gather_jit.lower(
+        jax.ShapeDtypeStruct((rows, lanes), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((live,), jnp.int32, sharding=one_chip),
+        bucket=bucket, sentinel=rows - 8).compile()
+    # the text as a trace names its events: operands with their shapes
+    from jax._src.lib import xla_client
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    options.print_percent = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(options)
+    assert text.startswith("HloModule jit__row_gather")
+    assert f"->f32[{bucket},{lanes}]" in text.splitlines()[0]
+    entry = text[text.index("ENTRY"):]
+    gathers = [line for line in entry.splitlines()
+               if GATHER_EVENT.search(line)]
+    assert len(gathers) == 1, gathers
+    assert int(GATHER_EVENT.search(gathers[0]).group(1)) == live
+    assert "kind=kCustom" in gathers[0]
+    assert re.search(r'"integer_config":\{"integer":"256"\}', gathers[0])
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= live * lanes * 4 + (1 << 20))
