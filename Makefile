@@ -8,6 +8,10 @@
 #   make smoke          chip_smoke.py: the main path on the TPU this
 #                       process holds, every phase checked against numpy;
 #                       fails without a TPU
+#   make cells          print the benchmark's command for every cell of
+#                       BENCHMARK.json (benchmark/run.py, the one the
+#                       driver runs on the chip; runs nothing itself:
+#                       a run fails without a TPU; benchmark/README.md)
 #   make lint           mvlint project-invariant static analysis (blocking
 #                       in CI; docs/static_analysis.md)
 #   make native         just the C++ layer (libmultiverso_tpu.so + C client)
@@ -35,15 +39,6 @@
 #                       answers, attribution table is non-empty
 #                       (docs/observability.md §13)
 #   make dryrun         multi-chip sharding compile+execute check (CPU mesh)
-#   make bench          the in-process device legs, one JSON line; fails
-#                       without a TPU
-#   make wire-bench     wire micro-bench only: codec ratio, TCP RTT and
-#                       bandwidth, served KV Adds, coalesced vs per-frame
-#   make profile-bench  sampling-profiler overhead A/B on the dense pass
-#   make apply-bench    apply-path micro-bench only: fused vs per-message
-#                       A/B, batch-size sweep, shm vs TCP RTT/throughput
-#   make read-bench     read-path A/B only: Zipf hot-key Gets, primary vs
-#                       replica vs replica+cache vs hedged
 #   make tiered         beyond-RAM tiered-storage smoke: cold-segment
 #                       codec, admission/LRU policy, tiered-vs-plain
 #                       equivalence, SIGKILL-mid-demotion recovery drill
@@ -55,24 +50,16 @@
 #                       (MV_CUT_KILL=coordinator|shard arms the
 #                       kill-mid-cut chaos drills; docs/fault_tolerance.md
 #                       §8, docs/observability.md §14)
-#   make audit-bench    auditor-overhead A/B + one timed consistent cut
-#                       against a live 2-shard group
 #   make autopilot      fleet-autopilot suite: policy hysteresis/cooldown,
 #                       divergence interlock freeze/ack, Zipf hotspot
 #                       split+replica drill with zero acked-Add loss
 #                       (MV_AUTOPILOT_KILL=before|mid arms the
 #                       kill-mid-action chaos drill; docs/autopilot.md)
-#   make autopilot-bench  Zipf hotspot shift against a live group:
-#                       time-to-split, p99 recovery, acked-Add
-#                       conservation
 #   make overload       overload-survival suite: deadline propagation,
 #                       priority lanes + admission shedding + tenant
 #                       quotas, retry budget + circuit breaker, stall
 #                       gray-failure chaos, and the train-while-serve
 #                       drill (docs/fault_tolerance.md §9)
-#   make overload-bench overload leg only: shed rate, per-lane p99s,
-#                       retry-budget denials, acked-Add conservation
-#                       under a stalled shard (BENCH_r11.json)
 #   make chargeback     per-tenant chargeback plane: tenant-resolved
 #                       tracing, cost attribution + labeled exposition,
 #                       burn-driven deadline tightening, and the live
@@ -82,35 +69,30 @@
 #                       shard merge vs single-shard oracle, replica-served
 #                       queries with zero primary dispatches
 #                       (docs/serving.md §8)
-#   make query-bench    query leg only: tiered cold-scan QPS/p99 with the
-#                       no-promotion proof + replica-served query QPS/p99
-#                       with zero primary dispatches (BENCH_r13.json)
 #   make autotune       self-tuning suite: config watch seam, live-knob
 #                       re-reads, sensor fusion, rule table, the
 #                       propose→step→verify→revert controller, the
 #                       autotune-off bit-identity contract
 #                       (docs/autotune.md)
-#   make autotune-bench self-tuning A/B only: hand-tuned-best static
-#                       posture vs the KnobController on the identical
-#                       storm, verdict via --compare with the same-env
-#                       refusal armed (BENCH_r14.json; the tuner's
-#                       audit trail lands in BENCH_autotune_flight.jsonl)
 
 PYTHON ?= python
 CPU_ENV := JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 CHAOS_SEED ?= 7
 
-.PHONY: check smoke lint chaos failover sharded replicas reshard \
-	metrics-smoke profile-smoke native test dryrun bench wire-bench \
-	profile-bench apply-bench read-bench tiered audit audit-bench autopilot \
-	autopilot-bench overload overload-bench chargeback query query-bench \
-	autotune autotune-bench clean
+.PHONY: check smoke cells lint chaos failover sharded replicas reshard \
+	metrics-smoke profile-smoke native test dryrun tiered audit autopilot \
+	overload chargeback query autotune clean
 
 check: lint native test dryrun profile-smoke tiered audit autopilot \
 	overload chargeback query autotune
 
 smoke:
 	$(PYTHON) chip_smoke.py
+
+cells:
+	@$(PYTHON) -c "import json; b = json.load(open('BENCHMARK.json')); \
+	[print(*b['command'], '--workload', w['name'], '--seed', 1, \
+	'--seconds', b['run_seconds'], '--trace', 0) for w in b['workloads']]"
 
 lint:
 	$(PYTHON) -m tools.mvlint
@@ -161,21 +143,6 @@ reshard:
 dryrun:
 	$(CPU_ENV) $(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun_multichip(8): ok')"
 
-bench:
-	$(PYTHON) bench.py
-
-wire-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --wire-bench
-
-profile-bench:
-	$(PYTHON) bench.py --profile-bench
-
-apply-bench:
-	$(PYTHON) bench.py --apply-bench
-
-read-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --read-bench
-
 tiered:
 	$(CPU_ENV) $(PYTHON) -m pytest tests/test_tiered.py -q \
 		-p no:cacheprovider -p no:randomly
@@ -185,22 +152,13 @@ audit:
 		tests/test_migrate_unit.py -q \
 		-p no:cacheprovider -p no:randomly
 
-audit-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --audit-bench
-
 autopilot:
 	$(CPU_ENV) $(PYTHON) -m pytest tests/test_autopilot.py -q \
 		-p no:cacheprovider -p no:randomly
 
-autopilot-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --autopilot-bench
-
 overload:
 	$(CPU_ENV) $(PYTHON) -m pytest tests/test_overload.py -q \
 		-p no:cacheprovider -p no:randomly
-
-overload-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --overload-bench
 
 chargeback:
 	$(CPU_ENV) $(PYTHON) -m pytest tests/test_chargeback.py -q \
@@ -211,15 +169,9 @@ query:
 		-p no:cacheprovider -p no:randomly
 	$(CPU_ENV) $(PYTHON) examples/word2vec_query.py
 
-query-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --query-bench
-
 autotune:
 	$(CPU_ENV) $(PYTHON) -m pytest tests/test_autotune.py -q \
 		-p no:cacheprovider -p no:randomly
-
-autotune-bench:
-	$(CPU_ENV) $(PYTHON) bench.py --autotune-bench
 
 clean:
 	$(MAKE) -C multiverso_tpu/native clean

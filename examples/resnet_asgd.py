@@ -33,7 +33,7 @@ SHAPE, CLASSES, N, BATCH, EPOCHS = (16, 16, 3), 4, 1024, 64, 3
 
 def _force(state):
     """Fetch-force: a dependent device→host read cannot return before the
-    work it depends on has run (see bench.py's timing note)."""
+    work it depends on has run."""
     np.asarray(jax.tree.leaves(state["params"])[0])
 
 

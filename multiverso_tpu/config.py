@@ -360,7 +360,7 @@ define_int("tier_admit_touches", 2,
 define_string("metrics_path", "",
               "append periodic JSONL dashboard snapshots (monitors, "
               "counters, gauges, histograms as bucket arrays) to this file "
-              "— the format bench.py's load_metrics ingests. Empty disables "
+              "— the format obs/logger.load_metrics ingests. Empty disables "
               "the MetricsLogger thread")
 define_double("metrics_interval_seconds", 10.0,
               "seconds between metrics_path snapshot lines")
@@ -550,7 +550,7 @@ define_bool("autotune", False,
             "runtime (no thread, no TUNE_* metrics)")
 define_double("autotune_interval_seconds", 2.0,
               "KnobController tick period; <= 0 disables the background "
-              "thread (tick_now() still works for drills and bench legs)")
+              "thread (tick_now() still works for drills)")
 define_double("autotune_window_seconds", 10.0,
               "observation window the tuner's sensors read wait-site "
               "deltas, rates and latency quantiles over (also the "

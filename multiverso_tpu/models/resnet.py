@@ -297,7 +297,7 @@ def synthetic_cifar(n: int, num_classes: int = 10, seed: int = 0,
                     shape=(32, 32, 3)) -> Tuple[np.ndarray, np.ndarray]:
     """Learnable CIFAR-shaped task: each class is a fixed random spatial
     template plus noise — linearly separable in principle but requiring a
-    real forward pass to fit. Used by tests and the bench."""
+    real forward pass to fit. Used by tests and examples."""
     rng = np.random.default_rng(seed)
     templates = rng.normal(size=(num_classes,) + shape).astype(np.float32)
     labels = rng.integers(0, num_classes, n)

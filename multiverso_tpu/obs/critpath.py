@@ -16,8 +16,7 @@ so attribution is a segment decomposition:
 largest segment, and :func:`attribute` aggregates a whole trace-store
 pull into an :class:`AttributionReport` — the "p99 Get: 61% replica
 apply-lag wait, 22% wire" table the self-tuning controller (ROADMAP)
-needs.  ``mv.attribution(fleet)`` is the front door; ``bench.py
---attribute`` attaches the same table to every bench leg.
+needs.  ``mv.attribution(fleet)`` is the front door.
 
 Clock-offset correction happens upstream in the collector; this module
 only trusts the corrected timestamps (negative gaps from residual skew

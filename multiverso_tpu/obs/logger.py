@@ -1,9 +1,9 @@
-"""Periodic JSONL metrics snapshots — the bench/offline-analysis feed.
+"""Periodic JSONL metrics snapshots — the offline-analysis feed.
 
 A :class:`MetricsLogger` thread appends one JSON object per interval to
 the ``metrics_path`` file: wall-clock timestamp plus the full dashboard
 snapshot (monitors, counters, gauges, histograms as bucket arrays). The
-format is what ``bench.py``'s :func:`load_metrics` ingests and what
+format is what :func:`load_metrics` ingests and what
 ``make metrics-smoke`` asserts over; ``mv.init`` starts the thread when
 the ``metrics_path`` flag is set and ``mv.shutdown`` writes a final
 snapshot and stops it.

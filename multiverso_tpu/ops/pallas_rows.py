@@ -112,20 +112,17 @@ current machine):
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# rows (= concurrent DMAs) per grid step; env-overridable for sweeps —
-# see the optimization record above for the measured sweep
-ROW_GROUP = int(os.environ.get("MVTPU_ROW_GROUP", "64"))
-if ROW_GROUP <= 0 or ROW_GROUP & (ROW_GROUP - 1):
-    # bucket sizes are powers of two >= the group; a non-power-of-two group
-    # would silently violate the batch-multiple contract and drop updates
-    raise ValueError(f"MVTPU_ROW_GROUP must be a power of two, got {ROW_GROUP}")
+# rows (= concurrent DMAs) per grid step: a power of two, because bucket
+# sizes are powers of two >= the group and any other group would violate
+# the batch-multiple contract and drop updates. See the optimization record
+# above for the measured sweep; a new sweep edits this line on a scratch copy.
+ROW_GROUP = 64
 
 
 def interpret_for(platform: str) -> bool:

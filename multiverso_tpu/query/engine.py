@@ -4,9 +4,13 @@ One ordering contract rules every path in this module AND the shard
 router's merge: candidates rank by score DESCENDING, ties by global id
 ASCENDING (``np.lexsort((ids, -scores))`` per query row). Because the
 single-table engine and the per-shard merge both finish with exactly
-this ordering, a global top-k assembled from per-shard partials is
-bit-identical — ids and score order — to a single-shard oracle over the
-same rows, including at tie boundaries.
+this ordering, a global top-k assembled from per-shard partials names
+the ids, in the order, of a single-shard oracle over the same rows,
+including at tie boundaries. Scores are float32 at full precision on
+every path; where every float32 sum is exact (``dot`` over integer-valued
+rows) they agree to the bit, and ``cosine`` scores agree to 1e-6 (a cosine
+is at most 1): a shard's block, the whole table's and numpy's sum in
+different orders.
 
 Three serving shapes:
 
@@ -124,7 +128,9 @@ def _topk_kernel(block, vecs, k: int, cosine: bool):
             jnp.linalg.norm(q, axis=1, keepdims=True), _EPS)
         b = b / jnp.maximum(
             jnp.linalg.norm(b, axis=1, keepdims=True), _EPS)
-    scores = q @ b.T
+    # float32 all the way: a TPU's default matmul is one bfloat16 pass,
+    # which cannot hold the ordering contract against a float32 oracle
+    scores = jnp.matmul(q, b.T, precision=jax.lax.Precision.HIGHEST)
     return jax.lax.top_k(scores, k)
 
 

@@ -64,7 +64,8 @@ from multiverso_tpu.runtime.message import Message, MsgType, next_msg_id
 from multiverso_tpu.runtime.net import TcpNet
 from multiverso_tpu.runtime import wire
 from multiverso_tpu.tables.array_table import ArrayWorker
-from multiverso_tpu.tables.base import Completion, WorkerTable
+from multiverso_tpu.tables.base import (Completion, WorkerTable,
+                                        merge_duplicate_rows)
 from multiverso_tpu.tables.kv_table import KVWorker
 from multiverso_tpu.tables.matrix_table import MatrixWorker
 from multiverso_tpu.tables.sparse_table import SparseWorker
@@ -183,8 +184,7 @@ class _QueryCompletion(_ReadCompletion):
     """Completion for a slot-free Request_Query on the primary: replies
     Reply_Query stamped with the append watermark. Idempotent like a
     read — no dedup entry; a replayed query just re-scores. The done
-    counter is the query plane's zero-primary-dispatch proof
-    (BENCH_r13 holds the read tier's bar on it)."""
+    counter is the query plane's zero-primary-dispatch proof."""
 
     __slots__ = ()
 
@@ -1668,34 +1668,6 @@ def _make_error_feedback(shape, dtype) -> Optional[Any]:
         return None
     from multiverso_tpu.utils.quantization import ErrorFeedback
     return ErrorFeedback(shape, bits)
-
-
-def merge_duplicate_rows(ids: np.ndarray, values: np.ndarray):
-    """Pre-aggregate duplicate row ids so every touched row's error-
-    feedback residual is read and written exactly once — duplicates would
-    otherwise share one residual read and last-write the update,
-    permanently losing part of the feedback. Shared by the per-proxy EF
-    path, the shard router's per-shard EF path, and the dispatcher's
-    fused-apply merge (tables.matrix_table.merge_add_requests).
-
-    Implementation note: copy each unique id's FIRST row, then sum only
-    the (few) genuinely duplicated groups — NOT ``np.add.at`` (the
-    unbuffered ufunc.at path) or ``np.add.reduceat`` over 2-D rows, both
-    of which cost more on row-matrix payloads than the fused scatter
-    they feed saves (measured 6 ms / 12 ms vs ~1 ms per 6k×128 merge)."""
-    id_arr = np.asarray(ids)
-    uniq, inverse, counts = np.unique(id_arr, return_inverse=True,
-                                      return_counts=True)
-    if len(uniq) == len(id_arr):
-        return ids, values
-    values = np.asarray(values)
-    order = np.argsort(inverse, kind="stable")
-    starts = np.cumsum(counts) - counts
-    merged = values[order[starts]]  # fancy index: a fresh writable array
-    for g in np.nonzero(counts > 1)[0]:
-        s = starts[g]
-        merged[g] = values[order[s:s + counts[g]]].sum(axis=0)
-    return uniq.astype(id_arr.dtype, copy=False), merged
 
 
 class _RemoteArrayWorker(ArrayWorker):

@@ -44,6 +44,7 @@ from multiverso_tpu.runtime.admission import resolve_tenant
 from multiverso_tpu.runtime.message import MsgType, next_msg_id
 from multiverso_tpu.shard.partition import (RangePartitioner,
                                             partitioner_from_spec)
+from multiverso_tpu.tables.base import merge_duplicate_rows
 from multiverso_tpu.updaters import AddOption, GetOption
 from multiverso_tpu.utils.backoff import Backoff
 
@@ -415,7 +416,6 @@ def dedup_add_ids(kind: str, request: Any) -> Any:
     ids, values, option = request
     if ids is None:
         return request
-    from multiverso_tpu.runtime.remote import merge_duplicate_rows
     ids_arr = np.asarray(ids).reshape(-1)
     vals = np.asarray(values, np.float32).reshape(len(ids_arr), -1)
     ids2, vals2 = merge_duplicate_rows(ids_arr, vals)

@@ -42,7 +42,8 @@ from multiverso_tpu import log
 from multiverso_tpu.dashboard import Dashboard, monitor, span
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.runtime.zoo import Zoo
-from multiverso_tpu.tables.base import ServerTable, WorkerTable
+from multiverso_tpu.tables.base import (ServerTable, WorkerTable,
+                                        merge_duplicate_rows)
 from multiverso_tpu.tables.array_table import _make_whole_update
 from multiverso_tpu.updaters import AddOption, GetOption, SGDUpdater, Updater, get_updater
 from multiverso_tpu.utils import async_upload, next_pow2 as _next_pow2
@@ -329,7 +330,7 @@ class MatrixServer(ServerTable):
         (ids, values) across the group and hand back one request whose
         apply is a single jitted/pallas scatter_add. Duplicate rows are
         pre-aggregated client-style INSIDE ``process_add`` (the shared
-        ``remote.merge_duplicate_rows``) exactly when the apply path
+        ``tables.base.merge_duplicate_rows``) exactly when the apply path
         requires unique ids — the pallas in-place row-DMA kernel and
         stateful updaters; XLA's scatter-add handles duplicates natively,
         so the linear non-pallas path skips the host-side aggregation
@@ -413,9 +414,6 @@ class MatrixServer(ServerTable):
                 # aggregation (fused micro-batches from the dispatcher
                 # concatenate without dedup for exactly this reason)
                 if not (self._linear and not self._pallas_scatter):
-                    # lazy import: remote imports this module (worker proxies)
-                    from multiverso_tpu.runtime.remote import \
-                        merge_duplicate_rows
                     row_ids, values = merge_duplicate_rows(row_ids, values)
                 ids_p, vals_p, prep.n, _ = self._bucket_ids(row_ids, values)
             with span("TABLE_ROW_LAUNCH") as launch:

@@ -3,7 +3,7 @@
 MetricsLogger on, then assert the JSONL snapshot stream parses and the
 key latency histograms are non-empty — the end-to-end contract between
 the telemetry flags (``metrics_path`` / ``metrics_interval_seconds``),
-the Dashboard registry, and ``bench.py``'s ingestion format
+the Dashboard registry, and the ingestion format
 (``obs/logger.py:load_metrics``). Runs standalone (not a pytest module):
 
     JAX_PLATFORMS=cpu python tests/metrics_smoke.py [out.jsonl]

@@ -380,7 +380,7 @@ def test_metrics_logger_jsonl_round_trip(tmp_path):
     hist = last["histograms"]["LOGGED_HIST_SECONDS"]
     assert hist["count"] == 1 and len(hist["buckets"]) == len(hist["bounds"])
     # the serialized form rebuilds into a quantile-capable histogram —
-    # the bench.py ingestion contract
+    # the obs/logger.load_metrics ingestion contract
     rebuilt = Histogram.from_dict("LOGGED_HIST_SECONDS", hist)
     assert rebuilt.p50 == Dashboard.histogram("LOGGED_HIST_SECONDS").p50
 

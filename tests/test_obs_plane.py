@@ -18,8 +18,6 @@ Covers the plane's charter:
   ``Dashboard.reset()`` straddling the window;
 * the flight recorder's per-reason rate limit + output-size cap
   (``FLIGHT_DUMPS_SUPPRESSED``);
-* ``bench.py --compare`` regression verdicts and exit codes, plus the
-  environment-fingerprint warn / ``--require-same-env`` refusal path;
 * ``mv.stats_all`` partial results with a killed replica;
 * ACCEPTANCE: one Get through a 2-shard x 1-replica fleet with
   ``read_preference=replica`` yields a single stitched trace with >= 6
@@ -31,7 +29,6 @@ Covers the plane's charter:
 
 import json
 import os
-import sys
 import threading
 import time
 
@@ -388,76 +385,6 @@ def test_flight_recorder_size_cap_suppresses(tmp_path):
     assert Dashboard.counter_value("FLIGHT_DUMPS_SUPPRESSED") == before + 2
     mv.set_flag("flight_recorder_max_bytes", 64 << 20)
     assert rec.dump("crc_reject") == path          # headroom back -> writes
-
-
-# -- bench --compare regression gate ------------------------------------------
-
-def test_bench_compare_verdicts_and_exit_codes(tmp_path):
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    a = {"ps_words_per_sec": 100_000.0, "ps_get_p99_us": 50.0,
-         "wire_rtt_us": 100.0, "note": "baseline", "n": 1}
-    ok = {**a, "ps_words_per_sec": 98_000.0, "note": "candidate"}
-    bad = {**a, "ps_words_per_sec": 70_000.0, "ps_get_p99_us": 80.0}
-    pa, pok, pbad = (str(tmp_path / f"{n}.json")
-                     for n in ("a", "ok", "bad"))
-    for payload, dst in ((a, pa), (ok, pok),
-                         # candidate may arrive as a BENCH_r*.json
-                         # round wrapper
-                         ({"n": 9, "rc": 0, "parsed": bad}, pbad)):
-        with open(dst, "w") as fh:
-            json.dump(payload, fh)
-    assert bench.bench_compare(pa, pok, threshold=0.10) == []
-    regressed = bench.bench_compare(pa, pbad, threshold=0.10)
-    assert set(regressed) == {"ps_words_per_sec", "ps_get_p99_us"}
-    # a looser threshold forgives the -30% throughput drop but still
-    # catches the +60% latency rise
-    assert bench.bench_compare(pa, pbad, threshold=0.40) == [
-        "ps_get_p99_us"]
-    assert bench._run_compare(["bench.py", "--compare", pa, pok]) == 0
-    assert bench._run_compare(["bench.py", "--compare", pa, pbad]) == 1
-    assert bench._run_compare(["bench.py", "--compare", pa]) == 2
-
-
-def test_bench_compare_env_fingerprint_warn_and_refuse(tmp_path, capsys):
-    """Cross-environment comparisons warn (or refuse under
-    ``--require-same-env``): a Mac-vs-TPU "regression" is not evidence."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    env_a = {"hostname": "laptop", "nproc": 8, "jax_backend": "cpu",
-             "device_kind": "cpu", "device_count": 1}
-    env_b = {**env_a, "hostname": "tpu-vm", "device_kind": "TPU v4",
-             "device_count": 4}
-    a = {"ps_words_per_sec": 100_000.0, "env": env_a}
-    b = {"ps_words_per_sec": 100_000.0, "env": env_b}
-    pa, pb = str(tmp_path / "a.json"), str(tmp_path / "b.json")
-    for payload, dst in ((a, pa), (b, pb)):
-        with open(dst, "w") as fh:
-            json.dump(payload, fh)
-    assert bench._env_mismatch(env_a, env_b) == [
-        "device_count", "device_kind", "hostname"]
-    # the env dict itself is NOT a compared metric: no bogus regressions
-    assert bench.bench_compare(pa, pb, threshold=0.10) == []
-    out = capsys.readouterr().out
-    assert "WARNING: environment fingerprints differ" in out
-    assert "device_kind: A='cpu'  B='TPU v4'" in out
-    # refuse-or-warn: --require-same-env turns the warning into exit 2
-    assert bench._run_compare(
-        ["bench.py", "--compare", pa, pb, "--require-same-env"]) == 2
-    err = capsys.readouterr().err
-    assert "refusing to compare" in err
-    # same env (or a pre-fingerprint file with none): no warning, exit 0
-    assert bench._run_compare(
-        ["bench.py", "--compare", pa, pa, "--require-same-env"]) == 0
-    assert "WARNING" not in capsys.readouterr().out
-    del a["env"]
-    with open(pa, "w") as fh:
-        json.dump(a, fh)
-    assert bench._env_mismatch(bench._load_bench_env(pa), env_b) == []
-    assert bench._run_compare(
-        ["bench.py", "--compare", pa, pb, "--require-same-env"]) == 0
 
 
 # -- fleet acceptance: stitched trace + partial stats --------------------------

@@ -21,7 +21,6 @@ chaos mode):
   gracefully, zero acked-Add loss, breaker trips and recovers.
 """
 
-import os
 import random
 import threading
 import time
@@ -38,6 +37,7 @@ from multiverso_tpu.runtime.admission import (AdmissionGate, TenantQuotas,
                                               LANE_TRAINING)
 from multiverso_tpu.runtime.message import Message, MsgType
 from multiverso_tpu.utils.backoff import Backoff, full_jitter
+from traffic_gen import TrafficGen
 
 
 # -- backoff helper (satellite: unified retry loops) --------------------------
@@ -466,15 +466,11 @@ def test_stall_slow_peer_survives_end_to_end():
 
 def test_overload_drill_train_while_serve(monkeypatch):
     """2-shard group, stall gray failure on shard 1's primary, a write
-    storm plus a read flood (the bench TrafficGen op mix): serving reads
+    storm plus a read flood (TrafficGen's Zipf keys): serving reads
     stay answered within a generous SLO, training writes shed gracefully
     (SHED_* counted, nothing errored), zero acked-Add loss — the sum of
     applied + shed equals exactly the completions the writers saw — and
     the client breaker trips on the stalled shard and recovers."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import TrafficGen
     from multiverso_tpu.shard.group import ShardGroup
 
     monkeypatch.setenv("MV_CHAOS_SHARD", "1")
@@ -510,8 +506,7 @@ def test_overload_drill_train_while_serve(monkeypatch):
         def writer(shard, seed):
             # the CTR-style training stream: Zipf-skewed single-row Adds
             # confined to one shard's span, unthrottled (the storm)
-            gen = TrafficGen(span, zipf_s=1.2, read_fraction=0.0,
-                             seed=seed)
+            gen = TrafficGen(span, zipf_s=1.2, seed=seed)
             vals = np.ones((1, cols), np.float32)
             ids = np.zeros(1, np.int32)
             while not stop.is_set():
@@ -529,7 +524,7 @@ def test_overload_drill_train_while_serve(monkeypatch):
 
         def reader():
             # the serving flood: hot-key Gets against the HEALTHY shard
-            gen = TrafficGen(span, zipf_s=1.2, read_fraction=1.0, seed=42)
+            gen = TrafficGen(span, zipf_s=1.2, seed=42)
             ids = np.zeros(1, np.int32)
             while not stop.is_set():
                 ids[0] = gen.draw_key()  # rows [0, span): shard 0

@@ -29,6 +29,7 @@ from multiverso_tpu.io import MemoryStream
 from multiverso_tpu.store import ColdStore, FrequencySketch, TieredStore
 from multiverso_tpu.tables.kv_table import KVServer, TieredKVServer
 from multiverso_tpu.tables.sparse_table import SparseServer, TieredSparseServer
+from traffic_gen import TrafficGen
 
 _CHILD = os.path.join(os.path.dirname(__file__), "tiered_kill_child.py")
 
@@ -196,26 +197,57 @@ def test_tiered_quant_integer_grid_survives_demotion_exactly(tmp_path):
 
 # -- tiered servers: equivalence with the in-RAM tables -----------------------
 
-def test_tiered_sparse_server_matches_plain_sparse(tmp_path):
-    plain = SparseServer(10_000, width=4)
-    tiered = TieredSparseServer(10_000, width=4, resident_bytes=6 * 4 * 4,
+def _uniform_rounds(rng, key_space):
+    """30 rounds of up to 11 uniform keys against a budget of 6 rows:
+    nearly every row written ends cold."""
+    for _ in range(30):
+        n = int(rng.integers(1, 12))
+        yield (rng.integers(0, key_space, n).astype(np.int64),
+               rng.integers(0, key_space, 8).astype(np.int64))
+
+
+def _zipf_rounds(rng, key_space):
+    """Every key written once (the table is 8x its budget), then 200
+    rounds of Zipf(1.1) traffic, one Add to nineteen Gets."""
+    keys = np.arange(key_space, dtype=np.int64)
+    for start in range(0, key_space, 500):
+        yield keys[start:start + 500], keys[start:start + 8]
+    gen = TrafficGen(key_space, zipf_s=1.1, seed=3)
+    for _ in range(200):
+        yield (np.array([gen.draw_key()], np.int64),
+               np.array([gen.draw_key() for _ in range(19)], np.int64))
+
+
+@pytest.mark.parametrize("key_space,hot_rows,rounds,min_hot_hit_rate", [
+    (10_000, 6, _uniform_rounds, 0.0),
+    # skewed reads of a table 8x over budget: admission and LRU keep
+    # the hot set hot (0.59 over the first 1,000 Gets, 0.78 by the last)
+    (4_000, 500, _zipf_rounds, 0.5),
+], ids=["uniform-6-rows", "zipf-8x-over-budget"])
+def test_tiered_sparse_server_matches_plain_sparse(
+        tmp_path, key_space, hot_rows, rounds, min_hot_hit_rate):
+    Dashboard.reset()
+    plain = SparseServer(key_space, width=4)
+    tiered = TieredSparseServer(key_space, width=4,
+                                resident_bytes=hot_rows * 4 * 4,
                                 cold_bits=0,
                                 tier_dir=str(tmp_path / "tier"))
     rng = np.random.default_rng(2)
-    for _ in range(30):
-        n = int(rng.integers(1, 12))
-        keys = rng.integers(0, 10_000, n).astype(np.int64)
-        vals = rng.normal(0, 1, (n, 4)).astype(np.float32)
+    for keys, probe in rounds(rng, key_space):
+        vals = rng.normal(0, 1, (len(keys), 4)).astype(np.float32)
         for srv in (plain, tiered):
             srv.process_add((keys, vals, None))
-        probe = rng.integers(0, 10_000, 8).astype(np.int64)
         np.testing.assert_array_equal(plain.process_get((probe, None)),
                                       tiered.process_get((probe, None)))
+    hot = Dashboard.counter_value("TIER_HOT_HITS")
+    cold = Dashboard.counter_value("TIER_COLD_HITS")
+    assert hot / max(hot + cold, 1) >= min_hot_hit_rate
     lk_p, lv_p = plain.process_get((None, None))
     lk_t, lv_t = tiered.process_get((None, None))
     np.testing.assert_array_equal(lk_p, lk_t)
     np.testing.assert_array_equal(lv_p, lv_t)
-    assert tiered.tier_stats()["cold_rows"] > 0  # it really spilled
+    stats = tiered.tier_stats()
+    assert stats["cold_rows"] > stats["hot_rows"]  # it really spilled
     tiered._tier.close()
 
 
@@ -279,19 +311,6 @@ def test_tiered_sparse_worker_via_dispatcher(mv_env, tmp_path):
     stats = t._server_table.tier_stats()
     assert stats["hot_rows"] + stats["cold_rows"] == 64
     assert stats["cold_rows"] > 0
-
-
-def test_bench_tiered_smoke():
-    """A miniature bench_tiered() run: the leg must produce the metric
-    keys CI's --compare step diffs, with a sane hit rate on a table 8x
-    over budget."""
-    import bench
-    out = bench.bench_tiered(key_space=20_000, width=4, ratio=8,
-                             ops=3_000, zipf_s=1.1)
-    assert out["tiered_size_ratio"] >= 8.0
-    assert out["tiered_cold_rows"] > out["tiered_hot_rows"]
-    assert 0.5 <= out["tiered_hot_hit_rate"] <= 1.0
-    assert out["tiered_ops_per_sec"] > 0
 
 
 # -- MV_TIER_KILL drill: SIGKILL mid-demotion, recover, exactly-once ----------
