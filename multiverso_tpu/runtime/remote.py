@@ -32,7 +32,12 @@ so the whole path runs under seeded fault injection via config flags.
 Payloads ride the :mod:`multiverso_tpu.runtime.wire` codec; float32 arrays
 are SparseFilter-compressed when the ``wire_compression`` flag is on and the
 sparse form is smaller (the reference applied SparseFilter on exactly these
-host hops, ``src/table/sparse_matrix_table.cpp:147-153``).
+host hops, ``src/table/sparse_matrix_table.cpp:147-153``). The codec decides
+that from one count of the array's nonzeros and runs the encoder only where
+its output is what is sent: a dense payload (a Get's reply on the dispatcher
+thread, a trainer's rows in ``RemoteClient._send``) crosses as the array
+itself, for the price of the count (``WIRE_FLOAT_DENSE`` /
+``WIRE_FLOAT_SPARSE`` count both ways).
 """
 
 from __future__ import annotations

@@ -11,10 +11,16 @@ TPU-era design: requests here are the *same* Python structures the in-process
 dispatcher consumes (tuples of ids/values/options), so a remote client and a
 local worker exercise identical server code. The codec maps such a structure
 to a blob list: blob 0 is a JSON structure tree (tags + scalar leaves), blobs
-1..N are raw ndarrays referenced by index. Float32 arrays are run through the
-SparseFilter codec when compression is enabled AND it actually shrinks the
-payload — the ``sparse`` tag is self-describing, so no negotiation handshake
-is needed.
+1..N are raw ndarrays referenced by index. Float32 arrays ride in the
+SparseFilter codec's form when compression is enabled AND that form is
+shorter than the array — the ``sparse`` tag is self-describing, so no
+negotiation handshake is needed. The choice is made from one count of the
+array's nonzeros (``sparse_is_shorter``: the sparse form's ``24 + 8 * nnz``
+bytes must beat the array's own, which only an array under half nonzero,
+the encoder's own test, can), so the encoder runs only when its output will
+be the blob; a dense payload, a trainer's rows or a Get's reply, costs the
+count and is sent as the array itself, a view. The counters
+``WIRE_FLOAT_DENSE`` / ``WIRE_FLOAT_SPARSE`` say how often each happened.
 """
 
 from __future__ import annotations
@@ -24,9 +30,10 @@ from typing import Any, List
 
 import numpy as np
 
-from multiverso_tpu.dashboard import monitor
+from multiverso_tpu.dashboard import count, monitor
 from multiverso_tpu.updaters import AddOption, GetOption
-from multiverso_tpu.utils.quantization import QuantizedDelta
+from multiverso_tpu.utils.quantization import (QuantizedDelta, sparse_encode,
+                                               sparse_is_shorter)
 
 # arrays below this size never win from sparse encoding (header overhead)
 _COMPRESS_MIN_SIZE = 64
@@ -74,12 +81,13 @@ def _encode(obj: Any, compress: bool) -> List[np.ndarray]:
             arr = np.ascontiguousarray(np.asarray(o))
             if (compress and arr.dtype == np.float32
                     and arr.size >= _COMPRESS_MIN_SIZE):
-                from multiverso_tpu.utils.quantization import sparse_encode
-                payload = sparse_encode(arr)
-                if len(payload) < arr.nbytes:
-                    blobs.append(np.frombuffer(payload, dtype=np.uint8))
+                if sparse_is_shorter(arr):
+                    count("WIRE_FLOAT_SPARSE")
+                    blobs.append(np.frombuffer(sparse_encode(arr),
+                                               dtype=np.uint8))
                     return {"t": "sparse", "i": len(blobs) - 1,
                             "shape": list(arr.shape)}
+                count("WIRE_FLOAT_DENSE")
             blobs.append(arr)
             return {"t": "arr", "i": len(blobs) - 1}
         if isinstance(o, (list, tuple)):

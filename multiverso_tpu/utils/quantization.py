@@ -61,8 +61,33 @@ def _load_native() -> Optional[ctypes.CDLL]:
     return _native
 
 
+# elements compared per pass of sparse_is_shorter: a 256 KB temporary
+_COUNT_STEP = 1 << 18
+
+
+def sparse_is_shorter(data: np.ndarray) -> bool:
+    """Whether :func:`sparse_encode` would return fewer bytes than the
+    float32 array itself holds, decided from a count alone: the sparse
+    form is ``24 + 8 * nnz`` bytes, and where that is under the array's
+    ``4 * size`` the encoders' own test (numpy and native alike go sparse
+    when ``2 * nnz < size``) holds with it. ``-0.0`` is a zero and NaN is
+    not, as in both encoders. The count is a boolean compare a piece at a
+    time, no index array, and stops once the answer is no: a dense
+    payload costs about half a pass and builds nothing."""
+    flat = np.ascontiguousarray(data, dtype=np.float32).reshape(-1)
+    room = flat.nbytes - 24  # bytes the (index, value) pairs may take
+    nnz = 0
+    for start in range(0, flat.size, _COUNT_STEP):
+        nnz += int(np.count_nonzero(flat[start:start + _COUNT_STEP] != 0))
+        if 8 * nnz >= room:
+            return False
+    return 8 * nnz < room
+
+
 def sparse_encode(data: np.ndarray, force_numpy: bool = False) -> bytes:
-    """Encode a float32 array; sparse form when <50% nonzero."""
+    """Encode a float32 array; sparse form when <50% nonzero. A caller
+    that sends the result only where it is shorter than the array asks
+    :func:`sparse_is_shorter` first and does not call this for nothing."""
     data = np.ascontiguousarray(data, dtype=np.float32).reshape(-1)
     lib = None if force_numpy else _load_native()
     if lib is not None:
