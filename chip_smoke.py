@@ -19,7 +19,11 @@ benchmark is ``benchmark/run.py``):
              to the CPU, never touches a JAX backend) connects and checks an
              Add, a Get and a top-k query against numpy.
 4. four chips (when JAX reports >= 4 devices) phases 1-2 again on a
-             four-device mesh, shards checked per device.
+             four-device mesh, shards checked per device: the tables' row
+             Adds take the Pallas kernel on every shard's block, ids routed
+             to their owners (``pallas_scatter: true``, launches counted by
+             path); the bare kernels, which take one device's array, are
+             left to the one-chip pass.
 
 It fails (non-zero, no result line) without a TPU, sets no platform in code,
 and catches no phase's failure: the first one is the exit code. Every line it
@@ -101,8 +105,9 @@ def check_shards(table, devices):
 
 def phase_kernels(rows, cols, n_ids, expect_scatter, seed=0):
     """Row kernels bare on the table's device state (where the table uses
-    them), then Add with duplicate ids, device Add and Get through the
-    dispatcher; every result against a numpy mirror of the table."""
+    them and lives on one device), then Add with duplicate ids, device Add
+    and Get through the dispatcher; every result against a numpy mirror of
+    the table."""
     import jax
 
     import multiverso_tpu as mv
@@ -128,6 +133,7 @@ def phase_kernels(rows, cols, n_ids, expect_scatter, seed=0):
     if pallas:
         platforms = {d.platform for d in server.data.devices()}
         assert interpret == pallas_rows.interpret_for(platforms.pop())
+    if pallas and len(server.data.devices()) == 1:
         # unique live ids plus sentinel pads (zero deltas) up to a whole
         # number of row groups
         pad = pallas_rows.ROW_GROUP - n_ids % pallas_rows.ROW_GROUP
@@ -360,8 +366,9 @@ def run_mesh(devices, clock, sizes, with_server):
     """Phases 1-2 (and 3) on a ``devices``-device table mesh."""
     import multiverso_tpu as mv
 
-    # the kernel serves one-device tables; sharded ones take XLA's scatter
-    expect = (True, False) if devices == 1 else (False, None)
+    # the kernel serves every table's row Adds, on one device or on every
+    # shard's block
+    expect = (True, False)
     mv.init(mesh_shape=str(devices), **_INIT_FLAGS)
     assert mv.num_servers() == devices, mv.num_servers()
 
@@ -432,9 +439,10 @@ def main():
     w_one = run_mesh(1, clock, FULL_SIZES, with_server=True)
     if len(devices) >= 4:
         w_four = run_mesh(4, clock, FULL_SIZES, with_server=False)
-        # same seeds, same blocks: the sharded XLA path and the one-chip
-        # kernel path must land on the same embeddings, up to the order of
-        # float32 sums
+        # same seeds, same blocks: the fused transaction over sharded
+        # tables (XLA's partitioned scatter inside the trainer's jit) and
+        # over one chip's (the kernel) must land on the same embeddings, up
+        # to the order of float32 sums
         diff = float(np.abs(w_four - w_one).max())
         assert diff <= 1e-2 * float(np.abs(w_one).max()), diff
         emit(four_chip="ran", max_abs_diff_vs_one_chip=diff)

@@ -451,8 +451,11 @@ class OpRecord(NamedTuple):
     ``req_id``, else its ``msg_id``; ``n`` counts rows, bytes or fused
     messages, by stage. A row launch (``TABLE_ROW_LAUNCH``) also says
     which program served it (``path``: ``pallas`` or ``xla``), the DMA
-    descriptors it issues and the bytes of table rows it moves; every
-    other stage leaves the three empty."""
+    descriptors it issues and the bytes of table rows it moves; on a table
+    whose rows are sharded over chips it also carries the ``shards`` that
+    launched (``n`` is then the slots of all of them), the fullest shard's
+    slots (``max_shard_n``) and the bytes of table rows that crossed chips
+    (``exchange_bytes``). Every other stage leaves the six empty."""
 
     seq: int
     id: int
@@ -466,6 +469,9 @@ class OpRecord(NamedTuple):
     path: str = ""
     descriptors: int = 0
     bytes: int = 0
+    shards: int = 0
+    max_shard_n: int = 0
+    exchange_bytes: int = 0
 
 
 class OpRing:
@@ -487,11 +493,13 @@ class OpRing:
 
     def append(self, span_id: int, parent: int, stage: str, start_ns: int,
                dur_ns: int, cpu_ns: int, op: int, n: int, path: str = "",
-               descriptors: int = 0, bytes: int = 0) -> None:
+               descriptors: int = 0, bytes: int = 0, shards: int = 0,
+               max_shard_n: int = 0, exchange_bytes: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
-                                         path, descriptors, bytes)
+                                         path, descriptors, bytes, shards,
+                                         max_shard_n, exchange_bytes)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -545,7 +553,8 @@ class _Section:
 
     __slots__ = ("_name", "_feeds", "_op", "n", "id", "start_ns", "dur_ns",
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
-                 "path", "descriptors", "bytes")
+                 "path", "descriptors", "bytes", "shards", "max_shard_n",
+                 "exchange_bytes")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
@@ -553,6 +562,7 @@ class _Section:
         self._cpu = cpu
         self.id = 0
         self.path, self.descriptors, self.bytes = "", 0, 0
+        self.shards = self.max_shard_n = self.exchange_bytes = 0
 
     def __enter__(self) -> "_Section":
         if Dashboard.profile_annotations:
@@ -582,7 +592,8 @@ class _Section:
             _op_tls.span, _op_tls.op = self._parent, self._outer_op
             RING.append(self.id, self._parent, self._name, self.start_ns,
                         self.dur_ns, cpu, self._op, self.n, self.path,
-                        self.descriptors, self.bytes)
+                        self.descriptors, self.bytes, self.shards,
+                        self.max_shard_n, self.exchange_bytes)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -594,7 +605,8 @@ class _Off:
     """What ``span`` hands out while the switch is off: nothing is timed
     and what a section would carry goes nowhere."""
 
-    __slots__ = ("n", "path", "descriptors", "bytes")
+    __slots__ = ("n", "path", "descriptors", "bytes", "shards",
+                 "max_shard_n", "exchange_bytes")
     id = 0
 
     def __enter__(self) -> "_Off":

@@ -262,8 +262,14 @@ def gather_rows(table: jax.Array, ids: jax.Array, *,
     return _gather_call(table, ids, interpret)
 
 
-def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
-                        scratch, read_sems, write_sems, *, rows, sign):
+def _scatter_add_kernel(*refs, rows, sign, counted):
+    if counted:
+        # a shard's launch: the slots from ``count`` on issue no descriptor
+        ids_ref, count_ref, delta_ref, table_in_ref, table_ref, scratch, \
+            read_sems, write_sems = refs
+    else:
+        ids_ref, delta_ref, table_in_ref, table_ref, scratch, \
+            read_sems, write_sems = refs
     del table_in_ref  # aliased with table_ref; all access goes through out
     g = pl.program_id(0)
     base = g * ROW_GROUP
@@ -279,33 +285,53 @@ def _scatter_add_kernel(ids_ref, delta_ref, table_in_ref, table_ref,
                                      _row_of(table_ref, rid),
                                      write_sems.at[k])
 
-    for k in range(ROW_GROUP):
-        read_dma(k).start()
-    for k in range(ROW_GROUP):
-        read_dma(k).wait()
-    delta = delta_ref[:, :]
-    if rows % ROW_GROUP:
-        # the last group hangs over the delta's end: that part of the block
-        # is unspecified (it may hold NaN), so select, never multiply
-        row = base + jax.lax.broadcasted_iota(jnp.int32, delta.shape, 0)
-        delta = jnp.where(row < rows, delta, 0.0)
-    if sign != 1.0:
-        delta = sign * delta
-    # the delta is as wide as the caller's columns: the lanes past them
-    # (the last tile's padding) are written back as they were read
-    width = delta.shape[1]
-    if len(scratch.shape) == 2:
-        scratch[:, :width] = scratch[:, :width] + delta
-    else:
-        for t in range(pl.cdiv(width, LANES)):
-            lo, w = t * LANES, min(LANES, width - t * LANES)
-            scratch[t, :, :w] = scratch[t, :, :w] + delta[:, lo:lo + w]
-    for k in range(ROW_GROUP):
-        write_dma(k).start()
-    # write-backs must land before the next grid step may read these rows
-    # (live ids are unique per call, but a later *call* may touch them)
-    for k in range(ROW_GROUP):
-        write_dma(k).wait()
+    def add_delta():
+        delta = delta_ref[:, :]
+        if rows % ROW_GROUP:
+            # the last group hangs over the delta's end: that part of the
+            # block is unspecified (it may hold NaN), so select, never
+            # multiply
+            row = base + jax.lax.broadcasted_iota(jnp.int32, delta.shape, 0)
+            delta = jnp.where(row < rows, delta, 0.0)
+        if sign != 1.0:
+            delta = sign * delta
+        # the delta is as wide as the caller's columns: the lanes past them
+        # (the last tile's padding) are written back as they were read
+        width = delta.shape[1]
+        if len(scratch.shape) == 2:
+            scratch[:, :width] = scratch[:, :width] + delta
+        else:
+            for t in range(pl.cdiv(width, LANES)):
+                lo, w = t * LANES, min(LANES, width - t * LANES)
+                scratch[t, :, :w] = scratch[t, :, :w] + delta[:, lo:lo + w]
+
+    def walk(live):
+        """Read, add and write back the group's first ``live`` slots: the
+        whole group unrolled where ``live`` is the static group size, a loop
+        where it is a count known on the chip (a shard's last group; the
+        slots past it hold whatever the scratch held and are not written)."""
+        def each(step):
+            if isinstance(live, int):
+                for k in range(live):
+                    step(k)
+            else:
+                jax.lax.fori_loop(0, live, lambda k, _: step(k), None)
+
+        each(lambda k: read_dma(k).start())
+        each(lambda k: read_dma(k).wait())
+        add_delta()
+        each(lambda k: write_dma(k).start())
+        # write-backs must land before the next grid step may read these
+        # rows (live ids are unique per call, but a later *call* may touch
+        # them)
+        each(lambda k: write_dma(k).wait())
+
+    if not counted:
+        walk(ROW_GROUP)
+        return
+    live = count_ref[0] - base
+    pl.when(live >= ROW_GROUP)(lambda: walk(ROW_GROUP))
+    pl.when(jnp.logical_and(live > 0, live < ROW_GROUP))(lambda: walk(live))
 
 
 def launched_slots(rows: int) -> int:
@@ -314,22 +340,25 @@ def launched_slots(rows: int) -> int:
     return pl.cdiv(rows, ROW_GROUP) * ROW_GROUP
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "sign"),
-                   donate_argnums=(0,))
-def _scatter_add_call(table, ids, deltas, interpret, sign=1.0):
+def _scatter_add(table, ids, deltas, interpret, sign, count=None):
+    """The scatter-add's ``pallas_call``, traceable: ``count`` (int32, one
+    element) is the number of leading id slots that are live; without it
+    every slot of the delta's row groups is."""
     rows, width = deltas.shape
     tiles = lane_tiles(table)
     deltas = deltas.astype(table.dtype)
     view = _tile_view(table)
+    counted = count is not None
+    prefetch = (ids, count) if counted else (ids,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(prefetch),
         # the delta sizes the grid: id slots past its last group (the tail
         # of a caller's bucket) are never read
         grid=(pl.cdiv(rows, ROW_GROUP),),
         in_specs=[
             # as wide as the delta itself (a block may span a whole
             # dimension whatever its size): no pad program in front
-            pl.BlockSpec((ROW_GROUP, width), lambda g, ids: (g, 0),
+            pl.BlockSpec((ROW_GROUP, width), lambda g, *_: (g, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -341,13 +370,20 @@ def _scatter_add_call(table, ids, deltas, interpret, sign=1.0):
         ],
     )
     return _row_view(pl.pallas_call(
-        functools.partial(_scatter_add_kernel, rows=rows, sign=sign),
+        functools.partial(_scatter_add_kernel, rows=rows, sign=sign,
+                          counted=counted),
         out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
         grid_spec=grid_spec,
-        # operand order: ids (scalar prefetch), deltas, table → alias table
-        input_output_aliases={2: 0},
+        # operand order: the scalar prefetch, deltas, table → alias table
+        input_output_aliases={len(prefetch) + 1: 0},
         interpret=interpret,
-    )(ids, deltas, view))
+    )(*prefetch, deltas, view))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "sign"),
+                   donate_argnums=(0,))
+def _scatter_add_call(table, ids, deltas, interpret, sign=1.0):
+    return _scatter_add(table, ids, deltas, interpret, sign)
 
 
 def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,

@@ -57,6 +57,32 @@ def table_sharding(mesh: Mesh, ndim: int, shard_dim: int = 0,
     return NamedSharding(mesh, P(*spec))
 
 
+def put_row_blocks(mesh: Mesh, rows: int, cols: int, block_of,
+                   axis: str = "server") -> jax.Array:
+    """A ``(rows, cols)`` table state sharded by rows over ``axis``, put up
+    block by block: ``block_of(lo, hi)`` gives rows ``[lo, hi)`` as a host
+    array and is asked for one block at a time in row order, each only when
+    the blocks before it are on their devices. The host holds one block
+    beside whatever ``block_of`` reads from, never a second table. The
+    last block's transfer is left in flight, as one ``device_put`` of the
+    whole state would be: a table on one device goes up as it always did."""
+    sharding = table_sharding(mesh, ndim=2, shard_dim=0, axis=axis)
+    spans = sorted(
+        ((index[0].start or 0, rows if index[0].stop is None
+          else index[0].stop, device)
+         for device, index in sharding.addressable_devices_indices_map(
+             (rows, cols)).items()), key=lambda span: span[:2])
+    pieces, last = [], None
+    for lo, hi, device in spans:
+        if last is None or last[0] != (lo, hi):  # replicas share a block
+            for piece in pieces:
+                piece.block_until_ready()
+            last = ((lo, hi), block_of(lo, hi))
+        pieces.append(jax.device_put(last[1], device))
+    return jax.make_array_from_single_device_arrays((rows, cols), sharding,
+                                                    pieces)
+
+
 def replicated(mesh: Mesh, ndim: int = 0) -> NamedSharding:
     return NamedSharding(mesh, P(*([None] * ndim)))
 

@@ -107,17 +107,19 @@ _row_gather_jit = jax.jit(_row_gather, static_argnames=("bucket", "sentinel"))
 
 def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
                         itemsize: int = 4) -> bool:
-    """Pallas row-DMA scatter serves single-shard TPU tables only:
-    pallas_call has no SPMD partitioning rule, so multi-device tables take
-    XLA's scatter (which partitions fine). ``platform`` is that of the
-    table mesh's devices. Any number of lane tiles goes (a row wider than
-    one tile is one strided descriptor, ``ops/pallas_rows``) up to the
-    width whose row group still fits the kernel's VMEM; past it XLA's
-    scatter serves, and the table's creation line and every launch record
-    say which."""
+    """Whether the Pallas row-DMA scatter serves a table's row Adds.
+    ``platform`` is that of the table mesh's devices: the kernel compiles
+    for the TPU. Any number of shards goes: ``pallas_call`` has no SPMD
+    partitioning rule, so a table sharded over the chips of one process
+    routes an op's ids to the shards that own them and runs the kernel on
+    every shard's block (``ops/sharded_rows``). Any number of lane tiles
+    goes (a row wider than one tile is one strided descriptor,
+    ``ops/pallas_rows``) up to the width whose row group still fits the
+    kernel's VMEM; past it XLA's scatter serves, and the table's creation
+    line and every launch record say which."""
+    del num_shards
     from multiverso_tpu.ops.pallas_rows import fits_vmem
-    return (platform == "tpu" and num_shards == 1
-            and fits_vmem(lanes, itemsize))
+    return platform == "tpu" and fits_vmem(lanes, itemsize)
 
 
 class MatrixServer(ServerTable):
@@ -145,26 +147,24 @@ class MatrixServer(ServerTable):
         # unlocks the Pallas row-DMA scatter path (ops/pallas_rows), which
         # is ~8x faster than XLA's serialized scatter for row Adds.
         self.padded_cols = mesh_lib.pad_to_multiple(self.num_col, 128)
-        if num_shards == 1 and self.padded_cols > 128:
+        if self.padded_cols > 128:
             # the row kernel reaches a row of several lane tiles through
-            # the table's (8, 128) tiles: whole tiles of rows (HBM holds
-            # them anyway; the extra rows are scratch like the sentinel).
-            # Decided from the shape alone: the gate below imports Pallas,
-            # seconds that belong beside the upload, not before it
-            self.padded_rows = mesh_lib.pad_to_multiple(self.padded_rows, 8)
+            # the table's (8, 128) tiles: whole tiles of rows on every
+            # shard (HBM holds them anyway; the extra rows are scratch like
+            # the sentinel). Decided from the shape alone: the gate below
+            # imports Pallas, seconds that belong beside the upload, not
+            # before it
+            self.padded_rows = mesh_lib.pad_to_multiple(self.padded_rows,
+                                                        8 * num_shards)
+        self._num_shards = num_shards
+        self._block_rows = self.padded_rows // num_shards
 
-        sharding = mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0)
-        init = np.zeros((self.padded_rows, self.padded_cols), dtype=self.dtype)
         if init_value is not None:
-            init[: self.num_row, : self.num_col] = np.asarray(
-                init_value, dtype=self.dtype).reshape(self.num_row, self.num_col)
-        elif init_range is not None:
-            # random-init server ctor overload (reference: matrix_table.cpp:372-384)
-            lo, hi = init_range
-            rng = np.random.default_rng(seed)
-            init[: self.num_row, : self.num_col] = rng.uniform(
-                lo, hi, size=(self.num_row, self.num_col)).astype(self.dtype)
-        self.data = jax.device_put(init, sharding)
+            init_value = np.asarray(init_value, dtype=self.dtype).reshape(
+                self.num_row, self.num_col)
+        self.data = self._put_rows(
+            init_value if init_range is None or init_value is not None
+            else functools.partial(self._uniform_rows, init_range, seed))
 
         self.updater = get_updater(self.dtype, updater_type)
         worker_dim = self.num_workers if self.updater.per_worker_state else 1
@@ -210,31 +210,50 @@ class MatrixServer(ServerTable):
         self._gather_out = lambda data, ids, bucket: jax.device_put(
             self._gather(data, ids, bucket=bucket), _out_dev)
         platform = first_dev.platform
+        # a mesh over several processes keeps XLA's partitioned programs:
+        # the routed ones assemble their operands from this process's
+        # devices alone
         self._pallas_scatter = _use_pallas_scatter(
-            platform, num_shards, self.padded_cols, self.dtype.itemsize)
+            platform, num_shards, self.padded_cols, self.dtype.itemsize
+        ) and (num_shards == 1 or zoo.multihost is None)
         # None where XLA's scatter serves the table
         self._pallas_interpret: Optional[bool] = None
+        # the routed row programs of a table sharded over chips, else None
+        self._shard_rows = None
         if self._pallas_scatter:
             from multiverso_tpu.ops import pallas_rows
             self._pallas_interpret = pallas_rows.interpret_for(platform)
+            why = "pallas row-DMA kernel, %s" % (
+                "interpreted" if self._pallas_interpret else "compiled")
+            if num_shards > 1:
+                from multiverso_tpu.ops import sharded_rows
+                self._shard_rows = sharded_rows.programs(
+                    self.mesh, self._pallas_interpret, self._sign)
+                why += (", on every shard's block of %d rows, ids routed "
+                        "to their owners" % self._block_rows)
+                if not self._linear:
+                    why += ("; this table's %s updater takes XLA's row "
+                            "update" % type(self.updater).__name__)
+        else:
+            why = "XLA scatter (%s)" % (
+                "the kernel compiles for tpu only" if platform != "tpu"
+                else "the mesh spans processes" if num_shards > 1
+                and zoo.multihost is not None
+                else "a row group of %d lanes is past the kernel's VMEM"
+                % self.padded_cols)
+        if self._pallas_scatter and num_shards == 1:
             # unique-id contract: see process_add
             self._scatter_add_raw = functools.partial(
                 pallas_rows.scatter_add_rows,
                 interpret=self._pallas_interpret, sign=self._sign)
             self._scatter_add = self._scatter_add_raw
-            why = "pallas row-DMA kernel, %s" % (
-                "interpreted" if self._pallas_interpret else "compiled")
         else:
+            # what a caller's fused jit embeds (`row_apply_traceable`) and,
+            # where no kernel serves the table, its row Adds
             self._scatter_add_raw = functools.partial(
                 _xla_scatter_add, sign=self._sign)
             self._scatter_add = jax.jit(self._scatter_add_raw,
                                         donate_argnums=(0,))
-            why = "XLA scatter (%s)" % (
-                "the kernel compiles for tpu only" if platform != "tpu"
-                else "pallas_call has no SPMD partitioning rule"
-                if num_shards > 1
-                else "a row group of %d lanes is past the kernel's VMEM"
-                % self.padded_cols)
         log.info("MatrixTable %dx%d on %d %s device(s): row scatter = %s",
                  self.num_row, self.num_col, num_shards, platform, why)
         # always on: which program served each row launch, by op
@@ -280,15 +299,52 @@ class MatrixServer(ServerTable):
         return self._make_row_update(self.updater, jit=False)
 
     # -- helpers -----------------------------------------------------------
-    def _note_launch(self, launch, op: str, slots: int,
-                     pallas: bool) -> None:
+    def _put_rows(self, rows=None) -> jax.Array:
+        """The table's device state from its logical rows, put up shard by
+        shard: ``rows`` is the ``(num_row, num_col)`` host array, a function
+        ``(lo, n) -> rows [lo, lo + n)``, or None for zeros. A block is
+        padded (scratch rows, lanes) only where it needs it: a block of
+        whole rows at the table's own width goes up as a view of ``rows``."""
+        def block_of(lo: int, hi: int) -> np.ndarray:
+            live = max(0, min(hi, self.num_row) - lo)
+            part = (None if rows is None or not live
+                    else rows(lo, live) if callable(rows)
+                    else rows[lo:lo + live])
+            if part is not None and part.shape == (hi - lo, self.padded_cols):
+                return part
+            block = np.zeros((hi - lo, self.padded_cols), self.dtype)
+            if part is not None:
+                block[:live, : self.num_col] = part
+            return block
+
+        return mesh_lib.put_row_blocks(self.mesh, self.padded_rows,
+                                       self.padded_cols, block_of)
+
+    def _uniform_rows(self, init_range, seed: int, lo: int,
+                      n: int) -> np.ndarray:
+        """Rows ``[lo, lo + n)`` of the random-init server ctor overload
+        (reference: matrix_table.cpp:372-384): the rows a draw of the whole
+        table from ``seed`` would hold there (one 64-bit step of the
+        generator a value, so a block starts ``lo * num_col`` steps in)."""
+        rng = np.random.default_rng(seed)
+        rng.bit_generator.advance(lo * self.num_col)
+        return rng.uniform(*init_range,
+                           size=(n, self.num_col)).astype(self.dtype)
+
+    def _note_launch(self, launch, op: str, slots: int, pallas: bool,
+                     segments=None, exchanged_cols: int = 0) -> None:
         """What a row launch did, on its TABLE_ROW_LAUNCH record and the
         always-on counters: ``n`` id slots (an Add's row groups, the slots
         a Get gathers: not the bucket), the program that served them
         (``pallas`` or ``xla``), the DMA descriptors the kernel issues for
         them (a read and a write a slot for an Add; XLA's programs issue
         their own, not counted: 0) and the bytes of table rows moved, at
-        the table's lane width."""
+        the table's lane width. On a table sharded over chips ``slots`` is
+        the sum over the shards and ``segments`` is ``(each shard's slots,
+        a segment's capacity)``: the record also carries the number of
+        shards, the fullest one's slots and the bytes of rows that crossed
+        chips: the segments of every shard but the first,
+        ``exchanged_cols`` wide."""
         path = "pallas" if pallas else "xla"
         self._launch_counters[op, path].add()
         moves = 2 if op == "add" else 1
@@ -297,6 +353,35 @@ class MatrixServer(ServerTable):
         launch.descriptors = moves * slots if pallas else 0
         launch.bytes = (moves * slots * self.padded_cols
                         * self.dtype.itemsize)
+        if segments is not None:
+            by_shard, capacity = segments
+            launch.shards = len(by_shard)
+            launch.max_shard_n = int(by_shard.max())
+            launch.exchange_bytes = ((len(by_shard) - 1) * capacity
+                                     * exchanged_cols * self.dtype.itemsize)
+
+    def _route(self, row_ids: np.ndarray):
+        """The host's part of routing an op over the shards: how many of
+        its ids each shard owns, and the slots of a shard's segment that
+        the fullest one needs. The chip that holds the ids does the rest
+        (``ops/sharded_rows``)."""
+        from multiverso_tpu.ops import sharded_rows
+        with span("TABLE_ROW_ROUTE") as routing:
+            routing.n = len(row_ids)
+            counts = sharded_rows.shard_counts(row_ids, self._block_rows,
+                                               self._num_shards)
+            return counts, sharded_rows.shard_capacity(
+                int(counts.max()), len(row_ids), self._num_shards)
+
+    def _get_bucket(self, n: int, ensure_pad: bool) -> int:
+        """The power-of-two bucket of a Get's result, so a caller's jit over
+        it is shape-stable; ``ensure_pad`` keeps at least one sentinel slot
+        in it (device-out gets hand the bucket itself to the caller as a
+        compact training space; its masked ops need a guaranteed non-live
+        row)."""
+        # min bucket = pallas ROW_GROUP (batch must be a group multiple)
+        from multiverso_tpu.ops.pallas_rows import ROW_GROUP
+        return max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
 
     def _bucket_ids(self, ids: np.ndarray, values: Optional[np.ndarray],
                     ensure_pad: bool = False
@@ -306,14 +391,9 @@ class MatrixServer(ServerTable):
         traces are shape-stable. An Add (``values`` given) uploads the whole
         bucket, the values zero-padded to it. A Get uploads the slots it
         gathers, ``_live_slots`` of them: the rest of its bucket is filled on
-        the device, not fetched. ``ensure_pad`` keeps at least one sentinel
-        slot in the bucket (device-out gets hand the bucket itself to the
-        caller as a compact training space; its masked ops need a guaranteed
-        non-live row)."""
+        the device, not fetched."""
         n = len(ids)
-        # min bucket = pallas ROW_GROUP (batch must be a group multiple)
-        from multiverso_tpu.ops.pallas_rows import ROW_GROUP
-        bucket = max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
+        bucket = self._get_bucket(n, ensure_pad)
         slots = bucket if values is not None else _live_slots(n, bucket)
         ids_p = np.concatenate(
             [ids, np.full(slots - n, self.sentinel_row, dtype=ids.dtype)])
@@ -323,6 +403,44 @@ class MatrixServer(ServerTable):
             padded[:n, : self.num_col] = values
             vals_p = async_upload(padded)
         return async_upload(ids_p), vals_p, n, bucket
+
+    def _gather_rows(self, row_ids: np.ndarray, device_out: bool = False
+                     ) -> jax.Array:
+        """The rows ``row_ids`` names as ``(bucket, padded_cols)`` on the
+        device, the slots past them copies of the sentinel row: from the
+        one program of a table on one chip (or XLA's partitioned one), or
+        from every shard's gather of the rows it owns, sent to the mesh's
+        first device and put back in the order asked. ``device_out``
+        results are committed to that device either way."""
+        n = len(row_ids)
+        if self._shard_rows is None:
+            with span("TABLE_ROW_PREP") as prep:
+                ids_p, _, prep.n, bucket = self._bucket_ids(
+                    row_ids, None, ensure_pad=device_out)
+            with span("TABLE_ROW_LAUNCH") as launch:
+                # the slots gathered, not the bucket the result fills
+                self._note_launch(launch, "get", ids_p.shape[0], False)
+                return (self._gather_out if device_out else self._gather)(
+                    self.data, ids_p, bucket=bucket)
+        rows = self._shard_rows
+        with span("TABLE_ROW_PREP") as prep:
+            prep.n = n
+            bucket = self._get_bucket(n, device_out)
+            live = _live_slots(n, bucket)
+            if live > n:
+                # pads aim past the table, where no shard owns them; the
+                # sentinel rides last: the tail of the result is its row's
+                # value, wherever its shard put it
+                row_ids = np.concatenate([
+                    row_ids, np.full(live - n - 1, self.padded_rows, np.int32),
+                    np.array([self.sentinel_row], np.int32)])
+            _, capacity = self._route(row_ids)
+            ids_d = rows.on_first(row_ids)
+        with span("TABLE_ROW_LAUNCH") as launch:
+            by_shard = np.full(self._num_shards, capacity)
+            self._note_launch(launch, "get", int(by_shard.sum()), False,
+                              (by_shard, capacity), self.padded_cols)
+            return rows.get(self.data, ids_d, capacity, bucket)
 
     # -- server ops --------------------------------------------------------
     def merge_add_requests(self, requests):
@@ -415,15 +533,26 @@ class MatrixServer(ServerTable):
                 # concatenate without dedup for exactly this reason)
                 if not (self._linear and not self._pallas_scatter):
                     row_ids, values = merge_duplicate_rows(row_ids, values)
-                ids_p, vals_p, prep.n, _ = self._bucket_ids(row_ids, values)
-            with span("TABLE_ROW_LAUNCH") as launch:
-                self._note_launch(launch, "add", ids_p.shape[0],
-                                  self._linear and self._pallas_scatter)
-                if self._linear:
-                    self.data = self._scatter_add(self.data, ids_p, vals_p)
+                routed = self._linear and self._shard_rows is not None
+                if routed:
+                    # the delta goes up to the first chip and takes a device
+                    # delta's route from there
+                    prep.n = len(row_ids)
+                    routed = self._route_add(row_ids, values)
                 else:
-                    self.data, self.states = self._row_update(
-                        self.data, self.states, ids_p, vals_p, worker, scalars)
+                    ids_p, vals_p, prep.n, _ = self._bucket_ids(row_ids, values)
+            with span("TABLE_ROW_LAUNCH") as launch:
+                if routed:
+                    self._launch_routed_add(launch, *routed)
+                else:
+                    self._note_launch(launch, "add", ids_p.shape[0],
+                                      self._linear and self._pallas_scatter)
+                    if self._linear:
+                        self.data = self._scatter_add(self.data, ids_p, vals_p)
+                    else:
+                        self.data, self.states = self._row_update(
+                            self.data, self.states, ids_p, vals_p, worker,
+                            scalars)
             touched = row_ids
         if self.is_sparse:
             with self._std_lock:
@@ -445,6 +574,7 @@ class MatrixServer(ServerTable):
 
     def _process_add_device(self, row_ids, values, option, worker,
                             scalars) -> None:
+        routed = self._linear and self._shard_rows is not None
         with span("TABLE_ROW_PREP") as prep:
             row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
             prep.n = n = len(row_ids)
@@ -453,26 +583,54 @@ class MatrixServer(ServerTable):
                           n, values.shape[0])
             from multiverso_tpu.ops.pallas_rows import (ROW_GROUP,
                                                         launched_slots)
-            bucket = max(_next_pow2(n), ROW_GROUP)
-            ids_p = async_upload(np.concatenate(
-                [row_ids, np.full(bucket - n, self.sentinel_row, np.int32)]))
-        with span("TABLE_ROW_LAUNCH") as launch:
-            # the pallas kernel takes the delta as it came and walks its row
-            # groups, not the bucket's: one device program an Add
-            pallas = self._linear and self._pallas_scatter
-            if not pallas:
-                values = self._bucket_delta(values, bucket)
-            self._note_launch(launch, "add",
-                              launched_slots(values.shape[0]), pallas)
-            if self._linear:
-                self.data = self._scatter_add(self.data, ids_p, values)
+            if routed:
+                routed = self._route_add(row_ids, values)
             else:
-                self.data, self.states = self._row_update(
-                    self.data, self.states, ids_p, values, worker, scalars)
+                bucket = max(_next_pow2(n), ROW_GROUP)
+                ids_p = async_upload(np.concatenate(
+                    [row_ids,
+                     np.full(bucket - n, self.sentinel_row, np.int32)]))
+        with span("TABLE_ROW_LAUNCH") as launch:
+            if routed:
+                self._launch_routed_add(launch, *routed)
+            else:
+                # the pallas kernel takes the delta as it came and walks its
+                # row groups, not the bucket's: one device program an Add
+                pallas = self._linear and self._pallas_scatter
+                if not pallas:
+                    values = self._bucket_delta(values, bucket)
+                self._note_launch(launch, "add",
+                                  launched_slots(values.shape[0]), pallas)
+                if self._linear:
+                    self.data = self._scatter_add(self.data, ids_p, values)
+                else:
+                    self.data, self.states = self._row_update(
+                        self.data, self.states, ids_p, values, worker,
+                        scalars)
         if self.is_sparse:
             with self._std_lock:
                 live = row_ids[row_ids < self.num_row]
                 self._up_to_date[:, live] = False
+
+    def _route_add(self, row_ids: np.ndarray, values):
+        """The operands of a linear Add on a table sharded over chips:
+        ``(counts by shard, a segment's capacity, ids, delta)``, the last
+        two on the mesh's first chip (``ShardedRows.on_first``: a host
+        delta goes up to it, a worker's device delta is there)."""
+        return (*self._route(row_ids), self._shard_rows.on_first(row_ids),
+                self._shard_rows.on_first(values))
+
+    def _launch_routed_add(self, launch, counts, capacity, ids,
+                           delta) -> None:
+        """That Add, one device program: on the chip that holds the delta
+        its rows are put in shard order and each shard is sent its own,
+        then every shard's kernel walks the rows it owns."""
+        from multiverso_tpu.ops.sharded_rows import launched_slots
+        by_shard = launched_slots(counts)
+        self._note_launch(launch, "add", int(by_shard.sum()), True,
+                          (by_shard, capacity), delta.shape[1])
+        self.data = self._shard_rows.add(self.data, ids, delta,
+                                         capacity=capacity)
 
     def _check_row_range(self, row_ids: np.ndarray, op: str) -> None:
         """Host-path ids must be in [0, num_row). Worker proxies already
@@ -568,20 +726,13 @@ class MatrixServer(ServerTable):
             # admin whole-table reads take the dense path
             out = self.updater.access(self.data)
             return self._host_read(out)[: self.num_row, : self.num_col]
-        with span("TABLE_ROW_PREP") as prep:
-            row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
-            if not device_out:
-                # device gets may carry sentinel-aimed pad ids (the compact
-                # training space contract); host/wire gets may not
-                self._check_row_range(row_ids, "get")
-            ids_p, _, n, bucket = self._bucket_ids(row_ids, None,
-                                                   ensure_pad=device_out)
-            prep.n = n
-        with span("TABLE_ROW_LAUNCH") as launch:
-            # the slots gathered, not the bucket the result fills
-            self._note_launch(launch, "get", ids_p.shape[0], False)
-            gathered = (self._gather_out if device_out else self._gather)(
-                self.data, ids_p, bucket=bucket)
+        row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
+        if not device_out:
+            # device gets may carry sentinel-aimed pad ids (the compact
+            # training space contract); host/wire gets may not
+            self._check_row_range(row_ids, "get")
+        n = len(row_ids)
+        gathered = self._gather_rows(row_ids, device_out)
         if self.is_sparse and self._is_worker(option):
             with self._std_lock:
                 self._up_to_date[option.worker_id, row_ids] = True
@@ -602,9 +753,8 @@ class MatrixServer(ServerTable):
         if len(stale) == self.num_row:
             return stale, self._host_read(
                 self.data)[: self.num_row, : self.num_col]
-        ids_p, _, n, bucket = self._bucket_ids(stale, None)
-        rows = self._host_read(self._gather(
-            self.data, ids_p, bucket=bucket))[:n, : self.num_col]
+        rows = self._host_read(
+            self._gather_rows(stale))[: len(stale), : self.num_col]
         return stale, rows
 
     def remote_spec(self):
@@ -629,10 +779,7 @@ class MatrixServer(ServerTable):
     def load(self, stream) -> None:
         from multiverso_tpu.checkpoint import read_array, read_state_dict
         arr = read_array(stream).astype(self.dtype).reshape(self.num_row, self.num_col)
-        padded = np.zeros((self.padded_rows, self.padded_cols), dtype=self.dtype)
-        padded[: self.num_row, : self.num_col] = arr
-        self.data = jax.device_put(
-            padded, mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
+        self.data = self._put_rows(arr)
         loaded = read_state_dict(stream)
         s_shard = mesh_lib.table_sharding(self.mesh, ndim=3, shard_dim=1)
         for name, cur in self.states.items():

@@ -50,6 +50,39 @@ def test_smoke_phases_through_the_interpreted_kernel(monkeypatch):
     assert report["query_ids_equal_numpy"]
 
 
+def test_four_chip_phase_runs_the_kernel_on_every_shard(monkeypatch):
+    """The smoke's four-device pass, tiny: with the gate open the tables
+    report the Pallas scatter, both Adds of the kernels phase are counted
+    under it (the kernel ran on every shard's block, ids routed to their
+    owners), the bare kernels are left to the one-device pass, the rows sit
+    in equal parts on four devices, and the trainer's fused transaction
+    still engages on the sharded tables."""
+    monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                        lambda platform, num_shards, *width: True)
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    mv.init(mesh_shape="4", **chip_smoke._INIT_FLAGS)
+    try:
+        interpreted = (True, True)
+        for cols, lanes in ((50, 128), (300, 384)):
+            table, checks = chip_smoke.phase_kernels(62, cols, 48,
+                                                     interpreted)
+            assert checks["pallas_scatter"] is True
+            assert checks["padded_cols"] == lanes
+            assert "bare_kernels" not in checks
+            assert checks["row_launches"] == {
+                "ROW_LAUNCH_PALLAS_ADD": 2, "ROW_LAUNCH_XLA_ADD": 0,
+                "ROW_LAUNCH_PALLAS_GET": 0, "ROW_LAUNCH_XLA_GET": 3}
+            assert table._server_table._shard_rows is not None
+            shards = chip_smoke.check_shards(table, 4)
+            assert len(shards["devices"]) == 4
+        trainer, _, checks = chip_smoke.phase_trainer(
+            62, 16, 64, 64, 2, 2, interpreted)
+        assert checks["untouched_rows_bit_equal"]
+        chip_smoke.check_shards(trainer.input_table, 4)
+    finally:
+        mv.shutdown()
+
+
 def test_mesh_shape_above_the_device_count_is_fatal():
     with pytest.raises(log.FatalError, match="needs 16 devices, have 8"):
         mesh_lib.build_mesh(shape=(16,))

@@ -542,3 +542,209 @@ def test_row_get_compiles_a_step_not_an_id_count(mv_env):
     assert str(jax.make_jaxpr(functools.partial(
         _row_gather, bucket=4096, sentinel=rows))(data, full)) == str(
             jax.make_jaxpr(lambda data, ids: data[ids])(data, full))
+
+
+# -- a table sharded over chips: the row kernel on every shard ---------------
+
+def _use_the_kernel_on_every_mesh(monkeypatch):
+    """The interpreted row kernel on the CPU mesh at any number of shards,
+    in groups of 8."""
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.tables import matrix_table
+
+    monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                        lambda platform, num_shards, *width: True)
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+
+
+SHARDED_ROWS, SHARDED_SEED = 4000, 30
+
+
+def _sharded_ops(cols, block):
+    """The ops of the sharded-table test, by name: (ids, delta in units).
+    ``block`` is the rows a shard of the mesh under test owns: the ops that
+    sit on a shard's edge are the same for the one-shard table they are
+    compared with."""
+    from benchmark import common
+
+    ref = common.load_module("reference", "dlrm-mlperf-emb128-40m")
+    rng = np.random.default_rng(SHARDED_SEED)
+    edge = min(block, SHARDED_ROWS - 1)
+    pick = {"spread": rng.choice(SHARDED_ROWS, 1500, replace=False),
+            "one shard takes all": rng.choice(min(block, 900), 60,
+                                              replace=False),
+            "a shard's last row and the next's first": np.array(
+                [edge, edge - 1, 7]),
+            "fewer ids than shards": np.array([SHARDED_ROWS - 1]),
+            "the table's first and last row": np.array(
+                [0, SHARDED_ROWS - 1, block // 2])}
+    return ref, [(name, ids.astype(np.int32), ref.delta_k(rng, len(ids), cols))
+                 for name, ids in pick.items()]
+
+
+def _run_sharded_ops(shards, cols, block=None):
+    """The ops through a table on ``shards`` devices, each as a device Add,
+    a host Add, a device Get and a host Get. Returns (the table's block
+    rows, every Get's rows, the whole table, the launch records by op)."""
+    import jax
+
+    from multiverso_tpu import dashboard
+
+    mv.init(mesh_shape=str(shards))
+    dashboard.Dashboard.profile_annotations = True  # after init: it resets
+    try:
+        ref, _ = _sharded_ops(cols, SHARDED_ROWS)
+        init, _ = ref.init_table(SHARDED_ROWS, cols, SHARDED_SEED)
+        table = mv.create_table("matrix", SHARDED_ROWS, cols, np.float32,
+                                init_value=init)
+        server = table._server_table
+        assert (server._shard_rows is not None) == (shards > 1)
+        np.testing.assert_array_equal(
+            np.asarray(table.get_device())[:SHARDED_ROWS, :cols], init)
+        block = block or server._block_rows
+        gets, launches = [], {}
+        for name, ids, dk in _sharded_ops(cols, block)[1]:
+            t0 = time.perf_counter()
+            table.wait(table.add_device_async(
+                jax.device_put(ref.to_float(dk)), ids))
+            table.add(ref.to_float(dk), row_ids=ids)
+            out = table.wait_device(table.get_device_async(ids), ids)
+            assert out.devices() == {server.mesh.devices.flat[0]}
+            gets.append(np.asarray(out))
+            gets.append(table.get(ids))
+            records, _ = dashboard.RING.window(t0, time.perf_counter())
+            launches[name] = [r for r in records
+                              if r.stage in ("TABLE_ROW_LAUNCH",
+                                             "TABLE_ROW_PREP",
+                                             "TABLE_ROW_ROUTE")]
+        return (server._block_rows, gets, np.asarray(table.get_device()),
+                launches)
+    finally:
+        dashboard.Dashboard.profile_annotations = False
+        mv.shutdown()
+
+
+@pytest.mark.parametrize("cols", [128, 300])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_sharded_table_equals_one_shard_and_the_reference(shards, cols,
+                                                          monkeypatch):
+    """The same seeded Adds and Gets (device and host deltas; ids spread
+    over the shards, all in one shard, on both sides of a shard's edge, and
+    fewer than the shards) through a table on 1, 2 and 4 devices, one and
+    three lane tiles a row: every Get and the whole table equal, to the
+    last bit, the one-shard table's and the benchmark's plain reference;
+    the slots launched, summed over the shards, stay within 1.15 of the
+    rows an evenly spread op names."""
+    _use_the_kernel_on_every_mesh(monkeypatch)
+    lanes = -(-cols // 128) * 128
+    block, gets, data, launches = _run_sharded_ops(shards, cols)
+    assert data.shape[1] == lanes and data.shape[0] == block * shards
+
+    ref, ops = _sharded_ops(cols, block)
+    mirror = ref.Mirror(cols, SHARDED_SEED)
+    counts = []
+    for _, ids, dk in ops:
+        mirror.add_pool(ids, dk)
+    for index, (name, ids, _) in enumerate(ops):
+        counts = [2] * (index + 1) + [0] * (len(ops) - index - 1)
+        want = mirror.rows_k(ids, counts)
+        device_get, host_get = gets[2 * index], gets[2 * index + 1]
+        assert ref.mismatches(device_get[:len(ids), :cols], want) == 0, name
+        assert ref.mismatches(host_get, want) == 0, name
+        # the bucket's tail is the sentinel row's value; lanes past the
+        # table's columns stay zero
+        assert device_get.shape[1] == lanes
+        assert not device_get[len(ids):].any(), name
+        assert not device_get[:, cols:].any(), name
+    everything = np.arange(SHARDED_ROWS)
+    assert ref.mismatches(data[:SHARDED_ROWS, :cols],
+                          mirror.rows_k(everything, [2] * len(ops))) == 0
+    assert not data[SHARDED_ROWS:].any() and not data[:, cols:].any()
+
+    if shards > 1:
+        _, gets_one, data_one, _ = _run_sharded_ops(1, cols, block)
+        for got, one in zip(gets, gets_one):
+            np.testing.assert_array_equal(got, one)
+        np.testing.assert_array_equal(data[:SHARDED_ROWS],
+                                      data_one[:SHARDED_ROWS])
+
+    for name, records in launches.items():
+        routes = [r for r in records if r.stage == "TABLE_ROW_ROUTE"]
+        launched = [r for r in records if r.stage == "TABLE_ROW_LAUNCH"]
+        named = [r for r in records if r.stage == "TABLE_ROW_PREP"]
+        assert len(launched) == len(named) == 4, name
+        assert len(routes) == (4 if shards > 1 else 0), name
+        assert [r.shards for r in launched] == [shards if shards > 1
+                                                else 0] * 4
+        if shards > 1 and name == "spread":
+            for launch, prep in zip(launched, named):
+                assert prep.n <= launch.n <= 1.15 * prep.n, (launch, prep)
+                assert (launch.n / shards <= launch.max_shard_n
+                        <= 1.2 * launch.n / shards)
+            device_add, host_add, device_get, host_get = launched
+            # rows that cross chips: every segment but the first chip's, at
+            # the delta's columns and at the table's lanes; a host delta
+            # goes up to the first chip and takes the device delta's route
+            segment = device_get.max_shard_n
+            assert device_get.n == shards * segment
+            assert device_get.exchange_bytes == host_get.exchange_bytes == (
+                (shards - 1) * segment * lanes * 4)
+            assert device_add.exchange_bytes == host_add.exchange_bytes == (
+                (shards - 1) * segment * cols * 4)
+            assert [r.path for r in launched] == ["pallas", "pallas",
+                                                  "xla", "xla"]
+        if shards > 1 and name == "one shard takes all":
+            # still right (above), at the price of idle shards: the fullest
+            # shard has every slot
+            assert launched[0].max_shard_n == launched[0].n
+
+
+def test_table_goes_up_shard_by_shard(monkeypatch):
+    """A table made from `init_value` on four shards equals the array, and
+    no host array of the whole padded table is made on the way: the blocks
+    handed to the devices are one shard's rows each, views of the caller's
+    array where a block needs no padding (128 columns, rows inside the
+    table), and `init_range` draws block by block what one draw of the whole
+    table would hold."""
+    from multiverso_tpu.parallel import mesh as mesh_lib
+
+    blocks = []
+    put = mesh_lib.put_row_blocks
+
+    def watched(mesh, rows, cols, block_of, **kw):
+        def block(lo, hi):
+            blocks.append((lo, hi, block_of(lo, hi)))
+            return blocks[-1][2]
+        return put(mesh, rows, cols, block, **kw)
+
+    monkeypatch.setattr(mesh_lib, "put_row_blocks", watched)
+    mv.init(mesh_shape="4")
+    try:
+        rows = 1003
+        rng = np.random.default_rng(30)
+        for cols in (128, 300):
+            del blocks[:]
+            init = rng.standard_normal((rows, cols)).astype(np.float32)
+            table = mv.create_table("matrix", rows, cols, np.float32,
+                                    init_value=init)
+            server = table._server_table
+            data = np.asarray(table.get_device())
+            np.testing.assert_array_equal(data[:rows, :cols], init)
+            assert not data[rows:].any() and not data[:, cols:].any()
+            block_rows = server.padded_rows // 4
+            assert [(lo, hi) for lo, hi, _ in blocks] == [
+                (i * block_rows, (i + 1) * block_rows) for i in range(4)]
+            assert all(b.shape == (block_rows, server.padded_cols)
+                       for *_, b in blocks)
+            if cols == 128:
+                assert [np.shares_memory(b, init) for *_, b in blocks] == [
+                    True, True, True, False]
+        del blocks[:]
+        drawn = mv.create_table("matrix", rows, 50, np.float32,
+                                init_range=(-0.5, 0.5), seed=11)
+        whole = np.random.default_rng(11).uniform(
+            -0.5, 0.5, size=(rows, 50)).astype(np.float32)
+        np.testing.assert_array_equal(drawn.get(), whole)
+        assert len(blocks) == 4
+    finally:
+        mv.shutdown()

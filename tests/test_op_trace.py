@@ -478,3 +478,174 @@ def test_wide_row_readers_count_the_rows_named_at_300_columns():
     run.trace = _reduction({other: [10, 0.004]})
     assert _metric("wide_gather_roofline", run) is None
     assert _metric("wide_scatter_device_ms", run) is None
+
+
+# -- a table sharded over chips: routing, and what a launch record carries ----
+
+def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
+    """On a table sharded over four devices every row op counts its ids by
+    shard in a TABLE_ROW_ROUTE inside its TABLE_ROW_PREP (`n` = ids routed:
+    a Get's are padded to its step of the bucket, the sentinel last; the
+    chip does the rest of the routing) and its TABLE_ROW_LAUNCH carries the
+    shards, the slots launched over all of them, the fullest shard's and
+    the bytes of rows that crossed chips. `shard_slots_share`,
+    `shard_exchange_bytes_share` and `shard_row_imbalance` read them; a
+    program whose records carry no `shards` (the parent's ring) gives None,
+    and so does a one-shard table."""
+    import jax
+
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.tables import matrix_table
+
+    monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                        lambda platform, num_shards, *width: True)
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    rows, cols, n, shards = 4096, 128, 1600, 4
+    rng = np.random.default_rng(30)
+    ids = rng.choice(rows, n, replace=False).astype(np.int32)
+    delta = jax.device_put(np.ones((n, cols), np.float32))
+    for mesh in (shards, 1):
+        mv.init(mesh_shape=str(mesh))
+        table = mv.create_table("matrix", rows, cols, np.float32)
+        t0 = time.perf_counter()
+        for _ in range(2):
+            table.wait(table.add_device_async(delta, ids))
+            table.wait_device(table.get_device_async(ids), ids)
+        run = _served_run(t0)
+        run.result = {"row_cols": cols}
+        trace = op_trace.of(run)
+        routes = trace.spans("TABLE_ROW_ROUTE")
+        launches = trace.spans("TABLE_ROW_LAUNCH")
+        if mesh == 1:
+            assert not routes and not any(r.shards for r in launches)
+            for name in ("shard_slots_share", "shard_exchange_bytes_share",
+                         "shard_row_imbalance"):
+                assert _metric(name, run) is None
+            mv.shutdown()
+            continue
+        from multiverso_tpu.tables.matrix_table import _live_slots
+        assert [r.n for r in routes] == [n, _live_slots(n, 2048)] * 2
+        preps = {r.id: r for r in trace.spans("TABLE_ROW_PREP")}
+        assert all(r.parent in preps for r in routes)
+        assert [r.shards for r in launches] == [shards] * 4
+        adds, gets = launches[0::2], launches[1::2]
+        counts = np.bincount(ids // table._server_table._block_rows,
+                             minlength=shards)
+        for add in adds:
+            assert add.path == "pallas"
+            assert add.n == int((-(-counts // 8) * 8).sum())
+            assert add.max_shard_n == -(-counts.max() // 8) * 8
+        segment = gets[0].max_shard_n
+        assert gets[0].n == shards * segment and segment > counts.max()
+        assert adds[0].exchange_bytes == gets[0].exchange_bytes == (
+            3 * segment * cols * 4)
+        share = _metric("shard_slots_share", run)
+        assert share == pytest.approx(
+            100.0 * (adds[0].n + gets[0].n) / (2 * n)) and share <= 115
+        assert _metric("shard_exchange_bytes_share", run) == pytest.approx(
+            100.0 * 3 * segment / n)
+        assert 1.0 <= _metric("shard_row_imbalance", run) <= 1.2
+        assert _metric("pallas_row_share.emb128x4", run) == 100.0
+        assert _metric("table_op_self_ms.emb128x4", run) > 0
+        # the parent's ring: the same records without the new fields
+        bare = SimpleNamespace(
+            window=run.window, result=run.result,
+            _op_trace=op_trace.Trace(
+                [SimpleNamespace(**{
+                    k: v for k, v in r._asdict().items()
+                    if k not in ("shards", "max_shard_n", "exchange_bytes")})
+                 for r in trace.records], trace.t0_ns, trace.t1_ns))
+        for name in ("shard_slots_share", "shard_exchange_bytes_share",
+                     "shard_row_imbalance"):
+            assert _metric(name, bare) is None
+        mv.shutdown()
+
+
+def test_sharded_trace_readers_by_chip():
+    """The readers of the sharded table's device programs, on a trace in
+    plain form built by hand: four chips, ten Adds and ten Gets of 100,000
+    rows; event names as the program compiled for a described v5e 2x2 gives
+    them. The scatter's and the gather's milliseconds are the slowest
+    chip's; the roofline counts the rows named over that chip's time on all
+    four chips; the exchange is the time the first chip's
+    collective-permutes were in flight, an Add's and a Get's told apart by
+    the program they lie in."""
+    from benchmark import shard_trace
+
+    scatter = ("%shard_scatter.1 = f32[10000001,128]{1,0:T(8,128)} "
+               "custom-call(s32[25664]{0:T(1024)S(1)} %copy-done.1, "
+               "s32[1]{0:T(128)S(6)} %copy.10, f32[25664,128]{1,0:T(8,128)} "
+               "%select_select_fusion, f32[10000001,128]{1,0:T(8,128)} "
+               "%param.5), custom_call_target=\"tpu_custom_call\"")
+    gather = ("%fusion = f32[25664,128]{1,0:T(8,128)S(1)} fusion("
+              "f32[10000001,128]{1,0:T(8,128)} %param.3, "
+              "s32[26624]{0:T(1024)S(1)} %pad_clamp_fusion.1), kind=kCustom, "
+              "calls=%fused_computation")
+    unroute = ("%fusion.1 = f32[102408,128]{1,0:T(8,128)S(1)} fusion("
+               "f32[102656,128]{1,0:T(8,128)S(1)} %dus_fusion, "
+               "s32[103424]{0:T(1024)S(1)} %pad_clamp_fusion), kind=kCustom, "
+               "calls=%fused_computation.1")
+    flight = ("%collective-permute-start.4 = (f32[25664,128]{1,0:T(8,128)}, "
+              "f32[25664,128]{1,0:T(8,128)}) collective-permute-start(...), "
+              "channel_id=1, source_target_pairs={{0,2}}")
+    wait = ("%collective-permute-done.4 = f32[25664,128]{1,0:T(8,128)} "
+            "collective-permute-done(...)")
+
+    def plane(chip, events, more=()):
+        line, at = [], 1_000
+        for name, count, each_ns in events:
+            for _ in range(count):
+                line.append([name, at, each_ns])
+                at += each_ns + 1_000
+        return {"name": f"/device:TPU:{chip}",
+                "lines": [{"name": "XLA Ops", "events": line}, *more]}
+
+    # the first chip's programs, 2 ms apart: ten Adds whose two transfers
+    # overlap (0.3 ms in flight together), ten Gets with one of 0.5 ms
+    modules, flights = [], []
+    for i in range(10):
+        at = 5_000_000 + i * 4_000_000
+        modules += [["jit_sharded_row_add(1)", at, 1_500_000],
+                    ["jit_sharded_row_get(2)", at + 2_000_000, 1_300_000]]
+        flights += [[flight, at + 100_000, 200_000],
+                    [flight, at + 200_000, 200_000],
+                    [flight, at + 2_100_000, 500_000]]
+    first = [{"name": "XLA Modules", "events": modules},
+             {"name": "Async XLA Ops", "events": flights}]
+    host = {"name": "/host:CPU", "lines": [{"name": "python", "events": [
+        ["bench.window", 0, 100_000_000]]}]}
+    trace = {"planes": [host] + [
+        plane(chip, [(scatter, 10, 600_000 + 50_000 * chip),
+                     (gather, 10, 150_000 - 10_000 * chip),
+                     (unroute, 10, 400_000), (wait, 20, 300_000)],
+              first if chip == 0 else ()) for chip in range(4)]}
+    chips = shard_trace.by_chip(trace, 4)
+    assert [c.window_s for c in chips] == [pytest.approx(0.1)] * 4
+    run = SimpleNamespace(
+        trace=chips[0], _shard_trace=chips, _shard_raw=trace, chips=4,
+        result={"add_rows": 1_000_000, "adds": 10, "gets": 10, "ops": 20,
+                "row_cols": 128},
+        peaks={"hbm_bytes_per_s": 819e9})
+    assert _metric("shard_scatter_device_ms", run) == pytest.approx(0.75)
+    assert _metric("shard_gather_device_ms", run) == pytest.approx(0.15)
+    # 3 x 1,000,000 x 128 x 4 B over 7.5 ms on each of four chips
+    assert _metric("shard_scatter_roofline", run) == pytest.approx(
+        100 * 1.536e9 / (0.0075 * 4) / 819e9)
+    assert shard_trace.exchange_in(trace) == {
+        "add": [10, pytest.approx(10 * 0.3e-3)],
+        "get": [10, pytest.approx(10 * 0.5e-3)]}
+    assert _metric("shard_exchange_ms", run) == pytest.approx(
+        (10 * 0.3 + 10 * 0.5) / 20)
+    run.result["adds"] = 11
+    with pytest.raises(ValueError, match="part of the work"):
+        _metric("shard_scatter_roofline", run)
+    # the parent's trace: XLA's partitioned programs, none of these events
+    run._shard_raw = {"planes": [host] + [plane(chip, [(unroute, 3, 1000)])
+                                          for chip in range(4)]}
+    run._shard_trace = shard_trace.by_chip(run._shard_raw, 4)
+    for name in ("shard_scatter_device_ms", "shard_scatter_roofline",
+                 "shard_exchange_ms"):
+        assert _metric(name, run) is None
+    run.trace = None
+    del run._shard_trace
+    assert _metric("shard_gather_device_ms", run) is None

@@ -132,28 +132,54 @@ def test_interpret_follows_the_tables_platform():
 
 
 def test_pallas_scatter_gate_predicate():
-    """pallas_call has no SPMD partitioning rule: the gate must refuse
-    multi-shard tables even on TPU (tested directly — on the CPU mesh the
-    backend clause alone would mask a regression of the shard clause)."""
+    """The gate reads the platform and the width, not the number of shards:
+    a table sharded over chips runs the kernel on every shard's block
+    (`ops/sharded_rows`; tested directly, since on the CPU mesh the backend
+    clause alone decides)."""
     from multiverso_tpu.tables.matrix_table import _use_pallas_scatter
 
     assert _use_pallas_scatter("tpu", 1)
-    assert not _use_pallas_scatter("tpu", 8)
+    assert _use_pallas_scatter("tpu", 8)
     assert not _use_pallas_scatter("cpu", 1)
     # any number of lane tiles, float32 or narrower, up to the width whose
     # row group (delta block twice, scratch once) still fits the VMEM budget
     for lanes in (128, 256, 384, 512, 4096):
         assert _use_pallas_scatter("tpu", 1, lanes, 4)
-        assert not _use_pallas_scatter("tpu", 4, lanes, 4)
+        assert _use_pallas_scatter("tpu", 4, lanes, 4)
     widest = pallas_rows.VMEM_BUDGET_BYTES // (3 * ROW_GROUP * 4)
     assert _use_pallas_scatter("tpu", 1, widest // 128 * 128, 4)
     assert not _use_pallas_scatter("tpu", 1, widest // 128 * 128 + 128, 4)
+    assert not _use_pallas_scatter("tpu", 4, widest // 128 * 128 + 128, 4)
     assert _use_pallas_scatter("tpu", 1, widest // 128 * 128 + 128, 2)
 
 
+@pytest.mark.parametrize("lanes", [128, 384])
+@pytest.mark.parametrize("count", [0, 1, 7, 8, 13, 24])
+def test_scatter_add_with_a_live_count(count, lanes, rng, monkeypatch):
+    """A shard's launch: 24 id slots and delta rows (three groups of 8) of
+    which the first ``count`` are live. The rows they name take their
+    deltas, to the bit; every slot past the count issues no descriptor, so
+    the row it names (a live row of the table, with a delta that is not
+    zero: a shard has no scratch row to aim a pad slot at) keeps its bytes:
+    no group at all, whole groups, and a group cut anywhere."""
+    import jax
+
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    rows, slots = 48, 24
+    table = rng.integers(-99, 99, (rows, lanes)).astype(np.float32)
+    ids = rng.choice(rows, slots, replace=False).astype(np.int32)
+    deltas = rng.integers(1, 9, (slots, lanes)).astype(np.float32)
+    expect = table.copy()
+    expect[ids[:count]] -= deltas[:count]
+    out = jax.jit(lambda t, i, d, n: pallas_rows._scatter_add(
+        t, i, d, True, -1.0, n))(table, ids, deltas,
+                                 np.array([count], np.int32))
+    np.testing.assert_array_equal(np.asarray(out), expect)
+
+
 def test_matrix_server_multi_shard_add_correct(mv_env):
-    """A table sharded over the 8-device mesh takes the XLA scatter branch
-    and row adds land correctly."""
+    """A table sharded over the 8-device CPU mesh takes the XLA scatter
+    branch (the kernel compiles for the TPU) and row adds land correctly."""
     import multiverso_tpu as mv
     from multiverso_tpu.runtime.zoo import Zoo
 

@@ -14,17 +14,29 @@ from multiverso_tpu.ops import pallas_rows
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The table mesh of a four-chip host: one axis, `server`."""
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices[:4]), ("server",))
 
 
 def _compile(fn, one_chip, *shapes, **jit_kwargs):
@@ -116,3 +128,111 @@ def test_row_get_gathers_the_ids_named_not_the_bucket(one_chip, rows, lanes):
     assert re.search(r'"integer_config":\{"integer":"256"\}', gathers[0])
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= live * lanes * 4 + (1 << 20))
+
+
+def _hlo_text(compiled):
+    """The compiled module as a trace names its events: operands with their
+    shapes."""
+    from jax._src.lib import xla_client
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    options.print_percent = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(options)
+
+
+# table rows (whole tiles of 8 on every shard where the table is wide),
+# lanes, delta columns: the four-chip cell's table and the wide one's shape
+SHARDED_TABLES = [(40_000_004, 128, 128), (3_000_032, 384, 300)]
+
+
+@pytest.mark.parametrize("rows,lanes,width", SHARDED_TABLES)
+def test_sharded_row_add_compiles_with_the_kernel_on_every_shard(
+        four_chips, rows, lanes, width):
+    """A device Add of 100,000 rows into a table row-sharded over a v5e
+    2x2, at `emb128x4.bulk-rows`' shapes: the ids are sorted on the chip,
+    Mosaic takes the kernel with its live count on a shard's block, named
+    `shard_scatter` (how `benchmark/shard_trace.py` finds it in a trace),
+    the table's blocks are aliased, the delta's rows leave the first chip
+    in three collective-permutes of one segment each, pairs (0, s), the
+    segments' ids and counts beside them, and no all-reduce, all-gather or
+    all-to-all carries anything."""
+    import re
+
+    from multiverso_tpu.ops import sharded_rows
+
+    programs = sharded_rows.ShardedRows(four_chips, False, -1.0)
+    named, shards = 100_000, 4
+    capacity = sharded_rows.shard_capacity(25_137, named, shards)
+    assert shards * capacity <= 1.15 * named and capacity % 1024
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=programs.by_rows)
+
+    compiled = programs.add.lower(
+        spec((rows, lanes), jnp.float32), spec((shards * named,), jnp.int32),
+        spec((shards * named, width), jnp.float32),
+        capacity=capacity).compile()
+    text, mem = _hlo_text(compiled), compiled.memory_analysis()
+    entry = text[text.index("ENTRY"):]
+    assert text.startswith("HloModule jit_sharded_row_add")
+    kernels = [line for line in entry.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 1 and re.match(r"\s*(ROOT )?%shard_scatter",
+                                          kernels[0]), kernels
+    assert mem.alias_size_in_bytes >= rows // shards * lanes * 4
+    sends = re.findall(r"collective-permute-start\(f32\[(\d+),(\d+)\].*?"
+                       r"source_target_pairs=\{\{0,(\d)\}\}", entry)
+    assert sorted(sends) == [(str(capacity), str(width), str(s))
+                             for s in (1, 2, 3)], sends
+    metas = re.findall(r"collective-permute-start\(s32\[(\d+)\].*?"
+                       r"source_target_pairs=\{\{0,(\d)\}\}", entry)
+    assert sorted(metas) == [(str(capacity + 1), str(s)) for s in (1, 2, 3)]
+    assert len(re.findall(r" sort\(", entry)) == 1
+    for collective in ("all-reduce", "all-gather", "all-to-all"):
+        assert collective not in entry
+    # beside the table: the pieces on their way, never the bucket
+    assert mem.temp_size_in_bytes <= 8 * capacity * lanes * 4
+
+
+def test_sharded_row_get_compiles_and_sends_the_rows_asked(four_chips):
+    """A Get of 100,000 ids from the sharded table: the first chip sends
+    each shard its segment's ids, each shard gathers them from its block in
+    the fast form (rows tiled by 256, PR 27), the three other shards send
+    their rows to the first chip, pairs (s, 0), the result is the
+    131,072-slot bucket on every shard (the first's is the answer), and
+    nothing is reduced or gathered over the mesh."""
+    import re
+
+    from benchmark.shard_trace import GATHER
+    from multiverso_tpu.ops import sharded_rows
+    from multiverso_tpu.tables.matrix_table import _live_slots
+
+    programs = sharded_rows.ShardedRows(four_chips, False, -1.0)
+    rows, lanes, _ = SHARDED_TABLES[0]  # XLA's gather: no kernel to widen
+    named, bucket, shards = 100_000, 131_072, 4
+    live = _live_slots(named, bucket)
+    capacity = sharded_rows.shard_capacity(25_137, live, shards)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=programs.by_rows)
+
+    compiled = programs._get.lower(
+        spec((rows, lanes), jnp.float32), spec((shards * live,), jnp.int32),
+        capacity=capacity, bucket=bucket).compile()
+    text = _hlo_text(compiled)
+    assert text.startswith("HloModule jit_sharded_row_get")
+    assert f"->f32[{bucket},{lanes}]" in text.splitlines()[0]
+    entry = text[text.index("ENTRY"):]
+    gathers = [(line, GATHER.search(line)) for line in entry.splitlines()
+               if GATHER.search(line)]
+    over_block = [line for line, m in gathers
+                  if int(m.group(3)) == rows // shards]
+    assert len(over_block) == 1 and len(gathers) == 2, gathers
+    assert int(GATHER.search(over_block[0]).group(1)) == capacity
+    assert re.search(r'"integer_config":\{"integer":"256"\}', over_block[0])
+    sends = re.findall(r"collective-permute-start\(f32\[(\d+),(\d+)\].*?"
+                       r"source_target_pairs=\{\{(\d),0\}\}", entry)
+    assert sorted(sends) == [(str(capacity), str(lanes), str(s))
+                             for s in (1, 2, 3)], sends
+    for collective in ("all-reduce", "all-gather", "all-to-all"):
+        assert collective not in entry
