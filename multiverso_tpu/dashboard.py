@@ -455,7 +455,10 @@ class OpRecord(NamedTuple):
     whose rows are sharded over chips it also carries the ``shards`` that
     launched (``n`` is then the slots of all of them), the fullest shard's
     slots (``max_shard_n``) and the bytes of table rows that crossed chips
-    (``exchange_bytes``). Every other stage leaves the six empty."""
+    (``exchange_bytes``). Every other stage leaves the six empty. The
+    ``TABLE_ROW_PREP`` of a host row Add says how many of its value rows
+    were summed into an earlier row of the same id (``dups``; its ``n`` is
+    the distinct rows that went up)."""
 
     seq: int
     id: int
@@ -472,6 +475,7 @@ class OpRecord(NamedTuple):
     shards: int = 0
     max_shard_n: int = 0
     exchange_bytes: int = 0
+    dups: int = 0
 
 
 class OpRing:
@@ -494,12 +498,13 @@ class OpRing:
     def append(self, span_id: int, parent: int, stage: str, start_ns: int,
                dur_ns: int, cpu_ns: int, op: int, n: int, path: str = "",
                descriptors: int = 0, bytes: int = 0, shards: int = 0,
-               max_shard_n: int = 0, exchange_bytes: int = 0) -> None:
+               max_shard_n: int = 0, exchange_bytes: int = 0,
+               dups: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
                                          path, descriptors, bytes, shards,
-                                         max_shard_n, exchange_bytes)
+                                         max_shard_n, exchange_bytes, dups)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -554,7 +559,7 @@ class _Section:
     __slots__ = ("_name", "_feeds", "_op", "n", "id", "start_ns", "dur_ns",
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
                  "path", "descriptors", "bytes", "shards", "max_shard_n",
-                 "exchange_bytes")
+                 "exchange_bytes", "dups")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
@@ -563,6 +568,7 @@ class _Section:
         self.id = 0
         self.path, self.descriptors, self.bytes = "", 0, 0
         self.shards = self.max_shard_n = self.exchange_bytes = 0
+        self.dups = 0
 
     def __enter__(self) -> "_Section":
         if Dashboard.profile_annotations:
@@ -593,7 +599,7 @@ class _Section:
             RING.append(self.id, self._parent, self._name, self.start_ns,
                         self.dur_ns, cpu, self._op, self.n, self.path,
                         self.descriptors, self.bytes, self.shards,
-                        self.max_shard_n, self.exchange_bytes)
+                        self.max_shard_n, self.exchange_bytes, self.dups)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -606,7 +612,7 @@ class _Off:
     and what a section would carry goes nowhere."""
 
     __slots__ = ("n", "path", "descriptors", "bytes", "shards",
-                 "max_shard_n", "exchange_bytes")
+                 "max_shard_n", "exchange_bytes", "dups")
     id = 0
 
     def __enter__(self) -> "_Off":

@@ -42,8 +42,8 @@ from multiverso_tpu import log
 from multiverso_tpu.dashboard import Dashboard, monitor, span
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.runtime.zoo import Zoo
-from multiverso_tpu.tables.base import (ServerTable, WorkerTable,
-                                        merge_duplicate_rows)
+from multiverso_tpu.tables.base import (RowOccurrences, ServerTable,
+                                        WorkerTable, sum_duplicate_rows)
 from multiverso_tpu.tables.array_table import _make_whole_update
 from multiverso_tpu.updaters import AddOption, GetOption, SGDUpdater, Updater, get_updater
 from multiverso_tpu.utils import async_upload, next_pow2 as _next_pow2
@@ -120,6 +120,30 @@ def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
     del num_shards
     from multiverso_tpu.ops.pallas_rows import fits_vmem
     return platform == "tpu" and fits_vmem(lanes, itemsize)
+
+
+class _StageSlot:
+    """A host Add's padded ids and values as they are uploaded, kept and
+    refilled: ``ids`` and ``vals`` hold the largest bucket seen; rows of
+    ``vals`` past ``rows``, and lanes past the table's columns, are zero;
+    ``read`` says a launch has read these arrays since they were last
+    known to be free."""
+
+    __slots__ = ("ids", "vals", "rows", "read")
+
+    def __init__(self, lanes: int, dtype) -> None:
+        self.ids = np.empty(0, np.int32)
+        self.vals = np.zeros((0, lanes), dtype)
+        self.rows = 0
+        self.read = False
+
+
+class RowPieces(list):
+    """The value rows of a fused host Add, one ``(rows, num_col)`` array of
+    the table's dtype a request, in the order of its concatenated ids:
+    what ``merge_add_requests`` hands ``process_add`` in place of one
+    array, so that a row is copied once, into the array that is
+    uploaded."""
 
 
 class MatrixServer(ServerTable):
@@ -262,6 +286,10 @@ class MatrixServer(ServerTable):
             ("add", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_ADD"),
             ("get", "pallas"): Dashboard.counter("ROW_LAUNCH_PALLAS_GET"),
             ("get", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_GET")}
+        self._duplicates_summed = Dashboard.counter(
+            "ROW_ADD_DUPLICATES_SUMMED")
+        self._stage_waits = Dashboard.counter("ROW_STAGE_WAITS")
+        self._stage = _StageSlot(self.padded_cols, self.dtype)
         self._row_update = self._make_row_update(self.updater)
 
     def _make_row_update(self, updater: Updater, jit: bool = True):
@@ -383,26 +411,43 @@ class MatrixServer(ServerTable):
         from multiverso_tpu.ops.pallas_rows import ROW_GROUP
         return max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
 
-    def _bucket_ids(self, ids: np.ndarray, values: Optional[np.ndarray],
-                    ensure_pad: bool = False
-                    ) -> Tuple[jax.Array, Optional[jax.Array], int, int]:
-        """Pad ``ids`` with sentinel-aimed slots and upload them: ``(ids,
-        values, n, bucket)``. The bucket is the next power of two, so jit
-        traces are shape-stable. An Add (``values`` given) uploads the whole
-        bucket, the values zero-padded to it. A Get uploads the slots it
-        gathers, ``_live_slots`` of them: the rest of its bucket is filled on
-        the device, not fetched."""
+    def _bucket_ids(self, ids: np.ndarray, ensure_pad: bool = False
+                    ) -> Tuple[jax.Array, int, int]:
+        """A Get's ids as they are uploaded: ``(ids, n, bucket)``. The
+        bucket is the next power of two, so jit traces are shape-stable;
+        the slots the Get gathers, ``_live_slots`` of them, go up, the ids
+        padded to them with sentinel-aimed slots: the rest of the bucket
+        is filled on the device, not fetched."""
         n = len(ids)
         bucket = self._get_bucket(n, ensure_pad)
-        slots = bucket if values is not None else _live_slots(n, bucket)
         ids_p = np.concatenate(
-            [ids, np.full(slots - n, self.sentinel_row, dtype=ids.dtype)])
-        vals_p = None
-        if values is not None:
-            padded = np.zeros((bucket, self.padded_cols), dtype=values.dtype)
-            padded[:n, : self.num_col] = values
-            vals_p = async_upload(padded)
-        return async_upload(ids_p), vals_p, n, bucket
+            [ids, np.full(_live_slots(n, bucket) - n, self.sentinel_row,
+                          dtype=ids.dtype)])
+        return async_upload(ids_p), n, bucket
+
+    def _staging(self, bucket: int) -> _StageSlot:
+        """The slot a row Add's padded ids and values are written into and
+        uploaded from, its arrays ``bucket`` slots or more: kept by the
+        table and refilled (fresh megabytes an Add cost the dispatcher
+        more than the rows it copied into them; PERF.md, Findings, PR 31).
+
+        An uploaded array may not change while the runtime can still read
+        it, and a CPU client's device array can be the host memory it was
+        put from for as long as it lives: so the slot is refilled only
+        after the launch that read it has run, which the table's newest
+        state being ready says; ``ROW_STAGE_WAITS`` counts the times it
+        had to be waited for."""
+        slot = self._stage
+        if slot.read:
+            if not self.data.is_ready():
+                self._stage_waits.add()
+                self.data.block_until_ready()
+            slot.read = False
+        if len(slot.ids) < bucket:
+            slot.ids = np.empty(bucket, np.int32)
+            slot.vals = np.zeros((bucket, self.padded_cols), self.dtype)
+            slot.rows = 0
+        return slot
 
     def _gather_rows(self, row_ids: np.ndarray, device_out: bool = False
                      ) -> jax.Array:
@@ -415,8 +460,8 @@ class MatrixServer(ServerTable):
         n = len(row_ids)
         if self._shard_rows is None:
             with span("TABLE_ROW_PREP") as prep:
-                ids_p, _, prep.n, bucket = self._bucket_ids(
-                    row_ids, None, ensure_pad=device_out)
+                ids_p, prep.n, bucket = self._bucket_ids(
+                    row_ids, ensure_pad=device_out)
             with span("TABLE_ROW_LAUNCH") as launch:
                 # the slots gathered, not the bucket the result fills
                 self._note_launch(launch, "get", ids_p.shape[0], False)
@@ -444,27 +489,31 @@ class MatrixServer(ServerTable):
 
     # -- server ops --------------------------------------------------------
     def merge_add_requests(self, requests):
-        """Fuse queued host row-Adds into ONE scatter: concatenate
-        (ids, values) across the group and hand back one request whose
-        apply is a single jitted/pallas scatter_add. Duplicate rows are
-        pre-aggregated client-style INSIDE ``process_add`` (the shared
-        ``tables.base.merge_duplicate_rows``) exactly when the apply path
-        requires unique ids — the pallas in-place row-DMA kernel and
-        stateful updaters; XLA's scatter-add handles duplicates natively,
-        so the linear non-pallas path skips the host-side aggregation
-        entirely. Linear updaters only — a stateful updater
-        (momentum/adagrad) applied once to a summed delta is a different
-        operator than N sequential applies. Whole-table, device-resident,
-        and transact forms stop the scan (None when FIRST — per-message
-        dispatch; otherwise the compatible prefix fuses and the rest
-        waits for the next call). The ``apply_batch_rows`` flag bounds
-        the fused row count so the power-of-two id bucket (and its
-        zero-padded upload) cannot blow up under backlog."""
+        """Fuse queued host row-Adds into ONE scatter: hand back one
+        request whose apply is a single jitted/pallas scatter_add, its ids
+        the group's ids concatenated (12 KB for three requests of 1,024)
+        and its values the group's arrays as they came (``RowPieces``):
+        ``process_add`` copies each value row once, into the array it
+        uploads, and sums the rows two requests both name on the way,
+        exactly when the apply path requires unique ids (the pallas
+        in-place row-DMA kernel and stateful updaters; XLA's scatter-add
+        handles duplicates natively). Concatenating the values here as well
+        was 1.5 MB copied to be copied again: with the merge it fed, 4.7 ms
+        of the one dispatcher thread a fused apply of three requests on the
+        chip's host (PERF.md, Findings, PR 31, has what it costs now).
+        Linear updaters only — a stateful updater (momentum/adagrad)
+        applied once to a summed delta is a different operator than N
+        sequential applies. Whole-table, device-resident, and transact
+        forms stop the scan (None when FIRST — per-message dispatch;
+        otherwise the compatible prefix fuses and the rest waits for the
+        next call). The ``apply_batch_rows`` flag bounds the fused row
+        count so the power-of-two id bucket (and its zero-padded upload)
+        cannot blow up under backlog."""
         if not self._linear:
             return None
         from multiverso_tpu import config as config_mod
         rows_cap = int(config_mod.get_flag("apply_batch_rows"))
-        ids_list, vals_list = [], []
+        ids_list, pieces = [], RowPieces()
         total = 0
         for request in requests:
             if not (isinstance(request, tuple) and len(request) == 3):
@@ -481,13 +530,12 @@ class MatrixServer(ServerTable):
                     and total + len(row_ids) > rows_cap:
                 break
             ids_list.append(row_ids)
-            vals_list.append(values)
+            pieces.append(values)
             total += len(row_ids)
         if not ids_list:
             return None
-        ids = np.concatenate(ids_list)
-        return ((ids, np.concatenate(vals_list), requests[0][2]),
-                int(len(ids)), len(ids_list))
+        return ((np.concatenate(ids_list), pieces, requests[0][2]),
+                total, len(ids_list))
 
     def process_add(self, request):
         with span("TABLE_PROCESS_ADD"):
@@ -522,25 +570,45 @@ class MatrixServer(ServerTable):
             with span("TABLE_ROW_PREP") as prep:
                 row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
                 self._check_row_range(row_ids, "add")
-                values = np.asarray(values, dtype=self.dtype).reshape(-1, self.num_col)
-                if len(row_ids) != len(values):
-                    log.fatal("Matrix.add: %d ids but %d value rows", len(row_ids), len(values))
+                pieces = values if isinstance(values, RowPieces) else [
+                    np.asarray(values, dtype=self.dtype).reshape(
+                        -1, self.num_col)]
+                total = sum(len(piece) for piece in pieces)
+                if len(row_ids) != total:
+                    log.fatal("Matrix.add: %d ids but %d value rows", len(row_ids), total)
                 # unique ids: required by stateful updaters (one apply per
                 # row) and by the pallas scatter kernel's in-place row DMA
                 # contract; XLA's scatter-add handles duplicates natively,
-                # so the linear non-pallas path skips the host-side
-                # aggregation (fused micro-batches from the dispatcher
-                # concatenate without dedup for exactly this reason)
-                if not (self._linear and not self._pallas_scatter):
-                    row_ids, values = merge_duplicate_rows(row_ids, values)
+                # so the linear non-pallas path copies a fused group's
+                # rows in arrival order and sorts nothing
+                found = None if self._linear and not self._pallas_scatter \
+                    else RowOccurrences(row_ids)
+                prep.n = n = total if found is None else found.n
+                if n < total:
+                    prep.dups = total - n
+                    self._duplicates_summed.add(total - n)
                 routed = self._linear and self._shard_rows is not None
                 if routed:
                     # the delta goes up to the first chip and takes a device
                     # delta's route from there
-                    prep.n = len(row_ids)
-                    routed = self._route_add(row_ids, values)
+                    rows = np.empty((n, self.num_col), self.dtype)
                 else:
-                    ids_p, vals_p, prep.n, _ = self._bucket_ids(row_ids, values)
+                    bucket = self._get_bucket(n, False)
+                    slot = self._staging(bucket)
+                    # what an earlier, longer Add left past these rows
+                    slot.vals[n:slot.rows, : self.num_col] = 0
+                    slot.rows = n
+                    rows = slot.vals[:n, : self.num_col]
+                row_ids = sum_duplicate_rows(row_ids, pieces, found, rows)
+                if routed:
+                    routed = self._route_add(row_ids, rows)
+                else:
+                    slot.ids[:n] = row_ids
+                    slot.ids[n:bucket] = self.sentinel_row
+                    # one call: each costs the host a quarter of a
+                    # millisecond whatever it carries
+                    ids_p, vals_p = async_upload((slot.ids[:bucket],
+                                                  slot.vals[:bucket]))
             with span("TABLE_ROW_LAUNCH") as launch:
                 if routed:
                     self._launch_routed_add(launch, *routed)
@@ -553,6 +621,7 @@ class MatrixServer(ServerTable):
                         self.data, self.states = self._row_update(
                             self.data, self.states, ids_p, vals_p, worker,
                             scalars)
+                    slot.read = True
             touched = row_ids
         if self.is_sparse:
             with self._std_lock:

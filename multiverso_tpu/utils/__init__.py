@@ -165,7 +165,11 @@ def async_upload(x):
     """Host->device transfer that ENQUEUES and returns immediately with a
     future-backed array, where ``jnp.asarray`` waits for the copy. The
     rule for every hot-path numpy upload (the difference is not measured
-    on the current machine); the input must not be mutated after the call
-    (the copy is in flight)."""
+    on the current machine); the input must not be mutated while the
+    result, or a program launched on it, can still read it: the copy is
+    in flight, and a CPU client's array may be the input's own memory for
+    as long as it lives (``MatrixServer._staging`` has the one place that
+    writes an uploaded array again). A tuple goes up in one call (a call
+    costs the chip's host 0.25 ms whatever it carries; PERF.md, PR 31)."""
     import jax
     return jax.device_put(x)
