@@ -458,7 +458,10 @@ class OpRecord(NamedTuple):
     (``exchange_bytes``). Every other stage leaves the six empty. The
     ``TABLE_ROW_PREP`` of a host row Add says how many of its value rows
     were summed into an earlier row of the same id (``dups``; its ``n`` is
-    the distinct rows that went up)."""
+    the distinct rows that went up). The launch of an Add under a stateful
+    updater names the updater (``updater``; empty under a linear one), the
+    id slots whose state it read and wrote (``state_rows``) and the bytes
+    of state that is (``state_bytes``, read and write)."""
 
     seq: int
     id: int
@@ -476,6 +479,9 @@ class OpRecord(NamedTuple):
     max_shard_n: int = 0
     exchange_bytes: int = 0
     dups: int = 0
+    updater: str = ""
+    state_rows: int = 0
+    state_bytes: int = 0
 
 
 class OpRing:
@@ -499,12 +505,14 @@ class OpRing:
                dur_ns: int, cpu_ns: int, op: int, n: int, path: str = "",
                descriptors: int = 0, bytes: int = 0, shards: int = 0,
                max_shard_n: int = 0, exchange_bytes: int = 0,
-               dups: int = 0) -> None:
+               dups: int = 0, updater: str = "", state_rows: int = 0,
+               state_bytes: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
                                          path, descriptors, bytes, shards,
-                                         max_shard_n, exchange_bytes, dups)
+                                         max_shard_n, exchange_bytes, dups,
+                                         updater, state_rows, state_bytes)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -559,7 +567,8 @@ class _Section:
     __slots__ = ("_name", "_feeds", "_op", "n", "id", "start_ns", "dur_ns",
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
                  "path", "descriptors", "bytes", "shards", "max_shard_n",
-                 "exchange_bytes", "dups")
+                 "exchange_bytes", "dups", "updater", "state_rows",
+                 "state_bytes")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
@@ -569,6 +578,7 @@ class _Section:
         self.path, self.descriptors, self.bytes = "", 0, 0
         self.shards = self.max_shard_n = self.exchange_bytes = 0
         self.dups = 0
+        self.updater, self.state_rows, self.state_bytes = "", 0, 0
 
     def __enter__(self) -> "_Section":
         if Dashboard.profile_annotations:
@@ -599,7 +609,8 @@ class _Section:
             RING.append(self.id, self._parent, self._name, self.start_ns,
                         self.dur_ns, cpu, self._op, self.n, self.path,
                         self.descriptors, self.bytes, self.shards,
-                        self.max_shard_n, self.exchange_bytes, self.dups)
+                        self.max_shard_n, self.exchange_bytes, self.dups,
+                        self.updater, self.state_rows, self.state_bytes)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -612,7 +623,8 @@ class _Off:
     and what a section would carry goes nowhere."""
 
     __slots__ = ("n", "path", "descriptors", "bytes", "shards",
-                 "max_shard_n", "exchange_bytes", "dups")
+                 "max_shard_n", "exchange_bytes", "dups", "updater",
+                 "state_rows", "state_bytes")
     id = 0
 
     def __enter__(self) -> "_Off":

@@ -1775,6 +1775,10 @@ class _RemoteMatrixWorker(MatrixWorker):
         raise RuntimeError("get_device() needs mesh residency; remote "
                            "clients are off-mesh — use get()")
 
+    def get_state_device(self, name):
+        raise RuntimeError("get_state_device() needs mesh residency; "
+                           "remote clients are off-mesh")
+
 
 class _RemoteKVWorker(KVWorker):
     def __init__(self, spec, table_id: int, channel: RemoteChannel) -> None:

@@ -72,6 +72,10 @@ class ArrayServer(ServerTable):
         self.data = jax.device_put(init, sharding)
 
         self.updater = get_updater(self.dtype, updater_type)
+        if self.updater.row_state:
+            log.fatal("updater_type %s keeps one value of state a row: it "
+                      "serves matrix tables, not an array table",
+                      self.updater.name)
         worker_dim = self.num_workers if self.updater.per_worker_state else 1
         self.states: Dict[str, jax.Array] = {}
         for name, (shape_suffix, sdtype) in self.updater.state_spec(

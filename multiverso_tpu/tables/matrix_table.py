@@ -11,9 +11,13 @@ compression variant (``src/table/sparse_matrix_table.cpp``).
 TPU-native re-design:
 
 * Server state is ONE row-sharded ``jax.Array`` in HBM; row Get is a jitted
-  device gather, row Add is a jitted scatter-add (linear updaters) or
-  gather→apply→scatter (stateful updaters) — the client-side per-server
-  ``Partition`` bucketing loop is gone, XLA partitions the scatter.
+  device gather, row Add is a jitted scatter-add (linear updaters),
+  gather→apply→scatter (stateful updaters whose state is shaped like the
+  table) or, for an updater with one value of state a row
+  (``Updater.row_state``), a state step over the delta and the named rows'
+  states followed by the same scatter-add of the scaled delta — the
+  client-side per-server ``Partition`` bucketing loop is gone, XLA
+  partitions the scatter.
 * Row-id batches are padded to power-of-two buckets aimed at a sentinel
   scratch row, so jit traces are reused across batch sizes and the MXU sees
   static shapes. The work follows the rows named, not the bucket: an Add's
@@ -122,6 +126,48 @@ def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
     return platform == "tpu" and fits_vmem(lanes, itemsize)
 
 
+def _state_of_rows(state: jax.Array, live: jax.Array) -> jax.Array:
+    """``state[live]`` of a lane-dense ``(rows,)`` state whose length is
+    whole lane tiles (the table pads it so): read as rows of 128 values (a
+    bitcast) and the one lane a slot wants picked under a mask (the sum has
+    one term that is not zero, so it is exact). XLA's TPU gather moves
+    100,000 rows of 512 bytes in 0.137 ms and the select takes 0.080; the
+    same gather of single floats took 1.195 ms (PERF.md, Findings,
+    PR 33)."""
+    rows = state.reshape(-1, 128)[live >> 7]
+    lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    return jnp.sum(jnp.where(lane == (live & 127)[:, None], rows, 0), axis=1)
+
+
+def _make_row_state_add(updater: Updater, scatter, cols: int,
+                        jit: bool = True):
+    """A row Add under an updater with one value of state a row
+    (``Updater.row_state``), ``(data, states, ids, delta, worker, scalars)
+    -> (data, states)``: the named rows' states are read, stepped from the
+    delta alone and written back, and the scaled delta goes to ``scatter``,
+    the scatter-add the table's linear Adds use (the Pallas row kernel
+    where it serves the table): one device program. ``ids`` may be a bucket
+    longer than ``delta`` (the kernel's contract); a sentinel slot's zero
+    delta leaves its state as it was and adds zero. ``cols`` is the table's
+    column count, the length of a gradient row whatever the delta's width
+    or the table's lanes."""
+
+    def _row_state_add(data, states, ids, delta, worker, scalars):
+        del worker  # the state is shared
+        live = ids[: delta.shape[0]]
+        step, new = updater.row_step(
+            {k: _state_of_rows(v, live) for k, v in states.items()}, delta,
+            scalars, cols)
+        # sentinel slots may repeat: each writes back the value it read
+        states = {k: states[k].at[live].set(new[k]) for k in states}
+        return scatter(data, ids, step), states
+
+    # named so that the compiled module is ``jit__row_state_add`` in a
+    # trace: what runs in it beside the kernel is the state step
+    return jax.jit(_row_state_add, donate_argnums=(0, 1)) if jit \
+        else _row_state_add
+
+
 class _StageSlot:
     """A host Add's padded ids and values as they are uploaded, kept and
     refilled: ``ids`` and ``vals`` hold the largest bucket seen; rows of
@@ -191,13 +237,23 @@ class MatrixServer(ServerTable):
             else functools.partial(self._uniform_rows, init_range, seed))
 
         self.updater = get_updater(self.dtype, updater_type)
+        # one value of state a row: ``(rows,)``, lane-dense in HBM (40 MB for
+        # 10,000,000 rows; as ``(rows, 1)`` a TPU would tile it to 128 lanes
+        # a row), sharded like the table's rows, with no worker dimension
+        self._row_state = self.updater.row_state
         worker_dim = self.num_workers if self.updater.per_worker_state else 1
         self.states: Dict[str, jax.Array] = {}
+        # a row state is whole lane tiles on every shard (`_state_of_rows`)
+        state_rows = mesh_lib.pad_to_multiple(
+            self.padded_rows, 1024 * num_shards) if self._row_state \
+            else self.padded_rows
         for name, (shape_suffix, sdtype) in self.updater.state_spec(
-                (self.padded_rows, self.padded_cols), self.dtype).items():
-            s_shard = mesh_lib.table_sharding(self.mesh, ndim=3, shard_dim=1)
-            self.states[name] = jax.device_put(
-                np.zeros((worker_dim,) + tuple(shape_suffix), dtype=sdtype), s_shard)
+                (state_rows, self.padded_cols), self.dtype).items():
+            shape = tuple(shape_suffix) if self._row_state \
+                else (worker_dim,) + tuple(shape_suffix)
+            # zeros made on the device: no host array of the state's size
+            self.states[name] = jnp.zeros(shape, sdtype,
+                                          device=self._state_sharding())
 
         # staleness metadata (gen-2 `up_to_date_`): host-side control plane.
         # is_pipelined doubles the planes (reference matrix.cpp:407-418):
@@ -215,7 +271,8 @@ class MatrixServer(ServerTable):
             self._up_to_date = np.zeros((self.num_slots, self.num_row), dtype=bool)
             self._std_lock = threading.Lock()
 
-        self._whole_update = _make_whole_update(self.updater)
+        self._whole_update = self._make_whole_row_state_update() \
+            if self._row_state else _make_whole_update(self.updater)
         self._linear = type(self.updater) in (Updater, SGDUpdater)
         self._sign = -1.0 if isinstance(self.updater, SGDUpdater) else 1.0
         self._gather = functools.partial(_row_gather_jit,
@@ -256,8 +313,8 @@ class MatrixServer(ServerTable):
                 why += (", on every shard's block of %d rows, ids routed "
                         "to their owners" % self._block_rows)
                 if not self._linear:
-                    why += ("; this table's %s updater takes XLA's row "
-                            "update" % type(self.updater).__name__)
+                    why += ("; this table's %s updater takes XLA's "
+                            "partitioned row update" % self.updater.name)
         else:
             why = "XLA scatter (%s)" % (
                 "the kernel compiles for tpu only" if platform != "tpu"
@@ -278,6 +335,16 @@ class MatrixServer(ServerTable):
                 _xla_scatter_add, sign=self._sign)
             self._scatter_add = jax.jit(self._scatter_add_raw,
                                         donate_argnums=(0,))
+        # the table rows of an Add go through the row kernel: a linear
+        # updater's delta, or a row-state updater's scaled delta (on a table
+        # sharded over chips the first is routed, the second takes XLA's
+        # partitioned programs)
+        self._kernel_rows = (self._pallas_scatter and num_shards == 1
+                             and (self._linear or self._row_state))
+        if self.states and num_shards == 1:
+            why += "; %s updater: %s" % (self.updater.name, (
+                "state step, then that scatter-add of the scaled delta"
+                if self._row_state else "XLA's row update"))
         log.info("MatrixTable %dx%d on %d %s device(s): row scatter = %s",
                  self.num_row, self.num_col, num_shards, platform, why)
         # always on: which program served each row launch, by op
@@ -286,13 +353,48 @@ class MatrixServer(ServerTable):
             ("add", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_ADD"),
             ("get", "pallas"): Dashboard.counter("ROW_LAUNCH_PALLAS_GET"),
             ("get", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_GET")}
+        # of the Add launches, those under a stateful updater
+        self._stateful_launches = {
+            "pallas": Dashboard.counter("ROW_LAUNCH_PALLAS_STATEFUL_ADD"),
+            "xla": Dashboard.counter("ROW_LAUNCH_XLA_STATEFUL_ADD")}
+        # bytes of state a launch reads for one id slot (and writes again)
+        self._state_slot_bytes = sum(
+            np.dtype(v.dtype).itemsize
+            * (1 if self._row_state else self.padded_cols)
+            for v in self.states.values())
         self._duplicates_summed = Dashboard.counter(
             "ROW_ADD_DUPLICATES_SUMMED")
         self._stage_waits = Dashboard.counter("ROW_STAGE_WAITS")
         self._stage = _StageSlot(self.padded_cols, self.dtype)
         self._row_update = self._make_row_update(self.updater)
 
+    def _state_sharding(self):
+        """Sharding of an updater state: its row dimension split like the
+        table's rows."""
+        if self._row_state:
+            return mesh_lib.table_sharding(self.mesh, ndim=1, shard_dim=0)
+        return mesh_lib.table_sharding(self.mesh, ndim=3, shard_dim=1)
+
+    def _make_whole_row_state_update(self):
+        """The whole-table Add under a row-state updater: every row is
+        named, the delta's lanes past the table's columns are zeros."""
+        updater, cols, rows = self.updater, self.num_col, self.padded_rows
+
+        def f(data, states, delta, worker, scalars):
+            del worker  # the state is shared
+            step, new = updater.row_step(
+                {k: v[:rows] for k, v in states.items()}, delta, scalars,
+                cols)
+            return data + step, {k: states[k].at[:rows].set(new[k])
+                                 for k in states}
+
+        return jax.jit(f, donate_argnums=(0, 1))
+
     def _make_row_update(self, updater: Updater, jit: bool = True):
+        if updater.row_state:
+            return _make_row_state_add(updater, self._scatter_add_raw,
+                                       self.num_col, jit)
+
         def f(data, states, ids, delta, worker, scalars):
             rows = data[ids]
             if updater.per_worker_state:
@@ -315,8 +417,9 @@ class MatrixServer(ServerTable):
         for embedding in a caller's fused jit (device transactions).
         Same semantics as the add path: linear updaters reduce to a
         scatter-add (sign folded in), stateful updaters run the row
-        update. ``ids`` must be unique apart from sentinel pads with
-        zero deltas."""
+        update (a row-state updater its state step and the scatter-add).
+        ``ids`` must be unique apart from sentinel pads with zero
+        deltas."""
         if self._linear:
             scatter = self._scatter_add_raw
 
@@ -381,6 +484,12 @@ class MatrixServer(ServerTable):
         launch.descriptors = moves * slots if pallas else 0
         launch.bytes = (moves * slots * self.padded_cols
                         * self.dtype.itemsize)
+        if op == "add" and self.states:
+            # a stateful Add: whose rule, and the state it read and wrote
+            self._stateful_launches[path].add()
+            launch.updater = self.updater.name
+            launch.state_rows = slots
+            launch.state_bytes = 2 * slots * self._state_slot_bytes
         if segments is not None:
             by_shard, capacity = segments
             launch.shards = len(by_shard)
@@ -548,6 +657,7 @@ class MatrixServer(ServerTable):
             return self._process_transact(self._resolve_named(request))
         row_ids, values, option = request
         option = option or AddOption()
+        self.updater.check_option(option)
         # administrative access (worker id -1) charges slot 0, not slot n-1
         worker, scalars = self._option_consts(option)
         if isinstance(values, jax.Array):
@@ -614,7 +724,7 @@ class MatrixServer(ServerTable):
                     self._launch_routed_add(launch, *routed)
                 else:
                     self._note_launch(launch, "add", ids_p.shape[0],
-                                      self._linear and self._pallas_scatter)
+                                      self._kernel_rows)
                     if self._linear:
                         self.data = self._scatter_add(self.data, ids_p, vals_p)
                     else:
@@ -665,7 +775,7 @@ class MatrixServer(ServerTable):
             else:
                 # the pallas kernel takes the delta as it came and walks its
                 # row groups, not the bucket's: one device program an Add
-                pallas = self._linear and self._pallas_scatter
+                pallas = self._kernel_rows
                 if not pallas:
                     values = self._bucket_delta(values, bucket)
                 self._note_launch(launch, "add",
@@ -834,15 +944,21 @@ class MatrixServer(ServerTable):
                 "num_workers": self.num_workers}
 
     # -- checkpoint --------------------------------------------------------
+    def _state_logical(self):
+        """The index of a state array's logical part (padding is a function
+        of the restoring mesh, not checkpoint content)."""
+        if self._row_state:
+            return slice(0, self.num_row)
+        return (slice(None), slice(0, self.num_row), slice(0, self.num_col))
+
     def store(self, stream) -> None:
         from multiverso_tpu.checkpoint import write_array, write_state_dict
         write_array(stream,
                     self._host_read(self.data)[: self.num_row,
                                                : self.num_col])
-        # updater state sliced to logical dims (padding is a function of
-        # the restoring mesh, not checkpoint content)
+        # updater state sliced to logical dims
         write_state_dict(stream, {
-            name: self._host_read(arr)[:, : self.num_row, : self.num_col]
+            name: self._host_read(arr)[self._state_logical()]
             for name, arr in self.states.items()})
 
     def load(self, stream) -> None:
@@ -850,12 +966,11 @@ class MatrixServer(ServerTable):
         arr = read_array(stream).astype(self.dtype).reshape(self.num_row, self.num_col)
         self.data = self._put_rows(arr)
         loaded = read_state_dict(stream)
-        s_shard = mesh_lib.table_sharding(self.mesh, ndim=3, shard_dim=1)
         for name, cur in self.states.items():
             got = loaded.get(name)
             if got is None:
                 continue  # v1 checkpoint: that state resets (pre-v2 behavior)
-            if got.shape[0] != cur.shape[0]:
+            if not self._row_state and got.shape[0] != cur.shape[0]:
                 # per-worker state from a world with a different worker
                 # count: elastic restarts keep working — reset like v1
                 log.info("checkpoint: %s worker dim %d != %d; resetting "
@@ -863,8 +978,8 @@ class MatrixServer(ServerTable):
                          cur.shape[0])
                 continue
             full = np.zeros(cur.shape, np.dtype(cur.dtype))
-            full[:, : self.num_row, : self.num_col] = got
-            self.states[name] = jax.device_put(full, s_shard)
+            full[self._state_logical()] = got
+            self.states[name] = jax.device_put(full, self._state_sharding())
         if self.is_sparse:
             # staleness is NOT restorable state: it certifies worker-side
             # client caches the snapshot does not cover — a restored
@@ -1170,3 +1285,9 @@ class MatrixWorker(WorkerTable):
     # -- TPU-era fast path -------------------------------------------------
     def get_device(self) -> jax.Array:
         return self._server_table.data
+
+    def get_state_device(self, name: str) -> jax.Array:
+        """An updater state's device array as the server holds it (for a
+        row-state updater ``(rows,)``, the table's rows and its scratch
+        rows): what a checkpoint stores, read without one."""
+        return self._server_table.states[name]

@@ -21,6 +21,11 @@ reproduced: AdaGrad accumulator was read via a copy and never persisted
 DCASGD is fully implemented (the reference only reserved the option): the
 delay-compensated ASGD rule ``data -= lr*(g + lambda * g*g*(data - backup))``
 with a per-worker backup of parameters at last read.
+
+Row-wise AdaGrad (``rowwise_adagrad``) is the one updater whose state is not
+shaped like the table: one float32 a row, shared by the workers
+(``row_state``). Its rule splits into a state step over the delta alone and
+a plain scaled Add, so a matrix table keeps it on the row kernel.
 """
 
 from __future__ import annotations
@@ -87,6 +92,14 @@ class Updater:
 
     name = "default"
     per_worker_state = False
+    # True: every state is one value a ROW, ``(rows,)``, shared by the
+    # workers, with no worker dimension; the updater gives ``row_step`` in
+    # place of ``apply`` and serves matrix tables only
+    row_state = False
+
+    def check_option(self, option: AddOption) -> None:
+        """Raises for an Add's option this updater cannot mean (a table
+        calls it before every Add; the waiter gets the error)."""
 
     def state_spec(self, table_shape: Tuple[int, ...],
                    dtype: Any) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
@@ -161,12 +174,77 @@ class DCASGDUpdater(Updater):
         return new_data, {"backup": new_data}
 
 
+class RowwiseAdaGradUpdater(Updater):
+    """Row-wise sparse AdaGrad: one float32 of state a row. For every row
+    ``r`` an Add names, ``g_r`` its raw gradient (``cols`` values)::
+
+        s_r <- s_r + mean_j(g_rj^2)                # initial 0
+        w_r <- w_r - lr * g_r / (sqrt(s_r) + eps)  # eps outside the root
+
+    Source: ``facebookresearch/dlrm``, ``dlrm_s_pytorch.py
+    --optimizer=rwsadagrad`` (``optim/rwsadagrad.py``, ``RWSAdagrad``);
+    FBGEMM's ``EXACT_ROWWISE_ADAGRAD`` is the same arithmetic. ``lr`` and
+    ``eps`` ride the Add's option as ``learning_rate`` and ``rho`` (the
+    source's defaults are 0.01 and 1e-10). The option's own defaults are
+    0.1 and 0.1, and an ``eps`` of 0.1 is a billion times the source's: a
+    table refuses an Add that brings no option, or one whose ``rho`` is
+    left at that default (``check_option``), so a trainer names both. One
+    departure: the source's
+    ``lr_decay``, ``weight_decay`` and ``initial_accumulator_value`` are
+    left at their defaults of 0 and have no option here.
+
+    Rows an Add does not name keep ``w`` and ``s`` to the last bit. The
+    state is shared, not per worker: Adds are optimizer steps in the order
+    the server acknowledges them, so two Adds that name one row do not
+    commute and are never summed into one (a table fuses no host Adds
+    under this updater). Within one Add the ids are distinct (a worker
+    sums its own duplicates; the host path does it for a request that did
+    not).
+
+    The rule splits: ``s`` depends on the delta alone, and with ``s_r``
+    known the table's update is a plain Add of
+    ``-(lr / (sqrt(s_r) + eps)) * g_r``. ``row_step`` is that split; the
+    table gathers and writes the named rows' ``s`` and hands the scaled
+    delta to the row scatter-add its linear updaters use."""
+
+    name = "rowwise_adagrad"
+    row_state = True
+
+    def state_spec(self, table_shape, dtype):
+        return {"s": (tuple(table_shape[:1]), jnp.float32)}
+
+    def check_option(self, option: AddOption) -> None:
+        if np.float32(option.rho) == np.float32(AddOption.rho):
+            raise ValueError(
+                "rowwise_adagrad: the Add's option leaves rho, the rule's "
+                "eps, at the option's default %g; name learning_rate and "
+                "rho (the source's are 0.01 and 1e-10)" % AddOption.rho)
+
+    def row_step(self, states, delta, option_scalars, cols: int):
+        """``(increment, new states)`` for rows whose states are
+        ``states`` (``(n,)`` each) and whose gradients are ``delta``
+        (``(n, <=cols)``; columns it lacks, and lanes past ``cols``, are
+        zeros): the table adds ``increment`` to those rows."""
+        lr, eps = option_scalars[1], option_scalars[2]
+        g = delta.astype(jnp.float32)
+        # times the reciprocal: exact where ``cols`` is a power of two,
+        # which a device's division need not be
+        s = states["s"] + jnp.sum(g * g, axis=1) * (1.0 / cols)
+        step = (-lr / (jnp.sqrt(s) + eps))[:, None] * g
+        return step.astype(delta.dtype), {"s": s}
+
+    def apply(self, data, states, delta, option_scalars):
+        raise NotImplementedError(
+            "rowwise_adagrad has no same-shape state: tables call row_step")
+
+
 _REGISTRY: Dict[str, Callable[[], Updater]] = {
     "default": Updater,
     "sgd": SGDUpdater,
     "momentum_sgd": MomentumUpdater,
     "adagrad": AdaGradUpdater,
     "dcasgd": DCASGDUpdater,
+    "rowwise_adagrad": RowwiseAdaGradUpdater,
 }
 
 

@@ -419,6 +419,11 @@ def test_two_tenant_drill_chargeback_and_exposition(tmp_path):
     Prometheus series exist for both tenants."""
     from multiverso_tpu.shard.group import ShardGroup
 
+    # isolate: the process-global trace store is part of the fleet's
+    # chargeback, and a file that ran earlier in this process (under
+    # xdist's `loadfile`, whichever the scheduler gave this worker) may
+    # have left seconds of default-tenant apply spans in it
+    TRACES.reset()
     spec = ("writer:tables=0,qps=1e6,burst=1e6;"
             "reader:tables=1,qps=1e6,burst=1e6")
     rows, cols = 16, 8

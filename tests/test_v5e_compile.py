@@ -236,3 +236,65 @@ def test_sharded_row_get_compiles_and_sends_the_rows_asked(four_chips):
                              for s in (1, 2, 3)], sends
     for collective in ("all-reduce", "all-gather", "all-to-all"):
         assert collective not in entry
+
+
+# table rows (a sentinel past the table), lanes, gradient columns: the
+# `emb128rws.bulk-updates` cell's table, and a width under one lane tile
+@pytest.mark.parametrize("rows,lanes,width", [(10_000_001, 128, 128),
+                                              (1_000_001, 128, 50)])
+def test_row_state_add_compiles_with_the_row_kernel(one_chip, rows, lanes,
+                                                    width):
+    """A device Add of 100,000 gradient rows under `rowwise_adagrad`, the
+    table's own program: ONE module, `jit__row_state_add` (how
+    `benchmark/rws_trace.py` finds it in a trace), whose table rows move
+    through the Pallas row kernel, named `_scatter_add_call` like the plain
+    Add's (how `row_scatter_roofline`'s reader finds it) with the id bucket
+    and the scaled gradient as its first operands; table and state are
+    aliased in place; the state is one float32 a row, lane-dense (no
+    `[rows, 1]` array tiled to 128 lanes anywhere) and gathered as rows of
+    128, and nothing of the table's size is a temporary. Fails if the updater leaves the kernel."""
+    import functools
+    import re
+
+    import numpy as np
+
+    from benchmark import common
+    from multiverso_tpu.tables.matrix_table import _make_row_state_add
+    from multiverso_tpu.updaters import get_updater
+
+    row_scatter_roofline = common.load_module("layers",
+                                              "row_scatter_roofline")
+    named, bucket = 100_000, 131_072
+    updater = get_updater(np.float32, "rowwise_adagrad")
+    scatter = functools.partial(pallas_rows.scatter_add_rows,
+                                interpret=False, sign=1.0)
+    program = _make_row_state_add(updater, scatter, width)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state_rows = -(-rows // 1024) * 1024  # as the table pads it
+    compiled = program.lower(
+        spec((rows, lanes), jnp.float32),
+        {"s": spec((state_rows,), jnp.float32)},
+        spec((bucket,), jnp.int32), spec((named, width), jnp.float32),
+        spec((), jnp.int32), spec((4,), jnp.float32)).compile()
+    text, mem = _hlo_text(compiled), compiled.memory_analysis()
+    assert text.startswith("HloModule jit__row_state_add")
+    entry = text[text.index("ENTRY"):]
+    kernels = [line for line in entry.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 1, kernels
+    assert re.match(r"\s*(ROOT )?%_scatter_add_call", kernels[0]), kernels
+    shapes = row_scatter_roofline.SHAPES.search(kernels[0])
+    assert shapes and shapes.groups() == (str(bucket), str(named),
+                                          str(width)), kernels
+    assert mem.alias_size_in_bytes >= rows * lanes * 4 + state_rows * 4
+    assert f"f32[{state_rows},1]" not in text
+    # the state is read as rows of 128 floats (XLA's row gather), not as
+    # single floats: 0.137 ms against 1.195 on the chip (PERF.md, PR 33)
+    assert re.search(rf"f32\[{named},128\]\S* fusion\(f32\[{state_rows // 128}"
+                     r",128\]", entry), "no row gather over the state"
+    # the scaled gradient, its row-major copy where it is narrower than
+    # the lanes, and the state in fast memory: never a table
+    assert mem.temp_size_in_bytes <= 3 * named * lanes * 4 + rows * 4 * 2
