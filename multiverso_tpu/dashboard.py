@@ -451,11 +451,13 @@ class OpRecord(NamedTuple):
     ``req_id``, else its ``msg_id``; ``n`` counts rows, bytes or fused
     messages, by stage. A row launch (``TABLE_ROW_LAUNCH``) also says
     which program served it (``path``: ``pallas`` or ``xla``), the DMA
-    descriptors it issues and the bytes of table rows it moves; on a table
+    descriptors it issues, the semaphore ``waits`` it issues for them (two
+    a whole row group: ``descriptors / waits`` is the kernel's group) and
+    the bytes of table rows it moves; on a table
     whose rows are sharded over chips it also carries the ``shards`` that
     launched (``n`` is then the slots of all of them), the fullest shard's
     slots (``max_shard_n``) and the bytes of table rows that crossed chips
-    (``exchange_bytes``). Every other stage leaves the six empty. The
+    (``exchange_bytes``). Every other stage leaves the seven empty. The
     ``TABLE_ROW_PREP`` of a host row Add says how many of its value rows
     were summed into an earlier row of the same id (``dups``; its ``n`` is
     the distinct rows that went up). The launch of an Add under a stateful
@@ -482,6 +484,7 @@ class OpRecord(NamedTuple):
     updater: str = ""
     state_rows: int = 0
     state_bytes: int = 0
+    waits: int = 0
 
 
 class OpRing:
@@ -506,13 +509,14 @@ class OpRing:
                descriptors: int = 0, bytes: int = 0, shards: int = 0,
                max_shard_n: int = 0, exchange_bytes: int = 0,
                dups: int = 0, updater: str = "", state_rows: int = 0,
-               state_bytes: int = 0) -> None:
+               state_bytes: int = 0, waits: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
                                          path, descriptors, bytes, shards,
                                          max_shard_n, exchange_bytes, dups,
-                                         updater, state_rows, state_bytes)
+                                         updater, state_rows, state_bytes,
+                                         waits)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -568,14 +572,14 @@ class _Section:
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
                  "path", "descriptors", "bytes", "shards", "max_shard_n",
                  "exchange_bytes", "dups", "updater", "state_rows",
-                 "state_bytes")
+                 "state_bytes", "waits")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
         self._name, self._feeds, self._op, self.n = name, feeds, op, n
         self._cpu = cpu
         self.id = 0
-        self.path, self.descriptors, self.bytes = "", 0, 0
+        self.path, self.descriptors, self.bytes, self.waits = "", 0, 0, 0
         self.shards = self.max_shard_n = self.exchange_bytes = 0
         self.dups = 0
         self.updater, self.state_rows, self.state_bytes = "", 0, 0
@@ -610,7 +614,8 @@ class _Section:
                         self.dur_ns, cpu, self._op, self.n, self.path,
                         self.descriptors, self.bytes, self.shards,
                         self.max_shard_n, self.exchange_bytes, self.dups,
-                        self.updater, self.state_rows, self.state_bytes)
+                        self.updater, self.state_rows, self.state_bytes,
+                        self.waits)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -624,7 +629,7 @@ class _Off:
 
     __slots__ = ("n", "path", "descriptors", "bytes", "shards",
                  "max_shard_n", "exchange_bytes", "dups", "updater",
-                 "state_rows", "state_bytes")
+                 "state_rows", "state_bytes", "waits")
     id = 0
 
     def __enter__(self) -> "_Off":

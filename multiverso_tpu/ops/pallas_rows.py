@@ -50,9 +50,12 @@ Optimization record (measured at PR 5 on a v5e, single core, 1024-row x
 current machine):
 
 * group-size sweep: 8→83us, 16→49us, 32→32us, 64→26.4us, 128→27.2us;
-  256 exceeds the semaphore-flag memory (sflag 2KB). The 64-group asymptote
-  is the per-row DMA issue cost (~13ns/descriptor on the scalar core), not
-  transfer latency.
+  256 exceeds the semaphore-flag memory (sflag 2KB): that kernel kept a
+  semaphore a slot each way. The 64-group asymptote was read as the
+  per-row DMA issue cost (~13ns/descriptor on the scalar core), not
+  transfer latency. Superseded by the PR 34 sweep below (two semaphores a
+  kernel, 100,000 rows): a descriptor costs 8.2 ns, a grid step 0.4-0.5 us
+  on top, and 64 rows a step left a third of a launch in the steps.
 * software pipelining (double-buffered scratch, group g+1 reads overlapped
   with group g writes): 35.8us — SLOWER than the simple kernel. Two causes:
   the dynamic buffer indexing taxes every descriptor, and the overlap
@@ -87,8 +90,9 @@ current machine):
   2.451 ms a launch, nothing in front. That is 24.5 ns a slot against
   25.6: the pad slots cost MORE than live ones (the 64 descriptors of an
   all-sentinel group write one row), so a quarter of the slots was 27% of
-  the time. The per-slot floor above stands; it is now asked of live slots
-  only (100,032 launched for 100,000 named).
+  the time. A slot's cost is asked of live slots only since (100,032
+  launched for 100,000 named); the 24.5 ns themselves stood until PR 34
+  (17.3 ns at one tile, 20.4 at three: below).
 * rows wider than one lane tile (PR 26). What Mosaic said to each form,
   compiled for a described v5e (libtpu 0.0.34), 3,000,008 x 384 float32:
   a one-row slice of the table as XLA holds it, ``(1, 384)`` or ``(1, 128)``
@@ -107,6 +111,53 @@ current machine):
   three descriptors a row, 3.86 as one with ``//`` and ``%``, 2.85 with
   the shift and the mask (28 ns a slot; 24.5 at one tile); 256 and 512
   lanes cost what 384 do. PERF.md, Findings, PR 26.
+* two semaphores a kernel and 256 rows a grid step (PR 34, 2026-09-29, one
+  v5e chip, `TPU v5 lite`; 100,000 distinct Zipf rows in a 131,072 bucket
+  into the cells' two tables, 10,000,001 x 128 and 3,000,008 x 384 with a
+  300-column delta; device ms a launch, the kernel's events in a trace of
+  nine calls after a warm one, every variant in one chip call, each result
+  checked against numpy: the rows named and every column's sum).
+  A DMA semaphore counts bytes, so a group's row copies signal ONE
+  semaphore a direction and one wait whose descriptor spans the whole
+  scratch block takes the group's bytes off it.
+    variant                          128 lanes      384 lanes
+    PR 33's kernel, group 64          2.4513         2.8053
+    one wait a group, group 64        2.2835         2.6189
+    a wait a slot on the one
+      semaphore, group 64             2.2832         2.6179
+    one wait a group, group 128       1.8978         2.2456
+    group 256 (kept)                  1.7308         2.0461
+    group 512                         1.6556         2.0728
+    group 1024                        1.6420         2.0764
+    write-backs awaited a step late on two scratch blocks named by the
+      step's parity under `pl.when` (group 64 / 128 / 256 / 512 / 1024):
+                       2.1688 / 1.8759 / 1.7323 / 1.6658 / 1.6429
+                       2.5484 / 2.2112 / 2.0457 / 2.0376 / 2.0566
+    each id read from SMEM once, kept in a register for the write-back
+      (group 64 / 128 / 256 / 512 / 1024):
+                       2.5704 / 2.2652 / 2.1089 / 2.0438 / 2.0174
+                       2.8257 / 2.5081 / 2.3502 / 2.3412 / 2.3808
+  What it says. (a) The WAIT costs nothing: 128 waits a group on one
+  semaphore time like one wait (2.2832 against 2.2835). What the 128
+  semaphores cost was their addresses: 0.168 ms a launch, 0.8 ns a
+  descriptor, not the 5-6 ns ISSUE 34 inferred. One wait a group is kept
+  because it is the shorter program (and two semaphores lift the limit on
+  the group). (b) The rest is the grid step: 64 -> 128 rows a step saves
+  0.386 ms over 782 fewer steps, 0.49 us a step (0.43 from 128 to 256, 0.38
+  from 256 to 512): what a step spends with nothing to issue, from its
+  last read issued to its landing and around the delta block's turn (not
+  the write-backs' landing: (c)), which more rows a step amortise. The
+  asymptote is 1.64 ms, 16.4 ns a slot, 8.2 ns a descriptor issued by the
+  scalar core. (c) Awaiting the write-backs a step late hides 0.07 us a
+  step at group 64 and nothing from 128 up: NOT kept. Ids kept in
+  registers spill: a loss at every group. (d) The two widths want
+  different groups by less than 5% of a launch (128 lanes: 512 over 256 by
+  4.3%; 384 lanes: 256 over 512 by 1.3%), so ONE constant, 256: 17.3 ns a
+  slot at one tile (24.5 before), 20.4 at three (28.0). 512 would save
+  0.075 ms more at 128 lanes and cost 0.027 at 384, a minimum bucket of
+  512 slots and half the width the VMEM budget admits. Left: reading group
+  g+1's rows while group g is added (two blocks by parity) could take at
+  most the 0.09 ms between group 256 and the asymptote.
 """
 
 from __future__ import annotations
@@ -121,8 +172,10 @@ from jax.experimental.pallas import tpu as pltpu
 # rows (= concurrent DMAs) per grid step: a power of two, because bucket
 # sizes are powers of two >= the group and any other group would violate
 # the batch-multiple contract and drop updates. See the optimization record
-# above for the measured sweep; a new sweep edits this line on a scratch copy.
-ROW_GROUP = 64
+# above for the measured sweep (PR 34); a new sweep sets this name on a
+# scratch copy. The table's minimum bucket, a shard's segment step and the
+# launch records' `waits` follow it.
+ROW_GROUP = 256
 
 
 def interpret_for(platform: str) -> bool:
@@ -141,11 +194,13 @@ LANES = 128     # one lane tile
 SUBLANES = 8    # rows of one (8, 128) tile of 32-bit values
 # VMEM a grid step of the scatter-add holds: the delta block, which the
 # pipeline double-buffers, and the scratch the rows are read into, each
-# ROW_GROUP x lanes values. The budget keeps that under half of the
-# 16 MiB a v5e kernel may use by default: 10,922 float32 lanes at a
-# group of 64. A wider table takes XLA's scatter (`fits_vmem` is part of
-# the table's gate), it does not fail in the compiler.
-VMEM_BUDGET_BYTES = 8 << 20
+# ROW_GROUP x lanes values. The budget keeps that under three quarters of
+# the 16 MiB a v5e kernel may use by default: 4,096 float32 lanes at a
+# group of 256 (compiled for the described v5e at 4,096 and at 5,120 lanes,
+# plain and counted; 8,192 runs out of VMEM in the compiler; PR 34). A
+# wider table takes XLA's scatter (`fits_vmem` is part of the table's
+# gate), it does not fail in the compiler.
+VMEM_BUDGET_BYTES = 12 << 20
 
 
 def fits_vmem(lanes: int, itemsize: int) -> bool:
@@ -211,19 +266,16 @@ def _block_shape(tiles: int):
     return (ROW_GROUP, LANES) if tiles == 1 else (tiles, ROW_GROUP, LANES)
 
 
-def _gather_kernel(ids_ref, table_ref, out_ref, sems):
+def _gather_kernel(ids_ref, table_ref, out_ref, sem):
     g = pl.program_id(0)
     base = g * ROW_GROUP
-
-    def row_dma(k):
+    for k in range(ROW_GROUP):
         rid = ids_ref[base + k]
-        return pltpu.make_async_copy(_row_of(table_ref, rid),
-                                     _slot_of(out_ref, k), sems.at[k])
-
-    for k in range(ROW_GROUP):
-        row_dma(k).start()
-    for k in range(ROW_GROUP):
-        row_dma(k).wait()
+        pltpu.make_async_copy(_row_of(table_ref, rid), _slot_of(out_ref, k),
+                              sem).start()
+    # every row copy signals the one semaphore, which counts bytes: one
+    # wait the size of the whole block (see `_scatter_add_kernel`)
+    pltpu.make_async_copy(out_ref, out_ref, sem).wait()
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -240,7 +292,7 @@ def _gather_call(table, ids, interpret):
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(_block_shape(tiles), out_map,
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((ROW_GROUP,))],
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
     out = pl.pallas_call(
         _gather_kernel,
@@ -266,24 +318,25 @@ def _scatter_add_kernel(*refs, rows, sign, counted):
     if counted:
         # a shard's launch: the slots from ``count`` on issue no descriptor
         ids_ref, count_ref, delta_ref, table_in_ref, table_ref, scratch, \
-            read_sems, write_sems = refs
+            sems = refs
     else:
-        ids_ref, delta_ref, table_in_ref, table_ref, scratch, \
-            read_sems, write_sems = refs
+        ids_ref, delta_ref, table_in_ref, table_ref, scratch, sems = refs
     del table_in_ref  # aliased with table_ref; all access goes through out
     g = pl.program_id(0)
     base = g * ROW_GROUP
+    # a DMA semaphore counts bytes: every read of a group signals the one,
+    # every write-back the other, and one wait takes a whole group's off
+    read_sem, write_sem = sems.at[0], sems.at[1]
 
     def read_dma(k):
         rid = ids_ref[base + k]
         return pltpu.make_async_copy(_row_of(table_ref, rid),
-                                     _slot_of(scratch, k), read_sems.at[k])
+                                     _slot_of(scratch, k), read_sem)
 
     def write_dma(k):
         rid = ids_ref[base + k]
         return pltpu.make_async_copy(_slot_of(scratch, k),
-                                     _row_of(table_ref, rid),
-                                     write_sems.at[k])
+                                     _row_of(table_ref, rid), write_sem)
 
     def add_delta():
         delta = delta_ref[:, :]
@@ -307,24 +360,35 @@ def _scatter_add_kernel(*refs, rows, sign, counted):
 
     def walk(live):
         """Read, add and write back the group's first ``live`` slots: the
-        whole group unrolled where ``live`` is the static group size, a loop
-        where it is a count known on the chip (a shard's last group; the
-        slots past it hold whatever the scratch held and are not written)."""
+        whole group unrolled and awaited once a direction where ``live`` is
+        the static group size; a loop and a wait a slot where it is a count
+        known on the chip (a shard's last group, once a launch; the slots
+        past it hold whatever the scratch held and are not written)."""
+        whole = isinstance(live, int)
+
         def each(step):
-            if isinstance(live, int):
+            if whole:
                 for k in range(live):
                     step(k)
             else:
                 jax.lax.fori_loop(0, live, lambda k, _: step(k), None)
 
+        def land(row_dma, sem):
+            if whole:
+                # a descriptor the size of the group's rows, to wait on and
+                # never to start: a wait reads the size and the semaphore
+                pltpu.make_async_copy(scratch, scratch, sem).wait()
+            else:
+                each(lambda k: row_dma(k).wait())
+
         each(lambda k: read_dma(k).start())
-        each(lambda k: read_dma(k).wait())
+        land(read_dma, read_sem)
         add_delta()
         each(lambda k: write_dma(k).start())
         # write-backs must land before the next grid step may read these
         # rows (live ids are unique per call, but a later *call* may touch
         # them)
-        each(lambda k: write_dma(k).wait())
+        land(write_dma, write_sem)
 
     if not counted:
         walk(ROW_GROUP)
@@ -338,6 +402,12 @@ def launched_slots(rows: int) -> int:
     """Id slots a scatter-add of ``rows`` delta rows reads, adds and writes:
     whole row groups."""
     return pl.cdiv(rows, ROW_GROUP) * ROW_GROUP
+
+
+def launch_waits(rows: int) -> int:
+    """Semaphore waits that launch issues: one for a group's reads and one
+    for its write-backs (its descriptors are two a slot)."""
+    return 2 * pl.cdiv(rows, ROW_GROUP)
 
 
 def _scatter_add(table, ids, deltas, interpret, sign, count=None):
@@ -365,8 +435,7 @@ def _scatter_add(table, ids, deltas, interpret, sign, count=None):
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM(_block_shape(tiles), table.dtype),
-            pltpu.SemaphoreType.DMA((ROW_GROUP,)),
-            pltpu.SemaphoreType.DMA((ROW_GROUP,)),
+            pltpu.SemaphoreType.DMA((2,)),  # the reads', the write-backs'
         ],
     )
     return _row_view(pl.pallas_call(

@@ -79,8 +79,9 @@ def shard_capacity(fullest: int, n: int, shards: int) -> int:
     shard owns ``fullest``: rounded up to a step of a thirty-second of the
     shard's even share of the op's power-of-two bucket (whole row groups),
     so that a bucket size compiles at most 32 programs a shard count and
-    evenly spread ids leave a few percent of the slots empty (102,656
-    slots for 100,000 ids over four shards). Then one row group more: XLA's
+    evenly spread ids leave a few percent of the slots empty (103,424
+    slots for 100,000 ids over four shards, at steps of 1,024 and a group
+    of 256). Then one row group more: XLA's
     TPU gather takes its ids in tiles of 1,024 and moves a row 2.4 times as
     fast where they do not fill their last tile (``_live_slots``, PERF.md,
     Findings, PR 27), and a whole number of steps is a whole number of
@@ -104,6 +105,13 @@ def launched_slots(counts: np.ndarray) -> np.ndarray:
     slots in whole row groups."""
     group = pallas_rows.ROW_GROUP
     return -(-counts // group) * group
+
+
+def launch_waits(counts: np.ndarray) -> int:
+    """Semaphore waits the shards' scatter-adds issue together: two a whole
+    group of live slots, and two a slot of a shard's last, partial one."""
+    group = pallas_rows.ROW_GROUP
+    return int(2 * (counts // group + counts % group).sum())
 
 
 class ShardedRows:

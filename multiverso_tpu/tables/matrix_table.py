@@ -463,13 +463,17 @@ class MatrixServer(ServerTable):
                            size=(n, self.num_col)).astype(self.dtype)
 
     def _note_launch(self, launch, op: str, slots: int, pallas: bool,
-                     segments=None, exchanged_cols: int = 0) -> None:
+                     segments=None, exchanged_cols: int = 0,
+                     waits: Optional[int] = None) -> None:
         """What a row launch did, on its TABLE_ROW_LAUNCH record and the
         always-on counters: ``n`` id slots (an Add's row groups, the slots
         a Get gathers: not the bucket), the program that served them
         (``pallas`` or ``xla``), the DMA descriptors the kernel issues for
         them (a read and a write a slot for an Add; XLA's programs issue
-        their own, not counted: 0) and the bytes of table rows moved, at
+        their own, not counted: 0), the semaphore waits it issues for them
+        (two a row group, so ``descriptors / waits`` reads the kernel's
+        group; ``waits`` where the caller counted them: a shard's last
+        group waits a slot) and the bytes of table rows moved, at
         the table's lane width. On a table sharded over chips ``slots`` is
         the sum over the shards and ``segments`` is ``(each shard's slots,
         a segment's capacity)``: the record also carries the number of
@@ -482,6 +486,9 @@ class MatrixServer(ServerTable):
         launch.n = slots
         launch.path = path
         launch.descriptors = moves * slots if pallas else 0
+        if pallas:
+            from multiverso_tpu.ops.pallas_rows import launch_waits
+            launch.waits = launch_waits(slots) if waits is None else waits
         launch.bytes = (moves * slots * self.padded_cols
                         * self.dtype.itemsize)
         if op == "add" and self.states:
@@ -804,10 +811,12 @@ class MatrixServer(ServerTable):
         """That Add, one device program: on the chip that holds the delta
         its rows are put in shard order and each shard is sent its own,
         then every shard's kernel walks the rows it owns."""
-        from multiverso_tpu.ops.sharded_rows import launched_slots
+        from multiverso_tpu.ops.sharded_rows import (launch_waits,
+                                                     launched_slots)
         by_shard = launched_slots(counts)
         self._note_launch(launch, "add", int(by_shard.sum()), True,
-                          (by_shard, capacity), delta.shape[1])
+                          (by_shard, capacity), delta.shape[1],
+                          launch_waits(counts))
         self.data = self._shard_rows.add(self.data, ids, delta,
                                          capacity=capacity)
 

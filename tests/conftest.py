@@ -20,7 +20,9 @@ Sanitizer env hooks (``docs/static_analysis.md``):
 """
 
 import faulthandler
+import fcntl
 import os
+import subprocess
 import threading
 import warnings
 
@@ -146,6 +148,42 @@ def pytest_sessionfinish(session, exitstatus):
                      "outliers recorded this session\n")
     except OSError:
         pass
+
+
+NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "multiverso_tpu",
+                          "native")
+NATIVE_LIB = os.path.join(NATIVE_DIR, "libmultiverso_tpu.so")
+
+
+def _make_native(*targets):
+    with open(os.path.join(NATIVE_DIR, "Makefile")) as makefile:
+        fcntl.flock(makefile, fcntl.LOCK_EX)
+        subprocess.run(["make", "-C", NATIVE_DIR, *targets], check=True,
+                       capture_output=True)
+
+
+@pytest.fixture(scope="session")
+def make_native():
+    """``make <targets>`` in the native directory, one at a time on this
+    machine: the suite's workers are processes, and two makes at once write
+    the same object files. The lock is on the Makefile itself (no file is
+    added to the tree)."""
+    return _make_native
+
+
+@pytest.fixture(scope="session")
+def native_lib(make_native):
+    """The built native library's path. Every test that needs the library
+    asks here, so that none depends on which worker built it first (a clean
+    tree has none). ``make`` is incremental: a built library costs a
+    ``stat`` a source. The codec's loader caches a failed load, and code
+    that ran in this worker before the build may have asked: it is made to
+    ask again, as it would find the library on a tree built beforehand."""
+    from multiverso_tpu.utils import quantization
+    make_native()
+    if quantization._native is None:
+        quantization._native_load_attempted = False
+    return NATIVE_LIB
 
 
 @pytest.fixture

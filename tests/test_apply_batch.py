@@ -429,7 +429,7 @@ def test_refilled_staging_cannot_change_an_applied_add(kernel, monkeypatch):
     assert st._pallas_scatter == (kernel == "pallas")
     rng = np.random.default_rng(31)
     model = np.zeros((rows, cols), np.float32)
-    sizes = [5, 70, 9, 200, 130, 3, 64, 300, 1, 2]
+    sizes = [5, 70, 9, 200, 130, 3, 64, 300, 1, 600, 2]
     adds = []
     for size in sizes:
         ids = rng.choice(rows, size, replace=False).astype(np.int32)

@@ -12,15 +12,14 @@ import pytest
 
 REPO = Path(__file__).resolve().parent.parent
 NATIVE = REPO / "multiverso_tpu" / "native"
-SO = NATIVE / "libmultiverso_tpu.so"
 
 
-def _build_native():
-    # unconditional: make is incremental, and a stale prebuilt .so after a
-    # c_api.h edit would otherwise fail these tests misleadingly
-    subprocess.run(["make", "-C", str(NATIVE)], check=True,
-                   capture_output=True)
-    return ctypes.CDLL(str(SO))
+@pytest.fixture
+def lib(native_lib):
+    # `native_lib` runs make whether or not a library is there: make is
+    # incremental, and a stale prebuilt .so after a c_api.h edit would
+    # otherwise fail these tests misleadingly
+    return ctypes.CDLL(native_lib)
 
 
 def _header_symbols():
@@ -28,8 +27,7 @@ def _header_symbols():
     return set(re.findall(r"\b(MV_\w+)\s*\(", hdr))
 
 
-def test_lua_binding_symbols_resolve():
-    lib = _build_native()
+def test_lua_binding_symbols_resolve(lib):
     lua = (REPO / "bindings" / "lua" / "multiverso.lua").read_text()
     cdef = re.search(r"ffi\.cdef\[\[(.*?)\]\]", lua, re.S).group(1)
     declared = set(re.findall(r"\b(MV_\w+)\s*\(", cdef))
@@ -44,7 +42,7 @@ def test_lua_binding_symbols_resolve():
         assert f"lib.{sym}(" in body, f"{sym} declared but never called"
 
 
-def test_lua_ffi_replay_end_to_end():
+def test_lua_ffi_replay_end_to_end(native_lib, make_native):
     """No LuaJIT ships in this image, so the Lua binding's exact FFI call
     sequence is executed by native/test_lua_ffi.c instead: dlopen+dlsym
     resolution (ffi.load), per-call heap buffers (ffi.new), argv/row-id
@@ -55,9 +53,7 @@ def test_lua_ffi_replay_end_to_end():
     xor.lua as exactly this kind of proof)."""
     import os
 
-    _build_native()
-    subprocess.run(["make", "-C", str(NATIVE), "test_lua_ffi", "CC=gcc"],
-                   check=True, capture_output=True)
+    make_native("test_lua_ffi", "CC=gcc")
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
@@ -143,8 +139,7 @@ def test_csharp_wrapper_calls_match_header_arities():
             f".lua with {sorted(lua_calls[sym])}")
 
 
-def test_csharp_binding_symbols_resolve():
-    lib = _build_native()
+def test_csharp_binding_symbols_resolve(lib):
     cs = (REPO / "bindings" / "csharp" / "MultiversoTPU.cs").read_text()
     declared = set(re.findall(r'EntryPoint = "(MV_\w+)"', cs))
     assert declared, "no DllImport entry points in the C# binding"

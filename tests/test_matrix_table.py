@@ -281,7 +281,8 @@ def test_device_add_of_an_odd_row_count(kernel, updater, monkeypatch,
         slots = 24
     else:
         mv.init()
-        slots = 64  # the id bucket: max(next_pow2(21), the row group)
+        # the id bucket: max(next_pow2(21), the row group)
+        slots = pallas_rows.ROW_GROUP
     monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
     rng = np.random.default_rng(25)
     mirror = rng.standard_normal((rows, cols)).astype(np.float32)
@@ -388,7 +389,9 @@ def test_word_embedding_table_pair_against_the_reference(monkeypatch):
 @pytest.mark.parametrize("kernel", ["xla", "pallas"])
 def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
     """Every TABLE_ROW_LAUNCH record says which program served it and
-    carries, beside `n` id slots, the descriptors issued and the bytes of
+    carries, beside `n` id slots, the descriptors issued, the semaphore
+    waits issued for them (two a row group, so `descriptors / waits` reads
+    the kernel's group for a launch of whole groups) and the bytes of
     table rows moved; the always-on counters count launches by path and
     op. 90 x 260 tables (three lane tiles) appear in no other test."""
     import jax
@@ -408,7 +411,8 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
         add_slots, bucket, path = 24, 32, "pallas"
     else:
         mv.init()
-        add_slots = bucket = 64  # max(next_pow2(21), the row group)
+        # max(next_pow2(21), the row group)
+        add_slots = bucket = pallas_rows.ROW_GROUP
         path = "xla"
     gathered = 32  # a Get's 21 ids rounded up to its step of 8, and 8
     try:
@@ -436,8 +440,14 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
             assert r.descriptors == (moves * r.n if r.path == "pallas" else 0)
         if kernel == "pallas":  # one strided descriptor a row, 3 x 512 bytes
             assert device_add.bytes // device_add.descriptors == 1536
-        # every other stage leaves the three empty
-        assert all((r.path, r.descriptors, r.bytes) == ("", 0, 0)
+            # one wait for a group's reads, one for its write-backs
+            assert (device_add.waits, host_add.waits) == (2 * 3, 2 * 4)
+            for r in (device_add, host_add):
+                assert r.descriptors / r.waits == pallas_rows.ROW_GROUP == 8
+        assert [r.waits for r in launches if r.path == "xla"] == [0] * (
+            3 if kernel == "xla" else 1)
+        # every other stage leaves the four empty
+        assert all((r.path, r.descriptors, r.bytes, r.waits) == ("", 0, 0, 0)
                    for r in records if r.stage != "TABLE_ROW_LAUNCH")
         grew = {name: dashboard.Dashboard.counter_value(name) - was
                 for name, was in counters.items()}
@@ -451,7 +461,8 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
 
 
 @pytest.mark.parametrize("cols", [100, 300])
-@pytest.mark.parametrize("n", [1, 63, 64, 65, 1000, 1023, 1024, 1025, 3000])
+@pytest.mark.parametrize("n", [1, 63, 64, 65, 255, 256, 1000, 1023, 1024,
+                               1025, 3000])
 def test_row_get_contract_around_steps_and_buckets(mv_env, n, cols):
     """A row Get on a one-tile and on a three-tile table (sharded over the
     test mesh), id counts on both sides of a gather step and of a bucket:
@@ -460,6 +471,7 @@ def test_row_get_contract_around_steps_and_buckets(mv_env, n, cols):
     holds it (made non-zero here), at least one of them; sentinel ids that
     the caller puts inside `row_ids` are served like any row; the host form
     returns the `n` rows at the table's columns."""
+    from multiverso_tpu.ops.pallas_rows import ROW_GROUP
     from multiverso_tpu.tables.matrix_table import _live_slots
     from multiverso_tpu.utils import next_pow2
 
@@ -476,7 +488,7 @@ def test_row_get_contract_around_steps_and_buckets(mv_env, n, cols):
     ids = rng.choice(rows, n, replace=False).astype(np.int32)
 
     out = table.wait_device(table.get_device_async(ids), ids)
-    bucket = max(next_pow2(n + 1), 64)
+    bucket = max(next_pow2(n + 1), ROW_GROUP)  # the smallest is a row group
     assert out.shape == (bucket, lanes) and out.dtype == np.float32
     assert len(out.sharding.device_set) == 1
     out = np.asarray(out)

@@ -12,23 +12,12 @@ import pytest
 
 NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "multiverso_tpu",
                           "native")
-LIB = os.path.join(NATIVE_DIR, "libmultiverso_tpu.so")
 C_TEST = os.path.join(NATIVE_DIR, "test_c_api")
 
 
 @pytest.fixture(scope="session")
-def native_lib():
-    if not os.path.exists(LIB):
-        subprocess.run(["make", "-C", NATIVE_DIR], check=True,
-                       capture_output=True)
-    return LIB
-
-
-@pytest.fixture(scope="session")
-def c_test_bin(native_lib):
-    if not os.path.exists(C_TEST):
-        subprocess.run(["make", "-C", NATIVE_DIR, "test_c_api", "CC=gcc"],
-                       check=True, capture_output=True)
+def c_test_bin(native_lib, make_native):
+    make_native("test_c_api", "CC=gcc")
     return C_TEST
 
 

@@ -535,6 +535,9 @@ def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
             assert add.path == "pallas"
             assert add.n == int((-(-counts // 8) * 8).sum())
             assert add.max_shard_n == -(-counts.max() // 8) * 8
+            # two waits a whole group, two a slot of a shard's last one
+            assert add.waits == int(2 * (counts // 8 + counts % 8).sum())
+            assert add.descriptors == 2 * add.n
         segment = gets[0].max_shard_n
         assert gets[0].n == shards * segment and segment > counts.max()
         assert adds[0].exchange_bytes == gets[0].exchange_bytes == (

@@ -3,8 +3,9 @@
 The TPU-compiled path is exercised by chip_smoke.py on hardware; these
 verify kernel semantics and the caller contracts (group-multiple batches,
 sentinel padding, unique live ids). The kernel inside the table path is
-covered by tests/test_chip_smoke.py. The one-group tests share one table
-shape: tracing the interpreted kernel costs seconds per new shape."""
+covered by tests/test_chip_smoke.py. The tests at the production group
+share one table shape: tracing the interpreted kernel costs a quarter of a
+minute per new shape there, so every other test runs at a group of 8."""
 
 import functools
 
@@ -25,6 +26,37 @@ ROWS = 1024
 @pytest.fixture
 def rng():
     return np.random.default_rng(7)
+
+
+@pytest.fixture
+def group_of_8(monkeypatch):
+    """The scatter-add at a row group of 8, its two semaphores read as every
+    grid step ends. A DMA semaphore counts bytes (the interpreter's too:
+    a start adds the destination's size, a wait takes its descriptor's
+    off), so a group's one wait the size of the whole block must leave
+    what the group's row copies signalled at exactly zero: a wait of any
+    other size would hang the chip or let the add run before the rows are
+    there. Fails the test that left either semaphore off zero."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    kernel, seen = pallas_rows._scatter_add_kernel, []
+
+    def watched(*refs, **static):
+        kernel(*refs, **static)
+        sems = refs[-1]
+        jax.debug.callback(
+            lambda *values: seen.append(tuple(int(v) for v in values)),
+            pl.semaphore_read(sems.at[0]), pl.semaphore_read(sems.at[1]))
+
+    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    monkeypatch.setattr(pallas_rows, "_scatter_add_kernel", watched)
+    # the jitted call is keyed by shapes, not by the group or the kernel
+    pallas_rows._scatter_add_call.clear_cache()
+    yield 8
+    pallas_rows._scatter_add_call.clear_cache()
+    jax.effects_barrier()
+    assert seen and set(seen) == {(0, 0)}, sorted(set(seen))
 
 
 def test_gather_matches_take(rng):
@@ -54,10 +86,11 @@ def test_scatter_add_unique_ids(rng):
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-6)
 
 
-def test_scatter_add_sentinel_padding(rng):
+def test_scatter_add_sentinel_padding(rng, group_of_8):
     """Pad slots aim at a sentinel row with zero deltas: live rows update,
     sentinel row is untouched (zero delta), matching the matrix-table
-    bucket contract."""
+    bucket contract. Six copies of one row each way in the group."""
+    ROW_GROUP = group_of_8
     rows, sentinel = ROWS, 100
     table = jnp.zeros((rows, 128), jnp.float32)
     live = np.array([5, 17], np.int32)
@@ -75,7 +108,7 @@ def test_scatter_add_sentinel_padding(rng):
 
 
 def test_multiple_groups(rng):
-    batch = ROW_GROUP * 4
+    batch = ROW_GROUP * 2
     table = jnp.asarray(rng.normal(size=(ROWS, 128)).astype(np.float32))
     ids = rng.choice(ROWS, batch, replace=False).astype(np.int32)
     deltas = rng.normal(size=(batch, 128)).astype(np.float32)
@@ -88,13 +121,15 @@ def test_multiple_groups(rng):
 
 
 @pytest.mark.parametrize("sign", [1.0, -1.0])
-@pytest.mark.parametrize("n", [4 * ROW_GROUP, 2 * ROW_GROUP,
-                               2 * ROW_GROUP + 5, 1])
-def test_scatter_add_grid_follows_the_delta(rng, n, sign):
+@pytest.mark.parametrize("n", [32, 16, 21, 1])
+def test_scatter_add_grid_follows_the_delta(rng, n, sign, group_of_8):
     """ids may outnumber the delta's rows (a bucket): the rows the delta
     names get ``sign * delta`` to the bit, and nothing else changes: not
     the sentinel row behind the last group's tail, and not the LIVE rows
-    that later slots name, which a kernel walking the ids would touch."""
+    that later slots name, which a kernel walking the ids would touch.
+    Whole groups, a delta that ends inside a group (its tail repeats the
+    sentinel, seven times for one row), and one wait a group each way."""
+    ROW_GROUP = group_of_8
     bucket, sentinel = 4 * ROW_GROUP, ROWS - 1
     start = rng.normal(size=(ROWS, 128)).astype(np.float32)
     named = rng.choice(sentinel, bucket, replace=False).astype(np.int32)
@@ -154,17 +189,18 @@ def test_pallas_scatter_gate_predicate():
 
 
 @pytest.mark.parametrize("lanes", [128, 384])
-@pytest.mark.parametrize("count", [0, 1, 7, 8, 13, 24])
-def test_scatter_add_with_a_live_count(count, lanes, rng, monkeypatch):
+@pytest.mark.parametrize("count", [0, 1, 7, 8, 9, 13, 24])
+def test_scatter_add_with_a_live_count(count, lanes, rng, group_of_8):
     """A shard's launch: 24 id slots and delta rows (three groups of 8) of
     which the first ``count`` are live. The rows they name take their
     deltas, to the bit; every slot past the count issues no descriptor, so
     the row it names (a live row of the table, with a delta that is not
     zero: a shard has no scratch row to aim a pad slot at) keeps its bytes:
-    no group at all, whole groups, and a group cut anywhere."""
+    no group at all, whole groups (one wait each way), and a group cut
+    anywhere (a wait a slot on the same two semaphores): one row, one short
+    of a group, a group, one past it."""
     import jax
 
-    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
     rows, slots = 48, 24
     table = rng.integers(-99, 99, (rows, lanes)).astype(np.float32)
     ids = rng.choice(rows, slots, replace=False).astype(np.int32)
@@ -193,14 +229,14 @@ def test_matrix_server_multi_shard_add_correct(mv_env):
 
 
 @pytest.mark.parametrize("cols", [129, 256, 300, 384, 512])
-def test_row_kernels_at_widths_past_one_lane_tile(cols, rng, monkeypatch):
+def test_row_kernels_at_widths_past_one_lane_tile(cols, rng, group_of_8):
     """A table of two, three or four lane tiles, rows reached through the
     tile view: unique live ids, sentinel padding up to the id bucket, a
     delta that ends inside the last row group (the masked tail) and inside
     the last lane tile (129 and 300 columns), both signs; the rows no id
-    names, and the lanes past the delta's columns, keep their bytes. 43
-    delta rows in a bucket of 64 appear in no other test."""
-    monkeypatch.setattr(pallas_rows, "ROW_GROUP", 8)
+    names, and the lanes past the delta's columns, keep their bytes; the
+    one wait a group spans the ``(T, 8, 128)`` block. 43 delta rows in a
+    bucket of 64 appear in no other test."""
     lanes = -(-cols // 128) * 128
     rows, n, bucket = 200, 43, 64  # 200 rows: 25 whole tiles of 8
     sentinel = rows - 1

@@ -4,7 +4,6 @@ where shorter" gives, a dense array never reaches the encoder, and the
 counters say which way each array went."""
 
 import json
-import os
 
 import numpy as np
 import pytest
@@ -13,9 +12,6 @@ from multiverso_tpu.dashboard import Dashboard
 from multiverso_tpu.runtime import wire
 from multiverso_tpu.updaters import AddOption
 from multiverso_tpu.utils import quantization
-
-LIB = os.path.join(os.path.dirname(quantization.__file__), "..", "native",
-                   "libmultiverso_tpu.so")
 
 SHAPES = {63: (63,), 64: (64,), 65: (5, 13), 4096: (64, 64),
           131072: (1024, 128)}
@@ -32,15 +28,15 @@ GRID = [(size, name) for size in SHAPES for name in NNZ]
 
 
 @pytest.fixture(params=["numpy", "native"])
-def codec_path(request, monkeypatch):
-    """Pin ``sparse_encode`` to one implementation for the test."""
+def codec_path(request, monkeypatch, native_lib):
+    """Pin ``sparse_encode`` to one implementation for the test. The
+    library is built by then (``native_lib``), on any tree and whichever
+    worker runs this file."""
     if request.param == "numpy":
         monkeypatch.setattr(quantization, "_native_load_attempted", True)
         monkeypatch.setattr(quantization, "_native", None)
-    elif not os.path.exists(LIB):
-        # asked of the file, not of the loader: a failed load is cached
-        pytest.skip("native library not built")
-    elif not quantization.native_available():
+        return request.param
+    if not quantization.native_available():
         pytest.skip("native library does not load")
     return request.param
 
