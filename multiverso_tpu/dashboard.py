@@ -521,8 +521,8 @@ class OpRing:
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
         calling thread is in."""
-        self.append(0, getattr(_op_tls, "span", 0), stage,
-                    time.perf_counter_ns(), 0, 0, op, 0)
+        self.append(0, current_span(), stage, time.perf_counter_ns(), 0, 0,
+                    op, 0)
 
     def _kept(self) -> List[tuple]:
         return sorted(r for r in list(self._slots) if r is not None)
@@ -552,14 +552,21 @@ _span_ids = itertools.count(1)
 _op_tls = threading.local()  # .span / .op: what the thread is inside
 
 
+def current_span() -> int:
+    """The id of the op-trace section the calling thread is in (0: none)."""
+    return getattr(_op_tls, "span", 0)
+
+
 class _Section:
     """One timed same-thread section. Always: its duration goes to the
     ``feeds`` it was given (``monitor``: the Monitor and Histogram of its
     name). While ``Dashboard.profile_annotations`` is on it is also a
     ``TraceAnnotation`` of its name and one span record in ``RING``,
     child of the section the thread was in; sections and hops inside it
-    inherit its ``op`` unless they name their own. ``n`` may be set
-    until the section ends; ``id`` is 0 while the switch is off.
+    inherit its ``op`` unless they name their own. ``n`` and ``op`` may
+    be set until the section ends (an ``op`` set late names this record
+    alone: what ran inside has inherited the one before); ``id`` is 0
+    while the switch is off.
 
     ``cpu`` asks for the thread's CPU time too. Only the few sections
     whose question it answers take it (is a busy dispatcher computing or
@@ -568,7 +575,7 @@ class _Section:
     into the sandbox's kernel and ticks in 10 ms, so it is a sampling
     estimate that means something over sums of a second or more."""
 
-    __slots__ = ("_name", "_feeds", "_op", "n", "id", "start_ns", "dur_ns",
+    __slots__ = ("_name", "_feeds", "op", "n", "id", "start_ns", "dur_ns",
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
                  "path", "descriptors", "bytes", "shards", "max_shard_n",
                  "exchange_bytes", "dups", "updater", "state_rows",
@@ -576,7 +583,7 @@ class _Section:
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
-        self._name, self._feeds, self._op, self.n = name, feeds, op, n
+        self._name, self._feeds, self.op, self.n = name, feeds, op, n
         self._cpu = cpu
         self.id = 0
         self.path, self.descriptors, self.bytes, self.waits = "", 0, 0, 0
@@ -590,10 +597,10 @@ class _Section:
             self._parent = getattr(tls, "span", 0)
             self._outer_op = getattr(tls, "op", 0)
             self.id = tls.span = next(_span_ids)
-            if self._op:
-                tls.op = self._op
+            if self.op:
+                tls.op = self.op
             else:
-                self._op = self._outer_op
+                self.op = self._outer_op
             self._ann = None
             if _TraceAnnotation is not None:
                 self._ann = _TraceAnnotation(self._name)
@@ -611,7 +618,7 @@ class _Section:
                 self._ann.__exit__(None, None, None)
             _op_tls.span, _op_tls.op = self._parent, self._outer_op
             RING.append(self.id, self._parent, self._name, self.start_ns,
-                        self.dur_ns, cpu, self._op, self.n, self.path,
+                        self.dur_ns, cpu, self.op, self.n, self.path,
                         self.descriptors, self.bytes, self.shards,
                         self.max_shard_n, self.exchange_bytes, self.dups,
                         self.updater, self.state_rows, self.state_bytes,
@@ -627,7 +634,7 @@ class _Off:
     """What ``span`` hands out while the switch is off: nothing is timed
     and what a section would carry goes nowhere."""
 
-    __slots__ = ("n", "path", "descriptors", "bytes", "shards",
+    __slots__ = ("n", "op", "path", "descriptors", "bytes", "shards",
                  "max_shard_n", "exchange_bytes", "dups", "updater",
                  "state_rows", "state_bytes", "waits")
     id = 0

@@ -224,6 +224,10 @@ class Message:
     # queue wait from it (SERVER_QUEUE_WAIT_SECONDS). Local to the process,
     # never on the wire; 0 = not queued, or its wait already observed.
     enq_ns: int = 0
+    # The op-trace span the sending thread was in at Server.send (0 while
+    # the switch is off): the parent of the message's SERVER_QUEUE_WAIT
+    # record. Like enq_ns local to the process and never on the wire.
+    enq_span: int = 0
     data: List[Any] = field(default_factory=list)
 
     def create_reply(self) -> "Message":

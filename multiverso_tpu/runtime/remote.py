@@ -1685,7 +1685,7 @@ class _RemoteArrayWorker(ArrayWorker):
         self.dtype = np.dtype(spec["dtype"])
         self._ef = _make_error_feedback((self.size,), self.dtype)
 
-    def _submit(self, msg_type, request):
+    def _submit(self, msg_type, request, submit=None):
         # quantize ADD deltas on the way out (error feedback keeps the
         # lost precision in the client residual) — the server decodes to
         # plain float32 before process_add
@@ -1694,7 +1694,7 @@ class _RemoteArrayWorker(ArrayWorker):
                 and isinstance(request[0], np.ndarray)
                 and request[0].dtype == np.float32):
             request = (self._ef.compress(request[0]),) + request[1:]
-        return super()._submit(msg_type, request)
+        return super()._submit(msg_type, request, submit)
 
     # device IO is in-process only (a remote hop IS a host hop); without
     # this override the class attribute inherited from ArrayWorker would
@@ -1758,7 +1758,7 @@ class _RemoteMatrixWorker(MatrixWorker):
         self._init_client_state(bool(spec.get("is_pipelined", False)),
                                 int(spec.get("num_workers", 1)))
 
-    def _submit(self, msg_type, request):
+    def _submit(self, msg_type, request, submit=None):
         # quantize row-delta ADDs with per-row error feedback (whole-table
         # adds use ids=None -> full-shape residual)
         if (self._ef is not None and msg_type == MsgType.Request_Add
@@ -1769,7 +1769,7 @@ class _RemoteMatrixWorker(MatrixWorker):
             if ids is not None:
                 ids, values = merge_duplicate_rows(ids, values)
             request = (ids, self._ef.compress(values, ids), option)
-        return super()._submit(msg_type, request)
+        return super()._submit(msg_type, request, submit)
 
     def get_device(self):
         raise RuntimeError("get_device() needs mesh residency; remote "

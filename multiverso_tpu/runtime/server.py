@@ -25,8 +25,8 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from multiverso_tpu import config, log
-from multiverso_tpu.dashboard import (RING, Dashboard, count, gauge_set,
-                                      monitor, observe, span)
+from multiverso_tpu.dashboard import (RING, Dashboard, count, current_span,
+                                      gauge_set, monitor, observe, span)
 from multiverso_tpu.obs.profiler import clear_wait, mark_wait
 from multiverso_tpu.obs.trace import flight_dump, hop
 from multiverso_tpu.runtime.admission import (AdmissionGate, DeadlineExceeded,
@@ -259,6 +259,8 @@ class Server:
     # -- client side -------------------------------------------------------
     def send(self, msg: Message) -> None:
         msg.enq_ns = time.perf_counter_ns()
+        if Dashboard.profile_annotations:
+            msg.enq_span = current_span()
         self._queue.push(msg)
 
     # -- dispatcher --------------------------------------------------------
@@ -338,8 +340,8 @@ class Server:
         waited = (until_ns or time.perf_counter_ns()) - enq_ns
         _apply_metrics()[4].observe(waited * 1e-9)
         if Dashboard.profile_annotations:
-            RING.append(0, 0, "SERVER_QUEUE_WAIT", enq_ns, waited, 0,
-                        msg.req_id or msg.msg_id, 0)
+            RING.append(0, msg.enq_span, "SERVER_QUEUE_WAIT", enq_ns, waited,
+                        0, msg.req_id or msg.msg_id, 0)
 
     def _dispatch_guarded(self, msg: Message) -> None:
         self._queue_waited(msg)

@@ -224,8 +224,20 @@ class WorkerTable:
         server_table.table_id = self.table_id
 
     # -- async machinery ---------------------------------------------------
-    def _submit(self, msg_type: MsgType, request: Any) -> int:
-        msg_id = next_msg_id()
+    def _submit(self, msg_type: MsgType, request: Any,
+                submit: Any = None) -> int:
+        """Hand one request to the channel, inside a ``WORKER_SUBMIT``
+        section: the caller's own (``submit``) where a public call opened
+        one around its argument work, else one opened here. It ends when
+        the channel has the message (in process: when ``Server.send`` has
+        returned), under the ``msg_id`` given here."""
+        if submit is not None:
+            return self._enqueue(msg_type, request, submit)
+        with span("WORKER_SUBMIT") as submit:
+            return self._enqueue(msg_type, request, submit)
+
+    def _enqueue(self, msg_type: MsgType, request: Any, submit: Any) -> int:
+        msg_id = submit.op = next_msg_id()
         completion = Completion()
         with self._lock:
             self._pending[msg_id] = completion

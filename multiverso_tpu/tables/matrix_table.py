@@ -45,6 +45,7 @@ import numpy as np
 from multiverso_tpu import log
 from multiverso_tpu.dashboard import Dashboard, monitor, span
 from multiverso_tpu.parallel import mesh as mesh_lib
+from multiverso_tpu.runtime.message import MsgType
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import (RowOccurrences, ServerTable,
                                         WorkerTable, sum_duplicate_rows)
@@ -1080,8 +1081,10 @@ class MatrixWorker(WorkerTable):
 
     def get_async(self, row_ids: Optional[np.ndarray] = None,
                   option: Optional[GetOption] = None) -> int:
-        option, phase = self._prep_get_option(option, row_ids)
-        msg_id = super().get_async((self._norm_ids(row_ids), option))
+        with span("WORKER_SUBMIT") as submit:
+            option, phase = self._prep_get_option(option, row_ids)
+            ids = self._named_ids(row_ids, submit)
+            msg_id = self._submit(MsgType.Request_Get, (ids, option), submit)
         self._phase_of[msg_id] = phase
         return msg_id
 
@@ -1154,8 +1157,11 @@ class MatrixWorker(WorkerTable):
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()
-        option, _ = self._prep_get_option(option, row_ids)
-        return super().get_async((self._norm_ids(row_ids), option, True))
+        with span("WORKER_SUBMIT") as submit:
+            option, _ = self._prep_get_option(option, row_ids)
+            ids = self._named_ids(row_ids, submit)
+            return self._submit(MsgType.Request_Get, (ids, option, True),
+                                submit)
 
     def wait_device(self, msg_id: int, row_ids: np.ndarray) -> "jax.Array":
         raw = self.wait(msg_id)
@@ -1171,9 +1177,12 @@ class MatrixWorker(WorkerTable):
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()
-        option = self._default_add_option(option)
-        return super().add_async(
-            (np.asarray(row_ids, np.int32).reshape(-1), values, option))
+        with span("WORKER_SUBMIT") as submit:
+            option = self._default_add_option(option)
+            ids = np.asarray(row_ids, np.int32).reshape(-1)
+            submit.n = len(ids)
+            return self._submit(MsgType.Request_Add, (ids, values, option),
+                                submit)
 
     def transact_device_async(self, fn, others: Sequence["MatrixWorker"],
                               args: tuple = (),
@@ -1267,11 +1276,21 @@ class MatrixWorker(WorkerTable):
 
     def add_async(self, values: np.ndarray, row_ids: Optional[np.ndarray] = None,
                   option: Optional[AddOption] = None) -> int:
-        row_ids, values = self._auto_sparse_rows(values, row_ids)
-        option = self._default_add_option(option)
-        return super().add_async((self._norm_ids(row_ids), values, option))
+        with span("WORKER_SUBMIT") as submit:
+            row_ids, values = self._auto_sparse_rows(values, row_ids)
+            option = self._default_add_option(option)
+            ids = self._named_ids(row_ids, submit)
+            return self._submit(MsgType.Request_Add, (ids, values, option),
+                                submit)
 
     # -- helpers -----------------------------------------------------------
+    def _named_ids(self, row_ids, submit) -> Optional[np.ndarray]:
+        """``_norm_ids``, counted on the op's WORKER_SUBMIT section (0: the
+        whole table)."""
+        ids = self._norm_ids(row_ids)
+        submit.n = 0 if ids is None else len(ids)
+        return ids
+
     def _norm_ids(self, row_ids) -> Optional[np.ndarray]:
         if row_ids is None:
             return None
