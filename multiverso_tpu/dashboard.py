@@ -440,7 +440,36 @@ class Dashboard:
         RING.reset()
 
 
-class OpRecord(NamedTuple):
+class _OpFields(NamedTuple):
+    seq: int
+    id: int
+    parent: int
+    stage: str
+    start_ns: int
+    dur_ns: int
+    cpu_ns: int
+    op: int
+    n: int
+    path: str = ""
+    descriptors: int = 0
+    bytes: int = 0
+    shards: int = 0
+    max_shard_n: int = 0
+    exchange_bytes: int = 0
+    dups: int = 0
+    updater: str = ""
+    state_rows: int = 0
+    state_bytes: int = 0
+    waits: int = 0
+    ids_from: str = ""
+    ids_ready: int = 0
+
+
+_OP_DEFAULTS = tuple(_OpFields._field_defaults.get(f)
+                     for f in _OpFields._fields)
+
+
+class OpRecord(_OpFields):
     """One stage of one op's passage through the program. ``seq`` is the
     append order; ``id`` names a span (0 for a point) and ``parent`` the
     span that caused this one (0 = none); times are
@@ -463,28 +492,21 @@ class OpRecord(NamedTuple):
     the distinct rows that went up). The launch of an Add under a stateful
     updater names the updater (``updater``; empty under a linear one), the
     id slots whose state it read and wrote (``state_rows``) and the bytes
-    of state that is (``state_bytes``, read and write)."""
+    of state that is (``state_bytes``, read and write). Every row launch
+    says who uploaded its ids (``ids_from``: ``caller``, an in-process
+    device-path op's own thread at submit, or ``dispatcher``, in the op's
+    ``TABLE_ROW_PREP``) and whether they had landed when the launch began
+    (``ids_ready``: the id array's ``is_ready()``, 1 or 0).
 
-    seq: int
-    id: int
-    parent: int
-    stage: str
-    start_ns: int
-    dur_ns: int
-    cpu_ns: int
-    op: int
-    n: int
-    path: str = ""
-    descriptors: int = 0
-    bytes: int = 0
-    shards: int = 0
-    max_shard_n: int = 0
-    exchange_bytes: int = 0
-    dups: int = 0
-    updater: str = ""
-    state_rows: int = 0
-    state_bytes: int = 0
-    waits: int = 0
+    ``_make`` also takes a row shorter than the fields, from a cut
+    recorded before the last of them existed: they read their defaults."""
+
+    __slots__ = ()
+
+    @classmethod
+    def _make(cls, row) -> "OpRecord":
+        row = tuple(row)
+        return tuple.__new__(cls, row + _OP_DEFAULTS[len(row):])
 
 
 class OpRing:
@@ -509,14 +531,15 @@ class OpRing:
                descriptors: int = 0, bytes: int = 0, shards: int = 0,
                max_shard_n: int = 0, exchange_bytes: int = 0,
                dups: int = 0, updater: str = "", state_rows: int = 0,
-               state_bytes: int = 0, waits: int = 0) -> None:
+               state_bytes: int = 0, waits: int = 0, ids_from: str = "",
+               ids_ready: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
                                          path, descriptors, bytes, shards,
                                          max_shard_n, exchange_bytes, dups,
                                          updater, state_rows, state_bytes,
-                                         waits)
+                                         waits, ids_from, ids_ready)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -579,7 +602,7 @@ class _Section:
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
                  "path", "descriptors", "bytes", "shards", "max_shard_n",
                  "exchange_bytes", "dups", "updater", "state_rows",
-                 "state_bytes", "waits")
+                 "state_bytes", "waits", "ids_from", "ids_ready")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
@@ -590,6 +613,7 @@ class _Section:
         self.shards = self.max_shard_n = self.exchange_bytes = 0
         self.dups = 0
         self.updater, self.state_rows, self.state_bytes = "", 0, 0
+        self.ids_from, self.ids_ready = "", 0
 
     def __enter__(self) -> "_Section":
         if Dashboard.profile_annotations:
@@ -622,7 +646,7 @@ class _Section:
                         self.descriptors, self.bytes, self.shards,
                         self.max_shard_n, self.exchange_bytes, self.dups,
                         self.updater, self.state_rows, self.state_bytes,
-                        self.waits)
+                        self.waits, self.ids_from, self.ids_ready)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -636,7 +660,8 @@ class _Off:
 
     __slots__ = ("n", "op", "path", "descriptors", "bytes", "shards",
                  "max_shard_n", "exchange_bytes", "dups", "updater",
-                 "state_rows", "state_bytes", "waits")
+                 "state_rows", "state_bytes", "waits", "ids_from",
+                 "ids_ready")
     id = 0
 
     def __enter__(self) -> "_Off":

@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -183,6 +183,41 @@ class _StageSlot:
         self.vals = np.zeros((0, lanes), dtype)
         self.rows = 0
         self.read = False
+
+
+# who sent a launch's ids up, by whether its request carried them
+# (``SentIds.took``): the dispatcher in the op's TABLE_ROW_PREP, or the
+# caller's own thread at submit
+_IDS_FROM = ("dispatcher", "caller")
+
+
+class LaunchIds(NamedTuple):
+    """The ids of one row op as its launch takes them, on their way to the
+    device (``MatrixServer.launch_ids``). ``ids``: on a table one program
+    serves, the ids padded with sentinel-aimed slots (an Add's to
+    ``bucket``, a Get's to ``_live_slots``); a Get's on a table sharded
+    over chips, the first shard's piece of the array the routed program
+    takes (``ShardedRows.on_first``). ``bucket``: the op's power of two
+    (the shape of a Get's result, and of a delta XLA's programs take).
+    ``counts`` and ``capacity``: the host's part of routing that Get
+    (``_route``), None and 0 where nothing is routed. ``nbytes`` went
+    up."""
+
+    ids: jax.Array
+    bucket: int
+    counts: Optional[np.ndarray]
+    capacity: int
+    nbytes: int
+
+
+class SentIds(np.ndarray):
+    """The int32 ids of an in-process device-path op on a table on one
+    device as its request holds them: an ndarray to everything that reads
+    ids on the host, that also carries what the caller sent up at submit
+    (``took``). The request keeps its shape, ``(ids, values, option)`` or ``(ids, option, True)``,
+    for whatever stands between the proxy and the table."""
+
+    took: Optional[LaunchIds] = None
 
 
 class RowPieces(list):
@@ -358,6 +393,18 @@ class MatrixServer(ServerTable):
         self._stateful_launches = {
             "pallas": Dashboard.counter("ROW_LAUNCH_PALLAS_STATEFUL_ADD"),
             "xla": Dashboard.counter("ROW_LAUNCH_XLA_STATEFUL_ADD")}
+        # and whose thread had uploaded the launch's ids
+        self._ids_from = {
+            "caller": Dashboard.counter("ROW_IDS_FROM_CALLER"),
+            "dispatcher": Dashboard.counter("ROW_IDS_FROM_DISPATCHER")}
+        # an in-process device-path caller sends its ids up itself, at
+        # submit (`launch_ids`), where the launch would otherwise wait for
+        # them to land: a table on one device. On a mesh it does not wait
+        # (the routed ids' `on_first` and a launch call on several devices
+        # outlast the landing: `launch_to_device_ms` 0.37 either way in
+        # `emb128x4.bulk-rows`, where the move cost 0.14-0.23 ms an op,
+        # PERF.md, PR 36), so the dispatcher keeps them
+        self.ids_at_submit = num_shards == 1
         # bytes of state a launch reads for one id slot (and writes again)
         self._state_slot_bytes = sum(
             np.dtype(v.dtype).itemsize
@@ -464,6 +511,7 @@ class MatrixServer(ServerTable):
                            size=(n, self.num_col)).astype(self.dtype)
 
     def _note_launch(self, launch, op: str, slots: int, pallas: bool,
+                     ids: jax.Array, ids_from: str = "dispatcher",
                      segments=None, exchanged_cols: int = 0,
                      waits: Optional[int] = None) -> None:
         """What a row launch did, on its TABLE_ROW_LAUNCH record and the
@@ -480,9 +528,16 @@ class MatrixServer(ServerTable):
         a segment's capacity)``: the record also carries the number of
         shards, the fullest one's slots and the bytes of rows that crossed
         chips: the segments of every shard but the first,
-        ``exchanged_cols`` wide."""
+        ``exchanged_cols`` wide. ``ids`` is the launch's uploaded id array
+        and ``ids_from`` the thread that sent it up (``_IDS_FROM``):
+        counted always, and while the op trace is on the record says
+        whether the ids had landed when the launch began."""
         path = "pallas" if pallas else "xla"
         self._launch_counters[op, path].add()
+        self._ids_from[ids_from].add()
+        if launch.id:
+            launch.ids_from = ids_from
+            launch.ids_ready = int(ids.is_ready())
         moves = 2 if op == "add" else 1
         launch.n = slots
         launch.path = path
@@ -528,19 +583,42 @@ class MatrixServer(ServerTable):
         from multiverso_tpu.ops.pallas_rows import ROW_GROUP
         return max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
 
-    def _bucket_ids(self, ids: np.ndarray, ensure_pad: bool = False
-                    ) -> Tuple[jax.Array, int, int]:
-        """A Get's ids as they are uploaded: ``(ids, n, bucket)``. The
-        bucket is the next power of two, so jit traces are shape-stable;
-        the slots the Get gathers, ``_live_slots`` of them, go up, the ids
-        padded to them with sentinel-aimed slots: the rest of the bucket
-        is filled on the device, not fetched."""
-        n = len(ids)
+    def launch_ids(self, row_ids: np.ndarray, op: str,
+                   ensure_pad: bool = False) -> LaunchIds:
+        """The int32 ``row_ids`` of a row Get or of a device Add (``op``:
+        ``get`` or ``add``) as the launch takes them, their upload begun:
+        on the thread that calls, which is the dispatcher in the op's
+        ``TABLE_ROW_PREP`` or, for an in-process device-path op on a table
+        on one device (``ids_at_submit``), the caller at submit
+        (``MatrixWorker._ids_at_submit``), so that the upload rides under
+        the queue wait. What goes up is a fresh array that nobody writes
+        again (``async_upload``'s rule): ``row_ids`` may change as soon as
+        this returns.
+
+        The bucket is the next power of two, so jit traces are
+        shape-stable. An Add's ids go up padded to it with sentinel-aimed
+        slots; of a Get's, the slots it gathers, ``_live_slots`` of them:
+        the rest of the bucket is filled on the device, not fetched. A
+        Get's on a table sharded over chips go to the mesh's first chip
+        with the host's count of them by shard (``_route``), padded with
+        ids past the table, which no shard owns, and the sentinel last
+        (the tail of the result is its row's value, wherever its shard put
+        it); a linear Add's there are routed with its delta
+        (``_route_add``), not here."""
+        n = len(row_ids)
         bucket = self._get_bucket(n, ensure_pad)
-        ids_p = np.concatenate(
-            [ids, np.full(_live_slots(n, bucket) - n, self.sentinel_row,
-                          dtype=ids.dtype)])
-        return async_upload(ids_p), n, bucket
+        routed = op == "get" and self._shard_rows is not None
+        pads = (bucket if op == "add" else _live_slots(n, bucket)) - n
+        ids = np.empty(n + pads, np.int32)
+        ids[:n] = row_ids
+        ids[n:] = self.padded_rows if routed else self.sentinel_row
+        if pads:
+            ids[-1] = self.sentinel_row
+        if not routed:
+            return LaunchIds(async_upload(ids), bucket, None, 0, ids.nbytes)
+        counts, capacity = self._route(ids)
+        return LaunchIds(self._shard_rows.on_first(ids), bucket, counts,
+                         capacity, ids.nbytes)
 
     def _staging(self, bucket: int) -> _StageSlot:
         """The slot a row Add's padded ids and values are written into and
@@ -566,43 +644,34 @@ class MatrixServer(ServerTable):
             slot.rows = 0
         return slot
 
-    def _gather_rows(self, row_ids: np.ndarray, device_out: bool = False
-                     ) -> jax.Array:
+    def _gather_rows(self, row_ids: np.ndarray, device_out: bool = False,
+                     took: Optional[LaunchIds] = None) -> jax.Array:
         """The rows ``row_ids`` names as ``(bucket, padded_cols)`` on the
         device, the slots past them copies of the sentinel row: from the
         one program of a table on one chip (or XLA's partitioned one), or
         from every shard's gather of the rows it owns, sent to the mesh's
         first device and put back in the order asked. ``device_out``
-        results are committed to that device either way."""
-        n = len(row_ids)
-        if self._shard_rows is None:
-            with span("TABLE_ROW_PREP") as prep:
-                ids_p, prep.n, bucket = self._bucket_ids(
-                    row_ids, ensure_pad=device_out)
-            with span("TABLE_ROW_LAUNCH") as launch:
-                # the slots gathered, not the bucket the result fills
-                self._note_launch(launch, "get", ids_p.shape[0], False)
-                return (self._gather_out if device_out else self._gather)(
-                    self.data, ids_p, bucket=bucket)
-        rows = self._shard_rows
+        results are committed to that device either way. ``took``: the
+        ids as the caller sent them up at submit; without it they go up
+        here."""
+        ids_from = _IDS_FROM[took is not None]
         with span("TABLE_ROW_PREP") as prep:
-            prep.n = n
-            bucket = self._get_bucket(n, device_out)
-            live = _live_slots(n, bucket)
-            if live > n:
-                # pads aim past the table, where no shard owns them; the
-                # sentinel rides last: the tail of the result is its row's
-                # value, wherever its shard put it
-                row_ids = np.concatenate([
-                    row_ids, np.full(live - n - 1, self.padded_rows, np.int32),
-                    np.array([self.sentinel_row], np.int32)])
-            _, capacity = self._route(row_ids)
-            ids_d = rows.on_first(row_ids)
+            prep.n = len(row_ids)
+            if took is None:
+                took = self.launch_ids(row_ids, "get", ensure_pad=device_out)
         with span("TABLE_ROW_LAUNCH") as launch:
-            by_shard = np.full(self._num_shards, capacity)
+            if took.counts is None:
+                # the slots gathered, not the bucket the result fills
+                self._note_launch(launch, "get", took.ids.shape[0], False,
+                                  took.ids, ids_from)
+                return (self._gather_out if device_out else self._gather)(
+                    self.data, took.ids, bucket=took.bucket)
+            by_shard = np.full(self._num_shards, took.capacity)
             self._note_launch(launch, "get", int(by_shard.sum()), False,
-                              (by_shard, capacity), self.padded_cols)
-            return rows.get(self.data, ids_d, capacity, bucket)
+                              took.ids, ids_from,
+                              (by_shard, took.capacity), self.padded_cols)
+            return self._shard_rows.get(self.data, took.ids, took.capacity,
+                                        took.bucket)
 
     # -- server ops --------------------------------------------------------
     def merge_add_requests(self, requests):
@@ -674,7 +743,7 @@ class MatrixServer(ServerTable):
             # skipped serialization the same way, communicator.cpp:93-105).
             # Caller contract: ids unique; pad slots aim at sentinel_row
             # with exactly-zero deltas.
-            self._process_add_device(row_ids, values, option, worker, scalars)
+            self._process_add_device(row_ids, values, worker, scalars)
             return
         if row_ids is None:
             delta = np.zeros((self.padded_rows, self.padded_cols), dtype=self.dtype)
@@ -732,7 +801,7 @@ class MatrixServer(ServerTable):
                     self._launch_routed_add(launch, *routed)
                 else:
                     self._note_launch(launch, "add", ids_p.shape[0],
-                                      self._kernel_rows)
+                                      self._kernel_rows, ids_p)
                     if self._linear:
                         self.data = self._scatter_add(self.data, ids_p, vals_p)
                     else:
@@ -759,40 +828,41 @@ class MatrixServer(ServerTable):
             _device_pad(values.astype(self.dtype), bucket, self.padded_cols),
             mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
 
-    def _process_add_device(self, row_ids, values, option, worker,
-                            scalars) -> None:
+    def _process_add_device(self, row_ids, values, worker, scalars) -> None:
+        """A device Add, launched on the ids its caller sent up at submit
+        (``SentIds``); ids that come without go up here."""
         routed = self._linear and self._shard_rows is not None
         with span("TABLE_ROW_PREP") as prep:
+            took = getattr(row_ids, "took", None)
+            ids_from = _IDS_FROM[took is not None]
             row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
             prep.n = n = len(row_ids)
             if values.shape[0] != n:
                 log.fatal("Matrix.add(device): %d ids but %d value rows",
                           n, values.shape[0])
-            from multiverso_tpu.ops.pallas_rows import (ROW_GROUP,
-                                                        launched_slots)
             if routed:
                 routed = self._route_add(row_ids, values)
-            else:
-                bucket = max(_next_pow2(n), ROW_GROUP)
-                ids_p = async_upload(np.concatenate(
-                    [row_ids,
-                     np.full(bucket - n, self.sentinel_row, np.int32)]))
+            elif took is None:
+                took = self.launch_ids(row_ids, "add")
         with span("TABLE_ROW_LAUNCH") as launch:
             if routed:
                 self._launch_routed_add(launch, *routed)
             else:
+                from multiverso_tpu.ops.pallas_rows import launched_slots
                 # the pallas kernel takes the delta as it came and walks its
                 # row groups, not the bucket's: one device program an Add
                 pallas = self._kernel_rows
                 if not pallas:
-                    values = self._bucket_delta(values, bucket)
+                    values = self._bucket_delta(values, took.bucket)
                 self._note_launch(launch, "add",
-                                  launched_slots(values.shape[0]), pallas)
+                                  launched_slots(values.shape[0]), pallas,
+                                  took.ids, ids_from)
                 if self._linear:
-                    self.data = self._scatter_add(self.data, ids_p, values)
+                    self.data = self._scatter_add(self.data, took.ids,
+                                                  values)
                 else:
                     self.data, self.states = self._row_update(
-                        self.data, self.states, ids_p, values, worker,
+                        self.data, self.states, took.ids, values, worker,
                         scalars)
         if self.is_sparse:
             with self._std_lock:
@@ -815,9 +885,10 @@ class MatrixServer(ServerTable):
         from multiverso_tpu.ops.sharded_rows import (launch_waits,
                                                      launched_slots)
         by_shard = launched_slots(counts)
-        self._note_launch(launch, "add", int(by_shard.sum()), True,
-                          (by_shard, capacity), delta.shape[1],
-                          launch_waits(counts))
+        self._note_launch(launch, "add", int(by_shard.sum()), True, ids,
+                          segments=(by_shard, capacity),
+                          exchanged_cols=delta.shape[1],
+                          waits=launch_waits(counts))
         self.data = self._shard_rows.add(self.data, ids, delta,
                                          capacity=capacity)
 
@@ -915,13 +986,15 @@ class MatrixServer(ServerTable):
             # admin whole-table reads take the dense path
             out = self.updater.access(self.data)
             return self._host_read(out)[: self.num_row, : self.num_col]
+        # what an in-process device-path caller sent up at submit
+        took = getattr(row_ids, "took", None)
         row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
         if not device_out:
             # device gets may carry sentinel-aimed pad ids (the compact
             # training space contract); host/wire gets may not
             self._check_row_range(row_ids, "get")
         n = len(row_ids)
-        gathered = self._gather_rows(row_ids, device_out)
+        gathered = self._gather_rows(row_ids, device_out, took)
         if self.is_sparse and self._is_worker(option):
             with self._std_lock:
                 self._up_to_date[option.worker_id, row_ids] = True
@@ -1153,14 +1226,26 @@ class MatrixWorker(WorkerTable):
         named, rounded up to a step of the bucket (``_live_slots``), and
         fills the rest from one read of the sentinel row: the result's
         shape follows the bucket alone, so a caller's own jit over it sees
-        one shape a bucket whatever the count of rows it names."""
+        one shape a bucket whatever the count of rows it names.
+
+        On a table on one device the ids are copied and their upload
+        begins here, on the caller's thread, before the message is queued
+        (``_ids_at_submit``): the caller may reuse or overwrite
+        ``row_ids`` as soon as this returns. On a mesh the request holds
+        ``row_ids`` itself and the dispatcher sends the ids up, as before:
+        leave the array alone until ``wait_device`` returns."""
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()
         with span("WORKER_SUBMIT") as submit:
             option, _ = self._prep_get_option(option, row_ids)
-            ids = self._named_ids(row_ids, submit)
-            return self._submit(MsgType.Request_Get, (ids, option, True),
+            ids = np.asarray(row_ids, np.int32).reshape(-1)
+            submit.n = len(ids)
+            sent = self._ids_at_submit(ids, "get")
+            # every Get's range check, made while the upload is in flight
+            # (0.05 ms for 100,000 ids): ids that fail it are never launched
+            self._norm_ids(ids)
+            return self._submit(MsgType.Request_Get, (sent, option, True),
                                 submit)
 
     def wait_device(self, msg_id: int, row_ids: np.ndarray) -> "jax.Array":
@@ -1173,7 +1258,16 @@ class MatrixWorker(WorkerTable):
                          option: Optional[AddOption] = None) -> int:
         """Async device-resident add. ``values`` is a jax.Array of shape
         ``(len(row_ids), <=num_col)``; live ids unique, pad slots (if the
-        caller pads) aim at ``num_row`` (the sentinel) with zero deltas."""
+        caller pads) aim at ``num_row`` (the sentinel) with zero deltas.
+
+        On a table on one device the ids are copied and their upload
+        begins here, on the caller's thread, before the message is queued
+        (``_ids_at_submit``): the caller may reuse or overwrite
+        ``row_ids`` as soon as this returns. On a mesh the request holds
+        ``row_ids`` itself and the dispatcher sends the ids up, as before:
+        leave the array alone until ``wait`` returns. A count of ids that
+        differs from the value rows' fails the op at its ``wait``, as on
+        every Add path."""
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()
@@ -1181,8 +1275,29 @@ class MatrixWorker(WorkerTable):
             option = self._default_add_option(option)
             ids = np.asarray(row_ids, np.int32).reshape(-1)
             submit.n = len(ids)
-            return self._submit(MsgType.Request_Add, (ids, values, option),
-                                submit)
+            return self._submit(
+                MsgType.Request_Add,
+                (self._ids_at_submit(ids, "add"), values, option), submit)
+
+    def _ids_at_submit(self, ids: np.ndarray, op: str) -> np.ndarray:
+        """A device-path op's ids for its request. Where the table says so
+        (``MatrixServer.ids_at_submit``: a table on one device) they carry
+        themselves as the launch takes them (``SentIds``): made by the
+        table this proxy holds and sent up from the caller's thread,
+        inside the op's WORKER_SUBMIT, so that the upload is in flight
+        while the message waits for the dispatcher, which launches on ids
+        already on their way (``MatrixServer.launch_ids``). Elsewhere the
+        ids go as they came and the dispatcher sends them up."""
+        if not self._server_table.ids_at_submit:
+            return ids
+        with span("WORKER_ROW_IDS") as up:
+            up.n = len(ids)
+            took = self._server_table.launch_ids(ids, op,
+                                                 ensure_pad=op == "get")
+            up.bytes = took.nbytes
+            ids = ids.view(SentIds)
+            ids.took = took
+            return ids
 
     def transact_device_async(self, fn, others: Sequence["MatrixWorker"],
                               args: tuple = (),

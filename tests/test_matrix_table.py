@@ -446,9 +446,22 @@ def test_row_launch_record_names_its_path_and_bytes(kernel, monkeypatch):
                 assert r.descriptors / r.waits == pallas_rows.ROW_GROUP == 8
         assert [r.waits for r in launches if r.path == "xla"] == [0] * (
             3 if kernel == "xla" else 1)
-        # every other stage leaves the four empty
+        # every other stage leaves the four empty, but for the bytes a
+        # device-path caller sent up at submit, where it does (a table on
+        # one device, here the kernel's): the Add's bucket of ids
         assert all((r.path, r.descriptors, r.bytes, r.waits) == ("", 0, 0, 0)
-                   for r in records if r.stage != "TABLE_ROW_LAUNCH")
+                   for r in records
+                   if r.stage not in ("TABLE_ROW_LAUNCH", "WORKER_ROW_IDS"))
+        at_submit = kernel == "pallas"
+        assert [(r.n, r.bytes) for r in records
+                if r.stage == "WORKER_ROW_IDS"] == [(n, 4 * bucket)] * at_submit
+        # who uploaded each launch's ids; only a launch says
+        assert [r.ids_from for r in launches] == [
+            "caller" if at_submit else "dispatcher", "dispatcher",
+            "dispatcher"]
+        assert all(r.ids_ready in (0, 1) for r in launches)
+        assert all((r.ids_from, r.ids_ready) == ("", 0) for r in records
+                   if r.stage != "TABLE_ROW_LAUNCH")
         grew = {name: dashboard.Dashboard.counter_value(name) - was
                 for name, was in counters.items()}
         assert grew == {f"ROW_LAUNCH_{path.upper()}_ADD": 2,
@@ -759,4 +772,248 @@ def test_table_goes_up_shard_by_shard(monkeypatch):
         np.testing.assert_array_equal(drawn.get(), whole)
         assert len(blocks) == 4
     finally:
+        mv.shutdown()
+
+
+# -- a device-path op's ids go up from the caller's thread at submit ---------
+
+def _held_dispatcher():
+    """Parks the dispatcher behind a serialized call until the returned
+    event is set: what is submitted meanwhile waits in its queue."""
+    import threading
+
+    from multiverso_tpu.runtime.zoo import Zoo
+
+    parked, release = threading.Event(), threading.Event()
+
+    def hold():
+        parked.set()
+        assert release.wait(60)
+
+    holder = threading.Thread(
+        target=Zoo.instance().server.run_serialized, args=(hold,))
+    holder.start()
+    assert parked.wait(60)
+    return release, holder
+
+
+def _ids_from_counts():
+    from multiverso_tpu.dashboard import Dashboard
+
+    return {name: Dashboard.counter_value(name)
+            for name in ("ROW_IDS_FROM_CALLER", "ROW_IDS_FROM_DISPATCHER",
+                         "ROW_LAUNCH_PALLAS_ADD", "ROW_LAUNCH_XLA_ADD",
+                         "ROW_LAUNCH_PALLAS_GET", "ROW_LAUNCH_XLA_GET")}
+
+
+def _sixty_fourths(rng, n, cols):
+    return rng.integers(-64, 64, (n, cols)).astype(np.float32) / 64
+
+
+def _host_add(table, vals, ids, option):
+    table.wait(table.add_async(vals, ids, option))
+
+
+def _device_ops_equal_host_ops(rows, cols, updater, option,
+                               values=_sixty_fourths, slow_add=_host_add,
+                               at_submit=True):
+    """Seeded Adds and Gets through two tables of one shape: one by
+    `add_device_async` / `get_device_async`, one by `slow_add` (`add_async`
+    unless the caller says) and `get_async`. `at_submit` (a table on one
+    device): the first table's ids go up at submit and the caller
+    overwrites them as soon as each call returns, while the dispatcher is
+    held so that nothing was served before the overwrite; a launch of the
+    first table says `caller`, of the second `dispatcher`. Without it (a
+    mesh) the dispatcher sends every op's ids up and the caller leaves
+    them alone. Every Get, the tables and the updater states equal to the
+    bit; the two counters add up to the launches."""
+    import jax
+
+    from multiverso_tpu import dashboard
+
+    rng = np.random.default_rng(36)
+    init = _sixty_fourths(rng, rows, cols)
+    fast = mv.create_table("matrix", rows, cols, np.float32,
+                           updater_type=updater, init_value=init)
+    slow = mv.create_table("matrix", rows, cols, np.float32,
+                           updater_type=updater, init_value=init)
+    before = _ids_from_counts()
+    t0 = time.perf_counter()
+    for n in (1500, 257, 3, 1, 1500):
+        ids = rng.choice(rows, n, replace=False).astype(np.int32)
+        other = rows - 1 - ids
+        vals = values(rng, n, cols)
+        release, holder = _held_dispatcher()
+        try:
+            mine = ids.copy()
+            add = fast.add_device_async(jax.device_put(vals), mine, option)
+            if at_submit:
+                mine[:] = other
+                get_other = fast.get_device_async(mine)
+                mine[:] = ids
+                get = fast.get_device_async(mine)
+                mine[:] = 0
+            else:
+                get_other = fast.get_device_async(other)
+                get = fast.get_device_async(ids)
+        finally:
+            release.set()
+            holder.join(60)
+        assert not holder.is_alive()
+        fast.wait(add)
+        got_other = np.asarray(fast.wait_device(get_other, other))
+        got = np.asarray(fast.wait_device(get, ids))
+        slow_add(slow, vals, ids, option)
+        want_other = slow.wait_get(slow.get_async(other), other)
+        want = slow.wait_get(slow.get_async(ids), ids)
+        np.testing.assert_array_equal(got[:n, :cols], want)
+        np.testing.assert_array_equal(got_other[:n, :cols], want_other)
+        # the bucket's tail is the sentinel row's value
+        assert not got[n:].any() and got.shape[0] > n
+    records, _ = dashboard.RING.window(t0, time.perf_counter())
+    np.testing.assert_array_equal(np.asarray(fast.get_device()),
+                                  np.asarray(slow.get_device()))
+    assert sorted(fast._server_table.states) == sorted(
+        slow._server_table.states)
+    for name, state in fast._server_table.states.items():
+        np.testing.assert_array_equal(
+            np.asarray(state), np.asarray(slow._server_table.states[name]))
+    grew = {name: value - before[name]
+            for name, value in _ids_from_counts().items()}
+    assert (grew["ROW_IDS_FROM_CALLER"], grew["ROW_IDS_FROM_DISPATCHER"]) == (
+        (15, 15) if at_submit else (0, 30))
+    assert sum(v for k, v in grew.items() if k.startswith("ROW_LAUNCH")) == 30
+    return records
+
+
+@pytest.mark.parametrize("cols", [128, 300])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_ids_sent_at_submit_equal_the_host_paths(shards, cols, monkeypatch):
+    """`_device_ops_equal_host_ops` on a plain table of one and of three
+    lane tiles: on one device, where a device-path op's ids go up from the
+    caller's thread at submit, and sharded over four (the routed programs,
+    the interpreted kernel on every shard), where the launch does not wait
+    for its ids and the dispatcher keeps them (`ids_at_submit`)."""
+    from multiverso_tpu import dashboard
+
+    _use_the_kernel_on_every_mesh(monkeypatch)
+    mv.init(mesh_shape=str(shards))
+    try:
+        monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
+        records = _device_ops_equal_host_ops(4000, cols, "", None,
+                                             at_submit=shards == 1)
+        launched = [r for r in records if r.stage == "TABLE_ROW_LAUNCH"]
+        # an Add and two Gets by the device path, then the three by the
+        # host path, five times
+        assert [r.ids_from for r in launched] == (
+            ["caller" if shards == 1 else "dispatcher"] * 3
+            + ["dispatcher"] * 3) * 5
+        assert len([r for r in records if r.stage == "WORKER_ROW_IDS"]) == (
+            15 if shards == 1 else 0)
+        assert [r.shards for r in launched] == [
+            shards if shards > 1 else 0] * 30
+        # an id array that waited in the queue behind a held dispatcher
+        # has landed by its launch
+        assert all(r.ids_ready == 1 for r in launched
+                   if r.ids_from == "caller")
+    finally:
+        mv.shutdown()
+
+
+@pytest.fixture(params=["1", "8"], ids=["ids_at_submit", "mesh"])
+def one_device_or_mesh(request):
+    mv.init(mesh_shape=request.param)
+    yield
+    mv.shutdown()
+
+
+def test_device_path_refusals_stand(one_device_or_mesh):
+    """What a device-path op refused before its ids went up at submit it
+    still refuses, no later, where they go up at submit (one device) and
+    where they do not (a mesh): an `is_sparse` table at the call, ids out
+    of range at a Get's call, ids and value rows of different counts at
+    the Add's `wait`; the table is as it was."""
+    import jax
+
+    from multiverso_tpu.log import FatalError
+
+    sparse = mv.create_table("matrix", 50, 8, np.float32, is_sparse=True)
+    ids = np.arange(5, dtype=np.int32)
+    vals = jax.device_put(np.ones((5, 8), np.float32))
+    with pytest.raises(FatalError, match="is_sparse"):
+        sparse.add_device_async(vals, ids)
+    with pytest.raises(FatalError, match="is_sparse"):
+        sparse.get_device_async(ids)
+    table = mv.create_table("matrix", 50, 8, np.float32)
+    with pytest.raises(FatalError, match="out of range"):
+        table.get_device_async(np.array([3, 50], np.int32))
+    with pytest.raises(FatalError, match="5 ids but 4 value rows"):
+        table.wait(table.add_device_async(vals[:4], ids))
+    assert not table.get().any()
+    table.wait(table.add_device_async(vals, ids))
+    np.testing.assert_array_equal(table.get(ids), np.ones((5, 8)))
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_two_workers_submit_device_path_ops_at_once(shards, monkeypatch):
+    """Two in-process workers submit device-path Adds and Gets to one table
+    at the same time, each from its own thread, the interpreter switching
+    threads as often as it can: on one device both send their ids up from
+    their own threads (`launch_ids` keeps nothing between calls), sharded
+    over four the dispatcher sends every op's (`ShardedRows.on_first`'s
+    placeholder cache stays the dispatcher's alone). The table ends at the
+    exact sum of what both added, and every Get held rows the table could
+    have held."""
+    import sys
+    import threading
+
+    import jax
+
+    from multiverso_tpu.dashboard import Dashboard
+
+    _use_the_kernel_on_every_mesh(monkeypatch)
+    rows, cols, rounds = 2000, 128, 12
+    mv.init(mesh_shape=str(shards), local_workers=2)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        table = mv.create_table("matrix", rows, cols, np.float32)
+        from_caller = Dashboard.counter_value("ROW_IDS_FROM_CALLER")
+        added = np.zeros((2, rows, cols), np.float32)
+        failures = []
+
+        def work(slot):
+            rng = np.random.default_rng(slot)
+            try:
+                with mv.worker(slot):
+                    for round_ in range(rounds):
+                        n = (700, 130, 9)[(round_ + slot) % 3]
+                        ids = rng.choice(rows, n, replace=False).astype(
+                            np.int32)
+                        vals = _sixty_fourths(rng, n, cols)
+                        add = table.add_device_async(jax.device_put(vals),
+                                                     ids)
+                        get = table.get_device_async(ids)
+                        added[slot][ids] += vals
+                        table.wait(add)
+                        got = np.asarray(table.wait_device(get, ids))
+                        # whole multiples of 1/64, whatever the other added
+                        assert not (got * 64 % 1).any()
+            except BaseException as exc:  # reported by the test's thread
+                failures.append(exc)
+
+        threads = [threading.Thread(target=work, args=(slot,))
+                   for slot in range(2)]
+        for thread in threads:
+            thread.start()
+        for thread in threads:
+            thread.join(300)
+        assert not any(thread.is_alive() for thread in threads)
+        assert not failures, failures
+        np.testing.assert_array_equal(
+            np.asarray(table.get_device())[:rows, :cols], added.sum(axis=0))
+        assert (Dashboard.counter_value("ROW_IDS_FROM_CALLER") - from_caller
+                == (4 * rounds if shards == 1 else 0))
+    finally:
+        sys.setswitchinterval(interval)
         mv.shutdown()

@@ -52,13 +52,17 @@ class Synthetic:
               "turnaround": (20_000, 100_000)}
 
     def __init__(self, pairs=6, to_trace=987_654_321, skew=0, seed=0,
-                 programs=(1, 2), jitter=0, runtime=False, step=None):
+                 programs=(1, 2), jitter=0, runtime=False, step=None,
+                 ids_at_submit=False):
         """`step`: (instant on the ring's clock, ns added to the skew from
         there on): the profiler re-basing the device clock. `runtime`: the
-        runtime's own enqueue and completion events around each program."""
+        runtime's own enqueue and completion events around each program.
+        `ids_at_submit`: the records of a program whose callers send their
+        ids up at submit: a WORKER_ROW_IDS inside every WORKER_SUBMIT, and
+        launches that say `caller` (every Get's ids landed, no Add's)."""
         rng = np.random.default_rng(seed)
         self.to_trace, self.skew, self.step = to_trace, skew, step
-        self.runtime = runtime
+        self.runtime, self.ids_at_submit = runtime, ids_at_submit
         self.ring, self.samples, self.ops = [], {}, []
         self.host, self.modules, self.device_ops = [], [], []
         self._ids = iter(range(1, 1 << 20))
@@ -91,6 +95,9 @@ class Synthetic:
         b = end + part["ready_tail"]
         submit = self._record("WORKER_SUBMIT", a + 1_001, enq - a - 1_001 + 2_003,
                               op, n=100_000)
+        if self.ids_at_submit:
+            self._record("WORKER_ROW_IDS", a + 2_001, (enq - a) // 2, 0,
+                         submit, n=100_000)
         self._record("SERVER_QUEUE_WAIT", enq, began - enq, op, submit,
                      span_id=0)
         dispatch = self._record("SERVER_DISPATCH_MSG", began + 11,
@@ -103,6 +110,9 @@ class Synthetic:
                      table)
         self._record("TABLE_ROW_LAUNCH", launch, 110_001, op, table,
                      n=100_096)
+        if self.ids_at_submit:
+            self.ring[-1] = self.ring[-1]._replace(
+                ids_from="caller", ids_ready=int(kind == "get"))
         self._record("WORKER_WAIT", enq + 3_001, launch + 120_000 - enq, op)
         wobble = int(rng.integers(-jitter, jitter + 1)) if jitter else 0
         self.host.append(["TABLE_ROW_LAUNCH",
@@ -638,6 +648,41 @@ def test_a_program_without_the_new_records_reads_none():
             assert common.load_module("layers", name).read(run) is None
 
 
+def test_ids_sent_at_submit_leave_the_tiling_as_it_was():
+    """A WORKER_ROW_IDS inside every WORKER_SUBMIT and two more fields on
+    every launch record move no tile: the six still sum to the op's
+    latency, and `row_ids_landed_share` reads the launches whose ids had
+    landed."""
+    plain, sent = Synthetic(pairs=4), Synthetic(pairs=4, ids_at_submit=True)
+    found = sent.timeline()
+    assert found["ops"] == plain.timeline()["ops"]
+    for got in found["ops"].values():
+        assert got["sum_of_means_ms"] == pytest.approx(
+            got["latency_ms"]["mean"], abs=1e-6)
+    _same_as_oracle(found, oracle(sent.raw, sent.trace, sent.samples))
+    read = common.load_module("layers", "row_ids_landed_share").read
+    assert read(_run(sent)) == 50.0
+    # half of the launches from a program that has not the field: they
+    # are not counted as launches whose ids were late
+    sent.ring = [r._replace(ids_from="", ids_ready=0)
+                 if r.stage == "TABLE_ROW_LAUNCH" and r.op % 4 < 2 else r
+                 for r in sent.ring]
+    assert read(_run(sent)) == 50.0
+
+
+def test_a_trace_without_the_field_reads_no_landed_share():
+    """The parent of the PR that brought `ids_from`: launch records without
+    it; a ring cut recorded before the field existed; an untraced run."""
+    read = common.load_module("layers", "row_ids_landed_share").read
+    assert read(_run(Synthetic(pairs=2))) is None
+    assert read(_run(Synthetic(pairs=2), _op_trace=None, trace=None)) is None
+    _, trace, _ = op_timeline.load_cut(FIXTURE + ".json.gz")
+    launches = trace.spans("TABLE_ROW_LAUNCH")
+    assert launches and all((r.ids_from, r.ids_ready) == ("", 0)
+                            for r in launches)
+    assert read(SimpleNamespace(_op_trace=trace)) is None
+
+
 # -- a cut recorded on the chip ------------------------------------------------
 
 def test_recorded_cut_reduces_to_what_the_oracle_worked_out():
@@ -703,7 +748,8 @@ def _one(trace, stage, op):
 @pytest.mark.parametrize("device", [False, True], ids=["host", "device"])
 def test_an_add_and_a_get_each_leave_one_causal_chain(tracing, device):
     import jax
-    mv.init()
+    # a table on one device: a device-path op's ids go up at submit
+    mv.init(mesh_shape="1")
     table = _table()
     table.add(ONES, row_ids=IDS)       # compile outside the window
     table.get(IDS)
@@ -738,6 +784,18 @@ def test_an_add_and_a_get_each_leave_one_causal_chain(tracing, device):
         assert process.parent == dispatch.id
         assert table_op.parent == process.id
         assert launch.parent == prep.parent == table_op.id
+        # a device-path op's ids go up inside its submit, on the caller's
+        # thread (a child of the span open there), before the message is
+        # queued; the dispatcher's prep stays, with the rows named
+        sent = [r for r in trace.spans("WORKER_ROW_IDS")
+                if r.parent == submit.id]
+        assert len(sent) == int(device) and prep.n == len(IDS)
+        assert launch.ids_from == ("caller" if device else "dispatcher")
+        assert launch.ids_ready in (0, 1)
+        for up in sent:
+            assert up.n == len(IDS) and up.bytes >= 4 * len(IDS)
+            assert submit.start_ns <= up.start_ns
+            assert up.start_ns + up.dur_ns <= wait.start_ns
         waited = _one(trace, "WORKER_WAIT", op)
         # the message is stamped into the queue inside the submit, service
         # begins when the wait ends, the launch lies inside the service:

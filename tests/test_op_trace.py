@@ -486,9 +486,13 @@ def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
     """On a table sharded over four devices every row op counts its ids by
     shard in a TABLE_ROW_ROUTE inside its TABLE_ROW_PREP (`n` = ids routed:
     a Get's are padded to its step of the bucket, the sentinel last; the
-    chip does the rest of the routing) and its TABLE_ROW_LAUNCH carries the
-    shards, the slots launched over all of them, the fullest shard's and
-    the bytes of rows that crossed chips. `shard_slots_share`,
+    chip does the rest of the routing): the dispatcher sends the ids up
+    there, a device-path op's too (no WORKER_ROW_IDS on a mesh), and the
+    TABLE_ROW_PREP keeps `n` = rows named; its TABLE_ROW_LAUNCH says so
+    (`ids_from`) and carries the shards, the slots launched over all of
+    them, the fullest shard's and the bytes of rows that crossed chips
+    (on one device the caller sends them up: WORKER_ROW_IDS, `caller`).
+    `shard_slots_share`,
     `shard_exchange_bytes_share` and `shard_row_imbalance` read them; a
     program whose records carry no `shards` (the parent's ring) gives None,
     and so does a one-shard table."""
@@ -496,6 +500,7 @@ def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
 
     from multiverso_tpu.ops import pallas_rows
     from multiverso_tpu.tables import matrix_table
+    from multiverso_tpu.tables.matrix_table import _live_slots
 
     monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
                         lambda platform, num_shards, *width: True)
@@ -518,16 +523,26 @@ def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
         launches = trace.spans("TABLE_ROW_LAUNCH")
         if mesh == 1:
             assert not routes and not any(r.shards for r in launches)
+            # on one device the caller sends a device-path op's ids up
+            assert [r.ids_from for r in launches] == ["caller"] * 4
+            assert [(r.n, r.bytes) for r in trace.spans("WORKER_ROW_IDS")
+                    ] == [(n, 4 * 2048), (n, 4 * _live_slots(n, 2048))] * 2
+            assert [r.n for r in trace.spans("TABLE_ROW_PREP")] == [n] * 4
             for name in ("shard_slots_share", "shard_exchange_bytes_share",
                          "shard_row_imbalance"):
                 assert _metric(name, run) is None
             mv.shutdown()
             continue
-        from multiverso_tpu.tables.matrix_table import _live_slots
         assert [r.n for r in routes] == [n, _live_slots(n, 2048)] * 2
         preps = {r.id: r for r in trace.spans("TABLE_ROW_PREP")}
         assert all(r.parent in preps for r in routes)
+        assert not trace.spans("WORKER_ROW_IDS")
+        assert [r.n for r in preps.values()] == [n] * 4
+        assert all(trace._by_id[r.parent].stage in (
+            "TABLE_PROCESS_ADD", "TABLE_PROCESS_GET")
+            for r in preps.values())
         assert [r.shards for r in launches] == [shards] * 4
+        assert [r.ids_from for r in launches] == ["dispatcher"] * 4
         adds, gets = launches[0::2], launches[1::2]
         counts = np.bincount(ids // table._server_table._block_rows,
                              minlength=shards)

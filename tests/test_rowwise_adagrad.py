@@ -348,6 +348,56 @@ def test_four_virtual_devices_run_it(ref, form):
     assert server.states["s"].sharding == state.sharding
 
 
+def _device_add_the_dispatcher_prepares(table, grad, ids, option):
+    """A device Add whose request carries bare numpy ids: the dispatcher
+    sends them up in its TABLE_ROW_PREP, as it did for every device Add
+    before the caller did."""
+    import jax
+
+    from multiverso_tpu.runtime.message import MsgType
+
+    table.wait(table._submit(MsgType.Request_Add,
+                             (ids, jax.device_put(grad), option)))
+
+
+@pytest.mark.parametrize("cols", [128, 300])
+@pytest.mark.parametrize("kernel,shards", [("pallas", 1), ("xla", 1),
+                                           ("xla", 4)])
+def test_ids_sent_at_submit_change_no_bit(monkeypatch, kernel, shards, cols):
+    """Raw gradients by `add_device_async`, their ids sent up from the
+    caller's thread at submit (and overwritten as soon as the call
+    returns), against the same device Adds with the ids sent up by the
+    dispatcher: tables, accumulators and every Get (by `get_device_async`
+    against `get_async`) equal to the bit, at one and at three lane tiles a
+    row, on the interpreted row kernel and on XLA's scatter; on four
+    devices (the state step takes XLA's partitioned programs, the Gets are
+    routed) the dispatcher sends every op's ids up and the two tables
+    still agree. Not against `add_async`: a host Add pads its rows to
+    the bucket, another shape of the same program, and on the CPU the two
+    differ in the last place of a few elements of a small op, before ids
+    went up at submit as after."""
+    from test_matrix_table import _device_ops_equal_host_ops
+
+    from multiverso_tpu import dashboard
+
+    _open_gate(monkeypatch, kernel, shards)
+    try:
+        monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
+        records = _device_ops_equal_host_ops(
+            4000, cols, NAME, OPTION,
+            lambda rng, n, cols: rng.integers(-128, 128, (n, cols)).astype(
+                np.float32) / 512,
+            _device_add_the_dispatcher_prepares, at_submit=shards == 1)
+        launched = [r for r in records if r.stage == "TABLE_ROW_LAUNCH"]
+        assert [r.ids_from for r in launched] == (
+            ["caller" if shards == 1 else "dispatcher"] * 3
+            + ["dispatcher"] * 3) * 5
+        assert [r.updater for r in launched] == [NAME, "", ""] * 10
+        assert launched[0].path == kernel
+    finally:
+        mv.shutdown()
+
+
 def test_an_array_table_refuses_it_by_name():
     from multiverso_tpu.log import FatalError
     mv.init()
