@@ -855,6 +855,7 @@ from multiverso_tpu.tables.array_table import ArrayServer, ArrayWorker  # noqa: 
 from multiverso_tpu.tables.kv_table import (  # noqa: E402
     DeviceKVServer, KVServer, KVWorker, TieredKVServer, make_tiered_kv)
 from multiverso_tpu.tables.matrix_table import MatrixServer, MatrixWorker  # noqa: E402
+from multiverso_tpu.tables.group_table import MatrixGroupWorker  # noqa: E402
 from multiverso_tpu.tables.sparse_table import (  # noqa: E402
     SparseWorker, TieredSparseServer, make_tiered_sparse)
 from multiverso_tpu.updaters import AddOption, GetOption  # noqa: E402,F401
@@ -865,6 +866,7 @@ MatrixTableHandler = MatrixWorker
 _TABLE_TYPES = {
     "array": ArrayWorker,
     "matrix": MatrixWorker,
+    "matrix_group": MatrixGroupWorker,
     "kv": KVWorker,
     "sparse": SparseWorker,
     # beyond-RAM variants (multiverso_tpu/store/, docs/tiered_storage.md)

@@ -23,6 +23,13 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
   of the last group past the delta's end are still walked; the kernel
   gives them a zero delta whatever the block holds there, so they must
   aim at the sentinel (or at any row the call does not name).
+* with ``tail_count`` the delta may instead outnumber the rows named (a
+  caller's buffer of one shape, every call another count of rows: PR 38):
+  the last id slot holds the count of leading slots that name rows, the
+  kernel reads it from SMEM, and a grid step past the count issues no
+  descriptor; the last step under the count walks its live slots in a loop.
+  The operands are the plain call's (no second upload, one custom call of
+  one name to a trace) and one program serves every count under a shape.
 * ``sign`` (a static float of the table's updater: -1.0 for SGD) scales
   the delta inside the kernel; the cast to the table's dtype is inside the
   same jitted program. A delta narrower than the table's lanes (300 columns
@@ -315,7 +322,7 @@ def gather_rows(table: jax.Array, ids: jax.Array, *,
 
 
 def _scatter_add_kernel(*refs, rows, sign, counted):
-    if counted:
+    if counted == "operand":
         # a shard's launch: the slots from ``count`` on issue no descriptor
         ids_ref, count_ref, delta_ref, table_in_ref, table_ref, scratch, \
             sems = refs
@@ -393,7 +400,10 @@ def _scatter_add_kernel(*refs, rows, sign, counted):
     if not counted:
         walk(ROW_GROUP)
         return
-    live = count_ref[0] - base
+    # "tail": a delta longer than its ids, the count in the last id slot
+    count = (count_ref[0] if counted == "operand"
+             else ids_ref[ids_ref.shape[0] - 1])
+    live = count - base
     pl.when(live >= ROW_GROUP)(lambda: walk(ROW_GROUP))
     pl.when(jnp.logical_and(live > 0, live < ROW_GROUP))(lambda: walk(live))
 
@@ -410,16 +420,20 @@ def launch_waits(rows: int) -> int:
     return 2 * pl.cdiv(rows, ROW_GROUP)
 
 
-def _scatter_add(table, ids, deltas, interpret, sign, count=None):
+def _scatter_add(table, ids, deltas, interpret, sign, count=None,
+                 tail_count=False):
     """The scatter-add's ``pallas_call``, traceable: ``count`` (int32, one
-    element) is the number of leading id slots that are live; without it
-    every slot of the delta's row groups is."""
+    element) is the number of leading id slots that are live, and with
+    ``tail_count`` the ids' own last slot holds that number (the operands
+    are the plain call's: no second upload, and the same custom call to a
+    trace); without either every slot of the delta's row groups is."""
     rows, width = deltas.shape
     tiles = lane_tiles(table)
     deltas = deltas.astype(table.dtype)
     view = _tile_view(table)
-    counted = count is not None
-    prefetch = (ids, count) if counted else (ids,)
+    counted = ("operand" if count is not None
+               else "tail" if tail_count else "")
+    prefetch = (ids, count) if count is not None else (ids,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         # the delta sizes the grid: id slots past its last group (the tail
@@ -449,18 +463,27 @@ def _scatter_add(table, ids, deltas, interpret, sign, count=None):
     )(*prefetch, deltas, view))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "sign"),
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "sign", "tail_count"),
                    donate_argnums=(0,))
-def _scatter_add_call(table, ids, deltas, interpret, sign=1.0):
-    return _scatter_add(table, ids, deltas, interpret, sign)
+def _scatter_add_call(table, ids, deltas, interpret, sign=1.0,
+                      tail_count=False):
+    return _scatter_add(table, ids, deltas, interpret, sign,
+                        tail_count=tail_count)
 
 
 def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
-                     *, interpret: bool, sign: float = 1.0) -> jax.Array:
+                     *, interpret: bool, sign: float = 1.0,
+                     tail_count: bool = False) -> jax.Array:
     """In-place ``table.at[ids[:n]].add(sign * deltas)`` for the ``n`` rows
     of ``deltas`` and unique live ids; the input table buffer is donated.
     ``ids`` may be longer than ``deltas``: the slots of the last row group
-    past ``n`` are read and written back unchanged, later ones not at all."""
+    past ``n`` are read and written back unchanged, later ones not at all.
+
+    ``tail_count``: the delta is longer than its ids. ``ids[-1]`` is the
+    number of leading slots that name rows; the delta's rows from there on
+    are not applied and their slots issue no descriptor, whatever either
+    holds. One program serves every count under a delta's shape."""
     if ids.shape[0] % ROW_GROUP:
         raise ValueError(
             f"scatter_add_rows: batch {ids.shape[0]} not a multiple of {ROW_GROUP}")
@@ -474,4 +497,4 @@ def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
             f"table of {table.shape[1]}")
     if not deltas.shape[0]:
         return table
-    return _scatter_add_call(table, ids, deltas, interpret, sign)
+    return _scatter_add_call(table, ids, deltas, interpret, sign, tail_count)

@@ -1659,6 +1659,10 @@ class RemoteClient:
             return _RemoteKVWorker(spec, table_id, self._channel)
         if kind == "sparse":
             return _RemoteSparseWorker(spec, table_id, self._channel)
+        if kind == "matrix_group":
+            raise KeyError(
+                f"table {table_id} is a matrix_group: the group op is not "
+                f"served to remote workers (ROADMAP Queue 2 item 10)")
         raise KeyError(f"unknown remote table kind {kind!r}")
 
     def tables(self) -> List[WorkerTable]:

@@ -62,14 +62,22 @@ def _device_pad(values: jax.Array, bucket: int, cols: int) -> jax.Array:
 
 
 def _xla_scatter_add(data: jax.Array, ids: jax.Array, deltas: jax.Array,
-                     *, sign: float = 1.0) -> jax.Array:
+                     *, sign: float = 1.0,
+                     tail_count: bool = False) -> jax.Array:
     """XLA's scatter-add in the call shape of
     ``pallas_rows.scatter_add_rows``: ``ids`` may be longer than ``deltas``
     (a bucket; its tail is sliced off here) and the updater's sign is
-    applied inside the program."""
+    applied inside the program. ``tail_count``: ``ids[-1]`` is the number
+    of leading slots that name rows; the rest add nothing, past the table's
+    end, where XLA drops an update."""
     if sign != 1.0:
         deltas = sign * deltas
-    return data.at[ids[: deltas.shape[0]]].add(deltas)
+    slots = ids[: deltas.shape[0]]
+    if not tail_count:
+        return data.at[slots].add(deltas)
+    live = jnp.arange(slots.shape[0]) < ids[-1]
+    return data.at[jnp.where(live, slots, data.shape[0])].add(
+        jnp.where(live[:, None], deltas, 0), mode="drop")
 
 
 def _live_slots(n: int, bucket: int) -> int:
@@ -201,13 +209,15 @@ class LaunchIds(NamedTuple):
     (the shape of a Get's result, and of a delta XLA's programs take).
     ``counts`` and ``capacity``: the host's part of routing that Get
     (``_route``), None and 0 where nothing is routed. ``nbytes`` went
-    up."""
+    up. ``host``: the ids named as they went up, a view of the uploaded
+    host array (to read, never to write)."""
 
     ids: jax.Array
     bucket: int
     counts: Optional[np.ndarray]
     capacity: int
     nbytes: int
+    host: np.ndarray
 
 
 class SentIds(np.ndarray):
@@ -265,9 +275,10 @@ class MatrixServer(ServerTable):
         self._num_shards = num_shards
         self._block_rows = self.padded_rows // num_shards
 
-        if init_value is not None:
+        if init_value is not None and not callable(init_value):
             init_value = np.asarray(init_value, dtype=self.dtype).reshape(
                 self.num_row, self.num_col)
+        # a callable is a block source, ``(lo, n) -> rows [lo, lo + n)``
         self.data = self._put_rows(
             init_value if init_range is None or init_value is not None
             else functools.partial(self._uniform_rows, init_range, seed))
@@ -370,7 +381,8 @@ class MatrixServer(ServerTable):
             self._scatter_add_raw = functools.partial(
                 _xla_scatter_add, sign=self._sign)
             self._scatter_add = jax.jit(self._scatter_add_raw,
-                                        donate_argnums=(0,))
+                                        donate_argnums=(0,),
+                                        static_argnames=("tail_count",))
         # the table rows of an Add go through the row kernel: a linear
         # updater's delta, or a row-state updater's scaled delta (on a table
         # sharded over chips the first is routed, the second takes XLA's
@@ -480,16 +492,20 @@ class MatrixServer(ServerTable):
     # -- helpers -----------------------------------------------------------
     def _put_rows(self, rows=None) -> jax.Array:
         """The table's device state from its logical rows, put up shard by
-        shard: ``rows`` is the ``(num_row, num_col)`` host array, a function
-        ``(lo, n) -> rows [lo, lo + n)``, or None for zeros. A block is
-        padded (scratch rows, lanes) only where it needs it: a block of
-        whole rows at the table's own width goes up as a view of ``rows``."""
+        shard and a shard piece by piece (``mesh_lib.put_row_blocks``):
+        ``rows`` is the ``(num_row, num_col)`` host array, a block source
+        ``(lo, n) -> rows [lo, lo + n)`` asked in row order, or None for
+        zeros. A block is padded (scratch rows, lanes) only where it needs
+        it: a block of whole rows at the table's own width goes up as it
+        came, a view of ``rows`` or the source's own array, so the host
+        never holds a second table, nor a first one under a source."""
         def block_of(lo: int, hi: int) -> np.ndarray:
             live = max(0, min(hi, self.num_row) - lo)
             part = (None if rows is None or not live
                     else rows(lo, live) if callable(rows)
                     else rows[lo:lo + live])
-            if part is not None and part.shape == (hi - lo, self.padded_cols):
+            if part is not None and part.dtype == self.dtype \
+                    and part.shape == (hi - lo, self.padded_cols):
                 return part
             block = np.zeros((hi - lo, self.padded_cols), self.dtype)
             if part is not None:
@@ -497,7 +513,8 @@ class MatrixServer(ServerTable):
             return block
 
         return mesh_lib.put_row_blocks(self.mesh, self.padded_rows,
-                                       self.padded_cols, block_of)
+                                       self.padded_cols, block_of,
+                                       itemsize=self.dtype.itemsize)
 
     def _uniform_rows(self, init_range, seed: int, lo: int,
                       n: int) -> np.ndarray:
@@ -584,7 +601,8 @@ class MatrixServer(ServerTable):
         return max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
 
     def launch_ids(self, row_ids: np.ndarray, op: str,
-                   ensure_pad: bool = False) -> LaunchIds:
+                   ensure_pad: bool = False, offsets=None,
+                   rows: Optional[int] = None) -> LaunchIds:
         """The int32 ``row_ids`` of a row Get or of a device Add (``op``:
         ``get`` or ``add``) as the launch takes them, their upload begun:
         on the thread that calls, which is the dispatcher in the op's
@@ -604,21 +622,35 @@ class MatrixServer(ServerTable):
         ids past the table, which no shard owns, and the sentinel last
         (the tail of the result is its row's value, wherever its shard put
         it); a linear Add's there are routed with its delta
-        (``_route_add``), not here."""
+        (``_route_add``), not here.
+
+        ``offsets`` (an int32 or an int32 array an id) are added to the
+        ids as they are written into the array that goes up: a table
+        group's bases (``tables/group_table.py``), in the one pass.
+
+        ``rows``: the rows of a device Add's delta. Where they outnumber
+        the ids (``_process_add_device``) the bucket holds the delta's row
+        groups, and its last slot, which then names no row, holds the
+        count of ids (``pallas_rows.scatter_add_rows``, ``tail_count``)."""
         n = len(row_ids)
-        bucket = self._get_bucket(n, ensure_pad)
+        longer = rows is not None and rows > n
+        bucket = self._get_bucket(rows if longer else n, ensure_pad)
         routed = op == "get" and self._shard_rows is not None
         pads = (bucket if op == "add" else _live_slots(n, bucket)) - n
         ids = np.empty(n + pads, np.int32)
-        ids[:n] = row_ids
+        if offsets is None:
+            ids[:n] = row_ids
+        else:
+            np.add(row_ids, offsets, out=ids[:n])
         ids[n:] = self.padded_rows if routed else self.sentinel_row
         if pads:
-            ids[-1] = self.sentinel_row
+            ids[-1] = n if longer else self.sentinel_row
         if not routed:
-            return LaunchIds(async_upload(ids), bucket, None, 0, ids.nbytes)
+            return LaunchIds(async_upload(ids), bucket, None, 0, ids.nbytes,
+                             ids[:n])
         counts, capacity = self._route(ids)
         return LaunchIds(self._shard_rows.on_first(ids), bucket, counts,
-                         capacity, ids.nbytes)
+                         capacity, ids.nbytes, ids[:n])
 
     def _staging(self, bucket: int) -> _StageSlot:
         """The slot a row Add's padded ids and values are written into and
@@ -830,36 +862,46 @@ class MatrixServer(ServerTable):
 
     def _process_add_device(self, row_ids, values, worker, scalars) -> None:
         """A device Add, launched on the ids its caller sent up at submit
-        (``SentIds``); ids that come without go up here."""
+        (``SentIds``); ids that come without go up here. A delta of more
+        rows than the op has ids (a caller's buffer of one shape for every
+        count of rows: ``MatrixWorker.add_device_async``) is applied as far
+        as the ids go, by the one program of that shape."""
         routed = self._linear and self._shard_rows is not None
         with span("TABLE_ROW_PREP") as prep:
             took = getattr(row_ids, "took", None)
             ids_from = _IDS_FROM[took is not None]
             row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
             prep.n = n = len(row_ids)
-            if values.shape[0] != n:
+            longer = values.shape[0] > n
+            if values.shape[0] < n:
                 log.fatal("Matrix.add(device): %d ids but %d value rows",
                           n, values.shape[0])
+            if longer and (routed or not self._linear):
+                log.fatal("Matrix.add(device): %d ids but %d value rows: a "
+                          "delta longer than its ids is served under "
+                          "default / sgd, and not where the Add is routed "
+                          "to the row kernels of several chips", n,
+                          values.shape[0])
             if routed:
                 routed = self._route_add(row_ids, values)
             elif took is None:
-                took = self.launch_ids(row_ids, "add")
+                took = self.launch_ids(row_ids, "add", rows=values.shape[0])
         with span("TABLE_ROW_LAUNCH") as launch:
             if routed:
                 self._launch_routed_add(launch, *routed)
             else:
                 from multiverso_tpu.ops.pallas_rows import launched_slots
-                # the pallas kernel takes the delta as it came and walks its
-                # row groups, not the bucket's: one device program an Add
+                # the pallas kernel takes the delta as it came and walks the
+                # row groups of the rows named, not the bucket's: one device
+                # program an Add
                 pallas = self._kernel_rows
                 if not pallas:
                     values = self._bucket_delta(values, took.bucket)
-                self._note_launch(launch, "add",
-                                  launched_slots(values.shape[0]), pallas,
+                self._note_launch(launch, "add", launched_slots(n), pallas,
                                   took.ids, ids_from)
                 if self._linear:
                     self.data = self._scatter_add(self.data, took.ids,
-                                                  values)
+                                                  values, tail_count=longer)
                 else:
                     self.data, self.states = self._row_update(
                         self.data, self.states, took.ids, values, worker,
@@ -1244,7 +1286,7 @@ class MatrixWorker(WorkerTable):
             sent = self._ids_at_submit(ids, "get")
             # every Get's range check, made while the upload is in flight
             # (0.05 ms for 100,000 ids): ids that fail it are never launched
-            self._norm_ids(ids)
+            self._check_range(ids)
             return self._submit(MsgType.Request_Get, (sent, option, True),
                                 submit)
 
@@ -1259,6 +1301,15 @@ class MatrixWorker(WorkerTable):
         """Async device-resident add. ``values`` is a jax.Array of shape
         ``(len(row_ids), <=num_col)``; live ids unique, pad slots (if the
         caller pads) aim at ``num_row`` (the sentinel) with zero deltas.
+
+        ``values`` may have MORE rows than there are ids (under ``default``
+        / ``sgd``; not on a table whose Adds are routed to the row kernels
+        of several chips, which refuses it by name): a caller's buffer of one
+        shape, such as the gradient of a device Get's ``(bucket, lanes)``
+        result. Its rows past the ids are not applied, whatever they hold,
+        and one device program serves every count of ids under that shape;
+        a delta of exactly ``len(row_ids)`` rows compiles a program a
+        count.
 
         On a table on one device the ids are copied and their upload
         begins here, on the caller's thread, before the message is queued
@@ -1277,9 +1328,11 @@ class MatrixWorker(WorkerTable):
             submit.n = len(ids)
             return self._submit(
                 MsgType.Request_Add,
-                (self._ids_at_submit(ids, "add"), values, option), submit)
+                (self._ids_at_submit(ids, "add", values.shape[0]), values,
+                 option), submit)
 
-    def _ids_at_submit(self, ids: np.ndarray, op: str) -> np.ndarray:
+    def _ids_at_submit(self, ids: np.ndarray, op: str,
+                       rows: Optional[int] = None) -> np.ndarray:
         """A device-path op's ids for its request. Where the table says so
         (``MatrixServer.ids_at_submit``: a table on one device) they carry
         themselves as the launch takes them (``SentIds``): made by the
@@ -1287,17 +1340,33 @@ class MatrixWorker(WorkerTable):
         inside the op's WORKER_SUBMIT, so that the upload is in flight
         while the message waits for the dispatcher, which launches on ids
         already on their way (``MatrixServer.launch_ids``). Elsewhere the
-        ids go as they came and the dispatcher sends them up."""
+        ids go as they came and the dispatcher sends them up. ``rows``: of
+        an Add, its delta's."""
         if not self._server_table.ids_at_submit:
-            return ids
+            return self._table_ids(ids)
         with span("WORKER_ROW_IDS") as up:
             up.n = len(ids)
-            took = self._server_table.launch_ids(ids, op,
-                                                 ensure_pad=op == "get")
+            took = self._server_table.launch_ids(
+                ids, op, ensure_pad=op == "get",
+                offsets=self._ids_offsets(ids), rows=rows)
             up.bytes = took.nbytes
-            ids = ids.view(SentIds)
-            ids.took = took
-            return ids
+            # the ids as they went up, not the caller's array
+            sent = took.host.view(SentIds)
+            sent.took = took
+            return sent
+
+    def _ids_offsets(self, ids: np.ndarray):
+        """What turns this proxy's ``ids`` into its server table's, for
+        ``launch_ids``: nothing for a table's own proxy; a table group's
+        proxies check their ids against their members' ends here and give
+        their bases (``tables/group_table.py``)."""
+        return None
+
+    def _table_ids(self, ids: np.ndarray) -> np.ndarray:
+        """``ids`` (int32, checked) as a request carries them where the
+        dispatcher sends them up: the server table's own ids."""
+        offsets = self._ids_offsets(ids)
+        return ids if offsets is None else ids + offsets
 
     def transact_device_async(self, fn, others: Sequence["MatrixWorker"],
                               args: tuple = (),
@@ -1407,12 +1476,17 @@ class MatrixWorker(WorkerTable):
         return ids
 
     def _norm_ids(self, row_ids) -> Optional[np.ndarray]:
+        """A host-path op's ids as its request carries them: int32, inside
+        the table, the server table's own (``_table_ids``)."""
         if row_ids is None:
             return None
         ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
+        self._check_range(ids)
+        return self._table_ids(ids)
+
+    def _check_range(self, ids: np.ndarray) -> None:
         if ids.size and (ids.min() < 0 or ids.max() >= self.num_row):
             log.fatal("Matrix row id out of range [0, %d)", self.num_row)
-        return ids
 
     def _default_add_option(self, option: Optional[AddOption]) -> AddOption:
         if option is None:

@@ -322,3 +322,74 @@ def test_row_state_add_compiles_with_the_row_kernel(one_chip, rows, lanes,
     # the scaled gradient, its row-major copy where it is narrower than
     # the lanes, and the state in fast memory: never a table
     assert mem.temp_size_in_bytes <= 3 * named * lanes * 4 + rows * 4 * 2
+
+
+# the table group's slab at the cell `dlrm26.step-rows`: the 26 members'
+# 19,063,992 rows and the sentinel, 2.44e9 elements in ONE array (the first
+# of more than 2^31 on a chip), and a step's 102,900 rows
+SLAB_ROWS, STEP_ROWS = 19_063_992 + 1, 102_900
+
+
+@pytest.mark.parametrize("program", ["add", "add of a held delta", "get",
+                                     "piece"])
+def test_group_slab_programs_compile_at_19_million_rows(one_chip, program):
+    """A group op is the matrix table's own programs over the slab: the Add
+    is ONE custom call of the row kernel (absent: a failure) that aliases
+    the 9.76 GB slab whole, under a delta of the step's rows and under one
+    held at the Get's bucket with the count of ids in the last id slot (the
+    cell's: the same operands, so the same event to a trace's readers, and
+    nothing in front of the kernel); the Get one gather fusion over `%data` of the
+    slots `_live_slots` gives, its result the bucket; a piece of the slab
+    on its way up is written in place (`mesh._set_rows`). None makes a
+    temporary of the slab's size."""
+    from benchmark.row_bytes import GATHER_EVENT
+    from multiverso_tpu.parallel import mesh as mesh_lib
+    from multiverso_tpu.tables.matrix_table import (_live_slots,
+                                                    _row_gather_jit)
+
+    slab = jax.ShapeDtypeStruct((SLAB_ROWS, 128), jnp.float32,
+                                sharding=one_chip)
+    bucket, slab_bytes = 131_072, SLAB_ROWS * 128 * 4
+    if program.startswith("add"):
+        from benchmark.layers import row_scatter_roofline
+
+        held = program != "add"
+        compiled = _compile(
+            lambda table, ids, deltas: pallas_rows.scatter_add_rows(
+                table, ids, deltas, interpret=False, tail_count=held),
+            one_chip, ((SLAB_ROWS, 128), jnp.float32),
+            ((bucket,), jnp.int32),
+            ((bucket if held else STEP_ROWS, 128), jnp.float32),
+            donate_argnums=(0,))
+        text = _hlo_text(compiled)
+        entry = text[text.index("ENTRY"):].splitlines()
+        kernels = [line for line in entry if "tpu_custom_call" in line]
+        assert len(kernels) == 1 and "_scatter_add_call" in kernels[0]
+        # the standing readers find the launch's shapes in the event's name
+        slots, rows, lanes = map(int, row_scatter_roofline.SHAPES.search(
+            kernels[0]).groups())
+        assert (slots, rows, lanes) == (bucket,
+                                        bucket if held else STEP_ROWS, 128)
+        assert not [line for line in entry if " fusion(" in line]
+        assert compiled.memory_analysis().alias_size_in_bytes >= slab_bytes
+    elif program == "get":
+        live = _live_slots(STEP_ROWS, bucket)
+        compiled = _row_gather_jit.lower(
+            slab, jax.ShapeDtypeStruct((live,), jnp.int32,
+                                       sharding=one_chip),
+            bucket=bucket, sentinel=SLAB_ROWS - 1).compile()
+        text = _hlo_text(compiled)
+        assert f"->f32[{bucket},128]" in text.splitlines()[0]
+        gathers = [line for line in text[text.index("ENTRY"):].splitlines()
+                   if GATHER_EVENT.search(line)]
+        assert len(gathers) == 1, gathers
+        assert int(GATHER_EVENT.search(gathers[0]).group(1)) == live
+    else:
+        compiled = mesh_lib._set_rows.lower(
+            slab, jax.ShapeDtypeStruct((524_288, 128), jnp.float32,
+                                       sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+        assert "dynamic-update-slice" in _hlo_text(compiled)
+        assert compiled.memory_analysis().alias_size_in_bytes >= slab_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        bucket * 128 * 4 + (1 << 20))
