@@ -310,11 +310,29 @@ class MatrixGroupWorker(MatrixWorker):
                               "rows", member, self.num_rows[member])
             return np.repeat(self._bases, ids.lengths)
 
+    def _named(self, ids: _Segments) -> _Segments:
+        """What the group keeps of an op (``KeptIds.named``): the members'
+        own ids and their lengths, copies both (``lengths`` may be the
+        caller's array)."""
+        named = super()._named(ids).view(_Segments)
+        named.lengths = ids.lengths.copy()
+        return named
+
+    def _names_kept(self, ids: _Segments, kept) -> bool:
+        # the same ids under other lengths are other rows of the slab
+        return np.array_equal(ids.lengths, kept.named.lengths) \
+            and super()._names_kept(ids, kept)
+
     def _send(self, op: str, ids, lengths, option, device: bool,
               values=None) -> int:
         """One group op as one message: ``op`` is ``get`` or ``add``; the
         device path's ids go up from this thread where the slab says so
-        (``_ids_at_submit``), the host path's go as the slab's ids."""
+        (``_ids_at_submit``), the host path's go as the slab's ids. A
+        device-path op that names what the group's last one named, the
+        same ids under the same lengths, launches on the array that one
+        sent up and skips ``WORKER_GROUP_IDS`` with the upload: segments
+        that were checked against their members' ends and given their
+        bases once are the same segments."""
         with span("WORKER_SUBMIT") as submit:
             segments = self._segments(ids, lengths)
             submit.n = len(segments)

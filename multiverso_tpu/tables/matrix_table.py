@@ -97,14 +97,21 @@ def _live_slots(n: int, bucket: int) -> int:
 
 
 def _row_gather(data: jax.Array, ids: jax.Array,
-                bucket: Optional[int] = None, sentinel: int = 0) -> jax.Array:
-    """The table's row Get: ``(bucket, lanes)`` whose first ``len(ids)``
-    slots are the rows ``ids`` names and whose every later slot is a copy
+                bucket: Optional[int] = None, sentinel: int = 0,
+                live: Optional[int] = None) -> jax.Array:
+    """The table's row Get: ``(bucket, lanes)`` whose first ``live`` slots
+    are the rows ``ids[:live]`` names and whose every later slot is a copy
     of row ``sentinel``, read once and broadcast (the gather follows the
-    ids, not the bucket). Ids that fill the bucket (or no bucket given)
-    leave the gather alone. Named, like its table parameter, so that the
-    compiled module is ``jit__row_gather`` in a trace and the gather a
-    fusion over ``%data``."""
+    ids named, not the bucket). ``ids`` come as an Add's do, ``bucket`` of
+    them (``MatrixServer.launch_ids``: one uploaded form, so that either
+    op can launch on the other's), and the slots gathered are a static
+    slice of them that XLA folds into the pass it makes over the ids
+    anyway; ``live`` None gathers every id given. Ids that fill the bucket
+    (or no bucket given) leave the gather alone. Named, like its table
+    parameter, so that the compiled module is ``jit__row_gather`` in a
+    trace and the gather a fusion over ``%data``."""
+    if live is not None:
+        ids = ids[:live]
     rows = data[ids]
     tail = (bucket or ids.shape[0]) - ids.shape[0]
     if not tail:
@@ -113,9 +120,10 @@ def _row_gather(data: jax.Array, ids: jax.Array,
         [rows, jnp.broadcast_to(data[sentinel], (tail, data.shape[1]))])
 
 
-# one jit for every table: the programs are keyed by shapes, bucket and
-# sentinel, and tables of one shape share them
-_row_gather_jit = jax.jit(_row_gather, static_argnames=("bucket", "sentinel"))
+# one jit for every table: the programs are keyed by shapes, bucket, live
+# slots and sentinel, and tables of one shape share them
+_row_gather_jit = jax.jit(_row_gather,
+                          static_argnames=("bucket", "sentinel", "live"))
 
 
 def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
@@ -202,15 +210,19 @@ _IDS_FROM = ("dispatcher", "caller")
 class LaunchIds(NamedTuple):
     """The ids of one row op as its launch takes them, on their way to the
     device (``MatrixServer.launch_ids``). ``ids``: on a table one program
-    serves, the ids padded with sentinel-aimed slots (an Add's to
-    ``bucket``, a Get's to ``_live_slots``); a Get's on a table sharded
-    over chips, the first shard's piece of the array the routed program
-    takes (``ShardedRows.on_first``). ``bucket``: the op's power of two
-    (the shape of a Get's result, and of a delta XLA's programs take).
+    serves, the ids padded to ``bucket`` with sentinel-aimed slots, an
+    Add's and a Get's alike; a Get's on a table sharded over chips, the
+    first shard's piece of the array the routed program takes
+    (``ShardedRows.on_first``). ``bucket``: the op's power of two (the
+    shape of a Get's result, and of a delta XLA's programs take).
     ``counts`` and ``capacity``: the host's part of routing that Get
     (``_route``), None and 0 where nothing is routed. ``nbytes`` went
     up. ``host``: the ids named as they went up, a view of the uploaded
-    host array (to read, never to write)."""
+    host array (to read, never to write). ``counted``: the bucket's last
+    slot holds the count of ids and not the sentinel (an Add whose delta
+    outnumbers its ids). ``host``, ``bucket`` and ``counted`` decide the
+    array: an op whose own would have the same three can launch on this
+    one (``MatrixWorker._ids_at_submit`` keeps the last)."""
 
     ids: jax.Array
     bucket: int
@@ -218,6 +230,7 @@ class LaunchIds(NamedTuple):
     capacity: int
     nbytes: int
     host: np.ndarray
+    counted: bool = False
 
 
 class SentIds(np.ndarray):
@@ -228,6 +241,35 @@ class SentIds(np.ndarray):
     for whatever stands between the proxy and the table."""
 
     took: Optional[LaunchIds] = None
+
+
+class KeptIds(NamedTuple):
+    """What a proxy keeps of the last device-path op it sent up
+    (``MatrixWorker._ids_at_submit``): ``took``, the array on the device,
+    and ``named``, a private host copy of the ids as the caller named them
+    (before a group's bases; ``took.host`` itself where nothing is
+    added)."""
+
+    took: LaunchIds
+    named: np.ndarray
+
+    def serves(self, ids: np.ndarray, op: str,
+               form: Tuple[int, bool]) -> bool:
+        """Whether the kept array could be the one ``op`` of ``ids`` in
+        ``form`` (``MatrixServer.launch_form``) would send up, by what is
+        cheap to see: the count, the bucket, the last slot, the first and
+        the last id. A Get reads the last slot only where it gathers the
+        whole bucket, and then not one that holds a count."""
+        named, took = self.named, self.took
+        n, (bucket, counted) = len(ids), form
+        if n != len(named) or bucket != took.bucket:
+            return False
+        if op == "add":
+            if counted != took.counted:
+                return False
+        elif took.counted and _live_slots(n, bucket) == bucket:
+            return False
+        return not n or (ids[0] == named[0] and ids[-1] == named[-1])
 
 
 class RowPieces(list):
@@ -335,8 +377,8 @@ class MatrixServer(ServerTable):
         from jax.sharding import SingleDeviceSharding
         first_dev = self.mesh.devices.flat[0]
         _out_dev = SingleDeviceSharding(first_dev)
-        self._gather_out = lambda data, ids, bucket: jax.device_put(
-            self._gather(data, ids, bucket=bucket), _out_dev)
+        self._gather_out = lambda data, ids, bucket, live: jax.device_put(
+            self._gather(data, ids, bucket=bucket, live=live), _out_dev)
         platform = first_dev.platform
         # a mesh over several processes keeps XLA's partitioned programs:
         # the routed ones assemble their operands from this process's
@@ -409,6 +451,9 @@ class MatrixServer(ServerTable):
         self._ids_from = {
             "caller": Dashboard.counter("ROW_IDS_FROM_CALLER"),
             "dispatcher": Dashboard.counter("ROW_IDS_FROM_DISPATCHER")}
+        # of the caller's, the ops that launched on the ids their proxy
+        # had kept from its last op (`MatrixWorker._ids_at_submit`)
+        self.ids_kept = Dashboard.counter("ROW_IDS_KEPT")
         # an in-process device-path caller sends its ids up itself, at
         # submit (`launch_ids`), where the launch would otherwise wait for
         # them to land: a table on one device. On a mesh it does not wait
@@ -600,6 +645,13 @@ class MatrixServer(ServerTable):
         from multiverso_tpu.ops.pallas_rows import ROW_GROUP
         return max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
 
+    def launch_form(self, n: int, op: str, ensure_pad: bool = False,
+                    rows: Optional[int] = None) -> Tuple[int, bool]:
+        """``(bucket, counted)`` of the array ``launch_ids`` makes for ``n``
+        ids under the same arguments: what decides it beside the ids."""
+        counted = op == "add" and rows is not None and rows > n
+        return self._get_bucket(rows if counted else n, ensure_pad), counted
+
     def launch_ids(self, row_ids: np.ndarray, op: str,
                    ensure_pad: bool = False, offsets=None,
                    rows: Optional[int] = None) -> LaunchIds:
@@ -614,10 +666,15 @@ class MatrixServer(ServerTable):
         this returns.
 
         The bucket is the next power of two, so jit traces are
-        shape-stable. An Add's ids go up padded to it with sentinel-aimed
-        slots; of a Get's, the slots it gathers, ``_live_slots`` of them:
-        the rest of the bucket is filled on the device, not fetched. A
-        Get's on a table sharded over chips go to the mesh's first chip
+        shape-stable. The ids go up padded to it with sentinel-aimed
+        slots, a Get's as an Add's: ONE form a bucket, so that the proxy
+        that keeps the last array it sent up can hand a Get the ids of the
+        Add before it and an Add the ids of its Get (a put costs by the
+        call, not the byte). The Get's program takes the slots it gathers,
+        ``_live_slots`` of them, as a static slice (``_row_gather``): the
+        rest of the bucket is filled on the device, not fetched. A
+        Get's on a table sharded over chips go to the mesh's first chip,
+        those ``_live_slots`` alone,
         with the host's count of them by shard (``_route``), padded with
         ids past the table, which no shard owns, and the sentinel last
         (the tail of the result is its row's value, wherever its shard put
@@ -631,12 +688,13 @@ class MatrixServer(ServerTable):
         ``rows``: the rows of a device Add's delta. Where they outnumber
         the ids (``_process_add_device``) the bucket holds the delta's row
         groups, and its last slot, which then names no row, holds the
-        count of ids (``pallas_rows.scatter_add_rows``, ``tail_count``)."""
+        count of ids (``pallas_rows.scatter_add_rows``, ``tail_count``;
+        ``LaunchIds.counted``). A Get that gathers less than the bucket
+        never reads that slot."""
         n = len(row_ids)
-        longer = rows is not None and rows > n
-        bucket = self._get_bucket(rows if longer else n, ensure_pad)
+        bucket, counted = self.launch_form(n, op, ensure_pad, rows)
         routed = op == "get" and self._shard_rows is not None
-        pads = (bucket if op == "add" else _live_slots(n, bucket)) - n
+        pads = (_live_slots(n, bucket) if routed else bucket) - n
         ids = np.empty(n + pads, np.int32)
         if offsets is None:
             ids[:n] = row_ids
@@ -644,10 +702,10 @@ class MatrixServer(ServerTable):
             np.add(row_ids, offsets, out=ids[:n])
         ids[n:] = self.padded_rows if routed else self.sentinel_row
         if pads:
-            ids[-1] = n if longer else self.sentinel_row
+            ids[-1] = n if counted else self.sentinel_row
         if not routed:
             return LaunchIds(async_upload(ids), bucket, None, 0, ids.nbytes,
-                             ids[:n])
+                             ids[:n], counted)
         counts, capacity = self._route(ids)
         return LaunchIds(self._shard_rows.on_first(ids), bucket, counts,
                          capacity, ids.nbytes, ids[:n])
@@ -693,11 +751,13 @@ class MatrixServer(ServerTable):
                 took = self.launch_ids(row_ids, "get", ensure_pad=device_out)
         with span("TABLE_ROW_LAUNCH") as launch:
             if took.counts is None:
-                # the slots gathered, not the bucket the result fills
-                self._note_launch(launch, "get", took.ids.shape[0], False,
-                                  took.ids, ids_from)
+                # the slots gathered, not the bucket the result fills (nor
+                # the bucket of ids that went up)
+                live = _live_slots(len(took.host), took.bucket)
+                self._note_launch(launch, "get", live, False, took.ids,
+                                  ids_from)
                 return (self._gather_out if device_out else self._gather)(
-                    self.data, took.ids, bucket=took.bucket)
+                    self.data, took.ids, bucket=took.bucket, live=live)
             by_shard = np.full(self._num_shards, took.capacity)
             self._note_launch(launch, "get", int(by_shard.sum()), False,
                               took.ids, ids_from,
@@ -1147,6 +1207,8 @@ class MatrixWorker(WorkerTable):
     # remote subclass overrides this (and the device methods) — callers
     # must branch on the flag, not on hasattr
     supports_device_io = True
+    # the last device-path op's ids as they went up (`_ids_at_submit`)
+    _kept: Optional[KeptIds] = None
 
     def __init__(self, num_row: int, num_col: int, dtype: Any = np.float32,
                  updater_type: str = "", init_value: Optional[np.ndarray] = None,
@@ -1273,9 +1335,14 @@ class MatrixWorker(WorkerTable):
         On a table on one device the ids are copied and their upload
         begins here, on the caller's thread, before the message is queued
         (``_ids_at_submit``): the caller may reuse or overwrite
-        ``row_ids`` as soon as this returns. On a mesh the request holds
-        ``row_ids`` itself and the dispatcher sends the ids up, as before:
-        leave the array alone until ``wait_device`` returns."""
+        ``row_ids`` as soon as this returns. The proxy keeps that copy and
+        the array on the device until its next device-path op, and a Get
+        that names the rows of the op before it (the Get after a push to
+        the same rows, the same pull again) sends nothing up and launches
+        on it; which it is, is decided by comparing ``row_ids`` with the
+        proxy's copy, never by the array's identity. On a mesh the request
+        holds ``row_ids`` itself and the dispatcher sends the ids up, as
+        before: leave the array alone until ``wait_device`` returns."""
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()
@@ -1314,7 +1381,14 @@ class MatrixWorker(WorkerTable):
         On a table on one device the ids are copied and their upload
         begins here, on the caller's thread, before the message is queued
         (``_ids_at_submit``): the caller may reuse or overwrite
-        ``row_ids`` as soon as this returns. On a mesh the request holds
+        ``row_ids`` as soon as this returns. An Add that names the rows of
+        this proxy's last device-path op, a trainer's push of the rows it
+        pulled, sends nothing up and launches on the ids that op left on
+        the device (compared element for element with the proxy's own
+        copy): hand over the ids as they were pulled, not padded to
+        another count. A delta longer than its ids takes a Get's array
+        only from an earlier such Add (its last slot holds the count). On
+        a mesh the request holds
         ``row_ids`` itself and the dispatcher sends the ids up, as before:
         leave the array alone until ``wait`` returns. A count of ids that
         differs from the value rows' fails the op at its ``wait``, as on
@@ -1341,19 +1415,52 @@ class MatrixWorker(WorkerTable):
         while the message waits for the dispatcher, which launches on ids
         already on their way (``MatrixServer.launch_ids``). Elsewhere the
         ids go as they came and the dispatcher sends them up. ``rows``: of
-        an Add, its delta's."""
-        if not self._server_table.ids_at_submit:
+        an Add, its delta's.
+
+        The proxy keeps what its last such op sent up (``KeptIds``: depth
+        one), and an op that names the same rows launches on it: a
+        trainer's push names the rows of its pull. Nothing is filled, put
+        or waited for; the op's WORKER_ROW_IDS record has ``bytes`` 0 and
+        ``ROW_IDS_KEPT`` counts it. The same rows: ``ids`` equal, element
+        for element, to the proxy's private copy of what the last op named
+        (never the caller's array: that may have been overwritten in
+        place), in an array of the same form (``launch_form``). Any other
+        op is a miss and replaces what is kept. Two threads on one proxy
+        read and write the one attribute: whichever array a thread takes
+        holds the ids it compared, and a miss is always right."""
+        server = self._server_table
+        if not server.ids_at_submit:
             return self._table_ids(ids)
         with span("WORKER_ROW_IDS") as up:
             up.n = len(ids)
-            took = self._server_table.launch_ids(
-                ids, op, ensure_pad=op == "get",
-                offsets=self._ids_offsets(ids), rows=rows)
-            up.bytes = took.nbytes
+            kept = self._kept
+            form = server.launch_form(len(ids), op, op == "get", rows)
+            if kept is not None and kept.serves(ids, op, form) \
+                    and self._names_kept(ids, kept):
+                took = kept.took
+                server.ids_kept.add()
+            else:
+                offsets = self._ids_offsets(ids)
+                took = server.launch_ids(ids, op, ensure_pad=op == "get",
+                                         offsets=offsets, rows=rows)
+                up.bytes = took.nbytes
+                # what went up is what was named where no base was added
+                self._kept = KeptIds(took, took.host if offsets is None
+                                     else self._named(ids))
             # the ids as they went up, not the caller's array
             sent = took.host.view(SentIds)
             sent.took = took
             return sent
+
+    def _named(self, ids: np.ndarray) -> np.ndarray:
+        """``ids`` as the caller named them, for ``KeptIds``: a copy
+        nobody else holds."""
+        return np.array(ids)
+
+    def _names_kept(self, ids: np.ndarray, kept: "KeptIds") -> bool:
+        """Whether ``ids`` name what the kept op named, element for
+        element (0.03 ms for 100,000 ids)."""
+        return np.array_equal(ids, kept.named)
 
     def _ids_offsets(self, ids: np.ndarray):
         """What turns this proxy's ``ids`` into its server table's, for

@@ -112,8 +112,12 @@ def test_group_and_member_ops_against_the_reference(ref, kernel,
     assert [r.n for r in records
             if r.stage == "TABLE_ROW_PREP"] == [at[-1]] * 2
     assert launches[0].path == ("pallas" if kernel == "pallas" else "xla")
+    # the Get names the Add's rows: it launches on the ids the Add sent up
+    # and checks no segment again
     checked = [r for r in records if r.stage == "WORKER_GROUP_IDS"]
-    assert [r.n for r in checked] == [at[-1]] * 2
+    assert [r.n for r in checked] == [at[-1]]
+    assert [r.bytes for r in records
+            if r.stage == "WORKER_ROW_IDS"] == [4 * out.shape[0], 0]
     by_id = {r.id: r for r in records if r.id}
     assert all(by_id[r.parent].stage == "WORKER_ROW_IDS" for r in checked)
     for stage in ("TABLE_PROCESS_ADD", "TABLE_PROCESS_GET"):
@@ -499,14 +503,16 @@ def test_the_groups_per_layer_readers(ref, monkeypatch):
     group, mirrors = _group(ref)
     plain = mv.create_table("matrix", 50, COLS, np.float32)
     rng = np.random.default_rng(6)
-    parts, at, dk = _step(ref, rng, mirrors)
-    delta = jax.device_put(np.concatenate(
-        [ref.to_float(dk), np.ones((128 - at[-1], COLS), np.float32)]))
+    steps = [_step(ref, rng, mirrors) for _ in range(3)]
     monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
     t0 = time.perf_counter()
-    for _ in range(3):
+    named = 0
+    for parts, at, dk in steps:
+        delta = jax.device_put(np.concatenate(
+            [ref.to_float(dk), np.ones((128 - at[-1], COLS), np.float32)]))
         group.wait(group.add_device_async(delta, parts))
         group.wait_device(group.get_device_async(parts))
+        named += -(-int(at[-1]) // 8) * 8
     t1 = time.perf_counter()
     plain.wait(plain.add_device_async(delta[:5], np.arange(5)))
     plain.get(np.arange(5))
@@ -517,10 +523,12 @@ def test_the_groups_per_layer_readers(ref, monkeypatch):
     read = {name: common.load_module("layers", name).read
             for name in ("group_ids_ms", "group_launches_per_op",
                          "group_slots_share")}
-    assert read["group_launches_per_op"](run) == 1.0
-    n = int(at[-1])
+    # the reader counts group ops by their WORKER_GROUP_IDS, and a step's
+    # Get launches on its Add's ids without one (PR 39): two launches a
+    # span where every op is one launch
+    assert read["group_launches_per_op"](run) == 2.0
     assert read["group_slots_share"](run) == pytest.approx(
-        100.0 * (-(-n // 8) * 8) / n)
+        100.0 * named / sum(int(at[-1]) for _, at, _ in steps))
     assert 0 < read["group_ids_ms"](run) < 50
     other = _fake_run([r for r in records if r.start_ns >= t1 * 1e9], t1, t2)
     assert [fn(other) for fn in read.values()] == [None, None, None]

@@ -959,7 +959,8 @@ def test_two_workers_submit_device_path_ops_at_once(shards, monkeypatch):
     """Two in-process workers submit device-path Adds and Gets to one table
     at the same time, each from its own thread, the interpreter switching
     threads as often as it can: on one device both send their ids up from
-    their own threads (`launch_ids` keeps nothing between calls), sharded
+    their own threads (the proxy keeps the last array sent up, one
+    attribute read and written whole: a miss is always right), sharded
     over four the dispatcher sends every op's (`ShardedRows.on_first`'s
     placeholder cache stays the dispatcher's alone). The table ends at the
     exact sum of what both added, and every Get held rows the table could

@@ -754,8 +754,10 @@ def test_an_add_and_a_get_each_leave_one_causal_chain(tracing, device):
     table.add(ONES, row_ids=IDS)       # compile outside the window
     table.get(IDS)
     delta = jax.device_put(ONES)
-    table.wait(table.add_device_async(delta, IDS))
-    table.wait_device(table.get_device_async(IDS), IDS).block_until_ready()
+    # other rows than the window's: its Add finds none of its ids kept
+    table.wait(table.add_device_async(delta, IDS + 100))
+    table.wait_device(table.get_device_async(IDS + 100),
+                      IDS).block_until_ready()
     t0 = time.perf_counter()
     samples = {}
     for kind in ("add", "get"):
@@ -793,7 +795,11 @@ def test_an_add_and_a_get_each_leave_one_causal_chain(tracing, device):
         assert launch.ids_from == ("caller" if device else "dispatcher")
         assert launch.ids_ready in (0, 1)
         for up in sent:
-            assert up.n == len(IDS) and up.bytes >= 4 * len(IDS)
+            # the Add sends its ids up; the Get names the same rows and
+            # launches on them where they lie (PR 39): nothing goes up
+            assert up.n == len(IDS)
+            assert up.bytes >= 4 * len(IDS) if kind == "add" \
+                else up.bytes == 0 and launch.ids_ready == 1
             assert submit.start_ns <= up.start_ns
             assert up.start_ns + up.dur_ns <= wait.start_ns
         waited = _one(trace, "WORKER_WAIT", op)

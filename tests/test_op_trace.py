@@ -525,8 +525,11 @@ def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
             assert not routes and not any(r.shards for r in launches)
             # on one device the caller sends a device-path op's ids up
             assert [r.ids_from for r in launches] == ["caller"] * 4
+            # one bucket-long form for both ops; the same rows again launch
+            # on the array the op before sent up: nothing more goes up
             assert [(r.n, r.bytes) for r in trace.spans("WORKER_ROW_IDS")
-                    ] == [(n, 4 * 2048), (n, 4 * _live_slots(n, 2048))] * 2
+                    ] == [(n, 4 * 2048)] + [(n, 0)] * 3
+            assert [r.n for r in launches] == [n, _live_slots(n, 2048)] * 2
             assert [r.n for r in trace.spans("TABLE_ROW_PREP")] == [n] * 4
             for name in ("shard_slots_share", "shard_exchange_bytes_share",
                          "shard_row_imbalance"):

@@ -105,10 +105,14 @@ def test_gather_rows_compiles(one_chip, lanes):
 @pytest.mark.parametrize("rows,lanes", [(10_000_001, 128), (3_000_008, 384)])
 def test_row_get_gathers_the_ids_named_not_the_bucket(one_chip, rows, lanes):
     """The table's row Get of 100,000 ids in their 131,072-slot bucket, at
-    the benchmark's two table shapes: the result is the bucket; exactly one
+    the benchmark's two table shapes, its ids the bucket-long array an Add
+    takes too and the slots it gathers a static slice of them (`live`,
+    PR 39): the result is the bucket; exactly one
     fusion gathers, over `%data` and an s32 id array (how
     `benchmark/row_bytes.py` finds it in a trace), of at least the ids
-    named and under one step more; the fill pass is not such a fusion; the
+    named and under one step more; the slice adds no pass over the ids
+    (XLA folds it into the one that was there) and no copy; the fill pass
+    is not such a fusion; the
     only temporary is the gathered rows (no second copy of the result);
     and the compiler tiles that gather's rows by 256, the form the chip ran
     2.4 times as fast as the 128 it picks for a whole number of id tiles
@@ -126,8 +130,8 @@ def test_row_get_gathers_the_ids_named_not_the_bucket(one_chip, rows, lanes):
     # trace is read by
     compiled = _row_gather_jit.lower(
         jax.ShapeDtypeStruct((rows, lanes), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((live,), jnp.int32, sharding=one_chip),
-        bucket=bucket, sentinel=rows - 8).compile()
+        jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip),
+        bucket=bucket, sentinel=rows - 8, live=live).compile()
     # the text as a trace names its events: operands with their shapes
     from jax._src.lib import xla_client
     options = xla_client._xla.HloPrintOptions()
@@ -143,6 +147,12 @@ def test_row_get_gathers_the_ids_named_not_the_bucket(one_chip, rows, lanes):
     assert int(GATHER_EVENT.search(gathers[0]).group(1)) == live
     assert "kind=kCustom" in gathers[0]
     assert re.search(r'"integer_config":\{"integer":"256"\}', gathers[0])
+    # the whole bucket of ids is read by one fusion alone, the pass that
+    # wraps negative ids, which now also cuts them to the slots gathered
+    over_ids = [line for line in entry.splitlines()
+                if f"s32[{bucket}]" in line and " fusion(" in line]
+    assert len(over_ids) == 1 and f"= s32[{live}]" in over_ids[0], over_ids
+    assert " slice(" not in entry and " copy(" not in entry
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= live * lanes * 4 + (1 << 20))
 
@@ -339,7 +349,7 @@ def test_group_slab_programs_compile_at_19_million_rows(one_chip, program):
     held at the Get's bucket with the count of ids in the last id slot (the
     cell's: the same operands, so the same event to a trace's readers, and
     nothing in front of the kernel); the Get one gather fusion over `%data` of the
-    slots `_live_slots` gives, its result the bucket; a piece of the slab
+    slots `_live_slots` gives out of the bucket of ids, its result the bucket; a piece of the slab
     on its way up is written in place (`mesh._set_rows`). None makes a
     temporary of the slab's size."""
     from benchmark.row_bytes import GATHER_EVENT
@@ -375,9 +385,9 @@ def test_group_slab_programs_compile_at_19_million_rows(one_chip, program):
     elif program == "get":
         live = _live_slots(STEP_ROWS, bucket)
         compiled = _row_gather_jit.lower(
-            slab, jax.ShapeDtypeStruct((live,), jnp.int32,
+            slab, jax.ShapeDtypeStruct((bucket,), jnp.int32,
                                        sharding=one_chip),
-            bucket=bucket, sentinel=SLAB_ROWS - 1).compile()
+            bucket=bucket, sentinel=SLAB_ROWS - 1, live=live).compile()
         text = _hlo_text(compiled)
         assert f"->f32[{bucket},128]" in text.splitlines()[0]
         gathers = [line for line in text[text.index("ENTRY"):].splitlines()
