@@ -856,6 +856,7 @@ from multiverso_tpu.tables.kv_table import (  # noqa: E402
     DeviceKVServer, KVServer, KVWorker, TieredKVServer, make_tiered_kv)
 from multiverso_tpu.tables.matrix_table import MatrixServer, MatrixWorker  # noqa: E402
 from multiverso_tpu.tables.group_table import MatrixGroupWorker  # noqa: E402
+from multiverso_tpu.tables.ftrl_table import FTRLWorker  # noqa: E402
 from multiverso_tpu.tables.sparse_table import (  # noqa: E402
     SparseWorker, TieredSparseServer, make_tiered_sparse)
 from multiverso_tpu.updaters import AddOption, GetOption  # noqa: E402,F401
@@ -869,6 +870,7 @@ _TABLE_TYPES = {
     "matrix_group": MatrixGroupWorker,
     "kv": KVWorker,
     "sparse": SparseWorker,
+    "ftrl": FTRLWorker,
     # beyond-RAM variants (multiverso_tpu/store/, docs/tiered_storage.md)
     "tiered_sparse": make_tiered_sparse,
     "tiered_kv": make_tiered_kv,

@@ -242,6 +242,7 @@ class PSLogReg(LogReg):
         self._gl = jax.jit(gl)
         self._reg = jax.jit(reg)
         self._predict = jax.jit(self._predict_fn(gl))
+        self._keyed = self._keyed_ftrl()
         # table selection (reference: CreateTable in ps_model.cpp — array /
         # sparse / ftrl-sparse keyed on config). Sparse-key tables carry one
         # OUTPUT COLUMN per feature key (width = output_size), so a touched
@@ -252,6 +253,17 @@ class PSLogReg(LogReg):
             mv.register_table_type("sparse", SparseWorker)
             mv.register_table_type("sparse_ftrl", make_sparse_ftrl)
             keys = config.input_size + 1  # + bias key
+            if self._keyed:
+                # one weight a key, optimizer state alone on the device: the
+                # trainer pulls its batch's keys and pushes their gradients,
+                # and holds no replica of the key space (`_update_keyed`)
+                self.table = mv.create_table(
+                    "ftrl", keys, alpha=config.alpha, beta=config.beta,
+                    lambda1=config.lambda1, lambda2=config.lambda2)
+                self.w = None
+                self._pending_adds = []
+                self._pending_get = None
+                return
             if config.objective == "ftrl":
                 self.table = mv.create_table(
                     "sparse_ftrl", keys, width=config.output_size,
@@ -262,8 +274,6 @@ class PSLogReg(LogReg):
                     "sparse", keys, width=config.output_size,
                     updater_type="sgd")
         elif config.objective == "ftrl":
-            from multiverso_tpu.tables.ftrl_table import FTRLWorker
-            mv.register_table_type("ftrl", FTRLWorker)
             self.table = mv.create_table(
                 "ftrl", self._n, alpha=config.alpha, beta=config.beta,
                 lambda1=config.lambda1, lambda2=config.lambda2)
@@ -274,6 +284,56 @@ class PSLogReg(LogReg):
         self._batches_since_sync = 0
         self._pending_get: Optional[int] = None
         self._pending_adds: list = []
+
+    def _keyed_ftrl(self) -> bool:
+        """Sparse FTRL with one output trains through the keyed FTRL table
+        (``tables/ftrl_table.py``); wider outputs keep the host
+        dictionaries of ``sparse_ftrl``, a row of ``output_size`` a key."""
+        config = self.config
+        return (config.sparse and config.objective == "ftrl"
+                and config.output_size == 1)
+
+    def _compact(self, batch: Dict[str, np.ndarray]):
+        """A sparse batch in the space of the keys it names: ``(keys, the
+        batch with ``idx`` pointing into them, w)``, ``w`` the ``(1, bucket +
+        1)`` weights pulled for them, the bias last, zeros between (a power
+        of two, so the jitted step sees few shapes)."""
+        idx = np.asarray(batch["idx"])
+        touched = np.unique(idx[idx >= 0]).astype(np.int32)
+        keys = np.concatenate([touched, [self._bias_key]]).astype(np.int32)
+        bucket = 1 << max(3, int(len(touched)).bit_length())
+        pulled = self.table.get(keys)
+        w = np.zeros((1, bucket + 1), np.float32)
+        w[0, :len(touched)] = pulled[:-1]
+        w[0, -1] = pulled[-1]
+        at = np.searchsorted(touched, np.maximum(idx, 0))
+        compact = dict(batch, idx=np.where(idx >= 0, at, -1).astype(idx.dtype))
+        return keys, {k: jnp.asarray(v) for k, v in compact.items()}, \
+            jnp.asarray(w)
+
+    def _update_keyed(self, batch: Dict[str, np.ndarray]) -> float:
+        """Upstream's order (``ps_model.cpp``): pull the batch's keys,
+        compute, push their raw gradients; the server runs FTRL."""
+        keys, compact, w = self._compact(batch)
+        grad, loss = self._gl(w, compact)
+        push = np.asarray(grad + self._reg(w))[0]
+        self._updates += 1
+        self.table.add(keys, np.concatenate(
+            [push[:len(keys) - 1], push[-1:]]))
+        return float(loss)
+
+    def predict(self, batch: Dict[str, np.ndarray]) -> np.ndarray:
+        if not self._keyed:
+            return super().predict(batch)
+        _, compact, w = self._compact(batch)
+        return np.asarray(self._predict(w, compact))
+
+    def weights(self) -> np.ndarray:
+        """The dense ``(O, I+1)`` weights; in keyed FTRL mode pulled whole
+        from the table (every key: for models small enough to print)."""
+        if not self._keyed:
+            return super().weights()
+        return np.asarray(self.table.get()).reshape(1, -1)
 
     def _to_w(self, raw) -> np.ndarray:
         """Reconstruct the dense (O, I+1) replica from a table reply."""
@@ -291,6 +351,8 @@ class PSLogReg(LogReg):
 
     def update(self, batch: Dict[str, np.ndarray],
                lr: Optional[float] = None) -> float:
+        if self._keyed:
+            return self._update_keyed(batch)
         lr = _effective_lr(self.config, self._updates, lr)
         self._updates += 1
         idx_np = np.asarray(batch["idx"]) if self.config.sparse else None
@@ -347,7 +409,8 @@ class PSLogReg(LogReg):
         if self._pending_get is not None:
             self.table.wait(self._pending_get)
             self._pending_get = None
-        self.w = jnp.asarray(self._pull())
+        if not self._keyed:
+            self.w = jnp.asarray(self._pull())
 
     def load_weights(self, w: np.ndarray) -> None:
         """Warm start THROUGH the table so every worker sees it (reference

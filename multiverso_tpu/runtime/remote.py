@@ -1663,6 +1663,10 @@ class RemoteClient:
             raise KeyError(
                 f"table {table_id} is a matrix_group: the group op is not "
                 f"served to remote workers (ROADMAP Queue 2 item 10)")
+        if kind == "ftrl":
+            raise KeyError(
+                f"table {table_id} is an ftrl table: its keyed ops are not "
+                f"served to remote workers (ROADMAP Queue 2 item 10)")
         raise KeyError(f"unknown remote table kind {kind!r}")
 
     def tables(self) -> List[WorkerTable]:

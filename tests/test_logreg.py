@@ -128,3 +128,50 @@ def test_libsvm_parsing(tmp_path):
     np.testing.assert_allclose(data["val"][0], [0.5, 1.5, 0, 0])
     label, idx, val = parse_libsvm_line("1 2:3", 2)
     assert label == 1 and idx[0] == 2 and val[0] == 3.0
+
+
+def test_sparse_ftrl_trains_through_the_keyed_table(mv_env, monkeypatch):
+    """Sparse FTRL with one output pulls its batch's keys from the keyed
+    FTRL table and pushes their gradients (no dense replica of the key
+    space: `model.w` is None). Over a seeded stream its losses equal those
+    of the path it replaces (the host dictionaries of `sparse_ftrl` behind a
+    dense replica pulled after every batch) within 2e-5, the two tables'
+    float32 roundings; the weights agree, and it predicts as well."""
+    from multiverso_tpu.models.logreg import PSLogReg
+
+    rng = np.random.default_rng(12)
+    features, nnz, samples = 400, 6, 1536
+    true_w = np.zeros(features, np.float32)
+    true_w[:24] = rng.normal(0, 3.0, 24)
+    idx = np.stack([rng.choice(features, nnz, replace=False)
+                    for _ in range(samples)]).astype(np.int32)
+    idx[rng.random(idx.shape) < 0.2] = -1          # ragged samples
+    val = np.ones(idx.shape, np.float32)
+    logits = (np.where(idx >= 0, true_w[np.maximum(idx, 0)], 0)).sum(axis=1)
+    y = (rng.random(samples) < 1 / (1 + np.exp(-logits))).astype(np.int32)
+    data = {"idx": idx, "val": val, "y": y}
+    config = LogRegConfig(input_size=features, objective="ftrl", use_ps=True,
+                          sparse=True, max_nnz=nnz, minibatch=64, alpha=0.5,
+                          lambda1=0.01, lambda2=0.1, updater_type="ftrl")
+    keyed = make_model(config)
+    assert keyed.w is None and keyed.table.size == features + 1
+    monkeypatch.setattr(PSLogReg, "_keyed_ftrl", lambda self: False)
+    replica = make_model(config)
+    monkeypatch.undo()
+    assert replica.w is not None
+    losses = {"keyed": [], "replica": []}
+    for _ in range(2):
+        for batch in minibatches(data, config.minibatch):
+            losses["keyed"].append(keyed.update(batch))
+            losses["replica"].append(replica.update(batch))
+    keyed.finish()
+    replica.finish()
+    want_w = replica.weights()
+    want_acc = replica.test(data)
+    assert len(losses["keyed"]) == 48
+    np.testing.assert_allclose(losses["keyed"], losses["replica"], atol=2e-5,
+                               rtol=0)
+    assert np.mean(losses["keyed"][-8:]) < losses["keyed"][0] - 0.005
+    np.testing.assert_allclose(keyed.weights(), want_w, atol=1e-5)
+    assert keyed.test(data) == pytest.approx(want_acc, abs=0.005) \
+        and want_acc > 0.55

@@ -164,5 +164,9 @@ def test_ps_sparse_ftrl_learns(mv_env):
             model.update(mb)
     model.finish()
     assert model.test(data) > 0.9
-    # server state ∝ live keys, not the 5000-key space
-    assert len(model.table._server_table._z) == 11
+    # since PR 40 one output trains through the keyed FTRL table, whose
+    # (z, n) hold the key space on the device: the keys stepped are the
+    # live ones (10 features and the bias), the rest keep their zeros
+    assert model.w is None
+    assert np.count_nonzero(np.asarray(
+        model.table.get_state_device("n"))) == 11
