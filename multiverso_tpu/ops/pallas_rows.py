@@ -48,6 +48,31 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
   count must then be a multiple of 8 (the caller pads; the tiles are
   there in HBM either way).
 
+* ``add_at_lanes`` (PR 42; PR 41 built it and was refused for what it cost
+  a process's set-up) is the scatter-add of SINGLE float32 values into
+  lane-dense 1-D states (the keyed FTRL table's ``z`` and ``n``), by the
+  same row descriptors: a state is read as rows of 128, a key lives in row
+  ``key >> 7``, lane ``key & 127``. Its contract is not
+  ``scatter_add_rows``': the keys are SORTED ascending (they are the scalar
+  prefetch as rows); keys that share a row are expected (a row's slots are
+  adjacent, each takes what all of them bring before it writes, a run that
+  straddles two grid steps is written in both, each adding what its own
+  slots bring, the first's write-backs awaited); a key several slots name
+  is stepped once, by its first slot, wherever a grid step's boundary
+  falls among them; pad slots may aim at a row that live keys share (the
+  FTRL table's scratch key does) and are part of its run; a lane no
+  stepping slot names is written back as read, by a select (adding
+  ``-0.0`` would do for every float32 but a denormal, which the vector
+  unit flushes: PR 41's first build's test found it); the slots, filled to
+  whole groups here, are at most ``PREFETCH_SLOTS``; a grid step walks
+  ``LANE_GROUP`` slots, a group of the kernel's own (the standing kernels
+  keep ``ROW_GROUP``). Its docstring has the rest.
+* nothing that ``import multiverso_tpu`` reaches imports this module at its
+  top: it brings ``jax.experimental.pallas``, a second of module code. A
+  table imports it inside the functions that need it; the keyed FTRL table
+  loads it on a thread under the fill of its state
+  (``tables/ftrl_table.py``; ``tests/test_ftrl_keyed.py`` holds both).
+
 Interpret mode is the caller's explicit choice, made once from the
 platform of the devices that hold the table (:func:`interpret_for`): ``cpu``
 interprets (the test mesh), ``tpu`` compiles, anything else is an error.
@@ -165,6 +190,69 @@ current machine):
   512 slots and half the width the VMEM budget admits. Left: reading group
   g+1's rows while group g is added (two blocks by parity) could take at
   most the 0.09 ms between group 256 and the asymptote.
+* single floats by row descriptors, `add_at_lanes` (2026-10-01, one v5e
+  chip, `TPU v5 lite`; PR 41 built and priced it at a group of 256 and was
+  refused for `setup_s`; PR 42 the same day gave it a group of its own. The
+  keyed FTRL Add of the benchmark's `ftrlctr.step-keys`: 111,244 Zipf keys
+  of a 16,384-sample step in 67,555 rows of 128, sorted, 114,696 live slots
+  of a 131,072 bucket, into `z` and `n` of 882,775,040 float32 each (3.53
+  GB); ms a launch, 30 launches back to back after a warm one, wall clock
+  over the count; PR 42's chip runs where a line says nothing, PR 41's
+  where it says so; the kernel's path against XLA's scatters bit for bit
+  over six Adds at the full key space, a key's slots either side of a grid
+  step's boundary among them, in the same call: 0 entries differ).
+    the table's program, XLA's two scatters (ledger, PR 40)      23.672
+    the table's program, `add_at_lanes` at a group of 128 (kept)   5.650
+      of it in front of the kernel (sort, gathers, the step)       1.386
+      `add_at_lanes` alone (the rows it takes made + the kernel)   4.285
+    the group, `add_at_lanes` alone, three rounds 64 128 256 / 256 128 64 /
+    64 128 256, and the table's program's seconds to `.lower()` on the
+    chip's host, twice (XLA's own program 0.037-0.089):
+      64    4.728 / 4.725 / 4.726 ms    0.184 / 0.208 s
+      128   4.287 / 4.282 / 4.285 ms    0.277 / 0.260 s   (kept)
+      256   4.311 / 4.312 / 4.305 ms    0.460 / 0.437 s
+    at 256, PR 41: without `_run_and` (wrong on shared rows: a price) 3.955
+    at 256, PR 41: `_scatter_add` twice on delta rows (wrong there)   4.304
+    at 256, PR 41: its descriptors issued from a loop of 256 / k passes
+      of k slots (k = 1 / 2 / 4 / 8 / 16 / 32 / 64; unrolled 4.336):
+                 8.053 / 7.815 / 7.221 / 6.928 / 6.779 / 6.704 / 6.666
+    at 256, PR 41: unrolled as it is lowered, `fori_loop(unroll=True)` 4.337
+  What it says. (a) The price follows the rows named, not the operand:
+  459,264 descriptors (two states, a read and a write-back a slot) in 3.96
+  ms (the custom call in the cell's traced run) are 8.6 ns each, PR 34's asymptote (8.2) at 3.53 GB as at 5.1 GB. (b)
+  **The group is set by what it costs to lower, not by the device**: 128
+  reads 0.02-0.03 ms UNDER 256 in every round (four descriptors a slot
+  leave a grid step's 0.45 us a twentieth of its issue time; the merge is
+  thirteen shifts of three `(128, 128)` blocks a group where PR 41's was
+  sixteen of `(256, 128)`), 64 reads 0.44 ms over (897 more steps, and
+  the merge no longer hides under the issue), and the program lowers in
+  0.26-0.28 s at 128 against 0.44-0.46 at 256, paid in every process's
+  warm-up before any cache is asked: the unrolled slots are the cost, XLA's
+  own program lowers in 0.04-0.09. The standing kernels keep `ROW_GROUP`
+  256 (two descriptors a slot: PR 34's sweep). (c) Merging the slots of a
+  row costs 0.38 ms (PR 41, at 256), partly under the descriptors' issue;
+  40% of the slots share a row with another (67,555 rows for 111,244
+  keys), and a kernel that issued one descriptor a ROW would save 1.5 ms
+  of the 3.96 but needs the rows compacted first (a branch a slot costs
+  more than the descriptor: the coalescing record above). (d) Two calls of
+  the standing kernel on delta rows cost the same 4.3 ms and cannot serve
+  a row two slots name; one kernel for both states reads the runs once and
+  keeps ONE custom call in the program. (e) The 0.28 ms XLA spends writing
+  the three blocks a slot (177 MB) would go with a kernel that derived the
+  `run` block from the rows it already has in SMEM and spread a slot's
+  value over its lanes itself: it changes the kernel's operands, a PR of
+  its own. (f) The descriptors must be issued from straight-line code:
+  from a loop, even of four passes of 64, a launch takes 2.3 ms more (the
+  vector work and the next descriptors' addresses no longer overlap the
+  issue). Unrolled in Python the kernel is traced once a slot and a
+  program took 1.04-1.28 s to lower (PR 41); `fori_loop(unroll=True)`
+  traces one slot and unrolls as it lowers: the same bits, the same ms.
+  (g) What the module itself costs: importing it runs
+  `jax.experimental.pallas`, 0.9-1.3 s of module code; PR 41 imported it
+  at the top of a table file the package imports and every process paid
+  (the benchmark's eight remote workers: `emb128.remote-workers`
+  `setup_s` +1.15 s by ISSUE 42's reading of PR 41's lines; parity
+  again in PR 42's pairs, 27.82 -> 27.95).
 """
 
 from __future__ import annotations
@@ -408,16 +496,18 @@ def _scatter_add_kernel(*refs, rows, sign, counted):
     pl.when(jnp.logical_and(live > 0, live < ROW_GROUP))(lambda: walk(live))
 
 
-def launched_slots(rows: int) -> int:
+def launched_slots(rows: int, group: int = 0) -> int:
     """Id slots a scatter-add of ``rows`` delta rows reads, adds and writes:
-    whole row groups."""
-    return pl.cdiv(rows, ROW_GROUP) * ROW_GROUP
+    whole groups of ``group`` slots, ``ROW_GROUP`` unless the kernel walks
+    groups of its own (``add_at_lanes``)."""
+    group = group or ROW_GROUP
+    return pl.cdiv(rows, group) * group
 
 
-def launch_waits(rows: int) -> int:
+def launch_waits(rows: int, group: int = 0) -> int:
     """Semaphore waits that launch issues: one for a group's reads and one
     for its write-backs (its descriptors are two a slot)."""
-    return 2 * pl.cdiv(rows, ROW_GROUP)
+    return 2 * pl.cdiv(rows, group or ROW_GROUP)
 
 
 def _scatter_add(table, ids, deltas, interpret, sign, count=None,
@@ -498,3 +588,165 @@ def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
     if not deltas.shape[0]:
         return table
     return _scatter_add_call(table, ids, deltas, interpret, sign, tail_count)
+
+
+# ids a kernel's scalar prefetch may hold: 512 KB of the chip's 1 MB of SMEM
+# (an op's bucket is a power of two; the next one would take all of it)
+PREFETCH_SLOTS = 131_072
+# the bits of a delta that steps nothing (`add_at_lanes`): all ones, the
+# identity of AND; as a float32 a NaN no arithmetic produces
+NO_DELTA = -1
+# slots a grid step of `add_at_lanes` walks: its own group, because its
+# descriptors are four a slot (a grid step's fixed cost is a twentieth of
+# their issue time at 128 as at 256) and a program lowers in the time its
+# unrolled slots take (the optimization record, PR 42, has both sweeps)
+LANE_GROUP = 128
+
+
+def _run_and(run, blocks):
+    """``blocks`` (int32, each ``(LANE_GROUP, 128)``, a slot a sublane) with
+    every slot's row replaced by the bitwise AND of the rows of its RUN:
+    the adjacent slots that hold its value of ``run`` (same shape, a slot's
+    value on all its lanes; the values ascend). By doubling, backwards and
+    forwards: a slot ``d`` away is in the run exactly where ``run`` says so
+    there, because the values ascend, and then so is every slot between.
+    AND takes a slot twice as once, so the windows may overlap, and a roll
+    that wraps round the group brings a slot of the run or none."""
+    ones = jnp.int32(NO_DELTA)
+    for p in range(LANE_GROUP.bit_length() - 1):
+        # backwards and forwards; the last distance is half the group
+        # either way
+        for shift in sorted({1 << p, LANE_GROUP - (1 << p)}):
+            joins = pltpu.roll(run, shift, 0) == run
+            blocks = [b & jnp.where(joins, pltpu.roll(b, shift, 0), ones)
+                      for b in blocks]
+    return blocks
+
+
+def _lane_add_kernel(rows_ref, run_ref, *refs, states, interpret):
+    deltas, refs = refs[:states], refs[states:]
+    # the inputs are aliased with the outputs; all access goes through out
+    tables, blocks, sems = (refs[states:2 * states],
+                            refs[2 * states:3 * states], refs[3 * states])
+    base = pl.program_id(0) * LANE_GROUP
+    read_sem, write_sem = sems.at[0], sems.at[1]
+
+    def each(slot):
+        # compiled, the loop is unrolled as it is lowered (from a rolled
+        # one, 64 slots a pass, the launch takes 6.67 ms for 4.34: the
+        # optimization record, PR 41); interpreted it stays rolled (XLA's
+        # CPU compiler takes half a minute a shape over 1,024 copies)
+        jax.lax.fori_loop(0, LANE_GROUP, lambda k, _: slot(k), None,
+                          unroll=not interpret)
+
+    def read(k):
+        rid = rows_ref[base + k]
+        for table, block in zip(tables, blocks):
+            pltpu.make_async_copy(table.at[rid], block.at[k],
+                                  read_sem).start()
+
+    def write(k):
+        rid = rows_ref[base + k]
+        for table, block in zip(tables, blocks):
+            pltpu.make_async_copy(block.at[k], table.at[rid],
+                                  write_sem).start()
+
+    each(read)
+    # slots of one row each read it; each then adds what ALL of them bring,
+    # so that whichever write-back lands last writes the row they all wrote
+    brought = _run_and(run_ref[:, :], [d[:, :] for d in deltas])
+    for block in blocks:  # a semaphore counts bytes: a block's a wait
+        pltpu.make_async_copy(block, block, read_sem).wait()
+    for block, bits in zip(blocks, brought):
+        # a lane nobody names is written back as read: a select, because
+        # the vector unit flushes a denormal that it adds anything to
+        was = block[:, :]
+        block[:, :] = jnp.where(
+            bits == NO_DELTA, was,
+            was + jax.lax.bitcast_convert_type(bits, was.dtype))
+    each(write)
+    # the next grid step may read these rows: a run may straddle two steps
+    for block in blocks:
+        pltpu.make_async_copy(block, block, write_sem).wait()
+
+
+def add_at_lanes(states, keys, deltas, steps, *, interpret: bool):
+    """``state[key] += delta`` at the slots ``steps`` marks, for every
+    ``(state, delta)`` of ``states`` and ``deltas``, in place, by row
+    descriptors: traceable, for a caller's jitted program that donates the
+    states. A state is a lane-dense float32 ``(n x 128,)`` array, read here
+    as rows of 128 (a bitcast); ``keys`` are int32 in ``[0, n x 128)``,
+    ASCENDING, at least one and at most ``PREFETCH_SLOTS`` with the slots
+    that fill their last group (those repeat the last key and step
+    nothing); a delta is a float32 a slot, ``steps`` a bool a slot. A grid
+    step reads the rows its ``LANE_GROUP`` keys live in (one descriptor a
+    key and a state), adds, and writes them back.
+
+    * **Keys that share a row** are served: a row's slots are adjacent,
+      each takes what ALL of them bring (``_run_and``), adds it to the copy
+      of the row it read, and all write the same bytes back. A run that
+      straddles two grid steps is written in both, each adding what ITS
+      slots bring: the first one's write-backs are awaited before the
+      second one reads.
+    * **A slot that steps nothing** (a pad, wherever it aims: the scratch
+      key may share its row with live keys) is part of its row's run and
+      writes back what the run's other slots bring, or the row as read.
+    * **A key named by several slots** (they are adjacent) is stepped once,
+      by what its FIRST slot brings; the others' deltas are not read. The
+      slots may lie either side of a grid step's boundary: those of the
+      second step bring nothing and write back what the first step wrote.
+    * **A lane no stepping slot names** is written back as read, by a
+      select: its bits stand, a denormal's too.
+    A stepped lane takes one float32 addition of the two numbers
+    ``state.at[key].add(delta)`` would add. A delta that is NaN goes in as
+    the canonical NaN (``NO_DELTA`` is a NaN's bit pattern)."""
+    slots = launched_slots(keys.shape[0], LANE_GROUP)
+    if not 0 < slots <= PREFETCH_SLOTS:
+        raise ValueError(
+            f"add_at_lanes: {keys.shape[0]} keys; 1 to {PREFETCH_SLOTS} "
+            f"in whole groups of {LANE_GROUP} are served")
+    # a key several slots name steps at its first: the others bring nothing
+    # (inside a grid step they take the first's from the run; in the next
+    # one, where a run straddles two, they must not add it again)
+    steps = steps & jnp.concatenate(
+        [jnp.ones(1, bool), keys[1:] != keys[:-1]])
+    tail = slots - keys.shape[0]
+    if tail:  # whole groups: more slots of the last key's run
+        keys = jnp.concatenate([keys, jnp.broadcast_to(keys[-1:], (tail,))])
+        steps = jnp.concatenate([steps, jnp.zeros(tail, bool)])
+        deltas = [jnp.concatenate([d, jnp.zeros(tail, d.dtype)])
+                  for d in deltas]
+    count = len(states)
+    rows = keys >> (LANES.bit_length() - 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (slots, LANES), 1)
+    named = (lane == (keys & (LANES - 1))[:, None]) & steps[:, None]
+
+    def brought(delta):
+        bits = jax.lax.bitcast_convert_type(
+            jnp.where(delta != delta, jnp.nan, delta), jnp.int32)
+        return jnp.where(named, bits[:, None], NO_DELTA)
+
+    views = [s.reshape(-1, LANES) for s in states]
+    block = pl.BlockSpec((LANE_GROUP, LANES), lambda g, rows: (g, 0),
+                         memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(slots // LANE_GROUP,),
+        in_specs=[block] * (1 + count)
+        + [pl.BlockSpec(memory_space=pl.ANY)] * count,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * count,
+        scratch_shapes=[pltpu.VMEM((LANE_GROUP, LANES), v.dtype)
+                        for v in views]
+        + [pltpu.SemaphoreType.DMA((2,))],  # the reads', the write-backs'
+    )
+    out = pl.pallas_call(
+        functools.partial(_lane_add_kernel, states=count,
+                          interpret=interpret),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype) for v in views],
+        grid_spec=grid_spec,
+        # operand order: the rows, the runs, the deltas, the states
+        input_output_aliases={2 + count + i: i for i in range(count)},
+        interpret=interpret,
+    )(rows, jnp.broadcast_to(rows[:, None], (slots, LANES)),
+      *[brought(d) for d in deltas], *views)
+    return tuple(o.reshape(-1) for o in out)

@@ -14,8 +14,38 @@ so the server never stores a stale ``w``. Both ops are **keyed**, one device
 program an op (``jit__ftrl_keyed_get`` / ``jit__ftrl_keyed_add`` in a trace):
 
 * a Get gathers ``z`` and ``n`` at the keys named and applies the closed form;
-* an Add gathers them, steps them from the raw gradient and scatters them
+* an Add gathers them, steps them from the raw gradient and writes them
   back, ``z`` and ``n`` donated.
+
+**Which program writes an Add back** is chosen once, at the table's
+creation, from the mesh and the platform, and by the op's bucket at each
+launch (``FTRLServer._rows_for``, which both the program launched and the
+launch's record take their answer from); the creation log line and every
+launch record's ``path`` say which. On ONE device whose platform the Pallas
+row kernels serve (compiled on ``tpu``, interpreted on ``cpu``) the rows of
+128 the keys live in are read, stepped and written back by row descriptors
+(``ops/pallas_rows.add_at_lanes``, still inside ``jit__ftrl_keyed_add``):
+the sorted keys are the kernel's scalar prefetch, a key's lane takes the
+one float32 addition ``z_old + (g - sigma * w)`` or ``n_old + g * g`` whose
+second number XLA worked out (once: a repeated key steps at the first of
+its slots, wherever a grid step's boundary falls among them), every other
+lane its bits (``path`` ``pallas``). XLA's two scatters of single floats,
+which a 3.53 GB operand prices at 11 ms each (PERF.md, Findings, PR 40 to
+PR 42), still serve a mesh of several devices (``pallas_call`` has no
+partitioning rule) and a bucket of more than ``pallas_rows.PREFETCH_SLOTS``
+keys, a step of 131,072 keys or more (``path`` ``xla``, as every Get's).
+Both write the same bits (``tests/test_ftrl_keyed.py``).
+
+**The kernel's module is loaded by the table that will launch it** and by
+nobody else: ``ops/pallas_rows`` brings ``jax.experimental.pallas``, a
+second of module code that every process importing this package would pay
+(PR 41 did; PERF.md, Findings), so nothing here imports it at the top.
+``FTRLServer.__init__`` starts the import on a thread of its own before it
+fills the state and joins it before it returns: the fill waits on the
+device a piece at a time, holding no interpreter lock, and the import runs
+under it. What this file reads of the module it reads inside functions,
+after that join. A table on a mesh of several devices, or on a platform
+the kernels do not serve, imports nothing.
 
 The keys go up padded to the op's power-of-two bucket with slots aimed at
 the scratch key ``size``, ONE form for a Get and an Add, so that a trainer's
@@ -50,6 +80,7 @@ commute: the order of acknowledgement is part of the result.
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -71,6 +102,19 @@ from multiverso_tpu.utils import async_upload, next_pow2
 _MIN_BUCKET = 128
 # keys a piece of a block source's state goes up in (two float32 arrays)
 _PIECE_KEYS = mesh_lib.PIECE_BYTES // 8
+# the platforms whose ONE device the Pallas row kernel writes an Add back on
+# (`pallas_rows.interpret_for` says how), known here so that a table anywhere
+# else never loads the kernel's module
+_ROW_KERNEL_PLATFORMS = ("tpu", "cpu")
+
+
+def _row_kernel():
+    """``ops/pallas_rows``, loaded on first call: a second of module code
+    (``jax.experimental.pallas``) that only a table whose Adds the kernel
+    writes back pays, once, under the fill of its state
+    (``FTRLServer.__init__``)."""
+    from multiverso_tpu.ops import pallas_rows
+    return pallas_rows
 
 
 def ftrl_weights(z: jax.Array, n: jax.Array, alpha: float, beta: float,
@@ -103,7 +147,9 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
                    lambda2: float, scratch: int):
     """The table's two device programs. ``ids`` is an op's bucket of keys
     (``DeviceIdsServer.launch_ids``), ``live`` the slots of it the program
-    works on (static)."""
+    works on (static). An Add's ``rows`` (static): None where XLA's
+    scatters write it back, else the row kernel does
+    (``pallas_rows.add_at_lanes``), interpreted (True) or compiled."""
 
     def _ftrl_keyed_get(z, n, ids, live):
         at = ids[:live]
@@ -118,7 +164,7 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
                             lambda2)
         return jnp.concatenate([w, jnp.broadcast_to(rest, (tail,))])
 
-    def _ftrl_keyed_add(z, n, ids, grad, live):
+    def _ftrl_keyed_add(z, n, ids, grad, live, rows=None):
         at = ids[:live]
         have = grad.shape[0]
         g = grad[:live] if have >= live else jnp.concatenate(
@@ -128,18 +174,25 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
         at, g = _summed_by_key(at, jnp.where(at == scratch, 0.0, g), scratch)
         z_old, n_old = state_of_slots(z, at), state_of_slots(n, at)
         w = ftrl_weights(z_old, n_old, alpha, beta, lambda1, lambda2)
-        grown = n_old + g * g
+        squared = g * g
+        grown = n_old + squared
         sigma = (jnp.sqrt(grown) - jnp.sqrt(n_old)) / alpha
-        z_new = z_old + (g - sigma * w)
-        # slots of one key (a repeated key, the scratch slots) write the
-        # value they all computed
-        return (z.at[at].set(z_new, indices_are_sorted=True),
-                n.at[at].set(grown, indices_are_sorted=True))
+        moved = g - sigma * w
+        if rows is None:
+            # slots of one key (a repeated key, the scratch slots) write the
+            # value they all computed
+            return (z.at[at].set(z_old + moved, indices_are_sorted=True),
+                    n.at[at].set(grown, indices_are_sorted=True))
+        # the rows of 128 the keys live in, read, stepped and written back
+        # by the row kernel: each key's lane takes the one addition above,
+        # once (the kernel steps a repeated key at its first slot)
+        return _row_kernel().add_at_lanes(
+            (z, n), at, (moved, squared), at != scratch, interpret=rows)
 
     # named so that the compiled modules are `jit__ftrl_keyed_get` and
     # `jit__ftrl_keyed_add` in a trace
     return (jax.jit(_ftrl_keyed_get, static_argnames=("live",)),
-            jax.jit(_ftrl_keyed_add, static_argnames=("live",),
+            jax.jit(_ftrl_keyed_add, static_argnames=("live", "rows"),
                     donate_argnums=(0, 1)))
 
 
@@ -174,7 +227,33 @@ class FTRLServer(DeviceIdsServer, ServerTable):
         self.padded = mesh_lib.pad_to_multiple(self.size + 1,
                                                1024 * num_shards)
         self._sharding = mesh_lib.table_sharding(self.mesh, ndim=1)
-        self._make_state(init)
+        platform = self.mesh.devices.flat[0].platform
+        # which program writes an Add back, chosen once from the mesh and
+        # the platform (a launch adds its bucket: `_rows_for`). The row
+        # kernel's module loads under the fill of the state, which waits on
+        # the device a piece at a time
+        loading = None
+        if num_shards == 1 and platform in _ROW_KERNEL_PLATFORMS:
+            loading = threading.Thread(
+                target=_row_kernel, name="ftrl-row-kernel-import",
+                daemon=True)
+            loading.start()
+        try:
+            self._make_state(init)
+        finally:
+            if loading is not None:
+                loading.join()
+        # None: XLA's scatters; else the row kernel, interpreted or compiled
+        self._rows_interpret: Optional[bool] = None
+        writes = "XLA scatter"
+        if loading is not None:
+            # here, not on the thread, a failed import raises
+            pallas_rows = _row_kernel()
+            self._rows_interpret = pallas_rows.interpret_for(platform)
+            writes = ("an Add's rows of 128 written back by the Pallas row "
+                      "kernel%s (XLA scatter past a bucket of %d keys)" % (
+                          ", interpreted" if self._rows_interpret else "",
+                          pallas_rows.PREFETCH_SLOTS))
         self._get, self._add = _make_programs(
             self.alpha, self.beta, self.lambda1, self.lambda2,
             self.scratch_key)
@@ -188,9 +267,8 @@ class FTRLServer(DeviceIdsServer, ServerTable):
         self._keys_get = Dashboard.counter("FTRL_KEYS_GET")
         self._keys_add = Dashboard.counter("FTRL_KEYS_ADD")
         log.info("FTRLTable %d keys (z, n: %d B) on %d %s device(s): keyed "
-                 "Get and Add, XLA gather and scatter", self.size,
-                 8 * self.padded, num_shards,
-                 self.mesh.devices.flat[0].platform)
+                 "Get and Add, XLA gather, %s", self.size, 8 * self.padded,
+                 num_shards, platform, writes)
 
     def _make_state(self, source=None) -> None:
         """``z`` and ``n`` as zeros made on the device, then ``source``'s
@@ -244,16 +322,37 @@ class FTRLServer(DeviceIdsServer, ServerTable):
                 took = self.launch_ids(keys, op)
         return took, live_slots(len(keys), took.bucket), ids_from
 
+    def _rows_for(self, bucket: int) -> Optional[bool]:
+        """What writes back an Add of ``bucket`` id slots: the row kernel
+        (its interpret mode) where this table's rows are its to serve and
+        the bucket's keys fit its scalar prefetch, else None: XLA's
+        scatters."""
+        if self._rows_interpret is None or \
+                bucket > _row_kernel().PREFETCH_SLOTS:
+            return None
+        return self._rows_interpret
+
     def _note_launch(self, launch, op: str, slots: int, took: LaunchIds,
-                     ids_from: str) -> None:
-        """The launch's record, under the matrix table's names: ``n`` slots
-        launched, ``bytes`` of state moved at them (``z`` and ``n`` read,
-        and written again by an Add), which is all this table holds."""
-        self._note_ids(launch, op, "xla", took.ids, ids_from)
+                     ids_from: str, pallas: bool = False) -> None:
+        """The launch's record, under the matrix table's names: ``path``,
+        the program that writes an Add back (``pallas``: the row kernel;
+        ``xla`` on every Get, and on an Add XLA's scatters serve), ``n``
+        slots launched, ``bytes`` of state moved at them (``z`` and ``n``
+        read, and written again by an Add), which is all this table holds;
+        on the kernel's path the ``descriptors`` it issues (a read and a
+        write-back of a row of ``z`` and of ``n`` for every slot of its
+        whole groups) and the ``waits`` for them."""
+        self._note_ids(launch, op, "pallas" if pallas else "xla", took.ids,
+                       ids_from)
         launch.n = slots
         launch.updater = "ftrl"
         launch.bytes = launch.state_bytes = (
             (16 if op == "add" else 8) * slots)
+        if pallas:
+            kernel = _row_kernel()
+            launch.descriptors = 4 * kernel.launched_slots(
+                slots, kernel.LANE_GROUP)
+            launch.waits = 2 * kernel.launch_waits(slots, kernel.LANE_GROUP)
 
     def process_add(self, request) -> None:
         with span("TABLE_PROCESS_ADD"):
@@ -273,10 +372,12 @@ class FTRLServer(DeviceIdsServer, ServerTable):
             elif self._replicated is not None:
                 # a worker's gradient is committed to one device
                 grad = jax.device_put(grad, self._replicated)
+            rows = self._rows_for(took.bucket)
             with span("TABLE_ROW_LAUNCH") as launch:
-                self._note_launch(launch, "add", live, took, ids_from)
+                self._note_launch(launch, "add", live, took, ids_from,
+                                  rows is not None)
                 self.z, self.n = self._add(self.z, self.n, took.ids, grad,
-                                           live=live)
+                                           live=live, rows=rows)
             self._keys_add.add(n)
 
     def process_get(self, request):

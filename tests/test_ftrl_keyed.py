@@ -152,6 +152,34 @@ def test_a_repeated_key_takes_one_step_from_the_sum(ref):
     assert replay.steps[distinct].tolist() == [2, 2, 2]
 
 
+@pytest.mark.parametrize("mesh", ["1", "4"])
+def test_a_repeated_key_of_a_long_add_takes_one_step_from_the_sum(ref, mesh):
+    """The same through an Add of more slots than a grid step of the row
+    kernel holds (`pallas_rows.LANE_GROUP`), so that the slots of some keys
+    lie either side of a step's boundary once sorted: on one device (the
+    kernel, interpreted) and on a mesh (XLA's scatters), against the
+    replay."""
+    from multiverso_tpu.ops.pallas_rows import LANE_GROUP
+
+    mv.init(mesh_shape=mesh)
+    table = _table(ref)
+    replay = ref.Replay(np.arange(SIZE), SEED, OPT)
+    rng = np.random.default_rng(41)
+    keys = (2000 + rng.integers(0, 150, 600)).astype(np.int32)
+    # gradients on a binary grid: their sums are exact in any order
+    grad = (rng.integers(-8, 9, 600) / 16).astype(np.float32)
+    slots = np.sort(keys)
+    assert sum(slots[edge - 1] == slots[edge]
+               for edge in range(LANE_GROUP, 600, LANE_GROUP)) >= 2
+    distinct, back = np.unique(keys, return_inverse=True)
+    summed = np.bincount(back, grad.astype(np.float64)).astype(np.float32)
+    for _ in range(2):
+        table.add(keys, grad)
+        replay.add(replay.plan(distinct), summed)
+        _check(ref, table, replay)
+    assert set(replay.steps[distinct].tolist()) == {2}
+
+
 def test_keys_outside_the_table_and_short_gradients_are_refused(ref):
     import jax
 
@@ -276,7 +304,7 @@ def test_the_kept_ids_hit_and_miss_as_on_a_matrix_table(ref):
     counts = {name: _count(name) for name in (
         "ROW_IDS_KEPT", "ROW_IDS_FROM_CALLER", "ROW_IDS_FROM_DISPATCHER",
         "FTRL_KEYS_GET", "FTRL_KEYS_ADD", "ROW_LAUNCH_XLA_GET",
-        "ROW_LAUNCH_XLA_ADD")}
+        "ROW_LAUNCH_XLA_ADD", "ROW_LAUNCH_PALLAS_ADD")}
 
     def grew(name):
         return _count(name) - counts[name]
@@ -302,7 +330,9 @@ def test_the_kept_ids_hit_and_miss_as_on_a_matrix_table(ref):
     assert grew("ROW_IDS_KEPT") == 3
     assert grew("ROW_IDS_FROM_CALLER") == 5
     assert grew("ROW_IDS_FROM_DISPATCHER") == 2
-    assert grew("ROW_LAUNCH_XLA_GET") == 4 and grew("ROW_LAUNCH_XLA_ADD") == 3
+    # on one device the row kernel writes an Add back (here interpreted)
+    assert grew("ROW_LAUNCH_XLA_GET") == 4 and grew("ROW_LAUNCH_XLA_ADD") == 0
+    assert grew("ROW_LAUNCH_PALLAS_ADD") == 3
     assert grew("FTRL_KEYS_GET") == 700 * 4 and grew("FTRL_KEYS_ADD") == 2100
     # the caller's array may change as soon as the call returns
     mine = keys.copy()
@@ -323,6 +353,7 @@ def test_the_records_of_a_keyed_op_carry_the_matrix_ops_fields(
     import jax.numpy as jnp
 
     from multiverso_tpu import dashboard
+    from multiverso_tpu.ops.pallas_rows import LANE_GROUP
     from multiverso_tpu.runtime.zoo import Zoo
     from multiverso_tpu.tables.device_ids import live_slots
 
@@ -349,17 +380,27 @@ def test_the_records_of_a_keyed_op_carry_the_matrix_ops_fields(
     assert [r.n for r in by["TABLE_ROW_PREP"]] == [700, 700]
     live = live_slots(700, 1024)
     get, add = by["TABLE_ROW_LAUNCH"]
-    for launch, nbytes in ((get, 8 * live), (add, 16 * live)):
+    for launch, path, nbytes in ((get, "xla", 8 * live),
+                                 (add, "pallas", 16 * live)):
         assert (launch.n, launch.path, launch.updater, launch.ids_from,
                 launch.bytes, launch.state_bytes) == (
-            live, "xla", "ftrl", "caller", nbytes, nbytes)
+            live, path, "ftrl", "caller", nbytes, nbytes)
     assert add.ids_ready == 1
+    # four descriptors a slot of the kernel's whole groups (a row of `z`
+    # and of `n`, read and written back), four waits a group
+    groups = -(-live // LANE_GROUP)
+    assert (add.descriptors, add.waits) == (4 * LANE_GROUP * groups,
+                                            4 * groups)
+    assert (get.descriptors, get.waits) == (0, 0)
 
     class Run:
         window = (t0, t1)
 
     read = common.load_module("layers", "ftrl_slots_share").read
     assert read(Run()) == pytest.approx(100.0 * live / 700)
+    # the Add's launch is the kernel's: the cell's counter of the mechanism
+    assert common.load_module(
+        "layers", "pallas_row_share.ftrlctr").read(Run()) == 100.0
 
 
 def test_a_remote_client_is_refused_by_name(ref):
@@ -683,3 +724,213 @@ def test_the_bfloat16_gradient_control_reads_not_correct():
             "final_sample_n_mismatch"} <= failed
     assert not {"created_state_mismatch", "start_unnamed_mismatch",
                 "unnamed_state_mismatch"} & failed
+
+
+# -- the row kernel's write-back against XLA's scatter (PR 41, PR 42) --------
+_KSIZE = 60_000          # 469 rows of 128; the scratch key shares the last
+
+
+def _row_keys(case, rng, group):
+    """(keys as a caller names them, gradient values past the keys);
+    ``group``: the slots a grid step of the kernel walks (G in a case's
+    name)."""
+    one_a_row = np.arange(0, 400) * 128 + rng.integers(0, 128, 400)
+    if case == "distinct keys one a row":
+        return one_a_row, 0
+    if case == "300 consecutive keys":
+        return np.arange(1000, 1300), 0
+    if case == "the 14 keys before the scratch key and pads":
+        return np.arange(_KSIZE - 14, _KSIZE), 0
+    if case == "a key thrice among others of its row":
+        return np.concatenate([np.arange(640, 700), [650, 650],
+                               one_a_row[:50]]), 0
+    if case == "a row's run across two groups":
+        # G - 56 keys a row each sort first; row 300's 128 keys take the
+        # next slots, 56 before slot G and 72 from it
+        return np.concatenate([one_a_row[:group - 56],
+                               300 * 128 + np.arange(128)]), 0
+    if case == "NaN in the gradient past the keys":
+        return np.concatenate([one_a_row[:100], np.arange(2000, 2100)]), 324
+    if case == "600 draws of 150 keys":
+        # every key some four times, in several groups
+        return 1100 + rng.integers(0, 150, 600), 0
+    if case in _STRADDLES:
+        # the key's slots lie either side of slot G, the boundary of two
+        # grid steps of the kernel, among other keys of its row on both
+        before, times = _STRADDLES[case]
+        key = 300 * 128 + 64
+        return np.concatenate([
+            one_a_row[:group - before - 3], key - np.arange(1, 4),
+            [key] * times, key + np.arange(1, 4), one_a_row[301:351]]), 0
+    raise ValueError(case)
+
+
+# a repeated key across two groups: (its slots before slot G, its slots)
+_STRADDLES = {"a key twice at slots G-1 and G": (1, 2),
+              "a key thrice at slots G-2 to G": (2, 3),
+              "a key thrice at slots G-1 to G+1": (1, 3),
+              "a key four times at slots G-2 to G+1": (2, 4)}
+
+
+@pytest.mark.parametrize("case", [
+    "distinct keys one a row", "300 consecutive keys",
+    "the 14 keys before the scratch key and pads",
+    "a key thrice among others of its row",
+    "a row's run across two groups",
+    "-0.0, inf and a denormal in unnamed lanes",
+    "NaN in the gradient past the keys",
+    "two live-slot counts of one bucket", *_STRADDLES,
+    "600 draws of 150 keys"])
+def test_the_row_kernel_writes_back_what_the_scatter_writes(ref, case):
+    """The keyed Add's two programs on one state: the row kernel's
+    (interpreted) and XLA's give `z` and `n` equal in EVERY bit, the keys
+    named and every other entry, the scratch entries too. A key several
+    slots name steps once, wherever the boundary of two grid steps (slot G)
+    falls among them."""
+    import jax.numpy as jnp
+
+    from multiverso_tpu.ops.pallas_rows import LANE_GROUP as G
+    from multiverso_tpu.tables import ftrl_table as ft
+    from multiverso_tpu.tables.device_ids import live_slots
+    from multiverso_tpu.utils import next_pow2
+
+    rng = np.random.default_rng(41)
+    padded = -(-(_KSIZE + 1) // 1024) * 1024
+    z0, n0 = (np.zeros(padded, np.float32) for _ in range(2))
+    z0[:_KSIZE], n0[:_KSIZE] = ref.init_zn(np.arange(_KSIZE), SEED)
+    if case == "two live-slot counts of one bucket":
+        ops = [(np.arange(3000, 3600), 0), (np.arange(3300, 4200), 0)]
+    elif case == "-0.0, inf and a denormal in unnamed lanes":
+        ops = [(np.arange(1000, 1300, 3), 0)]
+        # rows 7-10 are named, a key in three; these lanes are not
+        z0[[1001, 1004, 1007]] = -0.0, np.inf, 1e-42
+        n0[[1002, 1005, 1008]] = -0.0, np.inf, 1e-42
+    else:
+        ops = [_row_keys(case, rng, G)]
+    _, add = ft._make_programs(scratch=_KSIZE, **OPT)
+    states = [[jnp.asarray(z0), jnp.asarray(n0)] for _ in range(2)]
+    lives = set()
+    for keys, longer in ops:
+        count = len(keys)
+        bucket = max(next_pow2(count + 1), 128)
+        live = live_slots(count, bucket)
+        lives.add((bucket, live))
+        ids = np.full(bucket, _KSIZE, np.int32)
+        ids[:count] = keys
+        grad = np.full(count + longer, np.nan, np.float32)
+        grad[:count] = ref.to_float(ref.grad_k(rng, count))
+        for state, rows in zip(states, (None, True)):
+            state[:] = add(*state, jnp.asarray(ids), jnp.asarray(grad),
+                           live=live, rows=rows)
+    for want, got, was in zip(states[0], states[1], (z0, n0)):
+        want, got = (np.asarray(s).view(np.uint32) for s in (want, got))
+        np.testing.assert_array_equal(got, want)
+        assert (want != was.view(np.uint32)).any()
+    if case == "two live-slot counts of one bucket":
+        assert len(lives) == 2 and len({b for b, _ in lives}) == 1
+    if case == "a row's run across two groups":
+        slots = np.sort(ops[0][0]) >> 7
+        assert slots[G - 1] == slots[G] == 300
+    if case == "600 draws of 150 keys":
+        slots = np.sort(ops[0][0])
+        assert sum(slots[edge - 1] == slots[edge]
+                   for edge in range(G, 600, G)) >= 2
+    if case in _STRADDLES:
+        before, times = _STRADDLES[case]
+        slots = np.sort(ops[0][0])
+        assert 0 < before < times
+        assert (slots[G - before:G - before + times] == slots[G]).all()
+        assert (slots == slots[G]).sum() == times
+
+
+def test_a_mesh_of_four_keeps_xlas_scatter_and_says_so(ref, monkeypatch):
+    """On several devices the keyed Add is XLA's partitioned program, and
+    its launch record and counters say `xla`; on one device `pallas`. A
+    bucket past the kernel's scalar prefetch says `xla` too. All three
+    leave `z` and `n` with the same bits."""
+    import time
+
+    from multiverso_tpu import dashboard
+    from multiverso_tpu.ops import pallas_rows
+    from multiverso_tpu.runtime.zoo import Zoo
+
+    keys = _keys(np.random.default_rng(7), 300)
+    grads = np.ones(300, np.float32)
+    paths, states = {}, []
+    for mesh, limit in (("4", None), ("1", None), ("1", 256)):
+        mv.init(mesh_shape=mesh)
+        if limit:
+            monkeypatch.setattr(pallas_rows, "PREFETCH_SLOTS", limit)
+        table = _table(ref)
+        counts = {path: _count("ROW_LAUNCH_%s_ADD" % path)
+                  for path in ("XLA", "PALLAS")}
+        monkeypatch.setattr(Dashboard, "profile_annotations", True)
+        t0 = time.perf_counter()
+        table.add(keys, grads)
+        Zoo.instance().server.run_serialized(lambda: None)
+        t1 = time.perf_counter()
+        monkeypatch.setattr(Dashboard, "profile_annotations", False)
+        launches = [r for r in dashboard.RING.window(t0, t1)[0]
+                    if r.stage == "TABLE_ROW_LAUNCH"]
+        paths[mesh, limit] = (
+            [r.path for r in launches],
+            {path: _count("ROW_LAUNCH_%s_ADD" % path) - was
+             for path, was in counts.items()})
+        states.append([np.asarray(table.get_state_device(name))[:SIZE + 1]
+                       .view(np.uint32) for name in "zn"])
+        mv.shutdown()
+    assert paths["4", None] == (["xla"], {"XLA": 1, "PALLAS": 0})
+    assert paths["1", None] == (["pallas"], {"XLA": 0, "PALLAS": 1})
+    # 300 keys take a bucket of 512
+    assert paths["1", 256] == (["xla"], {"XLA": 1, "PALLAS": 0})
+    for other in states[1:]:
+        for want, got in zip(states[0], other):
+            np.testing.assert_array_equal(got, want)
+
+
+_WHO_LOADS_THE_KERNEL = """
+import json, sys, threading
+import numpy as np
+import multiverso_tpu as mv
+
+def loaded():
+    return "jax.experimental.pallas" in sys.modules
+
+seen = {"import": loaded()}
+for mesh in ("4", "1"):
+    mv.init(mesh_shape=mesh)
+    table = mv.create_table(
+        "ftrl", 3000, init=lambda lo, count: (np.ones(count, np.float32),
+                                              np.ones(count, np.float32)))
+    seen["threads " + mesh] = sorted(
+        t.name for t in threading.enumerate() if "kernel" in t.name)
+    table.add(np.arange(5, dtype=np.int32), np.ones(5, np.float32))
+    seen["mesh " + mesh] = loaded()
+    seen["z " + mesh] = float(np.asarray(table.get_state_device("z"))[4])
+    mv.shutdown()
+print("SEEN", json.dumps(seen))
+"""
+
+
+def test_only_a_table_the_row_kernel_serves_loads_its_module():
+    """`import multiverso_tpu` and an FTRL table on a mesh of four load no
+    `jax.experimental.pallas` (a second of module code every process that
+    imports the package would pay: PR 41 did); a table on one device does,
+    under the fill of its state, and its constructor leaves no thread."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    done = subprocess.run(
+        [sys.executable, "-c", _WHO_LOADS_THE_KERNEL], cwd=common.ROOT,
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, PYTHONPATH=common.ROOT))
+    assert done.returncode == 0, done.stderr[-2000:]
+    seen = json.loads(
+        [x for x in done.stdout.splitlines() if x.startswith("SEEN ")][-1][5:])
+    assert seen["import"] is False
+    assert seen["mesh 4"] is False and seen["mesh 1"] is True
+    assert seen["threads 4"] == seen["threads 1"] == []
+    # both programs stepped the key
+    assert seen["z 4"] == seen["z 1"] != 1.0

@@ -403,3 +403,43 @@ def test_group_slab_programs_compile_at_19_million_rows(one_chip, program):
         assert compiled.memory_analysis().alias_size_in_bytes >= slab_bytes
     assert compiled.memory_analysis().temp_size_in_bytes <= (
         bucket * 128 * 4 + (1 << 20))
+
+
+def test_keyed_ftrl_add_compiles_with_the_row_kernel_in_place(one_chip):
+    """The keyed FTRL Add of the benchmark's cell (882,774,573 keys, 111,300
+    named in a 131,072 bucket) on the path one chip takes (PR 42): ONE
+    program under the name a trace is read by, ONE custom call of the row
+    kernel and no scatter into a state, `z` and `n` (3.53 GB each) aliased
+    whole, and no temporary near a state's size: the rows the kernel takes
+    (the runs and two delta blocks a slot: 177 MB) are all there is. The
+    kernel refuses more keys than its scalar prefetch holds (the table
+    keeps XLA's program there: `FTRLServer._rows_for`)."""
+    from multiverso_tpu.tables import ftrl_table as ft
+    from multiverso_tpu.tables.device_ids import live_slots
+
+    size, bucket = 882_774_573, 131_072
+    padded = -(-(size + 1) // 1024) * 1024
+    live = live_slots(111_300, bucket)
+    _, add = ft._make_programs(0.1, 1.0, 1.0, 1.0, size)
+    state = jax.ShapeDtypeStruct((padded,), jnp.float32, sharding=one_chip)
+    compiled = add.lower(
+        state, state,
+        jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((bucket,), jnp.float32, sharding=one_chip),
+        live=live, rows=False).compile()
+    text, mem = _hlo_text(compiled), compiled.memory_analysis()
+    assert text.splitlines()[0].startswith("HloModule jit__ftrl_keyed_add")
+    entry = text[text.index("ENTRY"):].splitlines()
+    assert len([line for line in entry if "tpu_custom_call" in line]) == 1
+    # the only scatter left sums a repeated key's gradients, over the slots
+    assert "scatter(f32[%d]" % padded not in text
+    assert "scatter(f32[%d]" % live in text
+    assert mem.alias_size_in_bytes >= 2 * 4 * padded
+    assert mem.temp_size_in_bytes <= 3 * 115_200 * 512 + (8 << 20)
+    # the cell's bucket is the largest the kernel's scalar prefetch holds
+    assert bucket == pallas_rows.PREFETCH_SLOTS
+    with pytest.raises(ValueError, match="add_at_lanes"):
+        jax.eval_shape(
+            lambda s, k: pallas_rows.add_at_lanes(
+                (s,), k, (k.astype(jnp.float32),), k >= 0, interpret=False),
+            state, jax.ShapeDtypeStruct((bucket + 1,), jnp.int32))
