@@ -241,3 +241,34 @@ class Message:
             req_id=self.req_id,
             trace=self.trace,
         )
+
+
+class PendingHostRead:
+    """A keyed host Get's result while it is launched and not fetched: the
+    gathered device array, its copy to the host already started by the
+    dispatcher at launch, and the part of it the caller is owed. What a
+    table hands the dispatcher in place of the rows (``ServerTable.
+    launch_get``), for the completion to decide who fetches: a reply
+    framed over the wire is finished by the ``RemoteServer``'s finishing
+    thread, an in-process waiter fetches for itself in
+    ``Completion.wait``, any other completion gets the rows
+    (``server.complete_get``). ``resolve`` is the table's ``_host_read``
+    to the letter (one fresh host array, the ``TABLE_HOST_READ`` span) on
+    whichever thread calls it. The array is the Get's own: the gather ran
+    in the dispatcher's order, so the rows are the table's at the Get's
+    service whatever is applied before they are fetched."""
+
+    __slots__ = ("_read", "_arr", "_index")
+
+    def __init__(self, read, arr, index) -> None:
+        self._read, self._arr, self._index = read, arr, index
+
+    def resolve(self) -> Any:
+        return self._read(self._arr)[self._index]
+
+    @staticmethod
+    def fetched(result: Any) -> Any:
+        """``result``, its rows fetched here if it is a pending read."""
+        if isinstance(result, PendingHostRead):
+            return result.resolve()
+        return result

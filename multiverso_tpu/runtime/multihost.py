@@ -433,6 +433,12 @@ class LockstepTable:
                                      request)
         return self._inner.process_get(request)
 
+    def launch_get(self, request: Any) -> Any:
+        """The dispatcher's entry: the broadcast, as for every op (without
+        this, ``__getattr__`` would hand out the inner table's). Under a
+        multi-process mesh a table fetches its Get's rows itself."""
+        return self.process_get(request)
+
     def store(self, stream) -> None:
         """Snapshot through the DISPATCHER: the device->host read is a
         collective, so it must be serialized into the lockstep stream —

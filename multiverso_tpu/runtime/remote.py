@@ -34,10 +34,21 @@ are SparseFilter-compressed when the ``wire_compression`` flag is on and the
 sparse form is smaller (the reference applied SparseFilter on exactly these
 host hops, ``src/table/sparse_matrix_table.cpp:147-153``). The codec decides
 that from one count of the array's nonzeros and runs the encoder only where
-its output is what is sent: a dense payload (a Get's reply on the dispatcher
-thread, a trainer's rows in ``RemoteClient._send``) crosses as the array
-itself, for the price of the count (``WIRE_FLOAT_DENSE`` /
-``WIRE_FLOAT_SPARSE`` count both ways).
+its output is what is sent: a dense payload (a Get's reply, a trainer's rows
+in ``RemoteClient._send``) crosses as the array itself, for the price of the
+count (``WIRE_FLOAT_DENSE`` / ``WIRE_FLOAT_SPARSE`` count both ways).
+
+Who finishes a reply: the dispatcher thread is the serving process's whole
+capacity, so it launches a served keyed Get (the gather, and its copy to the
+host started) and goes on; ONE finishing thread of the ``RemoteServer``
+(``finish_reply`` / ``_finish_replies``, FIFO) waits for the rows, encodes,
+stores the dedup entry, stamps ``reply_sent`` and sends, behind it. Acks,
+errors, whole-table and sparse Gets, every reply under a multi-process mesh
+(the fetch is a collective there) and a Get that finds ``_MAX_UNFINISHED``
+replies waiting are finished by the dispatcher as before. Replies may
+therefore leave a connection out of service order; ``RemoteClient._pump``
+settles each by its ``msg_id``. A reply's ``watermark`` is the append
+watermark at its request's service on the dispatcher, whoever sends it.
 """
 
 from __future__ import annotations
@@ -48,15 +59,15 @@ import random
 import signal
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 from multiverso_tpu import config, log
 from multiverso_tpu import io as mv_io
-from multiverso_tpu.dashboard import (Dashboard, count, gauge_set, observe,
-                                      span)
+from multiverso_tpu.dashboard import (RING, Dashboard, count, current_span,
+                                      gauge_set, monitor, observe, span)
 from multiverso_tpu.fault.detector import LivenessDetector
 from multiverso_tpu.fault.inject import make_net
 from multiverso_tpu.fault.retry import (CircuitBreaker, RetryBudget,
@@ -65,7 +76,8 @@ from multiverso_tpu.obs.metrics import StatsSnapshot
 from multiverso_tpu.obs.trace import flight_dump, hop, tag_tenant
 from multiverso_tpu.runtime.admission import resolve_tenant
 from multiverso_tpu.runtime.contracts import slot_free
-from multiverso_tpu.runtime.message import Message, MsgType, next_msg_id
+from multiverso_tpu.runtime.message import (Message, MsgType,
+                                            PendingHostRead, next_msg_id)
 from multiverso_tpu.runtime.net import TcpNet
 from multiverso_tpu.runtime import wire
 from multiverso_tpu.tables.array_table import ArrayWorker
@@ -88,6 +100,11 @@ config.define_bool("wire_compression", True,
 # replay seen now is swallowed (the original's completion will reply)
 _INFLIGHT = object()
 
+# replies handed to the finishing thread and not yet sent; past it the
+# dispatcher finishes its Get's reply itself (each holds a gathered device
+# array and its host copy: 64 of the remote cell's are 64 MB)
+_MAX_UNFINISHED = 64
+
 
 class WrongShardError(Exception):
     """A Reply_WrongShard came back: the request was stamped with a layout
@@ -103,12 +120,15 @@ class WrongShardError(Exception):
         self.manifest = manifest
 
 
-class _NetCompletion:
-    """Dispatcher completion that frames the result back over the wire and
-    records it in the server's dedup window, so a replay of the same
-    request re-sends this reply instead of re-applying the request."""
+class _WireCompletion:
+    """A dispatcher completion whose result is framed back over the
+    connection the request arrived on. A keyed Get's result arrives
+    launched and not fetched (``takes_pending``): nobody in this process
+    waits for it, so the server's finishing thread fetches, encodes and
+    sends it behind the dispatcher (``RemoteServer.finish_reply``)."""
 
     __slots__ = ("_server", "_conn", "_template", "_compress")
+    takes_pending = True
 
     def __init__(self, server: "RemoteServer", conn, template: Message,
                  compress: bool) -> None:
@@ -117,13 +137,57 @@ class _NetCompletion:
         self._template = template
         self._compress = compress
 
-    def _reply(self, msg_type: MsgType, payload: Any) -> None:
+    def _settle(self, reply_type: MsgType, result: Any) -> None:
+        if isinstance(result, PendingHostRead):
+            self._server.finish_reply(self, reply_type, result)
+            return
+        if reply_type in (MsgType.Reply_Get, MsgType.Reply_Read):
+            self._server._finished_inline.add(1)
+        self._reply(reply_type, result)
+
+    def finish(self, reply_type: MsgType, pending: PendingHostRead,
+               watermark: int) -> None:
+        """Fetch the launched Get's rows and reply, on the thread that
+        calls: the finishing thread, or the dispatcher where nothing could
+        be handed over. ``watermark`` is the append watermark at the
+        Get's service."""
         t = self._template
+        with monitor("REPLY_FINISH", op=t.req_id or t.msg_id):
+            try:
+                rows = pending.resolve()
+            except Exception as exc:  # noqa: BLE001 — the waiter is remote
+                log.error("remote: fetching worker %d's Get failed: %r",
+                          t.src, exc)
+                self.fail(exc)
+                return
+            self._reply(reply_type, rows, watermark)
+
+    def fail(self, error: BaseException) -> None:
+        # admission refusals and deadline drops ship their exact truthful
+        # string (clients key graceful degradation on the "shed: " /
+        # "deadline_exceeded" prefixes); everything else ships its repr
+        self._reply(MsgType.Reply_Error,
+                    getattr(error, "wire_text", None) or repr(error))
+
+
+class _NetCompletion(_WireCompletion):
+    """Dispatcher completion that frames the result back over the wire and
+    records it in the server's dedup window, so a replay of the same
+    request re-sends this reply instead of re-applying the request. Until
+    the reply is stored the request stays ``_INFLIGHT`` there: a replay
+    that arrives while the finishing thread has the reply is swallowed."""
+
+    __slots__ = ()
+
+    def _reply(self, msg_type: MsgType, payload: Any,
+               watermark: Optional[int] = None) -> None:
+        t = self._template
+        if watermark is None:
+            watermark = self._server.append_watermark()
         with span("WIRE_REPLY", op=t.req_id or t.msg_id):
             msg = Message(src=t.dst, dst=t.src, type=msg_type,
                           table_id=t.table_id, msg_id=t.msg_id,
-                          req_id=t.req_id,
-                          watermark=self._server.append_watermark(),
+                          req_id=t.req_id, watermark=watermark,
                           data=wire.encode(payload, compress=self._compress))
             self._server._dedup_store(t.req_id, msg)
             hop(t.req_id, "reply_sent")
@@ -136,38 +200,26 @@ class _NetCompletion:
                           "cache)", t.src, exc)
 
     def done(self, result: Any) -> None:
-        reply_type = (MsgType.Reply_Get
-                      if self._template.type == MsgType.Request_Get
-                      else MsgType.Reply_Add)
-        self._reply(reply_type, result)
-
-    def fail(self, error: BaseException) -> None:
-        # admission refusals and deadline drops ship their exact truthful
-        # string (clients key graceful degradation on the "shed: " /
-        # "deadline_exceeded" prefixes); everything else ships its repr
-        self._reply(MsgType.Reply_Error,
-                    getattr(error, "wire_text", None) or repr(error))
+        self._settle(MsgType.Reply_Get
+                     if self._template.type == MsgType.Request_Get
+                     else MsgType.Reply_Add, result)
 
 
-class _ReadCompletion:
+class _ReadCompletion(_WireCompletion):
     """Completion for a slot-free Request_Read: replies Reply_Read stamped
     with the primary's append watermark. No dedup entry — reads are
     idempotent, a replayed read just re-serves."""
 
-    __slots__ = ("_server", "_conn", "_template", "_compress")
+    __slots__ = ()
 
-    def __init__(self, server: "RemoteServer", conn, template: Message,
-                 compress: bool) -> None:
-        self._server = server
-        self._conn = conn
-        self._template = template
-        self._compress = compress
-
-    def _reply(self, msg_type: MsgType, payload: Any) -> None:
+    def _reply(self, msg_type: MsgType, payload: Any,
+               watermark: Optional[int] = None) -> None:
         t = self._template
+        if watermark is None:
+            watermark = self._server.append_watermark()
         msg = Message(src=t.dst, dst=t.src, type=msg_type,
                       table_id=t.table_id, msg_id=t.msg_id, req_id=t.req_id,
-                      watermark=self._server.append_watermark(),
+                      watermark=watermark,
                       data=wire.encode(payload, compress=self._compress))
         hop(t.req_id, "read_reply_sent")
         try:
@@ -178,11 +230,7 @@ class _ReadCompletion:
 
     def done(self, result: Any) -> None:
         count("READS_SERVED_PRIMARY")
-        self._reply(MsgType.Reply_Read, result)
-
-    def fail(self, error: BaseException) -> None:
-        self._reply(MsgType.Reply_Error,
-                    getattr(error, "wire_text", None) or repr(error))
+        self._settle(MsgType.Reply_Read, result)
 
 
 class _QueryCompletion(_ReadCompletion):
@@ -242,6 +290,17 @@ class RemoteServer:
         # re-routes. 0 = no fencing (unsharded servers, pre-migration
         # groups); bumped only by a Control_Migrate_Cutover install.
         self.layout_version: int = 0
+        # the finishing thread (``_finish_replies``): the replies of keyed
+        # Gets the dispatcher launched and handed over, oldest first; the
+        # one being finished stays at the head until it is sent
+        self._unfinished: "deque" = deque()
+        self._finish_cv = threading.Condition()
+        self._finishing = False
+        self._finisher: Optional[threading.Thread] = None
+        self._finished_behind = Dashboard.counter("REPLIES_FINISHED_BEHIND")
+        self._finished_inline = Dashboard.counter("REPLIES_FINISHED_INLINE")
+        self._finish_waits = (Dashboard.get("REPLY_FINISH_WAIT"),
+                              Dashboard.histogram("REPLY_FINISH_WAIT"))
 
     def append_watermark(self) -> int:
         """The primary's WAL append sequence (-1 when serving without
@@ -261,6 +320,11 @@ class RemoteServer:
                 # replication fan-out: every durable append reaches the
                 # subscribed standbys over their replication connections
                 self._zoo.server.wal.add_observer(self._replicate_record)
+        self._finishing = True
+        self._finisher = threading.Thread(target=self._finish_replies,
+                                          daemon=True,
+                                          name="mv-remote-finish")
+        self._finisher.start()
         self._thread = threading.Thread(target=self._pump, daemon=True,
                                         name="mv-remote-serve")
         self._thread.start()
@@ -274,10 +338,84 @@ class RemoteServer:
         if self._standby_hb is not None:
             self._standby_hb.join(timeout=10)
             self._standby_hb = None
+        self._stop_finishing()
         self._net.finalize()
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
+
+    # -- replies finished behind the dispatcher -------------------------------
+    def finish_reply(self, completion: _WireCompletion, reply_type: MsgType,
+                     pending: PendingHostRead) -> None:
+        """The dispatcher's hand-over of a keyed Get it has launched: the
+        finishing thread fetches the rows, encodes, stores the dedup entry
+        and sends, in the order handed over, while the dispatcher serves
+        the next message. The append watermark is read here, at the Get's
+        service, and rides along: an Add applied before the reply leaves
+        must not show in it. With ``_MAX_UNFINISHED`` replies waiting, or
+        once ``stop`` has closed the hand-over, the dispatcher finishes
+        this one itself, as it did every reply before there was a
+        thread."""
+        watermark = self.append_watermark()
+        item = (completion, reply_type, pending, watermark,
+                time.perf_counter_ns(), current_span())
+        with self._finish_cv:
+            behind = (self._finishing
+                      and len(self._unfinished) < _MAX_UNFINISHED)
+            if behind:
+                self._unfinished.append(item)
+                self._finish_cv.notify()
+        if behind:
+            self._finished_behind.add(1)
+        else:
+            self._finished_inline.add(1)
+            completion.finish(reply_type, pending, watermark)
+
+    def _finish_replies(self) -> None:
+        while True:
+            with self._finish_cv:
+                while self._finishing and not self._unfinished:
+                    self._finish_cv.wait()
+                if not self._unfinished:
+                    return  # stopped, and everything handed over is sent
+                item = self._unfinished[0]
+            completion, reply_type, pending, watermark, handed_ns, at = item
+            waited = time.perf_counter_ns() - handed_ns
+            for unit in self._finish_waits:
+                unit.observe(waited * 1e-9)
+            if Dashboard.profile_annotations:
+                t = completion._template
+                RING.append(0, at, "REPLY_FINISH_WAIT", handed_ns, waited, 0,
+                            t.req_id or t.msg_id, 0)
+            try:
+                completion.finish(reply_type, pending, watermark)
+            except Exception as exc:  # noqa: BLE001 — keep finishing
+                log.error("remote: finishing a reply failed: %r", exc)
+            with self._finish_cv:
+                if self._unfinished and self._unfinished[0] is item:
+                    self._unfinished.popleft()
+
+    def _stop_finishing(self) -> None:
+        """Close the hand-over and let the finishing thread send what it
+        holds while the connections still stand. Where a fetch that never
+        returns still holds it after the join's time limit, the replies
+        behind that one are failed: each request is answered once and
+        leaves the dedup window's ``_INFLIGHT``."""
+        with self._finish_cv:
+            self._finishing = False
+            self._finish_cv.notify()
+        finisher, self._finisher = self._finisher, None
+        if finisher is None:
+            return
+        finisher.join(timeout=10)
+        if not finisher.is_alive():
+            return
+        with self._finish_cv:
+            left = list(self._unfinished)[1:]  # the head is that thread's
+            self._unfinished.clear()
+        for completion, *_ in left:
+            completion.fail(ConnectionError(
+                "server stopped before this Get's reply was finished"))
 
     # -- idempotent replay ---------------------------------------------------
     def _replayed(self, msg: Message) -> bool:
@@ -640,9 +778,10 @@ class RemoteServer:
         no lease, no dedup entry. The request rides the dispatcher queue
         as an administrative Get (src=-1 bypasses every round gate), so
         it serializes with applies, and the Reply_Read is stamped with the
-        append watermark at reply time. The primary is trivially "fresh",
-        so the request's staleness budget is always satisfied here — this
-        is the fallback target when no replica qualifies."""
+        append watermark at its service on the dispatcher (a keyed read's
+        rows follow from the finishing thread). The primary is trivially
+        "fresh", so the request's staleness budget is always satisfied
+        here — this is the fallback target when no replica qualifies."""
         request = wire.decode(msg.data)
         completion = _ReadCompletion(self, msg._conn, msg, compress)
         hop(msg.req_id, "dispatch_enqueue")

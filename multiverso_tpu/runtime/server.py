@@ -7,10 +7,12 @@ caches (``src/server.cpp:36-222``). Routing ran worker actor â†’ communicator â†
 network â†’ server actor.
 
 TPU-native re-design: table state is a sharded ``jax.Array`` in HBM; "apply
-an Add" is a jitted donated updater call; "answer a Get" is a device gather +
-host fetch. The actor zoo collapses to ONE dispatcher thread per process
-pulling typed messages from an in-process queue â€” the network hop no longer
-exists because workers and server shards share the mesh. The BSP contract is
+an Add" is a jitted donated updater call; "answer a Get" is a device gather,
+launched here in order, + a host fetch that whoever finishes the Get makes
+(``complete_get``). The actor zoo collapses to ONE dispatcher thread per
+process pulling typed messages from an in-process queue â€” the network hop no
+longer exists because workers and server shards share the mesh. The BSP
+contract is
 preserved exactly (and tested like ``Test/unittests/test_sync.cpp``):
 *every worker's i-th Get observes exactly i rounds of every worker's Adds*,
 implemented with the same two-sided clock: round-(i+1) Adds are deferred
@@ -32,7 +34,8 @@ from multiverso_tpu.obs.trace import flight_dump, hop
 from multiverso_tpu.runtime.admission import (AdmissionGate, DeadlineExceeded,
                                               ShedError, lane_order)
 from multiverso_tpu.runtime.contracts import dispatcher_only
-from multiverso_tpu.runtime.message import Message, MsgType
+from multiverso_tpu.runtime.message import (Message, MsgType,
+                                            PendingHostRead)
 from multiverso_tpu.utils import MtQueue
 
 _apply_metrics_cache = None
@@ -56,6 +59,19 @@ def _apply_metrics():
             Dashboard.histogram("SERVER_QUEUE_WAIT_SECONDS"),
         )
     return _apply_metrics_cache
+
+
+def complete_get(completion, result) -> None:
+    """Complete a Get with what its table's ``launch_get`` returned, served
+    at once or released from a round gate later. A keyed host Get comes
+    back launched and not fetched (``PendingHostRead``), and the
+    completion decides by its kind who fetches: one that says
+    ``takes_pending`` (an in-process waiter, a reply framed over the wire)
+    is done with the pending result and the dispatcher goes on; any other
+    gets the rows, fetched here as before."""
+    if not getattr(completion, "takes_pending", False):
+        result = PendingHostRead.fetched(result)
+    completion.done(result)
 
 
 class _NullCompletion:
@@ -518,8 +534,8 @@ class Server:
         with monitor("SERVER_PROCESS_GET_MSG"):
             request, completion = msg.data
             hop(msg.req_id, "serve_get")
-            result = self._tables[msg.table_id].process_get(request)
-            completion.done(result)
+            complete_get(completion,
+                         self._tables[msg.table_id].launch_get(request))
 
     @dispatcher_only
     def _process_query(self, msg: Message) -> None:
@@ -838,9 +854,9 @@ class SyncServer(Server):
         # round-i Gets wait until every worker's round-i Add is applied
         if self._min_adds(tid) >= round_:
             request, completion = msg.data
-            result = self._tables[tid].process_get(request)
+            result = self._tables[tid].launch_get(request)
             self._get_clock[tid][worker] = round_
-            completion.done(result)
+            complete_get(completion, result)
             self._drain(tid)
         else:
             self._gate_defer(msg)
@@ -867,9 +883,9 @@ class SyncServer(Server):
                 if self._min_adds(table_id) >= round_:
                     self._gate_release(msg)
                     request, completion = msg.data
-                    result = self._tables[table_id].process_get(request)
+                    result = self._tables[table_id].launch_get(request)
                     self._get_clock[table_id][worker] = round_
-                    completion.done(result)
+                    complete_get(completion, result)
                     progressed = True
                 else:
                     still.append(msg)
@@ -945,9 +961,9 @@ class SSPServer(SyncServer):
             return
         if self._min_adds(tid) >= self._gate_round(tid, worker):
             request, completion = msg.data
-            result = self._tables[tid].process_get(request)
+            result = self._tables[tid].launch_get(request)
             self._get_clock[tid][worker] += 1
-            completion.done(result)
+            complete_get(completion, result)
         else:
             self._gate_defer(msg)
             self._pending_get[tid].append(msg)
@@ -961,9 +977,9 @@ class SSPServer(SyncServer):
                                                             worker):
                 self._gate_release(msg)
                 request, completion = msg.data
-                result = self._tables[table_id].process_get(request)
+                result = self._tables[table_id].launch_get(request)
                 self._get_clock[table_id][worker] += 1
-                completion.done(result)
+                complete_get(completion, result)
             else:
                 still.append(msg)
         self._pending_get[table_id] = still

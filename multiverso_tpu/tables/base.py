@@ -25,7 +25,8 @@ import numpy as np
 
 from multiverso_tpu import log
 from multiverso_tpu.dashboard import Dashboard, monitor, span
-from multiverso_tpu.runtime.message import Message, MsgType, next_msg_id
+from multiverso_tpu.runtime.message import (Message, MsgType,
+                                            PendingHostRead, next_msg_id)
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.utils import Waiter
 
@@ -153,16 +154,22 @@ def merge_duplicate_rows(ids: np.ndarray, values: np.ndarray):
 class Completion:
     """One outstanding request: a waiter plus its result slot.
     ``done_ns`` is ``time.perf_counter_ns()`` at ``done`` while the op
-    trace is on (else 0): ``WorkerTable.wait`` reads its wake latency
-    from it."""
+    trace is on (else 0), and ``wake_ns`` how long after it the thread
+    that slept in ``wait`` ran again: ``WorkerTable.wait`` reports it.
 
-    __slots__ = ("_waiter", "result", "error", "done_ns")
+    A keyed host Get is done with its ``PendingHostRead``
+    (``takes_pending``): the waiter's own thread fetches the rows in
+    ``wait``, not the dispatcher, which has gone on to the next
+    message."""
+
+    __slots__ = ("_waiter", "result", "error", "done_ns", "wake_ns")
+    takes_pending = True
 
     def __init__(self) -> None:
         self._waiter = Waiter(1)
         self.result: Any = None
         self.error: Optional[BaseException] = None
-        self.done_ns = 0
+        self.done_ns = self.wake_ns = 0
 
     def done(self, result: Any) -> None:
         self.result = result
@@ -177,8 +184,12 @@ class Completion:
     def wait(self, timeout: Optional[float] = None) -> Any:
         if not self._waiter.wait(timeout):
             raise TimeoutError("table request timed out")
+        if self.done_ns:
+            self.wake_ns = time.perf_counter_ns() - self.done_ns
         if self.error is not None:
             raise self.error
+        # kept: a second wait finds the rows
+        self.result = PendingHostRead.fetched(self.result)
         return self.result
 
 
@@ -262,8 +273,9 @@ class WorkerTable:
             raw = completion.wait()
             if waited.id and completion.done_ns > waited.start_ns:
                 # n: how long after done() the thread that slept here ran
-                # again (0 where the result was there before the wait)
-                waited.n = time.perf_counter_ns() - completion.done_ns
+                # again (0 where the result was there before the wait);
+                # a host Get's fetch, made in the wait, is not in it
+                waited.n = completion.wake_ns
         if raw is None:
             return None
         return self.process_reply_get(raw, request)
@@ -385,9 +397,14 @@ class ServerTable:
         """Device->host read of table state. Under a multi-process mesh the
         array is globally sharded and not fully addressable from one
         controller, so route through a replicating jit first (an XLA
-        allgather — collective, which is safe here because every host-read
-        site runs on the lockstep dispatcher/replay thread). Single-process
-        meshes skip straight to ``device_get``."""
+        allgather — collective, which is safe there because under
+        ``multihost`` every host-read site runs on the lockstep
+        dispatcher/replay thread: ``_host_read_behind`` hands nothing
+        over). Single-process meshes skip straight to ``device_get``, on
+        the dispatcher for a whole-table or sparse read and a checkpoint,
+        and for a keyed Get on whichever thread resolves its
+        ``PendingHostRead``: the reply's finishing thread or the
+        in-process waiter's own."""
         import jax
         import numpy as np
         from multiverso_tpu.runtime.zoo import Zoo
@@ -403,6 +420,16 @@ class ServerTable:
             out = np.asarray(jax.device_get(arr))
             read.n = out.nbytes
         return out
+
+    def _host_read_behind(self, arr, index) -> Any:
+        """A keyed Get's ``_host_read(arr)[index]``, left for whoever
+        finishes the Get: the copy to the host is started here, on the
+        dispatcher at launch, and a ``PendingHostRead`` returned. Under a
+        multi-process mesh the read is a collective and is made here."""
+        if Zoo.instance().multihost is not None:
+            return self._host_read(arr)[index]
+        arr.copy_to_host_async()
+        return PendingHostRead(self._host_read, arr, index)
 
     def merge_add_requests(self, requests):
         """Fuse a PREFIX of a drained group of Add requests into ONE
@@ -428,6 +455,13 @@ class ServerTable:
 
     def process_get(self, request: Any) -> Any:
         raise NotImplementedError
+
+    def launch_get(self, request: Any) -> Any:
+        """``process_get`` as a dispatcher calls it: the same result,
+        except that a table kind whose keyed host Get can be fetched
+        behind the dispatcher returns a ``PendingHostRead`` for one (and
+        resolves it in its own ``process_get``)."""
+        return self.process_get(request)
 
     # Serializable (checkpoint) hooks
     def store(self, stream) -> None:

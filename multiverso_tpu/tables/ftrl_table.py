@@ -90,7 +90,7 @@ import numpy as np
 from multiverso_tpu import log
 from multiverso_tpu.dashboard import Dashboard, span
 from multiverso_tpu.parallel import mesh as mesh_lib
-from multiverso_tpu.runtime.message import MsgType
+from multiverso_tpu.runtime.message import MsgType, PendingHostRead
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import ServerTable, WorkerTable
 from multiverso_tpu.tables.device_ids import (IDS_FROM, DeviceIdsServer,
@@ -381,6 +381,9 @@ class FTRLServer(DeviceIdsServer, ServerTable):
             self._keys_add.add(n)
 
     def process_get(self, request):
+        return PendingHostRead.fetched(self.launch_get(request))
+
+    def launch_get(self, request):
         with span("TABLE_PROCESS_GET"):
             keys, device_out = request
             keys, took = self._keys_of(keys, "get")
@@ -393,7 +396,8 @@ class FTRLServer(DeviceIdsServer, ServerTable):
                 # (bucket,): the weights of the keys named, then the scratch
                 # key's
                 return jax.device_put(w, self._out_device)
-            return self._host_read(w)[:len(keys)]
+            # launched; fetched by whoever finishes the Get
+            return self._host_read_behind(w, slice(len(keys)))
 
     def remote_spec(self):
         return {"kind": "ftrl", "size": self.size}

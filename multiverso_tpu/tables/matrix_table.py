@@ -45,7 +45,7 @@ import numpy as np
 from multiverso_tpu import log
 from multiverso_tpu.dashboard import Dashboard, monitor, span
 from multiverso_tpu.parallel import mesh as mesh_lib
-from multiverso_tpu.runtime.message import MsgType
+from multiverso_tpu.runtime.message import MsgType, PendingHostRead
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import (RowOccurrences, ServerTable,
                                         WorkerTable, sum_duplicate_rows)
@@ -910,6 +910,9 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         return option is not None and 0 <= option.worker_id < self.num_slots
 
     def process_get(self, request):
+        return PendingHostRead.fetched(self.launch_get(request))
+
+    def launch_get(self, request):
         with span("TABLE_PROCESS_GET"):
             return self._process_get(request)
 
@@ -941,7 +944,9 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             # rows stay in HBM: (bucket, padded_cols), slots >= n are
             # sentinel copies — the caller's compact training space
             return gathered
-        return self._host_read(gathered)[:n, : self.num_col]
+        # launched; fetched by whoever finishes the Get
+        return self._host_read_behind(
+            gathered, (slice(n), slice(self.num_col)))
 
     def _sparse_get(self, option: GetOption):
         """Return only the rows stale for this worker: (ids, rows)."""

@@ -49,10 +49,17 @@ def _run(t0, t1):
 
 
 def _served_run(t0):
-    """The window from t0 to now, closed behind the dispatcher: a waiter
-    has its result before the dispatcher has left the spans around `done`,
-    and a no-op queued behind them returns after they have ended."""
-    Zoo.instance().server.run_serialized(lambda: None)
+    """The window from t0 to now, closed behind the dispatcher and the
+    finishing thread: a waiter has its result before the thread that
+    completed it has left the spans around `done` or the send; a no-op
+    queued behind the dispatcher's returns after they have ended, and a
+    reply leaves the finishing thread's queue after its spans have."""
+    zoo = Zoo.instance()
+    zoo.server.run_serialized(lambda: None)
+    limit = time.monotonic() + 10
+    while zoo.remote_server is not None and zoo.remote_server._unfinished:
+        assert time.monotonic() < limit, "a reply was never finished"
+        time.sleep(0.001)
     return _run(t0, time.perf_counter())
 
 
@@ -157,10 +164,13 @@ def test_in_process_pair_leaves_its_stages_nested(tracing):
     _chain(trace, add, ("TABLE_PROCESS_ADD", "TABLE_ROW_LAUNCH"))
     chain = _chain(trace, get, ("SERVER_DISPATCH_MSG",
                                 "SERVER_PROCESS_GET_MSG",
-                                "TABLE_PROCESS_GET", "TABLE_HOST_READ"))
-    assert chain[-1].n >= len(IDS) * COLS * 4  # bytes of the padded bucket
+                                "TABLE_PROCESS_GET", "TABLE_ROW_LAUNCH"))
     _chain(trace, get, ("TABLE_PROCESS_GET", "TABLE_ROW_PREP"))
-    _chain(trace, get, ("TABLE_PROCESS_GET", "TABLE_ROW_LAUNCH"))
+    # the dispatcher launched the gather and went on: the fetch is the
+    # waiter's own, made in its wait after the Get's service has ended
+    read = _chain(trace, get, ("WORKER_WAIT", "TABLE_HOST_READ"))[-1]
+    assert read.n >= len(IDS) * COLS * 4  # bytes of the padded bucket
+    assert read.start_ns >= chain[1].start_ns + chain[1].dur_ns
     for op in (add, get):
         wait, = [r for r in trace.spans("SERVER_QUEUE_WAIT") if r.op == op]
         service, = [r for r in trace.spans("SERVER_DISPATCH_MSG")
@@ -172,6 +182,7 @@ def test_in_process_pair_leaves_its_stages_nested(tracing):
         waited, = [r for r in trace.spans("WORKER_WAIT") if r.op == op]
         assert 0 <= waited.n < waited.dur_ns
     assert waited.n > 0  # the Get's: woken after done, before the span's end
+    assert waited.n < read.start_ns - waited.start_ns  # the fetch not in it
     assert trace.spans("DISPATCHER_PARKED")
 
     # the readers of the cell without a wire see what is theirs
@@ -212,12 +223,33 @@ def test_served_pair_tiles_its_residence(tracing):
     add, get = sorted(requests, key=lambda q: q.op)
     _chain(trace, add.op, ("SERVER_DISPATCH_MSG", "SERVER_PROCESS_ADD_MSG",
                            "WIRE_REPLY", "NET_SEND"))
-    chain = _chain(trace, get.op, (
-        "SERVER_DISPATCH_MSG", "SERVER_PROCESS_GET_MSG", "WIRE_REPLY",
-        "WIRE_ENCODE"))
+    # a Get leaves the dispatcher launched: its reply is finished behind
+    # it, by the finishing thread, in a span that is nobody's child
+    service = _chain(trace, get.op, (
+        "SERVER_DISPATCH_MSG", "SERVER_PROCESS_GET_MSG", "TABLE_PROCESS_GET",
+        "TABLE_ROW_LAUNCH"))[1]
+    chain = _chain(trace, get.op, ("REPLY_FINISH", "WIRE_REPLY",
+                                   "WIRE_ENCODE"))
     assert chain[-1].n >= len(IDS) * COLS * 4  # the rows, encoded
-    _chain(trace, get.op, ("SERVER_PROCESS_GET_MSG", "TABLE_PROCESS_GET",
-                           "TABLE_HOST_READ"))
+    finish = chain[0]
+    assert finish.parent == 0
+    _chain(trace, get.op, ("REPLY_FINISH", "WIRE_REPLY", "NET_SEND"))
+    read = _chain(trace, get.op, ("REPLY_FINISH", "TABLE_HOST_READ"))[-1]
+    assert read.n >= len(IDS) * COLS * 4
+    assert read.start_ns + read.dur_ns <= chain[1].start_ns
+    for stage in ("TABLE_HOST_READ", "WIRE_REPLY"):  # the dispatcher has none
+        assert not [r for r in trace.spans(stage) if r.op == get.op
+                    and _inside(r, service)]
+    # the new queue: from the hand-over inside the Get's service to the
+    # start of the finish
+    handed, = [r for r in trace.spans("REPLY_FINISH_WAIT")
+               if r.op == get.op]
+    assert handed.parent == service.id
+    assert service.start_ns <= handed.start_ns \
+        <= service.start_ns + service.dur_ns
+    assert handed.start_ns + handed.dur_ns <= finish.start_ns
+    assert not [r for r in trace.spans("REPLY_FINISH_WAIT")
+                + trace.spans("REPLY_FINISH") if r.op == add.op]
     for q in requests:  # the receive thread, before the request's arrival
         arrived = min(r.start_ns for r in trace.spans("net_recv")
                       if r.op == q.op)
@@ -236,8 +268,16 @@ def test_served_pair_tiles_its_residence(tracing):
     residence = _metric("server_residence_ms", run)
     assert 0 < ingress < residence
     assert 0 < _metric("wire_reply_ms", run) < residence
-    assert 0 < _metric("table_host_read_ms", run) < residence
     assert _metric("dispatch_queue_wait_ms", run) < residence
+    assert 0 < _metric("reply_finish_ms", run) < residence
+    assert 0 <= _metric("reply_finish_wait_ms", run) < residence
+    assert _metric("replies_behind_share", run) == 100.0
+    # no blocking fetch is left inside a served Get, and the op's self
+    # time subtracts none
+    assert _metric("table_host_read_ms", run) is None
+    ops = trace.spans("TABLE_PROCESS_ADD") + trace.spans("TABLE_PROCESS_GET")
+    assert 0 < _metric("table_op_self_ms", run) \
+        < sum(r.dur_ns for r in ops) / len(ops) / 1e6
     client.close()
     mv.shutdown()
 
@@ -354,6 +394,64 @@ def test_thread_cpu_time_of_a_sleeping_span_is_far_under_its_wall_time(
     assert (sleeps.stage, sleeps.op, sleeps.n) == ("SLEEPS", 9, 1)
     assert sleeps.dur_ns >= 50e6 and sleeps.cpu_ns < sleeps.dur_ns / 10
     assert spins.cpu_ns > spins.dur_ns / 4  # a busy thread, even when shared
+
+
+# -- (g) the readers of replies finished behind the dispatcher, by hand -------
+
+MS = 1_000_000
+
+
+def _made_up(rows):
+    """A reader's run over records written by hand: (id, parent, stage,
+    start_ns, dur_ns, op) each."""
+    records = [dashboard.OpRecord._make((seq, i, parent, stage, start, dur,
+                                         0, op))
+               for seq, (i, parent, stage, start, dur, op) in enumerate(rows)]
+    return SimpleNamespace(_op_trace=op_trace.Trace(records, 0, 100 * MS))
+
+
+def _served_get(op, at, finish_ms, wait_ms=None):
+    """A served Get's records: its table op, the hand-over's wait (None:
+    the dispatcher finished the reply itself), the finish and the stamp."""
+    rows = [(op * 10, 0, "TABLE_PROCESS_GET", at, MS, op)]
+    begun = at + MS
+    if wait_ms is not None:
+        rows.append((0, op * 10, "REPLY_FINISH_WAIT", begun,
+                     int(wait_ms * MS), op))
+        begun += int(wait_ms * MS)
+    if finish_ms is not None:
+        rows.append((op * 10 + 1, 0, "REPLY_FINISH", begun,
+                     int(finish_ms * MS), op))
+    rows.append((0, op * 10 + 1, "reply_sent", begun + MS // 2, 0, op))
+    return rows
+
+
+REPLY_TRACES = {
+    # two Gets handed over, one the dispatcher finished (the queue was
+    # full), an Add's reply, and an in-process Get nobody replies to
+    "behind": (_served_get(11, 0, 1.0, wait_ms=0.2)
+               + _served_get(12, 10 * MS, 2.0, wait_ms=0.4)
+               + _served_get(13, 20 * MS, 3.0)
+               + [(0, 0, "reply_sent", 30 * MS, 0, 14),
+                  (150, 0, "TABLE_PROCESS_GET", 40 * MS, MS, 15)]),
+    # the parent's program: a served Get has neither span
+    "parent": _served_get(11, 0, None) + _served_get(12, 10 * MS, None),
+    # an in-process cell: nothing is served over the wire
+    "in_process": [(150, 0, "TABLE_PROCESS_GET", 0, MS, 15)],
+}
+
+
+@pytest.mark.parametrize("name, expected", [
+    ("reply_finish_ms", {"behind": 2.0, "parent": None, "in_process": None}),
+    ("reply_finish_wait_ms", {"behind": 0.3, "parent": None,
+                              "in_process": None}),
+    ("replies_behind_share", {"behind": 200 / 3, "parent": 0.0,
+                              "in_process": None}),
+])
+def test_reply_finish_readers_on_a_made_up_trace(name, expected):
+    for which, want in expected.items():
+        got = _metric(name, _made_up(REPLY_TRACES[which]))
+        assert got == (want if want is None else pytest.approx(want)), which
 
 
 # -- hops: a point in the ring, the TraceStore as it was --------------------------------

@@ -7,12 +7,17 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
 
 import multiverso_tpu as mv
+from multiverso_tpu.dashboard import RING, Dashboard
+from multiverso_tpu.runtime import remote as remote_mod
 from multiverso_tpu.runtime import wire
+from multiverso_tpu.runtime.message import MsgType, PendingHostRead
+from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.updaters import AddOption, GetOption
 
 
@@ -554,3 +559,301 @@ def test_quant_duplicate_ids_preaggregated_before_error_feedback():
     finally:
         mv.shutdown()
         mv.set_flag("wire_quant_bits", 0)
+
+
+# -- a served Get's reply finished behind the dispatcher ---------------------
+#
+# The dispatcher launches a keyed Get's gather and starts its copy to the
+# host; the RemoteServer's finishing thread waits for the rows, encodes and
+# sends (``RemoteServer.finish_reply``). The tests hold a fetch back with an
+# event to see what may and may not pass it.
+
+FIN_ROWS, FIN_COLS = 64, 128
+FIN_IDS = np.array([3, 9, 33, 60], np.int32)
+FIN_ONES = np.ones((len(FIN_IDS), FIN_COLS), np.float32)
+FIN_ZEROS = np.zeros_like(FIN_ONES)
+
+
+def _finish_served(workers=1, **flags):
+    """A zeroed matrix table served to ``workers`` clients: (server table's
+    worker, the RemoteServer, [(client, its table proxy), ...])."""
+    mv.init(remote_workers=workers, **flags)
+    table = mv.create_table("matrix", FIN_ROWS, FIN_COLS, np.float32)
+    endpoint = mv.serve("127.0.0.1:0")
+    clients = [mv.remote_connect(endpoint) for _ in range(workers)]
+    return table, Zoo.instance().remote_server, [
+        (c, c.table(table.table_id)) for c in clients]
+
+
+def _hold_fetches(monkeypatch, first_only=False, error=None):
+    """Hold every (or the first) ``PendingHostRead.resolve`` until the
+    returned event is set, then fetch as usual, or raise ``error``."""
+    gate, calls = threading.Event(), []
+    fetch = PendingHostRead.resolve
+
+    def held(self):
+        calls.append(threading.current_thread().name)
+        if not (first_only and len(calls) > 1):
+            assert gate.wait(30), "the test never released the fetch"
+        if error is not None:
+            raise error
+        return fetch(self)
+
+    monkeypatch.setattr(PendingHostRead, "resolve", held)
+    gate.calls = calls
+    return gate
+
+
+def _until(condition, what, seconds=10.0):
+    limit = time.monotonic() + seconds
+    while not condition():
+        assert time.monotonic() < limit, f"never saw: {what}"
+        time.sleep(0.002)
+
+
+def _within(fn, seconds=20.0):
+    """``fn()``'s result, from a thread of its own so that a waiter that
+    hangs fails the test instead of stopping it."""
+    box = {}
+
+    def run():
+        try:
+            box["result"] = fn()
+        except BaseException as exc:  # noqa: BLE001 — handed to the test
+            box["error"] = exc
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), "a waiter hung"
+    if "error" in box:
+        raise box["error"]
+    return box["result"]
+
+
+def test_held_back_get_has_the_rows_at_its_service(monkeypatch):
+    """Guarantees 1 and 2: the gather is launched by the dispatcher in its
+    order, so a Get whose fetch is held back past a later Add (another
+    client's, acknowledged meanwhile) still answers with the rows as they
+    were at its service, and every later Get of any worker has the Add."""
+    _, rs, ((c1, t1), (c2, t2)) = _finish_served(workers=2)
+    gate = _hold_fetches(monkeypatch)
+    get = t1.get_async(FIN_IDS)
+    _until(lambda: len(rs._unfinished) == 1, "the Get handed over")
+    _within(lambda: t2.add(FIN_ONES, row_ids=FIN_IDS))  # acknowledged
+    assert not gate.is_set() and len(rs._unfinished) == 1
+    later = t2.get_async(FIN_IDS)
+    gate.set()
+    np.testing.assert_array_equal(
+        _within(lambda: t1.wait_get(get, FIN_IDS)), FIN_ZEROS)
+    np.testing.assert_array_equal(
+        _within(lambda: t2.wait_get(later, FIN_IDS)), FIN_ONES)
+    np.testing.assert_array_equal(_within(lambda: t1.get(FIN_IDS)), FIN_ONES)
+    assert set(gate.calls) == {"mv-remote-finish"}
+    assert Dashboard.counter_value("REPLIES_FINISHED_BEHIND") == 3
+    assert Dashboard.counter_value("REPLIES_FINISHED_INLINE") == 0
+    for c in (c1, c2):
+        c.close()
+    mv.shutdown()
+
+
+def test_add_ack_overtakes_an_earlier_gets_reply_on_one_connection(
+        monkeypatch):
+    """Guarantee 5: replies may leave a connection out of service order;
+    the client settles each by its ``msg_id``."""
+    _, rs, ((client, rt),) = _finish_served()
+    gate = _hold_fetches(monkeypatch)
+    get = rt.get_async(FIN_IDS)
+    add = rt.add_async(FIN_ONES, row_ids=FIN_IDS)
+    _within(lambda: rt.wait(add))  # the Add's acknowledgement is here ...
+    assert len(rs._unfinished) == 1 and not gate.is_set()  # ... the Get's not
+    with client._lock:
+        assert list(client._pending) == [get]
+    gate.set()
+    np.testing.assert_array_equal(
+        _within(lambda: rt.wait_get(get, FIN_IDS)), FIN_ZEROS)
+    np.testing.assert_array_equal(_within(lambda: rt.get(FIN_IDS)), FIN_ONES)
+    client.close()
+    mv.shutdown()
+
+
+def test_retransmitted_get_with_its_reply_pending_is_answered_once(
+        monkeypatch):
+    """Guarantee 3: the request stays ``_INFLIGHT`` in the dedup window
+    until the finishing thread stores the reply."""
+    _, rs, ((client, rt),) = _finish_served()
+    gate = _hold_fetches(monkeypatch)
+    get = rt.get_async(FIN_IDS)
+    _until(lambda: len(rs._unfinished) == 1, "the Get handed over")
+    with client._lock:
+        frame = client._inflight[get].msg
+    assert rs._dedup[frame.req_id] is remote_mod._INFLIGHT
+    client._net.send(frame)  # the client's retransmit, early
+    _until(lambda: Dashboard.counter_value("SERVER_DEDUP_HITS") == 1,
+           "the replay swallowed")
+    gate.set()
+    np.testing.assert_array_equal(
+        _within(lambda: rt.wait_get(get, FIN_IDS)), FIN_ZEROS)
+    _until(lambda: not rs._unfinished, "the reply finished")
+    assert Dashboard.watch("REPLY_FINISH").count == 1
+    assert Dashboard.watch("SERVER_PROCESS_GET_MSG").count == 1
+    assert len(gate.calls) == 1
+    assert rs._dedup[frame.req_id].type == MsgType.Reply_Get  # stored now
+    client.close()
+    mv.shutdown()
+
+
+def test_fetch_that_raises_arrives_as_the_errors_text(monkeypatch):
+    """Guarantee 6: a failed fetch becomes a Reply_Error through ``fail``,
+    and the finishing thread goes on serving."""
+    _, rs, ((client, rt),) = _finish_served()
+    with monkeypatch.context() as patched:
+        _hold_fetches(patched, error=ValueError("the fetch broke")).set()
+        with pytest.raises(RuntimeError, match="the fetch broke"):
+            _within(lambda: rt.get(FIN_IDS))
+    np.testing.assert_array_equal(_within(lambda: rt.get(FIN_IDS)),
+                                  FIN_ZEROS)
+    assert rs._finisher.is_alive()
+    client.close()
+    mv.shutdown()
+
+
+def test_stop_with_replies_pending_finishes_each_and_no_waiter_hangs(
+        monkeypatch):
+    """Guarantee 6: ``stop`` lets the finishing thread send what it holds
+    before the connections close, and hands nothing over afterwards. Under
+    a time limit of its own: a hang here is the fault it looks for."""
+    _, rs, ((c1, t1), (c2, t2)) = _finish_served(workers=2)
+    gate = _hold_fetches(monkeypatch)
+
+    def body():
+        gets = [(t, t.get_async(FIN_IDS)) for t in (t1, t2, t1)]
+        _until(lambda: len(rs._unfinished) == 3, "three Gets handed over")
+        stopper = threading.Thread(target=mv.stop_serving, daemon=True)
+        stopper.start()
+        time.sleep(0.1)
+        assert stopper.is_alive()  # waits for the replies, connections up
+        gate.set()
+        stopper.join(20)
+        assert not stopper.is_alive() and not rs._unfinished
+        for t, get in gets:
+            np.testing.assert_array_equal(t.wait_get(get, FIN_IDS),
+                                          FIN_ZEROS)
+
+    _within(body, seconds=60)
+    assert Dashboard.watch("REPLY_FINISH").count == 3
+    for c in (c1, c2):
+        c.close()
+    mv.shutdown()
+
+
+def test_under_a_multi_process_mesh_the_dispatcher_finishes_inline(
+        monkeypatch):
+    """Where ``_host_read`` is a collective it stays on the lockstep
+    thread: the table fetches at once and nothing is handed over."""
+    _, rs, ((client, rt),) = _finish_served()
+    rt.add(FIN_ONES, row_ids=FIN_IDS)
+    with monkeypatch.context() as patched:
+        patched.setattr(Zoo.instance(), "multihost", object())
+        gate = _hold_fetches(patched)  # nothing may come to wait for it
+        np.testing.assert_array_equal(_within(lambda: rt.get(FIN_IDS)),
+                                      FIN_ONES)
+        assert not gate.calls
+    assert Dashboard.counter_value("REPLIES_FINISHED_INLINE") == 1
+    assert Dashboard.counter_value("REPLIES_FINISHED_BEHIND") == 0
+    assert Dashboard.watch("REPLY_FINISH") is None \
+        or Dashboard.watch("REPLY_FINISH").count == 0
+    np.testing.assert_array_equal(_within(lambda: rt.get(FIN_IDS)), FIN_ONES)
+    assert Dashboard.counter_value("REPLIES_FINISHED_BEHIND") == 1
+    client.close()
+    mv.shutdown()
+
+
+def test_past_the_unfinished_limit_the_dispatcher_finishes_inline(
+        monkeypatch):
+    """With ``_MAX_UNFINISHED`` replies waiting for the finishing thread
+    the dispatcher finishes the next ones itself, and a counter says so."""
+    limit = remote_mod._MAX_UNFINISHED
+    _, rs, ((client, rt),) = _finish_served()
+    gate = _hold_fetches(monkeypatch, first_only=True)
+    held = [rt.get_async(FIN_IDS) for _ in range(limit)]
+    _until(lambda: len(rs._unfinished) == limit, "the queue full")
+    over = [rt.get_async(FIN_IDS) for _ in range(2)]
+    for get in over:  # answered by the dispatcher, past the held ones
+        np.testing.assert_array_equal(
+            _within(lambda: rt.wait_get(get, FIN_IDS)), FIN_ZEROS)
+    assert len(rs._unfinished) == limit and not gate.is_set()
+    assert Dashboard.counter_value("REPLIES_FINISHED_INLINE") == 2
+    assert Dashboard.counter_value("REPLIES_FINISHED_BEHIND") == limit
+    assert gate.calls[0] == "mv-remote-finish" \
+        and gate.calls.count("mv-server") == 2
+    gate.set()
+    for get in held:
+        np.testing.assert_array_equal(
+            _within(lambda: rt.wait_get(get, FIN_IDS)), FIN_ZEROS)
+    client.close()
+    mv.shutdown()
+
+
+def test_reply_watermark_is_the_one_at_the_gets_service(monkeypatch,
+                                                       tmp_path):
+    """Guarantee 4: the append watermark is read on the dispatcher at the
+    Get's service and rides with the pending result; an Add logged before
+    the reply leaves does not show in it."""
+    mv.set_flag("wal_dir", str(tmp_path / "wal"))
+    _, rs, ((client, rt),) = _finish_served()
+    rt.add(FIN_ONES, row_ids=FIN_IDS)
+    at_service = rs.append_watermark()
+    assert at_service >= 0
+    sent = []
+    send_via = rs._net.send_via
+
+    def recording(conn, msg, *args, **kwargs):
+        sent.append((msg.type, msg.watermark))
+        return send_via(conn, msg, *args, **kwargs)
+
+    monkeypatch.setattr(rs._net, "send_via", recording)
+    gate = _hold_fetches(monkeypatch)
+    get = rt.get_async(FIN_IDS)
+    _until(lambda: len(rs._unfinished) == 1, "the Get handed over")
+    _within(lambda: rt.add(FIN_ONES, row_ids=FIN_IDS))
+    assert rs.append_watermark() == at_service + 1
+    gate.set()
+    np.testing.assert_array_equal(
+        _within(lambda: rt.wait_get(get, FIN_IDS)), FIN_ONES)
+    assert sent == [(MsgType.Reply_Add, at_service + 1),
+                    (MsgType.Reply_Get, at_service)]
+    client.close()
+    mv.shutdown()
+
+
+def test_in_process_host_get_is_fetched_by_its_caller():
+    """No extra hop in process: the dispatcher launches, and the waiter's
+    own thread fetches in its wait; the rows are the device-out Get's."""
+    mv.init()
+    table = mv.create_table("matrix", FIN_ROWS, FIN_COLS, np.float32)
+    table.add(FIN_ONES, row_ids=FIN_IDS)
+    table.get(FIN_IDS)  # compile before the switch
+    mv.set_flag("profile_annotations", True)
+    Dashboard.profile_annotations = True
+    try:
+        t0 = time.perf_counter()
+        rows = table.get(FIN_IDS)
+        Zoo.instance().server.run_serialized(lambda: None)
+        records, _ = RING.window(t0, time.perf_counter())
+    finally:
+        Dashboard.profile_annotations = False
+    by_id = {r.id: r for r in records if r.id}
+    read, = [r for r in records if r.stage == "TABLE_HOST_READ"]
+    chain = []
+    while read.parent:
+        read = by_id[read.parent]
+        chain.append(read.stage)
+    # the caller's thread: under its own sync Get, in its wait
+    assert chain == ["WORKER_WAIT", "WORKER_TABLE_SYNC_GET"]
+    on_device = table.wait_device(table.get_device_async(FIN_IDS), FIN_IDS)
+    np.testing.assert_array_equal(
+        rows, np.asarray(on_device)[:len(FIN_IDS), :FIN_COLS])
+    np.testing.assert_array_equal(rows, FIN_ONES)
+    assert Dashboard.counter_value("REPLIES_FINISHED_BEHIND") == 0
+    mv.shutdown()
