@@ -72,7 +72,7 @@ class CompileClock:
 def scatter_facts(table):
     """(uses the Pallas scatter, interpret mode or None) as the table says."""
     server = table._server_table
-    return server._pallas_scatter, server._pallas_interpret
+    return server.plan.kernel, server.plan.interpret
 
 
 def zipf_setup(vocab, seed):

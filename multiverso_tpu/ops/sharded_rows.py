@@ -10,7 +10,7 @@ for one shard and mapped over the axis (``shard_map``); what crosses chips
 is named, not left to the partitioner.
 
 **Routing** happens on the chip that holds the op, the mesh's first (a
-device delta is committed there, the ``_gather_out`` contract, and a Get's
+device delta is committed there, a device Get's contract (``RowPlan._out_device``), and a Get's
 rows are wanted there). The host uploads the ids as they came, once, to that
 chip alone, and counts how many fall into each shard's range
 (:func:`shard_counts`, in ``TABLE_ROW_ROUTE``) to pick the static size of a

@@ -4,14 +4,13 @@ every table kind whose device ops launch on a padded id array shares.
 A table on one device has its caller send an op's ids up itself, at submit,
 so that the upload rides under the queue wait (PR 36), and the proxy keeps
 what its last op sent up, so that the next op that names the same ids
-launches on the array that is already there (PR 39). Both lived inside
-``MatrixWorker`` / ``MatrixServer``; the keyed FTRL table
-(``tables/ftrl_table.py``) is the second kind that needs them, so they live
-here, as two mixins:
+launches on the array that is already there (PR 39). The matrix and the
+keyed FTRL table share both, as two mixins:
 
 * ``DeviceIdsServer`` (beside ``ServerTable``): the form of the array an op's
-  ids go up in (``launch_form``), the array itself (``launch_ids``), and the
-  always-on counters of who sent a launch's ids up.
+  ids go up in (``launch_form``) and the array itself (``launch_ids``); the
+  table's row plan (``tables/row_plan.py``) launches on it and counts who
+  sent it up.
 * ``DeviceIdsWorker`` (beside ``WorkerTable``): ``_ids_at_submit``, the
   caller's half, with the kept ids.
 
@@ -74,8 +73,8 @@ class LaunchIds(NamedTuple):
     first shard's piece of the array the routed program takes
     (``ShardedRows.on_first``). ``bucket``: the op's power of two (the
     shape of a Get's result, and of a delta XLA's programs take).
-    ``counts`` and ``capacity``: the host's part of routing that Get
-    (``MatrixServer._route``), None and 0 where nothing is routed.
+    ``counts`` and ``capacity``: the host's part of routing an op over the
+    chips (``RowPlan.launch_ids``), None and 0 where nothing is routed.
     ``nbytes`` went up. ``host``: the ids named as they went up, a view of
     the uploaded host array (to read, never to write). ``counted``: the
     bucket's last slot holds the count of ids and not the sentinel (an Add
@@ -139,18 +138,8 @@ class DeviceIdsServer:
 
     def _init_device_ids(self, sentinel: int, one_device: bool) -> None:
         self._pad_id = int(sentinel)
-        # always on: which program served each row launch, by op
-        self._launch_counters = {
-            ("add", "pallas"): Dashboard.counter("ROW_LAUNCH_PALLAS_ADD"),
-            ("add", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_ADD"),
-            ("get", "pallas"): Dashboard.counter("ROW_LAUNCH_PALLAS_GET"),
-            ("get", "xla"): Dashboard.counter("ROW_LAUNCH_XLA_GET")}
-        # and whose thread had uploaded the launch's ids
-        self._ids_from = {
-            "caller": Dashboard.counter("ROW_IDS_FROM_CALLER"),
-            "dispatcher": Dashboard.counter("ROW_IDS_FROM_DISPATCHER")}
-        # of the caller's, the ops that launched on the ids their proxy
-        # had kept from its last op (`DeviceIdsWorker._ids_at_submit`)
+        # of the launches on ids their caller sent up, those on the ids the
+        # proxy had kept from its last op (`DeviceIdsWorker._ids_at_submit`)
         self.ids_kept = Dashboard.counter("ROW_IDS_KEPT")
         # an in-process device-path caller sends its ids up itself, at
         # submit (`launch_ids`), where the launch would otherwise wait for
@@ -160,18 +149,6 @@ class DeviceIdsServer:
         # `emb128x4.bulk-rows`, where the move cost 0.14-0.23 ms an op,
         # PERF.md, PR 36), so the dispatcher keeps them
         self.ids_at_submit = bool(one_device)
-
-    def _note_ids(self, launch, op: str, path: str, ids: jax.Array,
-                  ids_from: str) -> None:
-        """Count a launch by its program and by the thread that sent its
-        ids up (``IDS_FROM``); while the op trace is on the record also
-        says whether the ids had landed when the launch began."""
-        self._launch_counters[op, path].add()
-        self._ids_from[ids_from].add()
-        if launch.id:
-            launch.ids_from = ids_from
-            launch.ids_ready = int(ids.is_ready())
-        launch.path = path
 
     def launch_form(self, n: int, op: str, ensure_pad: bool = False,
                     rows: Optional[int] = None) -> Tuple[int, bool]:

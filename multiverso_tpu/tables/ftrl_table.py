@@ -19,33 +19,18 @@ program an op (``jit__ftrl_keyed_get`` / ``jit__ftrl_keyed_add`` in a trace):
 
 **Which program writes an Add back** is chosen once, at the table's
 creation, from the mesh and the platform, and by the op's bucket at each
-launch (``FTRLServer._rows_for``, which both the program launched and the
-launch's record take their answer from); the creation log line and every
-launch record's ``path`` say which. On ONE device whose platform the Pallas
-row kernels serve (compiled on ``tpu``, interpreted on ``cpu``) the rows of
-128 the keys live in are read, stepped and written back by row descriptors
-(``ops/pallas_rows.add_at_lanes``, still inside ``jit__ftrl_keyed_add``):
-the sorted keys are the kernel's scalar prefetch, a key's lane takes the
-one float32 addition ``z_old + (g - sigma * w)`` or ``n_old + g * g`` whose
-second number XLA worked out (once: a repeated key steps at the first of
-its slots, wherever a grid step's boundary falls among them), every other
-lane its bits (``path`` ``pallas``). XLA's two scatters of single floats,
-which a 3.53 GB operand prices at 11 ms each (PERF.md, Findings, PR 40 to
-PR 42), still serve a mesh of several devices (``pallas_call`` has no
-partitioning rule) and a bucket of more than ``pallas_rows.PREFETCH_SLOTS``
-keys, a step of 131,072 keys or more (``path`` ``xla``, as every Get's).
-Both write the same bits (``tests/test_ftrl_keyed.py``).
-
-**The kernel's module is loaded by the table that will launch it** and by
-nobody else: ``ops/pallas_rows`` brings ``jax.experimental.pallas``, a
-second of module code that every process importing this package would pay
-(PR 41 did; PERF.md, Findings), so nothing here imports it at the top.
-``FTRLServer.__init__`` starts the import on a thread of its own before it
-fills the state and joins it before it returns: the fill waits on the
-device a piece at a time, holding no interpreter lock, and the import runs
-under it. What this file reads of the module it reads inside functions,
-after that join. A table on a mesh of several devices, or on a platform
-the kernels do not serve, imports nothing.
+launch, by the table's row plan (``tables/row_plan.py``, which launches both
+ops and fills their records; the creation log line and every launch
+record's ``path`` say which): on ONE device whose platform the Pallas row
+kernels serve, the lane kernel (``ops/pallas_rows.add_at_lanes``, still
+inside ``jit__ftrl_keyed_add``), up to a bucket of
+``pallas_rows.PREFETCH_SLOTS`` keys; elsewhere XLA's two scatters of single
+floats, which a 3.53 GB operand prices at 11 ms each. Both write the same
+bits (``tests/test_ftrl_keyed.py``). The kernel's module brings
+``jax.experimental.pallas``, a second of module code, so nothing here
+imports it at the top: the plan loads it, for the table that will launch
+it and for nobody else, under the fill of the table's state
+(``FTRLServer._make_state``, handed to it).
 
 The keys go up padded to the op's power-of-two bucket with slots aimed at
 the scratch key ``size``, ONE form for a Get and an Add, so that a trainer's
@@ -80,7 +65,6 @@ commute: the order of acknowledgement is part of the result.
 from __future__ import annotations
 
 import functools
-import threading
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -93,28 +77,16 @@ from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.runtime.message import MsgType, PendingHostRead
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import ServerTable, WorkerTable
-from multiverso_tpu.tables.device_ids import (IDS_FROM, DeviceIdsServer,
-                                              DeviceIdsWorker, LaunchIds,
-                                              live_slots, state_of_slots)
+from multiverso_tpu.tables.device_ids import (DeviceIdsServer,
+                                              DeviceIdsWorker, live_slots,
+                                              state_of_slots)
+from multiverso_tpu.tables.row_plan import _row_kernel, row_plan
 from multiverso_tpu.utils import async_upload, next_pow2
 
 # the smallest bucket: a tile of lanes
 _MIN_BUCKET = 128
 # keys a piece of a block source's state goes up in (two float32 arrays)
 _PIECE_KEYS = mesh_lib.PIECE_BYTES // 8
-# the platforms whose ONE device the Pallas row kernel writes an Add back on
-# (`pallas_rows.interpret_for` says how), known here so that a table anywhere
-# else never loads the kernel's module
-_ROW_KERNEL_PLATFORMS = ("tpu", "cpu")
-
-
-def _row_kernel():
-    """``ops/pallas_rows``, loaded on first call: a second of module code
-    (``jax.experimental.pallas``) that only a table whose Adds the kernel
-    writes back pays, once, under the fill of its state
-    (``FTRLServer.__init__``)."""
-    from multiverso_tpu.ops import pallas_rows
-    return pallas_rows
 
 
 def ftrl_weights(z: jax.Array, n: jax.Array, alpha: float, beta: float,
@@ -227,48 +199,21 @@ class FTRLServer(DeviceIdsServer, ServerTable):
         self.padded = mesh_lib.pad_to_multiple(self.size + 1,
                                                1024 * num_shards)
         self._sharding = mesh_lib.table_sharding(self.mesh, ndim=1)
-        platform = self.mesh.devices.flat[0].platform
         # which program writes an Add back, chosen once from the mesh and
-        # the platform (a launch adds its bucket: `_rows_for`). The row
-        # kernel's module loads under the fill of the state, which waits on
-        # the device a piece at a time
-        loading = None
-        if num_shards == 1 and platform in _ROW_KERNEL_PLATFORMS:
-            loading = threading.Thread(
-                target=_row_kernel, name="ftrl-row-kernel-import",
-                daemon=True)
-            loading.start()
-        try:
-            self._make_state(init)
-        finally:
-            if loading is not None:
-                loading.join()
-        # None: XLA's scatters; else the row kernel, interpreted or compiled
-        self._rows_interpret: Optional[bool] = None
-        writes = "XLA scatter"
-        if loading is not None:
-            # here, not on the thread, a failed import raises
-            pallas_rows = _row_kernel()
-            self._rows_interpret = pallas_rows.interpret_for(platform)
-            writes = ("an Add's rows of 128 written back by the Pallas row "
-                      "kernel%s (XLA scatter past a bucket of %d keys)" % (
-                          ", interpreted" if self._rows_interpret else "",
-                          pallas_rows.PREFETCH_SLOTS))
-        self._get, self._add = _make_programs(
-            self.alpha, self.beta, self.lambda1, self.lambda2,
-            self.scratch_key)
-        # a device Get's result is committed to ONE device, the mesh's first
-        # (`MatrixServer._gather_out` has the reason)
-        from jax.sharding import SingleDeviceSharding
-        self._out_device = SingleDeviceSharding(self.mesh.devices.flat[0])
-        self._replicated = None if num_shards == 1 \
-            else mesh_lib.replicated(self.mesh, ndim=1)
+        # the platform (a launch adds its bucket), around the fill of the
+        # state, under which the row kernel's module loads
+        self.plan = row_plan(
+            self.mesh, zoo.multihost is not None,
+            keyed=_make_programs(self.alpha, self.beta, self.lambda1,
+                                 self.lambda2, self.scratch_key),
+            fill=functools.partial(self._make_state, init))
         self._init_device_ids(self.scratch_key, num_shards == 1)
         self._keys_get = Dashboard.counter("FTRL_KEYS_GET")
         self._keys_add = Dashboard.counter("FTRL_KEYS_ADD")
         log.info("FTRLTable %d keys (z, n: %d B) on %d %s device(s): keyed "
                  "Get and Add, XLA gather, %s", self.size, 8 * self.padded,
-                 num_shards, platform, writes)
+                 num_shards, self.mesh.devices.flat[0].platform,
+                 self.plan.why)
 
     def _make_state(self, source=None) -> None:
         """``z`` and ``n`` as zeros made on the device, then ``source``'s
@@ -312,48 +257,6 @@ class FTRLServer(DeviceIdsServer, ServerTable):
             log.fatal("FTRLTable.%s: key out of range [0, %d)", op, self.size)
         return keys, took
 
-    def _launch(self, op: str, keys: np.ndarray, took: Optional[LaunchIds]):
-        """TABLE_ROW_PREP of an op: its ids on their way up (the caller's,
-        or sent up here), and the slots its program works on."""
-        ids_from = IDS_FROM[took is not None]
-        with span("TABLE_ROW_PREP") as prep:
-            prep.n = len(keys)
-            if took is None:
-                took = self.launch_ids(keys, op)
-        return took, live_slots(len(keys), took.bucket), ids_from
-
-    def _rows_for(self, bucket: int) -> Optional[bool]:
-        """What writes back an Add of ``bucket`` id slots: the row kernel
-        (its interpret mode) where this table's rows are its to serve and
-        the bucket's keys fit its scalar prefetch, else None: XLA's
-        scatters."""
-        if self._rows_interpret is None or \
-                bucket > _row_kernel().PREFETCH_SLOTS:
-            return None
-        return self._rows_interpret
-
-    def _note_launch(self, launch, op: str, slots: int, took: LaunchIds,
-                     ids_from: str, pallas: bool = False) -> None:
-        """The launch's record, under the matrix table's names: ``path``,
-        the program that writes an Add back (``pallas``: the row kernel;
-        ``xla`` on every Get, and on an Add XLA's scatters serve), ``n``
-        slots launched, ``bytes`` of state moved at them (``z`` and ``n``
-        read, and written again by an Add), which is all this table holds;
-        on the kernel's path the ``descriptors`` it issues (a read and a
-        write-back of a row of ``z`` and of ``n`` for every slot of its
-        whole groups) and the ``waits`` for them."""
-        self._note_ids(launch, op, "pallas" if pallas else "xla", took.ids,
-                       ids_from)
-        launch.n = slots
-        launch.updater = "ftrl"
-        launch.bytes = launch.state_bytes = (
-            (16 if op == "add" else 8) * slots)
-        if pallas:
-            kernel = _row_kernel()
-            launch.descriptors = 4 * kernel.launched_slots(
-                slots, kernel.LANE_GROUP)
-            launch.waits = 2 * kernel.launch_waits(slots, kernel.LANE_GROUP)
-
     def process_add(self, request) -> None:
         with span("TABLE_PROCESS_ADD"):
             keys, grad = request
@@ -366,18 +269,13 @@ class FTRLServer(DeviceIdsServer, ServerTable):
             if grad.shape[0] < n:
                 log.fatal("FTRLTable.add: %d keys but %d gradient values",
                           n, grad.shape[0])
-            took, live, ids_from = self._launch("add", keys, took)
-            if not isinstance(grad, jax.Array):
-                grad = async_upload(grad[:n])
-            elif self._replicated is not None:
-                # a worker's gradient is committed to one device
-                grad = jax.device_put(grad, self._replicated)
-            rows = self._rows_for(took.bucket)
-            with span("TABLE_ROW_LAUNCH") as launch:
-                self._note_launch(launch, "add", live, took, ids_from,
-                                  rows is not None)
-                self.z, self.n = self._add(self.z, self.n, took.ids, grad,
-                                           live=live, rows=rows)
+            took, ids_from = self.plan.took_ids(self, keys, "add", took)
+            grad = (self.plan.device_delta(grad, took.bucket)
+                    if isinstance(grad, jax.Array)
+                    else async_upload(grad[:n]))
+            self.z, self.n = self.plan.launch_add(
+                (self.z, self.n), took, grad, live_slots(n, took.bucket),
+                ids_from)
             self._keys_add.add(n)
 
     def process_get(self, request):
@@ -387,15 +285,13 @@ class FTRLServer(DeviceIdsServer, ServerTable):
         with span("TABLE_PROCESS_GET"):
             keys, device_out = request
             keys, took = self._keys_of(keys, "get")
-            took, live, ids_from = self._launch("get", keys, took)
-            with span("TABLE_ROW_LAUNCH") as launch:
-                self._note_launch(launch, "get", live, took, ids_from)
-                w = self._get(self.z, self.n, took.ids, live=live)
+            # (bucket,): the weights of the keys named, then the scratch
+            # key's
+            w = self.plan.launch_get(self, (self.z, self.n), keys, took,
+                                     device_out)
             self._keys_get.add(len(keys))
             if device_out:
-                # (bucket,): the weights of the keys named, then the scratch
-                # key's
-                return jax.device_put(w, self._out_device)
+                return w
             # launched; fetched by whoever finishes the Get
             return self._host_read_behind(w, slice(len(keys)))
 

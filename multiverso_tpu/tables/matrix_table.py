@@ -11,13 +11,11 @@ compression variant (``src/table/sparse_matrix_table.cpp``).
 TPU-native re-design:
 
 * Server state is ONE row-sharded ``jax.Array`` in HBM; row Get is a jitted
-  device gather, row Add is a jitted scatter-add (linear updaters),
-  gather→apply→scatter (stateful updaters whose state is shaped like the
-  table) or, for an updater with one value of state a row
-  (``Updater.row_state``), a state step over the delta and the named rows'
-  states followed by the same scatter-add of the scaled delta — the
-  client-side per-server ``Partition`` bucketing loop is gone, XLA
-  partitions the scatter.
+  device gather, row Add a scatter-add (linear updaters), a
+  gather→apply→scatter (a state shaped like the table) or a state step in
+  front of that scatter-add (``Updater.row_state``), whichever program the
+  table's row plan chose at its creation (``tables/row_plan.py``) — the
+  client-side per-server ``Partition`` bucketing loop is gone.
 * Row-id batches are padded to power-of-two buckets aimed at a sentinel
   scratch row, so jit traces are reused across batch sizes and the MXU sees
   static shapes. The work follows the rows named, not the bucket: an Add's
@@ -49,70 +47,14 @@ from multiverso_tpu.runtime.message import MsgType, PendingHostRead
 from multiverso_tpu.runtime.zoo import Zoo
 from multiverso_tpu.tables.base import (RowOccurrences, ServerTable,
                                         WorkerTable, sum_duplicate_rows)
-from multiverso_tpu.tables.array_table import _make_whole_update
-# KeptIds and SentIds: names this module had before they moved
 from multiverso_tpu.tables.device_ids import (  # noqa: F401
-    IDS_FROM as _IDS_FROM, DeviceIdsServer, DeviceIdsWorker, KeptIds,
-    LaunchIds, SentIds, live_slots as _live_slots,
-    state_of_slots as _state_of_rows)
-from multiverso_tpu.updaters import AddOption, GetOption, SGDUpdater, Updater, get_updater
+    DeviceIdsServer, DeviceIdsWorker, LaunchIds, live_slots as _live_slots)
+# the device programs, which this module had before the plan took them
+from multiverso_tpu.tables.row_plan import (  # noqa: F401
+    _make_row_state_add, _row_gather, _row_gather_jit, _xla_scatter_add,
+    row_plan)
+from multiverso_tpu.updaters import AddOption, GetOption, get_updater
 from multiverso_tpu.utils import async_upload, next_pow2 as _next_pow2
-
-
-@functools.partial(jax.jit, static_argnames=("bucket", "cols"))
-def _device_pad(values: jax.Array, bucket: int, cols: int) -> jax.Array:
-    """(n, c) → (bucket, cols) zero-padded, entirely on device."""
-    out = jnp.zeros((bucket, cols), values.dtype)
-    return out.at[: values.shape[0], : values.shape[1]].set(values)
-
-
-def _xla_scatter_add(data: jax.Array, ids: jax.Array, deltas: jax.Array,
-                     *, sign: float = 1.0,
-                     tail_count: bool = False) -> jax.Array:
-    """XLA's scatter-add in the call shape of
-    ``pallas_rows.scatter_add_rows``: ``ids`` may be longer than ``deltas``
-    (a bucket; its tail is sliced off here) and the updater's sign is
-    applied inside the program. ``tail_count``: ``ids[-1]`` is the number
-    of leading slots that name rows; the rest add nothing, past the table's
-    end, where XLA drops an update."""
-    if sign != 1.0:
-        deltas = sign * deltas
-    slots = ids[: deltas.shape[0]]
-    if not tail_count:
-        return data.at[slots].add(deltas)
-    live = jnp.arange(slots.shape[0]) < ids[-1]
-    return data.at[jnp.where(live, slots, data.shape[0])].add(
-        jnp.where(live[:, None], deltas, 0), mode="drop")
-
-
-def _row_gather(data: jax.Array, ids: jax.Array,
-                bucket: Optional[int] = None, sentinel: int = 0,
-                live: Optional[int] = None) -> jax.Array:
-    """The table's row Get: ``(bucket, lanes)`` whose first ``live`` slots
-    are the rows ``ids[:live]`` names and whose every later slot is a copy
-    of row ``sentinel``, read once and broadcast (the gather follows the
-    ids named, not the bucket). ``ids`` come as an Add's do, ``bucket`` of
-    them (``MatrixServer.launch_ids``: one uploaded form, so that either
-    op can launch on the other's), and the slots gathered are a static
-    slice of them that XLA folds into the pass it makes over the ids
-    anyway; ``live`` None gathers every id given. Ids that fill the bucket
-    (or no bucket given) leave the gather alone. Named, like its table
-    parameter, so that the compiled module is ``jit__row_gather`` in a
-    trace and the gather a fusion over ``%data``."""
-    if live is not None:
-        ids = ids[:live]
-    rows = data[ids]
-    tail = (bucket or ids.shape[0]) - ids.shape[0]
-    if not tail:
-        return rows
-    return jnp.concatenate(
-        [rows, jnp.broadcast_to(data[sentinel], (tail, data.shape[1]))])
-
-
-# one jit for every table: the programs are keyed by shapes, bucket, live
-# slots and sentinel, and tables of one shape share them
-_row_gather_jit = jax.jit(_row_gather,
-                          static_argnames=("bucket", "sentinel", "live"))
 
 
 def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
@@ -130,35 +72,6 @@ def _use_pallas_scatter(platform: str, num_shards: int, lanes: int = 128,
     del num_shards
     from multiverso_tpu.ops.pallas_rows import fits_vmem
     return platform == "tpu" and fits_vmem(lanes, itemsize)
-
-
-def _make_row_state_add(updater: Updater, scatter, cols: int,
-                        jit: bool = True):
-    """A row Add under an updater with one value of state a row
-    (``Updater.row_state``), ``(data, states, ids, delta, worker, scalars)
-    -> (data, states)``: the named rows' states are read, stepped from the
-    delta alone and written back, and the scaled delta goes to ``scatter``,
-    the scatter-add the table's linear Adds use (the Pallas row kernel
-    where it serves the table): one device program. ``ids`` may be a bucket
-    longer than ``delta`` (the kernel's contract); a sentinel slot's zero
-    delta leaves its state as it was and adds zero. ``cols`` is the table's
-    column count, the length of a gradient row whatever the delta's width
-    or the table's lanes."""
-
-    def _row_state_add(data, states, ids, delta, worker, scalars):
-        del worker  # the state is shared
-        live = ids[: delta.shape[0]]
-        step, new = updater.row_step(
-            {k: _state_of_rows(v, live) for k, v in states.items()}, delta,
-            scalars, cols)
-        # sentinel slots may repeat: each writes back the value it read
-        states = {k: states[k].at[live].set(new[k]) for k in states}
-        return scatter(data, ids, step), states
-
-    # named so that the compiled module is ``jit__row_state_add`` in a
-    # trace: what runs in it beside the kernel is the state step
-    return jax.jit(_row_state_add, donate_argnums=(0, 1)) if jit \
-        else _row_state_add
 
 
 class _StageSlot:
@@ -219,7 +132,6 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             # before it
             self.padded_rows = mesh_lib.pad_to_multiple(self.padded_rows,
                                                         8 * num_shards)
-        self._num_shards = num_shards
         self._block_rows = self.padded_rows // num_shards
 
         if init_value is not None and not callable(init_value):
@@ -231,19 +143,20 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             else functools.partial(self._uniform_rows, init_range, seed))
 
         self.updater = get_updater(self.dtype, updater_type)
-        # one value of state a row: ``(rows,)``, lane-dense in HBM (40 MB for
-        # 10,000,000 rows; as ``(rows, 1)`` a TPU would tile it to 128 lanes
-        # a row), sharded like the table's rows, with no worker dimension
-        self._row_state = self.updater.row_state
+        # one value of state a row (`Updater.row_state`): ``(rows,)``,
+        # lane-dense in HBM (40 MB for 10,000,000 rows; as ``(rows, 1)`` a
+        # TPU would tile it to 128 lanes a row), sharded like the table's
+        # rows, with no worker dimension
+        row_state = self.updater.row_state
         worker_dim = self.num_workers if self.updater.per_worker_state else 1
         self.states: Dict[str, jax.Array] = {}
-        # a row state is whole lane tiles on every shard (`_state_of_rows`)
+        # a row state is whole lane tiles on every shard (`state_of_slots`)
         state_rows = mesh_lib.pad_to_multiple(
-            self.padded_rows, 1024 * num_shards) if self._row_state \
+            self.padded_rows, 1024 * num_shards) if row_state \
             else self.padded_rows
         for name, (shape_suffix, sdtype) in self.updater.state_spec(
                 (state_rows, self.padded_cols), self.dtype).items():
-            shape = tuple(shape_suffix) if self._row_state \
+            shape = tuple(shape_suffix) if row_state \
                 else (worker_dim,) + tuple(shape_suffix)
             # zeros made on the device: no host array of the state's size
             self.states[name] = jnp.zeros(shape, sdtype,
@@ -265,142 +178,28 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             self._up_to_date = np.zeros((self.num_slots, self.num_row), dtype=bool)
             self._std_lock = threading.Lock()
 
-        self._whole_update = self._make_whole_row_state_update() \
-            if self._row_state else _make_whole_update(self.updater)
-        self._linear = type(self.updater) in (Updater, SGDUpdater)
-        self._sign = -1.0 if isinstance(self.updater, SGDUpdater) else 1.0
-        self._gather = functools.partial(_row_gather_jit,
-                                         sentinel=self.sentinel_row)
-        # device-out gets feed WORKER-thread jits (the word2vec fast
-        # path's compact training space): committed to ONE device, the
-        # mesh's first, so those jits are single-device programs and every
-        # cross-shard collective stays on the dispatcher thread (the same
-        # decision as ArrayServer._leaf_codec; scatters re-shard on the
-        # way back in). Concurrent sharded executions from worker threads
-        # deadlock the CPU test mesh's rendezvous; on a real multi-chip
-        # mesh the effect is not measured.
-        from jax.sharding import SingleDeviceSharding
-        first_dev = self.mesh.devices.flat[0]
-        _out_dev = SingleDeviceSharding(first_dev)
-        self._gather_out = lambda data, ids, bucket, live: jax.device_put(
-            self._gather(data, ids, bucket=bucket, live=live), _out_dev)
-        platform = first_dev.platform
-        # a mesh over several processes keeps XLA's partitioned programs:
-        # the routed ones assemble their operands from this process's
-        # devices alone
-        self._pallas_scatter = _use_pallas_scatter(
-            platform, num_shards, self.padded_cols, self.dtype.itemsize
-        ) and (num_shards == 1 or zoo.multihost is None)
-        # None where XLA's scatter serves the table
-        self._pallas_interpret: Optional[bool] = None
-        # the routed row programs of a table sharded over chips, else None
-        self._shard_rows = None
-        if self._pallas_scatter:
-            from multiverso_tpu.ops import pallas_rows
-            self._pallas_interpret = pallas_rows.interpret_for(platform)
-            why = "pallas row-DMA kernel, %s" % (
-                "interpreted" if self._pallas_interpret else "compiled")
-            if num_shards > 1:
-                from multiverso_tpu.ops import sharded_rows
-                self._shard_rows = sharded_rows.programs(
-                    self.mesh, self._pallas_interpret, self._sign)
-                why += (", on every shard's block of %d rows, ids routed "
-                        "to their owners" % self._block_rows)
-                if not self._linear:
-                    why += ("; this table's %s updater takes XLA's "
-                            "partitioned row update" % self.updater.name)
-        else:
-            why = "XLA scatter (%s)" % (
-                "the kernel compiles for tpu only" if platform != "tpu"
-                else "the mesh spans processes" if num_shards > 1
-                and zoo.multihost is not None
-                else "a row group of %d lanes is past the kernel's VMEM"
-                % self.padded_cols)
-        if self._pallas_scatter and num_shards == 1:
-            # unique-id contract: see process_add
-            self._scatter_add_raw = functools.partial(
-                pallas_rows.scatter_add_rows,
-                interpret=self._pallas_interpret, sign=self._sign)
-            self._scatter_add = self._scatter_add_raw
-        else:
-            # what a caller's fused jit embeds (`row_apply_traceable`) and,
-            # where no kernel serves the table, its row Adds
-            self._scatter_add_raw = functools.partial(
-                _xla_scatter_add, sign=self._sign)
-            self._scatter_add = jax.jit(self._scatter_add_raw,
-                                        donate_argnums=(0,),
-                                        static_argnames=("tail_count",))
-        # the table rows of an Add go through the row kernel: a linear
-        # updater's delta, or a row-state updater's scaled delta (on a table
-        # sharded over chips the first is routed, the second takes XLA's
-        # partitioned programs)
-        self._kernel_rows = (self._pallas_scatter and num_shards == 1
-                             and (self._linear or self._row_state))
-        if self.states and num_shards == 1:
-            why += "; %s updater: %s" % (self.updater.name, (
-                "state step, then that scatter-add of the scaled delta"
-                if self._row_state else "XLA's row update"))
+        # which device program serves a row Add and a row Get, chosen once;
+        # the op methods ask it. After the upload: the gate imports Pallas
+        self.plan = row_plan(
+            self.mesh, zoo.multihost is not None, dtype=self.dtype,
+            lanes=self.padded_cols, updater=self.updater, cols=self.num_col,
+            padded_rows=self.padded_rows, sentinel=self.sentinel_row)
         log.info("MatrixTable %dx%d on %d %s device(s): row scatter = %s",
-                 self.num_row, self.num_col, num_shards, platform, why)
-        # of the Add launches, those under a stateful updater
-        self._stateful_launches = {
-            "pallas": Dashboard.counter("ROW_LAUNCH_PALLAS_STATEFUL_ADD"),
-            "xla": Dashboard.counter("ROW_LAUNCH_XLA_STATEFUL_ADD")}
-        # the ids' way up and the launch counters (`tables/device_ids.py`)
+                 self.num_row, self.num_col, num_shards,
+                 self.mesh.devices.flat[0].platform, self.plan.why)
+        # the ids' way up (`tables/device_ids.py`)
         self._init_device_ids(self.sentinel_row, num_shards == 1)
-        # bytes of state a launch reads for one id slot (and writes again)
-        self._state_slot_bytes = sum(
-            np.dtype(v.dtype).itemsize
-            * (1 if self._row_state else self.padded_cols)
-            for v in self.states.values())
         self._duplicates_summed = Dashboard.counter(
             "ROW_ADD_DUPLICATES_SUMMED")
         self._stage_waits = Dashboard.counter("ROW_STAGE_WAITS")
         self._stage = _StageSlot(self.padded_cols, self.dtype)
-        self._row_update = self._make_row_update(self.updater)
 
     def _state_sharding(self):
         """Sharding of an updater state: its row dimension split like the
         table's rows."""
-        if self._row_state:
+        if self.updater.row_state:
             return mesh_lib.table_sharding(self.mesh, ndim=1, shard_dim=0)
         return mesh_lib.table_sharding(self.mesh, ndim=3, shard_dim=1)
-
-    def _make_whole_row_state_update(self):
-        """The whole-table Add under a row-state updater: every row is
-        named, the delta's lanes past the table's columns are zeros."""
-        updater, cols, rows = self.updater, self.num_col, self.padded_rows
-
-        def f(data, states, delta, worker, scalars):
-            del worker  # the state is shared
-            step, new = updater.row_step(
-                {k: v[:rows] for k, v in states.items()}, delta, scalars,
-                cols)
-            return data + step, {k: states[k].at[:rows].set(new[k])
-                                 for k in states}
-
-        return jax.jit(f, donate_argnums=(0, 1))
-
-    def _make_row_update(self, updater: Updater, jit: bool = True):
-        if updater.row_state:
-            return _make_row_state_add(updater, self._scatter_add_raw,
-                                       self.num_col, jit)
-
-        def f(data, states, ids, delta, worker, scalars):
-            rows = data[ids]
-            if updater.per_worker_state:
-                sliced = {k: v[worker, ids] for k, v in states.items()}
-            else:
-                sliced = {k: v[0, ids] for k, v in states.items()}
-            new_rows, new_sliced = updater.apply(rows, sliced, delta, scalars)
-            data = data.at[ids].set(new_rows)
-            if updater.per_worker_state:
-                new_states = {k: states[k].at[worker, ids].set(new_sliced[k]) for k in states}
-            else:
-                new_states = {k: states[k].at[0, ids].set(new_sliced[k]) for k in states}
-            return data, new_states
-
-        return jax.jit(f, donate_argnums=(0, 1)) if jit else f
 
     def row_apply_traceable(self):
         """The per-row update as a TRACEABLE function
@@ -411,14 +210,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         update (a row-state updater its state step and the scatter-add).
         ``ids`` must be unique apart from sentinel pads with zero
         deltas."""
-        if self._linear:
-            scatter = self._scatter_add_raw
-
-            def apply_linear(data, states, ids, delta, worker, scalars):
-                return scatter(data, ids, delta), states
-
-            return apply_linear
-        return self._make_row_update(self.updater, jit=False)
+        return self.plan.row_apply
 
     # -- helpers -----------------------------------------------------------
     def _put_rows(self, rows=None) -> jax.Array:
@@ -458,64 +250,6 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         return rng.uniform(*init_range,
                            size=(n, self.num_col)).astype(self.dtype)
 
-    def _note_launch(self, launch, op: str, slots: int, pallas: bool,
-                     ids: jax.Array, ids_from: str = "dispatcher",
-                     segments=None, exchanged_cols: int = 0,
-                     waits: Optional[int] = None) -> None:
-        """What a row launch did, on its TABLE_ROW_LAUNCH record and the
-        always-on counters: ``n`` id slots (an Add's row groups, the slots
-        a Get gathers: not the bucket), the program that served them
-        (``pallas`` or ``xla``), the DMA descriptors the kernel issues for
-        them (a read and a write a slot for an Add; XLA's programs issue
-        their own, not counted: 0), the semaphore waits it issues for them
-        (two a row group, so ``descriptors / waits`` reads the kernel's
-        group; ``waits`` where the caller counted them: a shard's last
-        group waits a slot) and the bytes of table rows moved, at
-        the table's lane width. On a table sharded over chips ``slots`` is
-        the sum over the shards and ``segments`` is ``(each shard's slots,
-        a segment's capacity)``: the record also carries the number of
-        shards, the fullest one's slots and the bytes of rows that crossed
-        chips: the segments of every shard but the first,
-        ``exchanged_cols`` wide. ``ids`` is the launch's uploaded id array
-        and ``ids_from`` the thread that sent it up (``_IDS_FROM``):
-        counted always, and while the op trace is on the record says
-        whether the ids had landed when the launch began."""
-        path = "pallas" if pallas else "xla"
-        self._note_ids(launch, op, path, ids, ids_from)
-        moves = 2 if op == "add" else 1
-        launch.n = slots
-        launch.descriptors = moves * slots if pallas else 0
-        if pallas:
-            from multiverso_tpu.ops.pallas_rows import launch_waits
-            launch.waits = launch_waits(slots) if waits is None else waits
-        launch.bytes = (moves * slots * self.padded_cols
-                        * self.dtype.itemsize)
-        if op == "add" and self.states:
-            # a stateful Add: whose rule, and the state it read and wrote
-            self._stateful_launches[path].add()
-            launch.updater = self.updater.name
-            launch.state_rows = slots
-            launch.state_bytes = 2 * slots * self._state_slot_bytes
-        if segments is not None:
-            by_shard, capacity = segments
-            launch.shards = len(by_shard)
-            launch.max_shard_n = int(by_shard.max())
-            launch.exchange_bytes = ((len(by_shard) - 1) * capacity
-                                     * exchanged_cols * self.dtype.itemsize)
-
-    def _route(self, row_ids: np.ndarray):
-        """The host's part of routing an op over the shards: how many of
-        its ids each shard owns, and the slots of a shard's segment that
-        the fullest one needs. The chip that holds the ids does the rest
-        (``ops/sharded_rows``)."""
-        from multiverso_tpu.ops import sharded_rows
-        with span("TABLE_ROW_ROUTE") as routing:
-            routing.n = len(row_ids)
-            counts = sharded_rows.shard_counts(row_ids, self._block_rows,
-                                               self._num_shards)
-            return counts, sharded_rows.shard_capacity(
-                int(counts.max()), len(row_ids), self._num_shards)
-
     def _get_bucket(self, n: int, ensure_pad: bool) -> int:
         """The power-of-two bucket of a Get's result, so a caller's jit over
         it is shape-stable; ``ensure_pad`` keeps at least one sentinel slot
@@ -523,29 +257,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         compact training space; its masked ops need a guaranteed non-live
         row)."""
         # min bucket = pallas ROW_GROUP (batch must be a group multiple)
-        from multiverso_tpu.ops.pallas_rows import ROW_GROUP
-        return max(_next_pow2(n + 1 if ensure_pad else n), ROW_GROUP)
-
-    def launch_ids(self, row_ids: np.ndarray, op: str,
-                   ensure_pad: bool = False, offsets=None,
-                   rows: Optional[int] = None) -> LaunchIds:
-        """``DeviceIdsServer.launch_ids``, and the one case it does not
-        know: a Get's ids on a table sharded over chips go to the mesh's
-        first chip, the ``_live_slots`` it gathers alone, with the host's
-        count of them by shard (``_route``), padded with ids past the
-        table, which no shard owns, and the sentinel last (the tail of the
-        result is its row's value, wherever its shard put it); a linear
-        Add's there are routed with its delta (``_route_add``), not
-        here."""
-        if op != "get" or self._shard_rows is None:
-            return super().launch_ids(row_ids, op, ensure_pad, offsets, rows)
-        n = len(row_ids)
-        bucket, _ = self.launch_form(n, op, ensure_pad, rows)
-        ids = self._padded_ids(row_ids, _live_slots(n, bucket), offsets,
-                               self.padded_rows, self.sentinel_row)
-        counts, capacity = self._route(ids)
-        return LaunchIds(self._shard_rows.on_first(ids), bucket, counts,
-                         capacity, ids.nbytes, ids[:n])
+        return max(_next_pow2(n + 1 if ensure_pad else n), self.plan.group)
 
     def _staging(self, bucket: int) -> _StageSlot:
         """The slot a row Add's padded ids and values are written into and
@@ -574,33 +286,11 @@ class MatrixServer(DeviceIdsServer, ServerTable):
     def _gather_rows(self, row_ids: np.ndarray, device_out: bool = False,
                      took: Optional[LaunchIds] = None) -> jax.Array:
         """The rows ``row_ids`` names as ``(bucket, padded_cols)`` on the
-        device, the slots past them copies of the sentinel row: from the
-        one program of a table on one chip (or XLA's partitioned one), or
-        from every shard's gather of the rows it owns, sent to the mesh's
-        first device and put back in the order asked. ``device_out``
-        results are committed to that device either way. ``took``: the
-        ids as the caller sent them up at submit; without it they go up
-        here."""
-        ids_from = _IDS_FROM[took is not None]
-        with span("TABLE_ROW_PREP") as prep:
-            prep.n = len(row_ids)
-            if took is None:
-                took = self.launch_ids(row_ids, "get", ensure_pad=device_out)
-        with span("TABLE_ROW_LAUNCH") as launch:
-            if took.counts is None:
-                # the slots gathered, not the bucket the result fills (nor
-                # the bucket of ids that went up)
-                live = _live_slots(len(took.host), took.bucket)
-                self._note_launch(launch, "get", live, False, took.ids,
-                                  ids_from)
-                return (self._gather_out if device_out else self._gather)(
-                    self.data, took.ids, bucket=took.bucket, live=live)
-            by_shard = np.full(self._num_shards, took.capacity)
-            self._note_launch(launch, "get", int(by_shard.sum()), False,
-                              took.ids, ids_from,
-                              (by_shard, took.capacity), self.padded_cols)
-            return self._shard_rows.get(self.data, took.ids, took.capacity,
-                                        took.bucket)
+        device (``device_out``: the mesh's first), the slots past them
+        copies of the sentinel row. ``took``: the ids as the caller sent
+        them up at submit; without it they go up here."""
+        return self.plan.launch_get(self, (self.data, self.states), row_ids,
+                                    took, device_out)
 
     # -- server ops --------------------------------------------------------
     def merge_add_requests(self, requests):
@@ -612,10 +302,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         uploads, and sums the rows two requests both name on the way,
         exactly when the apply path requires unique ids (the pallas
         in-place row-DMA kernel and stateful updaters; XLA's scatter-add
-        handles duplicates natively). Concatenating the values here as well
-        was 1.5 MB copied to be copied again: with the merge it fed, 4.7 ms
-        of the one dispatcher thread a fused apply of three requests on the
-        chip's host (PERF.md, Findings, PR 31, has what it costs now).
+        handles duplicates natively).
         Linear updaters only — a stateful updater (momentum/adagrad)
         applied once to a summed delta is a different operator than N
         sequential applies. Whole-table, device-resident, and transact
@@ -624,7 +311,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         next call). The ``apply_batch_rows`` flag bounds the fused row
         count so the power-of-two id bucket (and its zero-padded upload)
         cannot blow up under backlog."""
-        if not self._linear:
+        if not self.plan.merge:
             return None
         from multiverso_tpu import config as config_mod
         rows_cap = int(config_mod.get_flag("apply_batch_rows"))
@@ -678,7 +365,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             delta = np.zeros((self.padded_rows, self.padded_cols), dtype=self.dtype)
             delta[: self.num_row, : self.num_col] = np.asarray(
                 values, dtype=self.dtype).reshape(self.num_row, self.num_col)
-            self.data, self.states = self._whole_update(
+            self.data, self.states = self.plan.whole_update(
                 self.data, self.states, async_upload(delta), worker,
                 scalars)
             touched: Optional[np.ndarray] = None
@@ -692,144 +379,66 @@ class MatrixServer(DeviceIdsServer, ServerTable):
                 total = sum(len(piece) for piece in pieces)
                 if len(row_ids) != total:
                     log.fatal("Matrix.add: %d ids but %d value rows", len(row_ids), total)
-                # unique ids: required by stateful updaters (one apply per
-                # row) and by the pallas scatter kernel's in-place row DMA
-                # contract; XLA's scatter-add handles duplicates natively,
-                # so the linear non-pallas path copies a fused group's
-                # rows in arrival order and sorts nothing
-                found = None if self._linear and not self._pallas_scatter \
-                    else RowOccurrences(row_ids)
+                # where the plan needs distinct ids; otherwise a fused
+                # group's rows are copied in arrival order, nothing sorted
+                found = RowOccurrences(row_ids) if self.plan.unique_ids \
+                    else None
                 prep.n = n = total if found is None else found.n
                 if n < total:
                     prep.dups = total - n
                     self._duplicates_summed.add(total - n)
-                routed = self._linear and self._shard_rows is not None
-                if routed:
-                    # the delta goes up to the first chip and takes a device
-                    # delta's route from there
-                    rows = np.empty((n, self.num_col), self.dtype)
-                else:
-                    bucket = self._get_bucket(n, False)
-                    slot = self._staging(bucket)
-                    # what an earlier, longer Add left past these rows
-                    slot.vals[n:slot.rows, : self.num_col] = 0
-                    slot.rows = n
-                    rows = slot.vals[:n, : self.num_col]
-                row_ids = sum_duplicate_rows(row_ids, pieces, found, rows)
-                if routed:
-                    routed = self._route_add(row_ids, rows)
-                else:
-                    slot.ids[:n] = row_ids
-                    slot.ids[n:bucket] = self.sentinel_row
-                    # one call: each costs the host a quarter of a
-                    # millisecond whatever it carries
-                    ids_p, vals_p = async_upload((slot.ids[:bucket],
-                                                  slot.vals[:bucket]))
-            with span("TABLE_ROW_LAUNCH") as launch:
-                if routed:
-                    self._launch_routed_add(launch, *routed)
-                else:
-                    self._note_launch(launch, "add", ids_p.shape[0],
-                                      self._kernel_rows, ids_p)
-                    if self._linear:
-                        self.data = self._scatter_add(self.data, ids_p, vals_p)
-                    else:
-                        self.data, self.states = self._row_update(
-                            self.data, self.states, ids_p, vals_p, worker,
-                            scalars)
-                    slot.read = True
+                bucket = self._get_bucket(n, False)
+                slot = self._staging(bucket)
+                # what an earlier, longer Add left past these rows
+                slot.vals[n:slot.rows, : self.num_col] = 0
+                slot.rows = n
+                row_ids = sum_duplicate_rows(row_ids, pieces, found,
+                                             slot.vals[:n, : self.num_col])
+                slot.ids[:n] = row_ids
+                slot.ids[n:bucket] = self.sentinel_row
+                took, delta = self.plan.host_operands(
+                    self, slot.ids, slot.vals, n, bucket)
+            # the whole bucket went up, and the program walks it
+            self.data, self.states = self.plan.launch_add(
+                (self.data, self.states), took, delta, bucket, "dispatcher",
+                worker, scalars)
+            slot.read = True
             touched = row_ids
+        self._stale(touched)
+
+    def _stale(self, ids: Optional[np.ndarray]) -> None:
+        """Rows ``ids`` changed (None: every row; an id past the table, a
+        pad slot's, names none): no worker's copy of them is fresh."""
         if self.is_sparse:
             with self._std_lock:
-                if touched is None:
-                    self._up_to_date[:, :] = False
-                else:
-                    self._up_to_date[:, touched] = False
-
-    def _bucket_delta(self, values: jax.Array, bucket: int) -> jax.Array:
-        """A device delta as XLA's programs take it: zero-padded to the id
-        bucket and the table's lanes, on the table's devices. Worker-thread
-        kernels hand deltas back committed to ONE device (the gather_out
-        contract); re-shard here — on the dispatcher thread, where
-        cross-shard collectives are legal — or the jit would reject the
-        mixed device sets."""
-        return jax.device_put(
-            _device_pad(values.astype(self.dtype), bucket, self.padded_cols),
-            mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
+                self._up_to_date[:, slice(None) if ids is None
+                                 else ids[ids < self.num_row]] = False
 
     def _process_add_device(self, row_ids, values, worker, scalars) -> None:
         """A device Add, launched on the ids its caller sent up at submit
         (``SentIds``); ids that come without go up here. A delta of more
-        rows than the op has ids (a caller's buffer of one shape for every
-        count of rows: ``MatrixWorker.add_device_async``) is applied as far
-        as the ids go, by the one program of that shape."""
-        routed = self._linear and self._shard_rows is not None
-        with span("TABLE_ROW_PREP") as prep:
-            took = getattr(row_ids, "took", None)
-            ids_from = _IDS_FROM[took is not None]
-            row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
-            prep.n = n = len(row_ids)
-            longer = values.shape[0] > n
-            if values.shape[0] < n:
-                log.fatal("Matrix.add(device): %d ids but %d value rows",
-                          n, values.shape[0])
-            if longer and (routed or not self._linear):
-                log.fatal("Matrix.add(device): %d ids but %d value rows: a "
-                          "delta longer than its ids is served under "
-                          "default / sgd, and not where the Add is routed "
-                          "to the row kernels of several chips", n,
-                          values.shape[0])
-            if routed:
-                routed = self._route_add(row_ids, values)
-            elif took is None:
-                took = self.launch_ids(row_ids, "add", rows=values.shape[0])
-        with span("TABLE_ROW_LAUNCH") as launch:
-            if routed:
-                self._launch_routed_add(launch, *routed)
-            else:
-                from multiverso_tpu.ops.pallas_rows import launched_slots
-                # the pallas kernel takes the delta as it came and walks the
-                # row groups of the rows named, not the bucket's: one device
-                # program an Add
-                pallas = self._kernel_rows
-                if not pallas:
-                    values = self._bucket_delta(values, took.bucket)
-                self._note_launch(launch, "add", launched_slots(n), pallas,
-                                  took.ids, ids_from)
-                if self._linear:
-                    self.data = self._scatter_add(self.data, took.ids,
-                                                  values, tail_count=longer)
-                else:
-                    self.data, self.states = self._row_update(
-                        self.data, self.states, took.ids, values, worker,
-                        scalars)
-        if self.is_sparse:
-            with self._std_lock:
-                live = row_ids[row_ids < self.num_row]
-                self._up_to_date[:, live] = False
-
-    def _route_add(self, row_ids: np.ndarray, values):
-        """The operands of a linear Add on a table sharded over chips:
-        ``(counts by shard, a segment's capacity, ids, delta)``, the last
-        two on the mesh's first chip (``ShardedRows.on_first``: a host
-        delta goes up to it, a worker's device delta is there)."""
-        return (*self._route(row_ids), self._shard_rows.on_first(row_ids),
-                self._shard_rows.on_first(values))
-
-    def _launch_routed_add(self, launch, counts, capacity, ids,
-                           delta) -> None:
-        """That Add, one device program: on the chip that holds the delta
-        its rows are put in shard order and each shard is sent its own,
-        then every shard's kernel walks the rows it owns."""
-        from multiverso_tpu.ops.sharded_rows import (launch_waits,
-                                                     launched_slots)
-        by_shard = launched_slots(counts)
-        self._note_launch(launch, "add", int(by_shard.sum()), True, ids,
-                          segments=(by_shard, capacity),
-                          exchanged_cols=delta.shape[1],
-                          waits=launch_waits(counts))
-        self.data = self._shard_rows.add(self.data, ids, delta,
-                                         capacity=capacity)
+        rows than ids (a caller's buffer of one shape for every count of
+        rows: ``MatrixWorker.add_device_async``) is applied as far as the
+        ids go, by the one program of that shape."""
+        took = getattr(row_ids, "took", None)
+        row_ids = np.asarray(row_ids, dtype=np.int32).reshape(-1)
+        n, rows = len(row_ids), values.shape[0]
+        if rows < n:
+            log.fatal("Matrix.add(device): %d ids but %d value rows", n, rows)
+        if rows > n and not self.plan.longer_delta:
+            log.fatal("Matrix.add(device): %d ids but %d value rows: a "
+                      "delta longer than its ids is served under default / "
+                      "sgd, and not where the Add is routed to the row "
+                      "kernels of several chips", n, rows)
+        took, ids_from = self.plan.took_ids(self, row_ids, "add", took,
+                                            rows=rows)
+        # the program walks the row groups of the rows named, not the
+        # bucket's: one device program an Add
+        self.data, self.states = self.plan.launch_add(
+            (self.data, self.states), took,
+            self.plan.device_delta(values, took.bucket),
+            self.plan.launched(n), ids_from, worker, scalars)
+        self._stale(row_ids)
 
     def _check_row_range(self, row_ids: np.ndarray, op: str) -> None:
         """Host-path ids must be in [0, num_row). Worker proxies already
@@ -896,9 +505,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             t.data, t.states = d, s
         for t, ids in zip(tables, touched or [None] * len(tables)):
             if getattr(t, "is_sparse", False) and ids is not None:
-                with t._std_lock:
-                    live = ids[ids < t.num_row]
-                    t._up_to_date[:, live] = False
+                t._stale(ids)
         return extra
 
     def _is_worker(self, option) -> bool:
@@ -974,7 +581,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
     def _state_logical(self):
         """The index of a state array's logical part (padding is a function
         of the restoring mesh, not checkpoint content)."""
-        if self._row_state:
+        if self.updater.row_state:
             return slice(0, self.num_row)
         return (slice(None), slice(0, self.num_row), slice(0, self.num_col))
 
@@ -997,7 +604,8 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             got = loaded.get(name)
             if got is None:
                 continue  # v1 checkpoint: that state resets (pre-v2 behavior)
-            if not self._row_state and got.shape[0] != cur.shape[0]:
+            if not self.updater.row_state \
+                    and got.shape[0] != cur.shape[0]:
                 # per-worker state from a world with a different worker
                 # count: elastic restarts keep working — reset like v1
                 log.info("checkpoint: %s worker dim %d != %d; resetting "
@@ -1007,14 +615,12 @@ class MatrixServer(DeviceIdsServer, ServerTable):
             full = np.zeros(cur.shape, np.dtype(cur.dtype))
             full[self._state_logical()] = got
             self.states[name] = jax.device_put(full, self._state_sharding())
-        if self.is_sparse:
-            # staleness is NOT restorable state: it certifies worker-side
-            # client caches the snapshot does not cover — a restored
-            # table must serve every row fresh once (values re-pulled,
-            # resume-exactness preserved; claiming freshness against
-            # unknown caches would serve stale rows silently)
-            with self._std_lock:
-                self._up_to_date[:, :] = False
+        # staleness is NOT restorable state: it certifies worker-side
+        # client caches the snapshot does not cover — a restored table must
+        # serve every row fresh once (values re-pulled, resume-exactness
+        # preserved; claiming freshness against unknown caches would serve
+        # stale rows silently)
+        self._stale(None)
 
     # -- live migration (shard/reshard.py) ---------------------------------
     def extract_range(self, lo: int, hi: int):
@@ -1036,9 +642,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
         padded[start:start + n, : self.num_col] = values
         self.data = jax.device_put(
             padded, mesh_lib.table_sharding(self.mesh, ndim=2, shard_dim=0))
-        if self.is_sparse:
-            with self._std_lock:
-                self._up_to_date[:, start:start + n] = False
+        self._stale(np.arange(start, start + n))
 
 
 class MatrixWorker(DeviceIdsWorker, WorkerTable):
@@ -1103,9 +707,6 @@ class MatrixWorker(DeviceIdsWorker, WorkerTable):
             msg_id = self._submit(MsgType.Request_Get, (ids, option), submit)
         self._phase_of[msg_id] = phase
         return msg_id
-
-    def process_reply_get(self, raw, request):
-        return raw
 
     def wait_get(self, msg_id: int, row_ids: Optional[np.ndarray] = None) -> np.ndarray:
         phase = self._phase_of.pop(msg_id, 0)
@@ -1368,11 +969,6 @@ class MatrixWorker(DeviceIdsWorker, WorkerTable):
         if option is None:
             option = AddOption()
             option.worker_id = self._channel.worker_id()
-        return option
-
-    def _default_get_option(self, option: Optional[GetOption]) -> GetOption:
-        if option is None:
-            option = GetOption(worker_id=self._channel.worker_id())
         return option
 
     # -- TPU-era fast path -------------------------------------------------

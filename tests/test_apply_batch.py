@@ -426,7 +426,7 @@ def test_refilled_staging_cannot_change_an_applied_add(kernel, monkeypatch):
     rows, cols = 700, 100
     table = mv.create_table("matrix", num_row=rows, num_col=cols)
     st = table._server_table
-    assert st._pallas_scatter == (kernel == "pallas")
+    assert st.plan.kernel == (kernel == "pallas")
     rng = np.random.default_rng(31)
     model = np.zeros((rows, cols), np.float32)
     sizes = [5, 70, 9, 200, 130, 3, 64, 300, 1, 600, 2]

@@ -43,7 +43,7 @@ def test_smoke_phases_through_the_interpreted_kernel(monkeypatch):
     trainer, w_in, checks = chip_smoke.phase_trainer(
         60, 16, 64, 64, 2, 2, interpreted)
     assert checks["untouched_rows_bit_equal"]
-    assert trainer.input_table._server_table._pallas_interpret is True
+    assert trainer.input_table._server_table.plan.interpret is True
 
     report = chip_smoke.phase_server(trainer.input_table, w_in, 32, 5, 4)
     assert report["backends_initialized"] is False
@@ -72,7 +72,7 @@ def test_four_chip_phase_runs_the_kernel_on_every_shard(monkeypatch):
             assert checks["row_launches"] == {
                 "ROW_LAUNCH_PALLAS_ADD": 2, "ROW_LAUNCH_XLA_ADD": 0,
                 "ROW_LAUNCH_PALLAS_GET": 0, "ROW_LAUNCH_XLA_GET": 3}
-            assert table._server_table._shard_rows is not None
+            assert "add" in table._server_table.plan.routed
             shards = chip_smoke.check_shards(table, 4)
             assert len(shards["devices"]) == 4
         trainer, _, checks = chip_smoke.phase_trainer(

@@ -86,7 +86,7 @@ def test_group_and_member_ops_against_the_reference(ref, kernel,
     mv.init(mesh_shape="1")
     group, mirrors = _group(ref)
     server = group._server_table
-    assert server._pallas_scatter == (kernel == "pallas")
+    assert server.plan.kernel == (kernel == "pallas")
     assert group.num_rows == ROWS and group.num_row == sum(ROWS)
     assert [m.num_row for m in group.tables] == ROWS
     rng = np.random.default_rng(SEED)
@@ -247,7 +247,7 @@ def test_a_delta_longer_than_its_ids_is_one_program(ref, kernel,
     group, mirrors = _group(ref)
     server = group._server_table
     program = (pallas_rows._scatter_add_call if kernel == "pallas"
-               else server._scatter_add)
+               else server.plan.scatter_add)
     rng = np.random.default_rng(SEED + 1)
     held = 128      # the delta's rows, every step
     # the first count warms the program; it serves the counts that follow

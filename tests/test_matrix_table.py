@@ -288,7 +288,7 @@ def test_device_add_of_an_odd_row_count(kernel, updater, monkeypatch,
     mirror = rng.standard_normal((rows, cols)).astype(np.float32)
     table = mv.create_table("matrix", rows, cols, np.float32,
                             updater_type=updater, init_value=mirror)
-    assert table._server_table._pallas_scatter == (kernel == "pallas")
+    assert table._server_table.plan.kernel == (kernel == "pallas")
     ids = rng.choice(rows, n, replace=False).astype(np.int32)
     vals = rng.standard_normal((n, cols)).astype(np.float32)
     dev_vals = jax.device_put(vals)
@@ -356,7 +356,7 @@ def test_word_embedding_table_pair_against_the_reference(monkeypatch):
                                           init_value=init))
             mirrors.append(ref.Mirror(cols, seed, index))
             server = tables[-1]._server_table
-            assert server._pallas_scatter and server._pallas_interpret
+            assert server.plan.kernel and server.plan.interpret
             assert server.padded_cols == 384 and server.padded_rows % 8 == 0
             assert tables[-1].get_device().shape == (server.padded_rows, 384)
         ids = [rng.choice(rows, n, replace=False).astype(np.int32)
@@ -537,7 +537,8 @@ def test_row_get_compiles_a_step_not_an_id_count(mv_env):
 
     import jax
 
-    from multiverso_tpu.tables.matrix_table import _live_slots, _row_gather
+    from multiverso_tpu.tables.matrix_table import (_live_slots, _row_gather,
+                                                    _row_gather_jit)
 
     rows, cols = 5000, 20
     init = np.random.default_rng(27).standard_normal(
@@ -545,7 +546,7 @@ def test_row_get_compiles_a_step_not_an_id_count(mv_env):
     table = mv.create_table("matrix", rows, cols, np.float32, init_value=init)
     server = table._server_table
     # one jit serves every table; 5,000 x 20 tables appear in no other test
-    gather = server._gather.func
+    gather = _row_gather_jit
     before = gather._cache_size()
     counts = np.unique(np.linspace(2049, 4096, 200).astype(int))
     assert len(counts) == 200
@@ -623,7 +624,7 @@ def _run_sharded_ops(shards, cols, block=None):
         table = mv.create_table("matrix", SHARDED_ROWS, cols, np.float32,
                                 init_value=init)
         server = table._server_table
-        assert (server._shard_rows is not None) == (shards > 1)
+        assert ("get" in server.plan.routed) == (shards > 1)
         np.testing.assert_array_equal(
             np.asarray(table.get_device())[:SHARDED_ROWS, :cols], init)
         block = block or server._block_rows

@@ -221,8 +221,8 @@ def test_matrix_server_multi_shard_add_correct(mv_env):
 
     assert Zoo.instance().num_servers > 1  # the 8-device virtual mesh
     table = mv.create_table("matrix", 64, 16, np.float32)
-    assert not table._server_table._pallas_scatter
-    assert table._server_table._pallas_interpret is None
+    assert not table._server_table.plan.kernel
+    assert table._server_table.plan.interpret is None
     ids = np.array([1, 9, 42], np.int32)
     table.add(np.full((3, 16), 2.0, np.float32), row_ids=ids)
     np.testing.assert_allclose(table.get(ids), np.full((3, 16), 2.0))

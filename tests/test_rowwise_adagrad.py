@@ -92,7 +92,7 @@ def test_updater_against_the_reference(ref, monkeypatch, kernel, form):
     monkeypatch.setattr(dashboard.Dashboard, "profile_annotations", True)
     table = _table(ref, rows, cols, seed)
     server = table._server_table
-    assert server._kernel_rows == (kernel == "pallas")
+    assert (server.plan.path == "pallas") == (kernel == "pallas")
     assert server.states["s"].shape == (1024,)  # whole lane tiles
     ops = _ops(ref, np.random.default_rng(seed), rows, cols, 6, n)
     t0 = time.perf_counter()
