@@ -22,6 +22,7 @@ round-i Adds are applied.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -347,7 +348,8 @@ class Server:
         moment its service begins (``until_ns``, else now), so the time
         behind earlier messages of its own drain counts. Observed once:
         a message a clock gate re-dispatches later waited at the gate
-        (SYNC_GATE_WAIT_SECONDS), not in the queue."""
+        (the SYNC_GATE_WAIT_SECONDS histogram and, in the op trace, its
+        SYNC_GATE_WAIT record), not in the queue."""
         enq_ns = msg.enq_ns
         if not enq_ns or msg.type not in (MsgType.Request_Get,
                                           MsgType.Request_Add):
@@ -650,6 +652,18 @@ class SyncServer(Server):
         self._finished: List[bool] = [False] * num_workers
         self._pending_add: Dict[int, List[Message]] = {}
         self._pending_get: Dict[int, List[Message]] = {}
+        # rounds as the op trace and the counters see them: per table, the
+        # rounds every gated worker's Add has reached, and when the first
+        # Add of each round still open began
+        self._rounds_done: Dict[int, int] = {}
+        self._round_began: Dict[int, Dict[int, int]] = {}
+        self._rounds = Dashboard.counter("SYNC_ROUNDS")
+        self._served = {
+            MsgType.Request_Add: Dashboard.counter("SYNC_SERVED_ADD"),
+            MsgType.Request_Get: Dashboard.counter("SYNC_SERVED_GET")}
+        self._deferred = {
+            MsgType.Request_Add: Dashboard.counter("SYNC_DEFERRED_ADD"),
+            MsgType.Request_Get: Dashboard.counter("SYNC_DEFERRED_GET")}
         # Straggler tolerance: the reference defined `backup_worker_ratio`
         # but never read it (src/server.cpp:21); here it is real — the
         # slowest floor(ratio * num_workers) workers' clocks are ignored by
@@ -747,18 +761,26 @@ class SyncServer(Server):
 
     # -- gate-wait telemetry (obs/): a deferred request's queue time is the
     # tail the BSP/SSP contract creates — stamped at defer, observed at
-    # release, visible as the SYNC_GATE_WAIT_SECONDS histogram
+    # release: the SYNC_GATE_WAIT_SECONDS histogram and, while the op trace
+    # is on, one SYNC_GATE_WAIT record (written as SERVER_QUEUE_WAIT is: it
+    # starts at the deferral, its parent is the span the sender was in, its
+    # `op` the request's own id, its `n` the round the request waited for)
     @staticmethod
-    def _gate_defer(msg: Message) -> None:
-        msg._gated_at = time.perf_counter()
+    def _gate_defer(msg: Message, round_: int = 0) -> None:
+        msg._gated_at = time.perf_counter_ns()
+        msg._gated_for = round_
         hop(msg.req_id, "gate_deferred")
 
     @staticmethod
     def _gate_release(msg: Message) -> None:
         gated_at = getattr(msg, "_gated_at", None)
         if gated_at is not None:
-            observe("SYNC_GATE_WAIT_SECONDS",
-                    time.perf_counter() - gated_at)
+            waited = time.perf_counter_ns() - gated_at
+            observe("SYNC_GATE_WAIT_SECONDS", waited * 1e-9)
+            if Dashboard.profile_annotations:
+                RING.append(0, msg.enq_span, "SYNC_GATE_WAIT", gated_at,
+                            waited, 0, msg.req_id or msg.msg_id,
+                            msg._gated_for)
         hop(msg.req_id, "gate_released")
 
     @dispatcher_only
@@ -786,6 +808,7 @@ class SyncServer(Server):
                     for msg in mine:
                         hop(msg.req_id, "gate_failed_eviction")
                         msg.data[-1].fail(exc)
+            self._note_rounds(tid)
             self._drain(tid)
         # post-mortem: the last N request traces (including the corpse's
         # deferred ones, hop by hop) + a dashboard snapshot
@@ -798,6 +821,8 @@ class SyncServer(Server):
             self._get_clock[table_id] = [0] * self.num_workers
             self._pending_add[table_id] = []
             self._pending_get[table_id] = []
+            self._rounds_done[table_id] = 0
+            self._round_began[table_id] = {}
         return table_id
 
     # clock helpers: finished workers never hold anyone back, and the
@@ -831,17 +856,10 @@ class SyncServer(Server):
         round_ = self._add_clock[tid][worker] + 1
         # round-r Adds wait until every worker has finished its round-(r-1) Gets
         if self._min_gets(tid) >= round_ - 1:
-            request, completion = msg.data
-            self._wal_append(msg)
-            # forward the fused-sync reply (ArrayTable leaf mode) rather
-            # than discarding it — the client would otherwise re-run the
-            # whole merged-value split in a fallback get
-            completion.done(self._tables[tid].process_add(request))
-            self._add_clock[tid][worker] = round_
+            self._serve(msg)
             self._drain(tid)
         else:
-            self._gate_defer(msg)
-            self._pending_add[tid].append(msg)
+            self._defer(msg, round_)
 
     @dispatcher_only
     def _process_get(self, msg: Message) -> None:
@@ -853,58 +871,141 @@ class SyncServer(Server):
         round_ = self._get_clock[tid][worker] + 1
         # round-i Gets wait until every worker's round-i Add is applied
         if self._min_adds(tid) >= round_:
-            request, completion = msg.data
-            result = self._tables[tid].launch_get(request)
-            self._get_clock[tid][worker] = round_
-            complete_get(completion, result)
+            self._serve(msg)
             self._drain(tid)
         else:
-            self._gate_defer(msg)
-            self._pending_get[tid].append(msg)
+            self._defer(msg, round_)
+
+    @dispatcher_only
+    def _serve(self, msg: Message) -> None:
+        """Serve one worker's Add or Get whose clock condition holds, on
+        arrival or released from the gate, and step the worker's clock:
+        what the async server's ``_process_add`` / ``_process_get`` do and
+        record, under the request's OWN id. A released request is served
+        inside the dispatch of the message whose arrival released it, so
+        the id is given to the section, and every record of the service
+        (the table's, the launch's, the reply's hand-over) inherits it."""
+        tid, worker = msg.table_id, msg.src
+        op = msg.req_id or msg.msg_id
+        request, completion = msg.data
+        if msg.type == MsgType.Request_Add:
+            began_ns = time.perf_counter_ns()
+            with monitor("SERVER_PROCESS_ADD_MSG", op=op, n=1):
+                self._wal_append(msg)
+                hop(msg.req_id, "apply_add")
+                # forward the fused-sync reply (ArrayTable leaf mode)
+                # rather than discarding it — the client would otherwise
+                # re-run the whole merged-value split in a fallback get
+                completion.done(self._tables[tid].process_add(request))
+            self._add_clock[tid][worker] += 1
+            self._round_began[tid].setdefault(self._add_clock[tid][worker],
+                                              began_ns)
+            self._note_rounds(tid)
+        else:
+            with monitor("SERVER_PROCESS_GET_MSG", op=op):
+                hop(msg.req_id, "serve_get")
+                result = self._tables[tid].launch_get(request)
+                self._get_clock[tid][worker] += 1
+                complete_get(completion, result)
+        self._served[msg.type].add(1)
+
+    @dispatcher_only
+    def _defer(self, msg: Message, round_: int) -> None:
+        """Keep a request behind its clock gate; ``round_`` is the round
+        of every worker's Adds (a Get) or Gets (an Add) it waits for."""
+        self._gate_defer(msg, round_)
+        self._deferred[msg.type].add(1)
+        pending = (self._pending_add if msg.type == MsgType.Request_Add
+                   else self._pending_get)
+        pending[msg.table_id].append(msg)
+
+    @dispatcher_only
+    def _note_rounds(self, table_id: int) -> None:
+        """Count the rounds that every gated worker's Adds have now reached
+        (``_min_adds``, so a finished or evicted worker holds none open)
+        and give each one SYNC_ROUND record: from the start of the first
+        Add applied in the round to now, the last's end; ``op`` the table,
+        ``n`` the round."""
+        reached = min(self._min_adds(table_id),
+                      max(self._add_clock[table_id], default=0))
+        done = self._rounds_done[table_id]
+        if reached <= done:
+            return
+        self._rounds_done[table_id] = reached
+        self._rounds.add(reached - done)
+        now_ns = time.perf_counter_ns()
+        began = self._round_began[table_id]
+        for round_ in range(done + 1, reached + 1):
+            began_ns = began.pop(round_, now_ns)
+            if Dashboard.profile_annotations:
+                RING.append(0, 0, "SYNC_ROUND", began_ns, now_ns - began_ns,
+                            0, table_id, round_)
 
     def _process_finish_train(self, msg: Message) -> None:
         if self._is_admin(msg.src):
             return
         self._finished[msg.src] = True
         for tid in list(self._tables):
+            self._note_rounds(tid)
             self._drain(tid)
 
     @dispatcher_only
     def _drain(self, table_id: int) -> None:
-        """Release deferred messages whose clock condition now holds."""
+        """Release the deferred messages whose clock condition now holds,
+        each served as on arrival (``_serve``). A pass that releases any is
+        one SYNC_RELEASE section, ``n`` the messages it released; a pass
+        that releases none records nothing. A released request that fails
+        fails its own waiter, as one served on arrival does."""
+        releasable = self._releasable(table_id)
+        first = next(releasable, None)
+        if first is None:
+            return
+        with monitor("SYNC_RELEASE") as release:
+            for msg in itertools.chain((first,), releasable):
+                release.n += 1
+                self._gate_release(msg)
+                try:
+                    self._serve(msg)
+                except Exception as exc:  # the waiter's, not the releaser's
+                    log.error("server dispatcher error on released %s: %r",
+                              msg.type.name, exc)
+                    msg.data[-1].fail(exc)
+
+    @staticmethod
+    def _take(pending: List[Message], ready: Callable[[Message], bool]):
+        """Yield the messages of ``pending`` that ``ready`` admits, in
+        order, each taken off the list first: the list is at every moment
+        what still waits."""
+        i = 0
+        while i < len(pending):
+            if ready(pending[i]):
+                yield pending.pop(i)
+            else:
+                i += 1
+
+    def _releasable(self, table_id: int):
+        """The deferred messages whose clock condition holds. The caller
+        serves each before it asks for the next, so every test reads the
+        clocks as that service left them. Passes of Gets then Adds, until
+        one finds none."""
+        def get_ready(msg: Message) -> bool:
+            return (self._min_adds(table_id)
+                    >= self._get_clock[table_id][msg.src] + 1)
+
+        def add_ready(msg: Message) -> bool:
+            return (self._min_gets(table_id)
+                    >= self._add_clock[table_id][msg.src])
+
         progressed = True
         while progressed:
             progressed = False
             # gets first (they unblock next-round adds)
-            still: List[Message] = []
-            for msg in self._pending_get[table_id]:
-                worker = msg.src
-                round_ = self._get_clock[table_id][worker] + 1
-                if self._min_adds(table_id) >= round_:
-                    self._gate_release(msg)
-                    request, completion = msg.data
-                    result = self._tables[table_id].launch_get(request)
-                    self._get_clock[table_id][worker] = round_
-                    complete_get(completion, result)
-                    progressed = True
-                else:
-                    still.append(msg)
-            self._pending_get[table_id] = still
-            still = []
-            for msg in self._pending_add[table_id]:
-                worker = msg.src
-                round_ = self._add_clock[table_id][worker] + 1
-                if self._min_gets(table_id) >= round_ - 1:
-                    self._gate_release(msg)
-                    request, completion = msg.data
-                    self._wal_append(msg)
-                    completion.done(
-                        self._tables[table_id].process_add(request))
-                    self._add_clock[table_id][worker] = round_
-                    progressed = True
-                else:
-                    still.append(msg)
-            self._pending_add[table_id] = still
+            for msg in self._take(self._pending_get[table_id], get_ready):
+                progressed = True
+                yield msg
+            for msg in self._take(self._pending_add[table_id], add_ready):
+                progressed = True
+                yield msg
 
 
 class SSPServer(SyncServer):
@@ -935,11 +1036,7 @@ class SSPServer(SyncServer):
         if self._is_admin(worker):
             super(SyncServer, self)._process_add(msg)
             return
-        request, completion = msg.data
-        self._wal_append(msg)
-        hop(msg.req_id, "apply_add")
-        completion.done(self._tables[tid].process_add(request))
-        self._add_clock[tid][worker] += 1
+        self._serve(msg)
         # observed staleness: how many add-rounds this worker now leads
         # the slowest unfinished worker by (0 = in lockstep; bounded by
         # the staleness flag for its Gets to be served)
@@ -960,29 +1057,16 @@ class SSPServer(SyncServer):
             super(SyncServer, self)._process_get(msg)
             return
         if self._min_adds(tid) >= self._gate_round(tid, worker):
-            request, completion = msg.data
-            result = self._tables[tid].launch_get(request)
-            self._get_clock[tid][worker] += 1
-            complete_get(completion, result)
+            self._serve(msg)
         else:
-            self._gate_defer(msg)
-            self._pending_get[tid].append(msg)
+            self._defer(msg, self._gate_round(tid, worker))
 
-    @dispatcher_only
-    def _drain(self, table_id: int) -> None:
-        still: List[Message] = []
-        for msg in self._pending_get[table_id]:
-            worker = msg.src
-            if self._min_adds(table_id) >= self._gate_round(table_id,
-                                                            worker):
-                self._gate_release(msg)
-                request, completion = msg.data
-                result = self._tables[table_id].launch_get(request)
-                self._get_clock[table_id][worker] += 1
-                complete_get(completion, result)
-            else:
-                still.append(msg)
-        self._pending_get[table_id] = still
+    def _releasable(self, table_id: int):
+        def get_ready(msg: Message) -> bool:
+            return (self._min_adds(table_id)
+                    >= self._gate_round(table_id, msg.src))
+
+        return self._take(self._pending_get[table_id], get_ready)
 
 
 def make_server(num_workers: int) -> Server:
