@@ -13,6 +13,14 @@ benchmark is ``benchmark/run.py``):
              the same on 300,000 x 300 (rows of three lane tiles), so a
              width that stops working on the chip fails here. Each reports
              which program served its row launches.
+   keyed     (one chip) the keyed FTRL table at the benchmark's key space,
+             882,774,573 keys of ``(z, n)``: six Adds of 111,000 keys
+             through the dispatcher, whose program steps the rows of 128 its
+             keys live in inside the Pallas row kernel (PR 49), against the
+             same Adds by XLA's gathers and scatters on a bare state: ``n``
+             equal in every bit, ``z`` inside the benchmark reference's
+             tolerance (the largest error printed), and no entry outside
+             the rows named changed on either side.
 2. trainer   word2vec PSTrainer, 100,000 x 128, three submissions of
              64 x 8,192 Zipf tokens through the fused device transaction.
 3. server    ``mv.serve`` on the trainer's tables; ONE child process (pinned
@@ -180,6 +188,132 @@ def phase_kernels(rows, cols, n_ids, expect_scatter, seed=0):
     served = "PALLAS" if pallas else "XLA"
     assert checks["row_launches"][f"ROW_LAUNCH_{served}_ADD"] == 2, checks
     assert checks["row_launches"]["ROW_LAUNCH_XLA_GET"] == 3, checks
+    return table, checks
+
+
+# -- phase 1b: the keyed FTRL table ---------------------------------------------
+
+_FTRL_OPT = dict(alpha=0.1, beta=1.0, lambda1=1.0, lambda2=1.0)
+
+
+def phase_keyed(size, n_keys, adds, expect_kernel=(True, False), seed=0):
+    """``adds`` keyed FTRL Adds of ``n_keys`` keys (half of them in a dense
+    head, so that rows are shared; a few named twice) on a table of
+    ``size`` keys through the dispatcher, against the same Adds by the
+    table's XLA program (`rows=None`: gathers, the rule, scatters) on a bare
+    state of the same seeded values. The chip holds one state at a time:
+    what is compared is every entry of every row of 128 an Add names, and
+    the count of entries that differ from the seeded state anywhere, which
+    must be the count inside those rows."""
+    import jax
+    import jax.numpy as jnp
+
+    import multiverso_tpu as mv
+    from benchmark import common
+    from multiverso_tpu.dashboard import Dashboard
+    from multiverso_tpu.tables import ftrl_table as ft
+    from multiverso_tpu.tables.device_ids import live_slots
+    from multiverso_tpu.utils import next_pow2
+
+    ref = common.load_module("reference", "logreg-ftrl-criteo-tb")
+    rng = np.random.default_rng(seed)
+    piece = min(1 << 25, size)
+    padded = -(-(size + 1) // 1024) * 1024
+
+    @jax.jit
+    def seeded(lo):
+        keys = lo + jnp.arange(piece, dtype=jnp.int32)
+        z, n = ref.init_zn(keys, seed, jnp)
+        # the entries past the keys (the scratch key's) start as zeros
+        return jnp.where(keys < size, z, 0.0), jnp.where(keys < size, n, 0.0)
+
+    def source(lo, count):
+        return tuple(s[:count] for s in seeded(jnp.int32(lo)))
+
+    @jax.jit
+    def changed_in(z, n, at, lo):
+        """Entries of ``z`` and ``n`` from ``lo`` on, in the piece at
+        ``at``, whose bits are not the seeded state's."""
+        want_z, want_n = seeded(at)
+        counted = at + jnp.arange(piece, dtype=jnp.int32) >= lo
+
+        def differ(state, want):
+            return jnp.sum(counted & (
+                jax.lax.bitcast_convert_type(
+                    jax.lax.dynamic_slice(state, (at,), (piece,)), jnp.int32)
+                != jax.lax.bitcast_convert_type(want, jnp.int32)))
+
+        return differ(z, want_z) + differ(n, want_n)
+
+    def changed(z, n):
+        # the last piece is laid back to end with the state and counts
+        # from where the one before it ended
+        return sum(int(changed_in(z, n, jnp.int32(min(lo, padded - piece)),
+                                  jnp.int32(lo)))
+                   for lo in range(0, padded, piece))
+
+    ops = []
+    for _ in range(adds):
+        keys = np.concatenate([
+            rng.integers(0, min(size, 1 << 17), n_keys // 2),
+            rng.integers(0, size, n_keys - n_keys // 2)]).astype(np.int32)
+        ops.append((keys, ref.to_float(ref.grad_k(rng, n_keys))))
+    rows = np.unique(np.concatenate(
+        [keys >> 7 for keys, _ in ops] + [[size >> 7]])).astype(np.int32)
+    take = jax.jit(lambda s, r: s.reshape(-1, 128)[r])
+
+    # XLA's path first, on a bare state (its program is the table's own)
+    z = jnp.zeros(padded, jnp.float32)
+    n = jnp.zeros(padded, jnp.float32)
+    for lo in range(0, size, piece):
+        z, n = ft._write_piece(z, n, *source(lo, min(piece, size - lo)),
+                               jnp.int32(lo))
+    _, add = ft._make_programs(scratch=size, **_FTRL_OPT)
+    for keys, grad in ops:
+        bucket = max(next_pow2(len(keys) + 1), 128)
+        ids = np.full(bucket, size, np.int32)
+        ids[:len(keys)] = keys
+        z, n = add(z, n, jnp.asarray(ids), jnp.asarray(grad),
+                   live=live_slots(len(keys), bucket), rows=None)
+    want = [np.asarray(take(s, rows)) for s in (z, n)]
+    changed_xla = changed(z, n)
+    del z, n
+
+    launched = Dashboard.counter_value("ROW_LAUNCH_PALLAS_ADD")
+    table = mv.create_table("ftrl", size, init=source, **_FTRL_OPT)
+    plan = table._server_table.plan
+    assert (plan.kernel, plan.interpret) == tuple(expect_kernel), plan.why
+    for keys, grad in ops:
+        table.add(keys, grad)
+    state = [table.get_state_device(name) for name in "zn"]
+    got = [np.asarray(take(s, rows)) for s in state]
+    changed_kernel = changed(*state)
+    row_keys = (rows.astype(np.int64)[:, None] * 128
+                + np.arange(128)).reshape(-1)
+    first = [np.where(row_keys < size, s, 0).astype(np.float32)
+             for s in ref.init_zn(np.minimum(row_keys, size - 1), seed)]
+    in_rows = [sum(ref.n_mismatch(side[i].reshape(-1), first[i])
+                   for i in range(2)) for side in (want, got)]
+    steps = np.zeros(len(row_keys), np.int64)
+    for keys, _ in ops:
+        named = np.unique(keys)     # a key named twice takes one step
+        steps[np.searchsorted(rows, named >> 7) * 128 + (named & 127)] += 1
+    checks = {
+        "table": f"ftrl {size} keys", "adds": adds, "keys_an_add": n_keys,
+        "rows_named": len(rows), "kernel": plan.why,
+        "pallas_adds": Dashboard.counter_value("ROW_LAUNCH_PALLAS_ADD")
+        - launched,
+        "n_entries_differ": ref.n_mismatch(got[1], want[1]),
+        "z_entries_differ": ref.n_mismatch(got[0], want[0]),
+        "z_max_error_of_allowed": float(ref.z_error(
+            got[0].reshape(-1), want[0].reshape(-1), steps)),
+        "changed_outside_the_rows_named": [changed_xla - in_rows[0],
+                                           changed_kernel - in_rows[1]]}
+    assert checks["pallas_adds"] == adds, checks
+    assert checks["n_entries_differ"] == 0, checks
+    assert checks["z_max_error_of_allowed"] <= 1.0, checks
+    assert checks["changed_outside_the_rows_named"] == [0, 0], checks
+    assert in_rows[1] > adds * n_keys // 2, checks
     return table, checks
 
 
@@ -387,6 +521,11 @@ def run_mesh(devices, clock, sizes, with_server):
     _, checks = phase_kernels(*sizes["kernels_wide"], expect_scatter=expect)
     report("kernels_wide", t0, checks)
 
+    if devices == 1:
+        # the lane kernel serves a table on one device alone
+        t0 = time.perf_counter()
+        report("keyed", t0, phase_keyed(*sizes["keyed"])[1])
+
     t0 = time.perf_counter()
     trainer, w_in, checks = phase_trainer(*sizes["trainer"],
                                           expect_scatter=expect)
@@ -408,6 +547,8 @@ FULL_SIZES = {
     "kernels": (1_000_000, 50, 1000),
     # the word-embedding width: three lane tiles a row (384 lanes)
     "kernels_wide": (300_000, 300, 1000),
+    # keys, keys an Add, Adds   (the benchmark's `ftrlctr.step-keys`)
+    "keyed": (882_774_573, 111_000, 6),
     # vocab, dim, batch_pairs, block_tokens, group, submissions
     "trainer": (100_000, 128, 32768, 8192, 64, 3),
     # rows per Add/Get, k, queries

@@ -49,24 +49,32 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
   there in HBM either way).
 
 * ``add_at_lanes`` (PR 42; PR 41 built it and was refused for what it cost
-  a process's set-up) is the scatter-add of SINGLE float32 values into
-  lane-dense 1-D states (the keyed FTRL table's ``z`` and ``n``), by the
-  same row descriptors: a state is read as rows of 128, a key lives in row
-  ``key >> 7``, lane ``key & 127``. Its contract is not
-  ``scatter_add_rows``': the keys are SORTED ascending (they are the scalar
-  prefetch as rows); keys that share a row are expected (a row's slots are
-  adjacent, each takes what all of them bring before it writes, a run that
-  straddles two grid steps is written in both, each adding what its own
-  slots bring, the first's write-backs awaited); a key several slots name
-  is stepped once, by its first slot, wherever a grid step's boundary
-  falls among them; pad slots may aim at a row that live keys share (the
-  FTRL table's scratch key does) and are part of its run; a lane no
-  stepping slot names is written back as read, by a select (adding
-  ``-0.0`` would do for every float32 but a denormal, which the vector
-  unit flushes: PR 41's first build's test found it); the slots, filled to
-  whole groups here, are at most ``PREFETCH_SLOTS``; a grid step walks
-  ``LANE_GROUP`` slots, a group of the kernel's own (the standing kernels
-  keep ``ROW_GROUP``). Its docstring has the rest.
+  a process's set-up; since PR 49 it works a caller's rule out itself) is
+  the update of SINGLE float32 values of lane-dense 1-D states (the keyed
+  FTRL table's ``z`` and ``n``) by the same row descriptors: a state is
+  read as rows of 128, a key lives in row ``key >> 7``, lane ``key & 127``,
+  and the rule (``step``, plain ``jax.numpy``: the FTRL table hands it
+  ``ftrl_table.ftrl_step``; without one, ``state[key] += delta``) is traced
+  into the kernel and computed on the ``(LANE_GROUP, 128)`` blocks the rows
+  landed in, so that the caller's program gathers no state and writes no
+  block a slot for the kernel: the keys and the deltas go in lane-dense and
+  a group's row of 128 slots is turned onto sublanes in VMEM. This module
+  knows nothing of FTRL. Its contract is not ``scatter_add_rows``': the
+  keys are SORTED ascending (their rows are the scalar prefetch); keys
+  that share a row are expected (a row's slots are adjacent, each takes
+  what all of them bring before it steps and writes, a run that straddles
+  two grid steps is written in both, each stepping the lanes its own slots
+  name, the first's write-backs awaited); a key several slots name is
+  stepped once, by its first slot, wherever a grid step's boundary falls
+  among them; pad slots may aim at a row that live keys share (the FTRL
+  table's scratch key does) and are part of its run; a lane no stepping
+  slot names is written back as read, by a select (the rule is computed on
+  it and dropped; adding ``-0.0`` would do for every float32 but a
+  denormal, which the vector unit flushes: PR 41's first build's test
+  found it); the slots, filled to whole groups here, are at most
+  ``PREFETCH_SLOTS``; a grid step walks ``LANE_GROUP`` slots, a group of
+  the kernel's own (the standing kernels keep ``ROW_GROUP``). Its docstring
+  has the rest.
 * nothing that ``import multiverso_tpu`` reaches imports this module at its
   top: it brings ``jax.experimental.pallas``, a second of module code. A
   table imports it inside the functions that need it; the keyed FTRL table
@@ -253,6 +261,62 @@ current machine):
   (the benchmark's eight remote workers: `emb128.remote-workers`
   `setup_s` +1.15 s by ISSUE 42's reading of PR 41's lines; parity
   again in PR 42's pairs, 27.82 -> 27.95).
+* the step worked out in VMEM, `add_at_lanes` under a caller's rule (PR 49,
+  2026-10-03, one v5e chip, `TPU v5 lite`; the shapes of the record above:
+  111,118 Zipf keys in 67,539 rows, 114,696 live slots of a 131,072 bucket,
+  897 grid steps, `z` and `n` of 882,775,040 float32; the table's whole
+  program, ms a launch, 30 launches back to back after a warm one, wall
+  clock over the count, three rounds in one chip call, the order reversed
+  in the second; seconds to `.lower()` on the chip's host, a fresh `jit`
+  each round; every variant's first launch held to XLA's gathers, the same
+  rule and XLA's scatters on the rows it names: `n` AND `z` equal in every
+  bit of 8.6M entries, so Mosaic's float32 root and quotient round as
+  XLA's do on this chip; my chip runs, PR 49).
+    (i) the standing program: XLA gathers `z` and `n` as rows of 128, steps
+        a slot, writes a `run` block and two delta blocks of (slots, 128)
+        (118 MB of temporaries compiled, not 177: one block is folded);
+        the kernel merges and adds    5.654 / 5.657 / 5.668   0.33 / 0.26 / 0.27 s
+    (ii) the rule in the kernel on the blocks it read; XLA still writes the
+        `run` block and ONE gradient block (59 MB)
+                                      4.625 / 4.624 / 4.617   0.26 / 0.26 / 0.25 s
+    (iii) keys and gradient handed lane-dense, (slots / 128, 128) int32 (1 MB
+        of temporaries); the pipeline brings an (8, 128) tile every eighth
+        step, the kernel takes its group's row by a dynamic sublane index,
+        broadcasts it over 128 sublanes and transposes: slot k's key and
+        bits on every lane of sublane k; `run` is `key >> 7`, a slot's lane
+        `key & 127` against an iota
+                                      4.299 / 4.291 / 4.291   0.27 / 0.27 / 0.25 s
+    (iii) and the rule a chunk of 32 slots at a time, each chunk's 64
+        write-backs issued before the next chunk is stepped (kept)
+                                      4.174 / 4.165 / 4.171   0.29 / 0.33 / 0.29 s
+      the same in chunks of 16        4.172 / 4.169 / 4.174   0.34 / 0.36 / 0.34 s
+      the same in chunks of 64 (a second call, two rounds; (i) 5.660 /
+        5.662 and the kept 4.168 / 4.169 beside it)   4.318 / 4.320   0.28 / 0.31 s
+    (ii) in chunks of 32 / of 16      4.525-4.530 / 4.529-4.536
+    (iii) with two additions for a rule (wrong; the price of the
+        arithmetic), one round        4.004 (and (ii) so: 4.314)
+  What Mosaic said (compiled here for the described v5e first, libtpu
+  0.0.34, then on the chip): nothing against any form. `jnp.sign`, `abs`,
+  `maximum`, `sqrt` and `/` of float32 `(128, 128)` and `(32, 128)` values
+  lower as written; a `(1, 128)` row read at `pl.ds(step % 8, 1)` of an
+  `(8, 128)` int32 block, `broadcast_to` `(128, 128)` and `.T` lower (a
+  32-bit square transpose); the last `(8, 128)` block of a 897-row operand
+  hangs over its end and is taken (its rows past the end are never
+  indexed). What it says. (a) **XLA's gathers, selects and step were 1.03
+  ms** ((i) - (ii)) and the blocks 0.33 more ((ii) - (iii)): 118 MB written
+  and read again cost what ISSUE 49 read from PR 42's breakdown (0.28). (b)
+  **The rule costs 0.29 ms where it sits between the read wait and the
+  first write-back** (4.291 - 4.004: 0.32 us a grid step for three roots,
+  two quotients and a dozen more operations on two blocks of 16 vregs) and
+  0.17 in chunks: the LLO scheduler does run the vector unit under the
+  scalar core's descriptor issue when the write-backs of the slots already
+  stepped stand between the chunks; 16 slots hide no more than 32 and
+  lower 0.05 s slower (the rule is traced once a chunk), and 64 read
+  0.03 ms OVER the rule in one piece: 32 it is, `STEP_SLOTS`. (c) The two
+  transposes a step are under the reads' landing: (iii) without a rule,
+  4.004 ms for the whole program, is the sort (0.14) and 459,264
+  descriptors at 8.4 ns. What is left is the descriptors: (a) and (b) of
+  ROADMAP Queue 1 item 2.
 """
 
 from __future__ import annotations
@@ -599,8 +663,15 @@ NO_DELTA = -1
 # slots a grid step of `add_at_lanes` walks: its own group, because its
 # descriptors are four a slot (a grid step's fixed cost is a twentieth of
 # their issue time at 128 as at 256) and a program lowers in the time its
-# unrolled slots take (the optimization record, PR 42, has both sweeps)
-LANE_GROUP = 128
+# unrolled slots take (the optimization record, PR 42, has both sweeps). It
+# is the lanes of a row: the kernel takes a group's keys and deltas as ONE
+# lane-dense row and turns it square (PR 49)
+LANE_GROUP = LANES
+# slots whose rule is worked out between two batches of write-backs: the
+# vector unit steps the next chunk while the scalar core issues the last
+# one's descriptors (whole sublane tiles; the record, PR 49, prices 16, 32,
+# 64 and the whole group)
+STEP_SLOTS = 32
 
 
 def _run_and(run, blocks):
@@ -623,20 +694,27 @@ def _run_and(run, blocks):
     return blocks
 
 
-def _lane_add_kernel(rows_ref, run_ref, *refs, states, interpret):
-    deltas, refs = refs[:states], refs[states:]
+def _add_brought(olds, brought):
+    """The step of a caller without a rule: every state takes its delta."""
+    return [old + delta for old, delta in zip(olds, brought)]
+
+
+def _lane_add_kernel(rows_ref, keys_ref, *refs, states, brings, step,
+                     interpret):
+    deltas, refs = refs[:brings], refs[brings:]
     # the inputs are aliased with the outputs; all access goes through out
     tables, blocks, sems = (refs[states:2 * states],
                             refs[2 * states:3 * states], refs[3 * states])
-    base = pl.program_id(0) * LANE_GROUP
+    group = pl.program_id(0)
+    base = group * LANE_GROUP
     read_sem, write_sem = sems.at[0], sems.at[1]
 
-    def each(slot):
+    def each(slot, lo=0, count=LANE_GROUP):
         # compiled, the loop is unrolled as it is lowered (from a rolled
         # one, 64 slots a pass, the launch takes 6.67 ms for 4.34: the
         # optimization record, PR 41); interpreted it stays rolled (XLA's
         # CPU compiler takes half a minute a shape over 1,024 copies)
-        jax.lax.fori_loop(0, LANE_GROUP, lambda k, _: slot(k), None,
+        jax.lax.fori_loop(lo, lo + count, lambda k, _: slot(k), None,
                           unroll=not interpret)
 
     def read(k):
@@ -652,62 +730,104 @@ def _lane_add_kernel(rows_ref, run_ref, *refs, states, interpret):
                                   write_sem).start()
 
     each(read)
-    # slots of one row each read it; each then adds what ALL of them bring,
-    # so that whichever write-back lands last writes the row they all wrote
-    brought = _run_and(run_ref[:, :], [d[:, :] for d in deltas])
+    # under the landing reads, what does not depend on them. The group's
+    # slots come lane-dense, 128 on one row of an (8, 128) block: slot k's
+    # key and deltas turned onto every lane of sublane k
+    mine = pl.ds(group % SUBLANES, 1)
+
+    def turned(ref):
+        return jnp.broadcast_to(ref[mine, :], (LANE_GROUP, LANES)).T
+
+    keys = turned(keys_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    names = lane == (keys & (LANES - 1))
+    # slots of one row each read it; each then takes what ALL of them
+    # bring, so that whichever write-back lands last writes the row they
+    # all wrote
+    brought = _run_and(keys >> (LANES.bit_length() - 1),
+                       [jnp.where(names, turned(d), NO_DELTA) for d in deltas])
+    stepped = brought[0] != NO_DELTA
     for block in blocks:  # a semaphore counts bytes: a block's a wait
         pltpu.make_async_copy(block, block, read_sem).wait()
-    for block, bits in zip(blocks, brought):
-        # a lane nobody names is written back as read: a select, because
-        # the vector unit flushes a denormal that it adds anything to
-        was = block[:, :]
-        block[:, :] = jnp.where(
-            bits == NO_DELTA, was,
-            was + jax.lax.bitcast_convert_type(bits, was.dtype))
-    each(write)
+    brought = [jax.lax.bitcast_convert_type(b, blocks[0].dtype)
+               for b in brought]
+    # the rule a chunk of slots at a time, each chunk's write-backs issued
+    # before the next chunk is stepped: the vector unit works under the
+    # scalar core's issue of the descriptors
+    for lo in range(0, LANE_GROUP, STEP_SLOTS):
+        part = slice(lo, lo + STEP_SLOTS)
+        olds = [block[part, :] for block in blocks]
+        news = step(olds, [b[part, :] for b in brought])
+        for block, old, new in zip(blocks, olds, news):
+            # a lane nobody names is written back as read, whatever the
+            # rule made of it: a select, because the vector unit flushes a
+            # denormal that it adds anything to
+            block[part, :] = jnp.where(stepped[part, :], new, old)
+        each(write, lo, STEP_SLOTS)
     # the next grid step may read these rows: a run may straddle two steps
     for block in blocks:
         pltpu.make_async_copy(block, block, write_sem).wait()
 
 
-def add_at_lanes(states, keys, deltas, steps, *, interpret: bool):
-    """``state[key] += delta`` at the slots ``steps`` marks, for every
-    ``(state, delta)`` of ``states`` and ``deltas``, in place, by row
-    descriptors: traceable, for a caller's jitted program that donates the
-    states. A state is a lane-dense float32 ``(n x 128,)`` array, read here
-    as rows of 128 (a bitcast); ``keys`` are int32 in ``[0, n x 128)``,
-    ASCENDING, at least one and at most ``PREFETCH_SLOTS`` with the slots
-    that fill their last group (those repeat the last key and step
-    nothing); a delta is a float32 a slot, ``steps`` a bool a slot. A grid
-    step reads the rows its ``LANE_GROUP`` keys live in (one descriptor a
-    key and a state), adds, and writes them back.
+def add_at_lanes(states, keys, deltas, steps, *, step=None,
+                 interpret: bool):
+    """``states = step(states at keys, deltas)`` at the slots ``steps``
+    marks, in place, by row descriptors: traceable, for a caller's jitted
+    program that donates the states. A state is a lane-dense float32
+    ``(n x 128,)`` array, read here as rows of 128 (a bitcast); ``keys``
+    are int32 in ``[0, n x 128)``, ASCENDING, at least one and at most
+    ``PREFETCH_SLOTS`` with the slots that fill their last group (those
+    repeat the last key and step nothing); a delta is a float32 a slot,
+    ``steps`` a bool a slot. ``step(olds, brought) -> news`` is the
+    caller's rule, plain ``jax.numpy``, elementwise: ``olds`` the states'
+    values and ``brought`` the deltas' (as many as the caller gave, not
+    one a state), all of one shape, and it returns a new value a state;
+    without one every state takes the sum of its own delta, ``state[key]
+    += delta``. A grid step reads the rows its ``LANE_GROUP`` keys live in
+    (one descriptor a key and a state), applies the rule to the
+    ``(LANE_GROUP, 128)`` blocks where they landed, in VMEM, and writes
+    the rows back: the caller's program neither gathers a state nor
+    spreads anything over the lanes of a row (the keys and the deltas go
+    in lane-dense, ``slots / 128`` rows of 128, and a group's row is
+    turned onto sublanes in the kernel).
 
     * **Keys that share a row** are served: a row's slots are adjacent,
-      each takes what ALL of them bring (``_run_and``), adds it to the copy
-      of the row it read, and all write the same bytes back. A run that
-      straddles two grid steps is written in both, each adding what ITS
-      slots bring: the first one's write-backs are awaited before the
-      second one reads.
+      each takes what ALL of them bring (``_run_and``), steps the copy of
+      the row it read, and all write the same bytes back. A run that
+      straddles two grid steps is written in both, each stepping the lanes
+      ITS slots name: the first one's write-backs are awaited before the
+      second one reads. The rule is applied lane by lane, so this holds
+      for any rule.
     * **A slot that steps nothing** (a pad, wherever it aims: the scratch
       key may share its row with live keys) is part of its row's run and
-      writes back what the run's other slots bring, or the row as read.
+      writes back what the run's other slots make of the row, or the row
+      as read.
     * **A key named by several slots** (they are adjacent) is stepped once,
       by what its FIRST slot brings; the others' deltas are not read. The
       slots may lie either side of a grid step's boundary: those of the
       second step bring nothing and write back what the first step wrote.
     * **A lane no stepping slot names** is written back as read, by a
-      select: its bits stand, a denormal's too.
-    A stepped lane takes one float32 addition of the two numbers
-    ``state.at[key].add(delta)`` would add. A delta that is NaN goes in as
+      select: its bits stand, a denormal's too. The rule IS computed on it,
+      from its value and a NaN delta (``NO_DELTA``'s bits), and the result
+      dropped: a rule may make of that what it likes, it cannot trap.
+    A stepped lane takes the rule's float32 operations on the numbers it
+    would take on gathered values (without a rule, the one addition
+    ``state.at[key].add(delta)`` makes). A delta that is NaN goes in as
     the canonical NaN (``NO_DELTA`` is a NaN's bit pattern)."""
     slots = launched_slots(keys.shape[0], LANE_GROUP)
     if not 0 < slots <= PREFETCH_SLOTS:
         raise ValueError(
             f"add_at_lanes: {keys.shape[0]} keys; 1 to {PREFETCH_SLOTS} "
             f"in whole groups of {LANE_GROUP} are served")
+    if step is None:
+        if len(deltas) != len(states):
+            raise ValueError(
+                f"add_at_lanes: {len(deltas)} deltas for {len(states)} "
+                f"states and no rule")
+        step = _add_brought
     # a key several slots name steps at its first: the others bring nothing
     # (inside a grid step they take the first's from the run; in the next
-    # one, where a run straddles two, they must not add it again)
+    # one, where a run straddles two, they must not step it again)
     steps = steps & jnp.concatenate(
         [jnp.ones(1, bool), keys[1:] != keys[:-1]])
     tail = slots - keys.shape[0]
@@ -717,22 +837,22 @@ def add_at_lanes(states, keys, deltas, steps, *, interpret: bool):
         deltas = [jnp.concatenate([d, jnp.zeros(tail, d.dtype)])
                   for d in deltas]
     count = len(states)
-    rows = keys >> (LANES.bit_length() - 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (slots, LANES), 1)
-    named = (lane == (keys & (LANES - 1))[:, None]) & steps[:, None]
 
     def brought(delta):
         bits = jax.lax.bitcast_convert_type(
             jnp.where(delta != delta, jnp.nan, delta), jnp.int32)
-        return jnp.where(named, bits[:, None], NO_DELTA)
+        return jnp.where(steps, bits, NO_DELTA).reshape(-1, LANES)
 
     views = [s.reshape(-1, LANES) for s in states]
-    block = pl.BlockSpec((LANE_GROUP, LANES), lambda g, rows: (g, 0),
+    # a grid step's 128 slots are one row of these; the pipeline brings a
+    # tile of eight rows every eighth step
+    block = pl.BlockSpec((SUBLANES, LANES),
+                         lambda g, rows: (g // SUBLANES, 0),
                          memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(slots // LANE_GROUP,),
-        in_specs=[block] * (1 + count)
+        in_specs=[block] * (1 + len(deltas))
         + [pl.BlockSpec(memory_space=pl.ANY)] * count,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * count,
         scratch_shapes=[pltpu.VMEM((LANE_GROUP, LANES), v.dtype)
@@ -741,12 +861,12 @@ def add_at_lanes(states, keys, deltas, steps, *, interpret: bool):
     )
     out = pl.pallas_call(
         functools.partial(_lane_add_kernel, states=count,
-                          interpret=interpret),
+                          brings=len(deltas), step=step, interpret=interpret),
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype) for v in views],
         grid_spec=grid_spec,
-        # operand order: the rows, the runs, the deltas, the states
-        input_output_aliases={2 + count + i: i for i in range(count)},
+        # operand order: the rows, the keys, the deltas, the states
+        input_output_aliases={2 + len(deltas) + i: i for i in range(count)},
         interpret=interpret,
-    )(rows, jnp.broadcast_to(rows[:, None], (slots, LANES)),
+    )(keys >> (LANES.bit_length() - 1), keys.reshape(-1, LANES),
       *[brought(d) for d in deltas], *views)
     return tuple(o.reshape(-1) for o in out)

@@ -14,19 +14,27 @@ so the server never stores a stale ``w``. Both ops are **keyed**, one device
 program an op (``jit__ftrl_keyed_get`` / ``jit__ftrl_keyed_add`` in a trace):
 
 * a Get gathers ``z`` and ``n`` at the keys named and applies the closed form;
-* an Add gathers them, steps them from the raw gradient and writes them
-  back, ``z`` and ``n`` donated.
+* an Add steps them from the raw gradient (:func:`ftrl_step`, THE rule,
+  written once) and writes them back, ``z`` and ``n`` donated.
 
-**Which program writes an Add back** is chosen once, at the table's
-creation, from the mesh and the platform, and by the op's bucket at each
-launch, by the table's row plan (``tables/row_plan.py``, which launches both
-ops and fills their records; the creation log line and every launch
-record's ``path`` say which): on ONE device whose platform the Pallas row
-kernels serve, the lane kernel (``ops/pallas_rows.add_at_lanes``, still
-inside ``jit__ftrl_keyed_add``), up to a bucket of
-``pallas_rows.PREFETCH_SLOTS`` keys; elsewhere XLA's two scatters of single
-floats, which a 3.53 GB operand prices at 11 ms each. Both write the same
-bits (``tests/test_ftrl_keyed.py``). The kernel's module brings
+**Where an Add's step runs** is chosen once, at the table's creation, from
+the mesh and the platform, and by the op's bucket at each launch, by the
+table's row plan (``tables/row_plan.py``, which launches both ops and fills
+their records; the creation log line and every launch record's ``path`` say
+which): on ONE device whose platform the Pallas row kernels serve, inside
+the lane kernel (``ops/pallas_rows.add_at_lanes``, the one custom call of
+``jit__ftrl_keyed_add``, handed :func:`ftrl_step` as its rule: it reads the
+rows of 128 the keys live in, computes the step on them where they landed
+in VMEM and writes them back; since PR 49 that program gathers no state and
+spreads nothing over a row's lanes), up to a bucket of
+``pallas_rows.PREFETCH_SLOTS`` keys; elsewhere XLA gathers the keys' ``z``
+and ``n``, the same function steps them a slot, and XLA's two scatters of
+single floats write them, which a 3.53 GB operand prices at 11 ms each.
+Both write the same ``n`` to the bit and, where both run XLA's operations
+(the CPU, kernel interpreted), the same ``z``; on the chip Mosaic's root
+and quotient need not round as XLA's do and ``z`` is held to the
+reference's tolerance (``tests/test_ftrl_keyed.py``; ``chip_smoke.py``,
+phase ``keyed``). The kernel's module brings
 ``jax.experimental.pallas``, a second of module code, so nothing here
 imports it at the top: the plan loads it, for the table that will launch
 it and for nobody else, under the fill of the table's state
@@ -97,6 +105,20 @@ def ftrl_weights(z: jax.Array, n: jax.Array, alpha: float, beta: float,
     return -shrunk / denom
 
 
+def ftrl_step(z: jax.Array, n: jax.Array, g: jax.Array, alpha: float,
+              beta: float, lambda1: float, lambda2: float
+              ) -> Tuple[jax.Array, jax.Array]:
+    """One FTRL-Proximal step of ``(z, n)`` from the raw gradient ``g``
+    (McMahan et al., Algorithm 1), elementwise, float32: the new ``(z,
+    n)``. THE rule, written once: XLA's path calls it on the values it
+    gathered a slot, the row kernel traces it on the ``(LANE_GROUP, 128)``
+    blocks of ``z`` and ``n`` it has read into VMEM."""
+    grown = n + g * g
+    sigma = (jnp.sqrt(grown) - jnp.sqrt(n)) / alpha
+    w = ftrl_weights(z, n, alpha, beta, lambda1, lambda2)
+    return z + (g - sigma * w), grown
+
+
 def _summed_by_key(keys: jax.Array, grad: jax.Array, scratch: int):
     """``(keys, grad)`` sorted by key, every slot of a key holding the sum
     of the gradients of all its slots: slots of one key then compute one
@@ -119,9 +141,13 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
                    lambda2: float, scratch: int):
     """The table's two device programs. ``ids`` is an op's bucket of keys
     (``DeviceIdsServer.launch_ids``), ``live`` the slots of it the program
-    works on (static). An Add's ``rows`` (static): None where XLA's
-    scatters write it back, else the row kernel does
-    (``pallas_rows.add_at_lanes``), interpreted (True) or compiled."""
+    works on (static). An Add's ``rows`` (static): None where XLA gathers,
+    steps and scatters, else the row kernel reads, steps and writes the
+    keys' rows (``pallas_rows.add_at_lanes`` under :func:`ftrl_step`),
+    interpreted (True) or compiled."""
+
+    step = functools.partial(ftrl_step, alpha=alpha, beta=beta,
+                             lambda1=lambda1, lambda2=lambda2)
 
     def _ftrl_keyed_get(z, n, ids, live):
         at = ids[:live]
@@ -144,22 +170,21 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
         # a slot aimed at the scratch key steps nothing, whatever the
         # caller's buffer holds past its keys
         at, g = _summed_by_key(at, jnp.where(at == scratch, 0.0, g), scratch)
-        z_old, n_old = state_of_slots(z, at), state_of_slots(n, at)
-        w = ftrl_weights(z_old, n_old, alpha, beta, lambda1, lambda2)
-        squared = g * g
-        grown = n_old + squared
-        sigma = (jnp.sqrt(grown) - jnp.sqrt(n_old)) / alpha
-        moved = g - sigma * w
         if rows is None:
-            # slots of one key (a repeated key, the scratch slots) write the
-            # value they all computed
-            return (z.at[at].set(z_old + moved, indices_are_sorted=True),
-                    n.at[at].set(grown, indices_are_sorted=True))
-        # the rows of 128 the keys live in, read, stepped and written back
-        # by the row kernel: each key's lane takes the one addition above,
-        # once (the kernel steps a repeated key at its first slot)
+            # XLA's gathers, the rule a slot, XLA's scatters: slots of one
+            # key (a repeated key, the scratch slots) write the value they
+            # all computed
+            z_new, n_new = step(state_of_slots(z, at), state_of_slots(n, at),
+                                g)
+            return (z.at[at].set(z_new, indices_are_sorted=True),
+                    n.at[at].set(n_new, indices_are_sorted=True))
+        # the rows of 128 the keys live in, read, stepped where they landed
+        # in VMEM and written back by the row kernel: each key's lane takes
+        # the rule, once (the kernel steps a repeated key at its first
+        # slot), and this program gathers no state
         return _row_kernel().add_at_lanes(
-            (z, n), at, (moved, squared), at != scratch, interpret=rows)
+            (z, n), at, (g,), at != scratch, interpret=rows,
+            step=lambda state, brought: step(*state, *brought))
 
     # named so that the compiled modules are `jit__ftrl_keyed_get` and
     # `jit__ftrl_keyed_add` in a trace
@@ -211,9 +236,9 @@ class FTRLServer(DeviceIdsServer, ServerTable):
         self._keys_get = Dashboard.counter("FTRL_KEYS_GET")
         self._keys_add = Dashboard.counter("FTRL_KEYS_ADD")
         log.info("FTRLTable %d keys (z, n: %d B) on %d %s device(s): keyed "
-                 "Get and Add, XLA gather, %s", self.size, 8 * self.padded,
-                 num_shards, self.mesh.devices.flat[0].platform,
-                 self.plan.why)
+                 "Get and Add, a Get by XLA gather, %s", self.size,
+                 8 * self.padded, num_shards,
+                 self.mesh.devices.flat[0].platform, self.plan.why)
 
     def _make_state(self, source=None) -> None:
         """``z`` and ``n`` as zeros made on the device, then ``source``'s

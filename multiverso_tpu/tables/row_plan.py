@@ -395,10 +395,13 @@ def row_plan(mesh, spans_processes: bool, *, dtype: Any = np.float32,
             plan.interpret = pallas_rows.interpret_for(platform)
             plan.path, plan.group = "pallas", pallas_rows.LANE_GROUP
             plan.largest_bucket = pallas_rows.PREFETCH_SLOTS
-            plan.why = ("an Add's rows of 128 written back by the Pallas row "
-                        "kernel%s (XLA scatter past a bucket of %d keys)" % (
-                            ", interpreted" if plan.interpret else "",
-                            plan.largest_bucket))
+            plan.why = ("an Add's rows of 128 read, stepped in VMEM and "
+                        "written back by the Pallas row kernel%s (XLA's "
+                        "gathers, step and scatters past a bucket of %d "
+                        "keys)" % (", interpreted" if plan.interpret else "",
+                                   plan.largest_bucket))
+        else:
+            plan.why = "an Add's step on XLA's gathers, written by XLA scatter"
         get, add = keyed
         # an FTRL step is not linear and sums a repeated key's gradients on
         # the device; the table holds `z` and `n` and nothing else

@@ -50,6 +50,25 @@ def test_smoke_phases_through_the_interpreted_kernel(monkeypatch):
     assert report["query_ids_equal_numpy"]
 
 
+def test_the_keyed_phase_through_the_interpreted_lane_kernel():
+    """`chip_smoke.phase_keyed` tiny: three keyed FTRL Adds through the
+    dispatcher on one CPU device, where the plan takes the lane kernel
+    interpreted (it computes the FTRL step on the rows it read: PR 49),
+    against XLA's program on a bare state: every entry of the rows named,
+    and the count of entries changed anywhere. On the CPU both sides run
+    XLA's operations, so `z` is equal in every bit too."""
+    mv.init(mesh_shape="1", **chip_smoke._INIT_FLAGS)
+    table, checks = chip_smoke.phase_keyed(5000, 600, 3,
+                                           expect_kernel=(True, True))
+    assert table._server_table.plan.path == "pallas"
+    assert checks["pallas_adds"] == 3 and checks["n_entries_differ"] == 0
+    assert checks["z_entries_differ"] == 0
+    assert checks["changed_outside_the_rows_named"] == [0, 0]
+    # 5,000 keys are 40 rows of 128: the dense head names them all
+    assert checks["rows_named"] == 40
+    mv.shutdown()
+
+
 def test_four_chip_phase_runs_the_kernel_on_every_shard(monkeypatch):
     """The smoke's four-device pass, tiny: with the gate open the tables
     report the Pallas scatter, both Adds of the kernels phase are counted

@@ -726,7 +726,8 @@ def test_the_bfloat16_gradient_control_reads_not_correct():
                 "unnamed_state_mismatch"} & failed
 
 
-# -- the row kernel's write-back against XLA's scatter (PR 41, PR 42) --------
+# -- the row kernel's step and write-back against XLA's gathers and scatters
+# (PR 41, PR 42; since PR 49 the kernel works the step out itself) -----------
 _KSIZE = 60_000          # 469 rows of 128; the scratch key shares the last
 
 
@@ -780,13 +781,22 @@ _STRADDLES = {"a key twice at slots G-1 and G": (1, 2),
     "-0.0, inf and a denormal in unnamed lanes",
     "NaN in the gradient past the keys",
     "two live-slot counts of one bucket", *_STRADDLES,
-    "600 draws of 150 keys"])
+    "600 draws of 150 keys",
+    "n = 0, |z| <= lambda1, inf and NaN in unnamed lanes",
+    "four Adds of one row's keys in a row",
+    "gradients off the binary grid"])
 def test_the_row_kernel_writes_back_what_the_scatter_writes(ref, case):
     """The keyed Add's two programs on one state: the row kernel's
-    (interpreted) and XLA's give `z` and `n` equal in EVERY bit, the keys
-    named and every other entry, the scratch entries too. A key several
-    slots name steps once, wherever the boundary of two grid steps (slot G)
-    falls among them."""
+    (interpreted), which since PR 49 computes the FTRL step on the rows it
+    read, and XLA's (gathers, the same `ftrl_step`, scatters) give `z` and
+    `n` equal in EVERY bit, the keys named and every other entry, the
+    scratch entries too: interpreted on the CPU the rule's operations are
+    XLA's on both sides. A key several slots name steps once, wherever the
+    boundary of two grid steps (slot G) falls among them. The kernel
+    computes the rule on every lane of a row it read and writes the lanes
+    named alone: what the rule makes of `n = 0`, of `|z| <= lambda1`, of
+    `inf` and of NaN in a lane nobody names is never written. Several Adds
+    on one state: each step reads what the one before wrote."""
     import jax.numpy as jnp
 
     from multiverso_tpu.ops.pallas_rows import LANE_GROUP as G
@@ -805,6 +815,20 @@ def test_the_row_kernel_writes_back_what_the_scatter_writes(ref, case):
         # rows 7-10 are named, a key in three; these lanes are not
         z0[[1001, 1004, 1007]] = -0.0, np.inf, 1e-42
         n0[[1002, 1005, 1008]] = -0.0, np.inf, 1e-42
+    elif case == "n = 0, |z| <= lambda1, inf and NaN in unnamed lanes":
+        ops = [(np.arange(1000, 1300, 3), 0)]
+        # rows 7-10 again; the rule divides by what these make
+        odd = np.array([0.5, -1.0, np.inf, -np.inf, np.nan], np.float32)
+        z0[[1001, 1004, 1007, 1010, 1013]] = odd
+        n0[[1001, 1004, 1007, 1010, 1013]] = 0.0
+        n0[[1016, 1019]] = np.nan, -1.0       # sqrt of a negative too
+        # a payload no arithmetic makes: it must stand as well
+        z0[1022:1023].view(np.uint32)[:] = 0x7fc12345
+    elif case == "four Adds of one row's keys in a row":
+        # a whole row, its neighbours' halves, the same keys every time
+        ops = [(np.arange(300 * 128 - 64, 301 * 128 + 64), 0)] * 4
+    elif case == "gradients off the binary grid":
+        ops = [(np.arange(1000, 1300), 0), (np.arange(1100, 1500), 0)]
     else:
         ops = [_row_keys(case, rng, G)]
     _, add = ft._make_programs(scratch=_KSIZE, **OPT)
@@ -819,6 +843,9 @@ def test_the_row_kernel_writes_back_what_the_scatter_writes(ref, case):
         ids[:count] = keys
         grad = np.full(count + longer, np.nan, np.float32)
         grad[:count] = ref.to_float(ref.grad_k(rng, count))
+        if case == "gradients off the binary grid":
+            # n + g*g rounds here: a contracted multiply-add would differ
+            grad[:count] = rng.standard_normal(count).astype(np.float32) * 3
         for state, rows in zip(states, (None, True)):
             state[:] = add(*state, jnp.asarray(ids), jnp.asarray(grad),
                            live=live, rows=rows)

@@ -270,3 +270,91 @@ def test_wide_table_needs_whole_tiles_of_rows():
         scatter_add_rows(jnp.zeros((16, 256)), ids, jnp.zeros((1, 300)))
     with pytest.raises(ValueError, match="lane tiles"):
         gather_rows(jnp.zeros((16, 200)), ids)
+
+
+# -- `add_at_lanes`: single floats into lane-dense states, by row descriptors --
+def _lane_case(rng):
+    """Two states of 80 rows of 128 and 300 sorted slots over them: keys
+    that share a row, a key three slots name (its slots either side of slot
+    `LANE_GROUP`), pads that repeat the last key, a slot that steps
+    nothing among its row's live slots, a denormal, `-0.0` and `inf` in
+    lanes nobody names."""
+    group = pallas_rows.LANE_GROUP
+    states = [rng.integers(-99, 99, 80 * 128).astype(np.float32)
+              for _ in range(2)]
+    keys = np.sort(rng.choice(9_000, 296, replace=False)).astype(np.int32)
+    keys = np.sort(np.concatenate([keys, [keys[group - 1]] * 2,
+                                   [keys[-1]] * 2])).astype(np.int32)
+    assert keys[group - 1] == keys[group] == keys[group + 1]
+    steps = np.ones(len(keys), bool)
+    steps[-2:] = False          # pads: they repeat the last key
+    steps[40] = False           # and one slot among live ones
+    quiet = np.setdiff1d(np.arange(80 * 128), keys)[:3]
+    states[0][quiet] = 1e-42, -0.0, np.inf
+    deltas = [rng.integers(1, 9, len(keys)).astype(np.float32)
+              for _ in range(2)]
+    # a key takes what its FIRST slot brings, if that slot steps
+    first = np.concatenate([[True], keys[1:] != keys[:-1]]) & steps
+    return states, keys, deltas, steps, first, quiet
+
+
+def test_add_at_lanes_without_a_rule_adds(rng):
+    """`add_at_lanes` with no `step`: every state takes its own delta at
+    the keys whose first slot steps, one float32 addition; every other
+    entry keeps its bits (300 slots: three groups, the last filled with
+    slots of the last key's run)."""
+    import jax
+
+    states, keys, deltas, steps, first, quiet = _lane_case(rng)
+    expect = [s.copy() for s in states]
+    for want, delta in zip(expect, deltas):
+        want[keys[first]] += delta[first]
+    out = jax.jit(lambda s, k, d, m: pallas_rows.add_at_lanes(
+        s, k, d, m, interpret=True))(
+            tuple(states), keys, tuple(deltas), steps)
+    for want, got in zip(expect, out):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      want.view(np.uint32))
+    assert not np.array_equal(expect[0], states[0])
+    assert (expect[0][quiet].view(np.uint32)
+            == states[0][quiet].view(np.uint32)).all()
+
+
+def test_add_at_lanes_applies_a_callers_rule_in_the_kernel(rng):
+    """A rule of the caller's, traced on the blocks the kernel read: ONE
+    delta for two states (`a += d`, `b = max(b, a_old * d)`), computed on
+    every lane of a row and written at the lanes named alone."""
+    import jax
+
+    states, keys, deltas, steps, first, quiet = _lane_case(rng)
+    a, b = (s.copy() for s in states)
+    d = deltas[0]
+    b[keys[first]] = np.maximum(b[keys[first]], a[keys[first]] * d[first])
+    a[keys[first]] += d[first]
+
+    def rule(olds, brought):
+        (a, b), (d,) = olds, brought
+        return a + d, jnp.maximum(b, a * d)
+
+    out = jax.jit(lambda s, k, d, m: pallas_rows.add_at_lanes(
+        s, k, (d,), m, step=rule, interpret=True))(
+            tuple(states), keys, d, steps)
+    for want, got in zip((a, b), out):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      want.view(np.uint32))
+
+
+def test_add_at_lanes_refuses_what_it_cannot_serve():
+    """No keys, more than the scalar prefetch holds, and without a rule a
+    count of deltas that is not the states'."""
+    state = jnp.zeros(1024, jnp.float32)
+    some = jnp.zeros(8, jnp.int32)
+    for keys in (jnp.zeros(0, jnp.int32),
+                 jnp.zeros(pallas_rows.PREFETCH_SLOTS + 1, jnp.int32)):
+        with pytest.raises(ValueError, match="add_at_lanes"):
+            pallas_rows.add_at_lanes((state,), keys, (keys.astype(
+                jnp.float32),), keys >= 0, interpret=True)
+    with pytest.raises(ValueError, match="no rule"):
+        pallas_rows.add_at_lanes((state, state), some,
+                                 (some.astype(jnp.float32),), some >= 0,
+                                 interpret=True)

@@ -137,9 +137,12 @@ def test_the_ftrl_tables_plan(case, devices, platform, want, monkeypatch):
     assert (plan.slot_bytes, plan.state_slot_bytes, plan.arrays) == (8, 8, 2)
     if want[0] == "pallas":
         assert plan.group == pallas_rows.LANE_GROUP
+        # the creation log line says where the step runs (PR 49)
+        assert "stepped in VMEM" in plan.why
         assert "Pallas row kernel" in plan.why
     else:
-        assert plan.why == "XLA scatter"
+        assert "step on XLA's gathers" in plan.why
+        assert "XLA scatter" in plan.why
     # a launch by bucket: past the kernel's scalar prefetch XLA's scatters
     # serve the Add, and the counters and the program's `rows` say which
     served = []

@@ -410,10 +410,14 @@ def test_keyed_ftrl_add_compiles_with_the_row_kernel_in_place(one_chip):
     named in a 131,072 bucket) on the path one chip takes (PR 42): ONE
     program under the name a trace is read by, ONE custom call of the row
     kernel and no scatter into a state, `z` and `n` (3.53 GB each) aliased
-    whole, and no temporary near a state's size: the rows the kernel takes
-    (the runs and two delta blocks a slot: 177 MB) are all there is. The
-    kernel refuses more keys than its scalar prefetch holds (the table
-    keeps XLA's program there: `FTRLServer._rows_for`)."""
+    whole. Since PR 49 the kernel works the FTRL step out on the rows it
+    reads: the program gathers NO state (nothing but the custom call and
+    the bitcasts around it touches `z` or `n`, as a flat array or as rows
+    of 128), and it writes no block a slot for the kernel: the keys and
+    the gradient go in lane-dense, so the temporaries are the sort's (a
+    few arrays of the slots, under 8 MB where the three blocks a slot were
+    177). The kernel refuses more keys than its scalar prefetch holds (the
+    table keeps XLA's program there: `RowPlan.largest_bucket`)."""
     from multiverso_tpu.tables import ftrl_table as ft
     from multiverso_tpu.tables.device_ids import live_slots
 
@@ -434,8 +438,20 @@ def test_keyed_ftrl_add_compiles_with_the_row_kernel_in_place(one_chip):
     # the only scatter left sums a repeated key's gradients, over the slots
     assert "scatter(f32[%d]" % padded not in text
     assert "scatter(f32[%d]" % live in text
+    # no gather of a state: a state, flat or as rows of 128, is seen by no
+    # computation but the entry, and there by the custom call alone
+    flat, rows = "f32[%d]" % padded, "f32[%d,128]" % (padded // 128)
+    fused = "\n".join(text[:text.index("ENTRY")].splitlines()[1:])
+    assert flat not in fused and rows not in fused
+    touching = [line for line in entry[1:] if flat in line or rows in line]
+    assert touching and all(
+        any(" %s(" % op in line for op in (
+            "parameter", "bitcast", "custom-call", "get-tuple-element",
+            "tuple")) for line in touching), touching
+    assert not [line for line in text.splitlines()
+                if " gather(" in line and "slice_sizes={1,128}" in line]
     assert mem.alias_size_in_bytes >= 2 * 4 * padded
-    assert mem.temp_size_in_bytes <= 3 * 115_200 * 512 + (8 << 20)
+    assert mem.temp_size_in_bytes <= 8 << 20
     # the cell's bucket is the largest the kernel's scalar prefetch holds
     assert bucket == pallas_rows.PREFETCH_SLOTS
     with pytest.raises(ValueError, match="add_at_lanes"):
