@@ -463,6 +463,7 @@ class _OpFields(NamedTuple):
     waits: int = 0
     ids_from: str = ""
     ids_ready: int = 0
+    ordinal: int = 0
 
 
 _OP_DEFAULTS = tuple(_OpFields._field_defaults.get(f)
@@ -532,14 +533,15 @@ class OpRing:
                max_shard_n: int = 0, exchange_bytes: int = 0,
                dups: int = 0, updater: str = "", state_rows: int = 0,
                state_bytes: int = 0, waits: int = 0, ids_from: str = "",
-               ids_ready: int = 0) -> None:
+               ids_ready: int = 0, ordinal: int = 0) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
                                          path, descriptors, bytes, shards,
                                          max_shard_n, exchange_bytes, dups,
                                          updater, state_rows, state_bytes,
-                                         waits, ids_from, ids_ready)
+                                         waits, ids_from, ids_ready,
+                                         ordinal)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the
@@ -602,7 +604,7 @@ class _Section:
                  "_parent", "_outer_op", "_cpu", "_cpu0", "_ann",
                  "path", "descriptors", "bytes", "shards", "max_shard_n",
                  "exchange_bytes", "dups", "updater", "state_rows",
-                 "state_bytes", "waits", "ids_from", "ids_ready")
+                 "state_bytes", "waits", "ids_from", "ids_ready", "ordinal")
 
     def __init__(self, name: str, feeds: Optional[tuple], op: int, n: int,
                  cpu: bool) -> None:
@@ -614,6 +616,7 @@ class _Section:
         self.dups = 0
         self.updater, self.state_rows, self.state_bytes = "", 0, 0
         self.ids_from, self.ids_ready = "", 0
+        self.ordinal = 0
 
     def __enter__(self) -> "_Section":
         if Dashboard.profile_annotations:
@@ -646,7 +649,8 @@ class _Section:
                         self.descriptors, self.bytes, self.shards,
                         self.max_shard_n, self.exchange_bytes, self.dups,
                         self.updater, self.state_rows, self.state_bytes,
-                        self.waits, self.ids_from, self.ids_ready)
+                        self.waits, self.ids_from, self.ids_ready,
+                        self.ordinal)
         if self._feeds is not None:
             seconds = self.dur_ns * 1e-9
             for unit in self._feeds:
@@ -661,7 +665,7 @@ class _Off:
     __slots__ = ("n", "op", "path", "descriptors", "bytes", "shards",
                  "max_shard_n", "exchange_bytes", "dups", "updater",
                  "state_rows", "state_bytes", "waits", "ids_from",
-                 "ids_ready")
+                 "ids_ready", "ordinal")
     id = 0
 
     def __enter__(self) -> "_Off":

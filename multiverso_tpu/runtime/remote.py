@@ -83,6 +83,7 @@ from multiverso_tpu.runtime import wire
 from multiverso_tpu.tables.array_table import ArrayWorker
 from multiverso_tpu.tables.base import (Completion, WorkerTable,
                                         merge_duplicate_rows)
+from multiverso_tpu.tables.ftrl_table import FTRLWorker
 from multiverso_tpu.tables.kv_table import KVWorker
 from multiverso_tpu.tables.matrix_table import MatrixWorker
 from multiverso_tpu.tables.sparse_table import SparseWorker
@@ -125,10 +126,12 @@ class _WireCompletion:
     connection the request arrived on. A keyed Get's result arrives
     launched and not fetched (``takes_pending``): nobody in this process
     waits for it, so the server's finishing thread fetches, encodes and
-    sends it behind the dispatcher (``RemoteServer.finish_reply``)."""
+    sends it behind the dispatcher (``RemoteServer.finish_reply``).
+    ``ordinal``: the Add ordinal the dispatcher stamped the op with
+    (``Server._stamp``: a table whose Adds are ordered), else None."""
 
-    __slots__ = ("_server", "_conn", "_template", "_compress")
-    takes_pending = True
+    __slots__ = ("_server", "_conn", "_template", "_compress", "ordinal")
+    takes_pending = takes_ordinal = True
 
     def __init__(self, server: "RemoteServer", conn, template: Message,
                  compress: bool) -> None:
@@ -136,6 +139,7 @@ class _WireCompletion:
         self._conn = conn
         self._template = template
         self._compress = compress
+        self.ordinal: Optional[int] = None
 
     def _settle(self, reply_type: MsgType, result: Any) -> None:
         if isinstance(result, PendingHostRead):
@@ -184,6 +188,11 @@ class _NetCompletion(_WireCompletion):
         t = self._template
         if watermark is None:
             watermark = self._server.append_watermark()
+        if self.ordinal is not None and msg_type != MsgType.Reply_Error:
+            # in the payload, so the dedup store keeps it with the reply: a
+            # retried Add is answered with the ordinal of its one apply
+            payload = wire.Ordered(payload, self.ordinal)
+            self._server._ordered.add(1)
         with span("WIRE_REPLY", op=t.req_id or t.msg_id):
             msg = Message(src=t.dst, dst=t.src, type=msg_type,
                           table_id=t.table_id, msg_id=t.msg_id,
@@ -297,6 +306,8 @@ class RemoteServer:
         self._finish_cv = threading.Condition()
         self._finishing = False
         self._finisher: Optional[threading.Thread] = None
+        # replies stamped with an Add ordinal (`_NetCompletion._reply`)
+        self._ordered = Dashboard.counter("ADDS_ORDERED")
         self._finished_behind = Dashboard.counter("REPLIES_FINISHED_BEHIND")
         self._finished_inline = Dashboard.counter("REPLIES_FINISHED_INLINE")
         self._finish_waits = (Dashboard.get("REPLY_FINISH_WAIT"),
@@ -756,6 +767,11 @@ class RemoteServer:
             return
         request = wire.decode(msg.data)
         completion = _NetCompletion(self, msg._conn, msg, compress)
+        # a table kind that counts its ops that came over the wire
+        served = getattr(self._zoo.server._tables.get(msg.table_id),
+                         "served_over_wire", None)
+        if served is not None:
+            served[msg.type].add(1)
         # req_id rides into the dispatcher so server-side stages (gate
         # defer/release, WAL append, apply) land on the request's trace
         forward = Message(
@@ -1623,10 +1639,13 @@ class RemoteClient:
                     completion.fail(WrongShardError(
                         refusal.get("layout_version", 0),
                         refusal.get("manifest")))
-                elif msg.type == MsgType.Reply_Add:
-                    completion.done(None)
                 else:
-                    completion.done(wire.decode(msg.data))
+                    result = wire.decode(msg.data)
+                    if isinstance(result, wire.Ordered):
+                        completion.ordinal = result.ordinal
+                        result = result.value
+                    completion.done(None if msg.type == MsgType.Reply_Add
+                                    else result)
             except Exception as exc:  # noqa: BLE001 — a malformed reply must
                 # fail its waiter, not kill the pump (which would hang every
                 # later request forever)
@@ -1803,9 +1822,7 @@ class RemoteClient:
                 f"table {table_id} is a matrix_group: the group op is not "
                 f"served to remote workers (ROADMAP Queue 2 item 10)")
         if kind == "ftrl":
-            raise KeyError(
-                f"table {table_id} is an ftrl table: its keyed ops are not "
-                f"served to remote workers (ROADMAP Queue 2 item 10)")
+            return _RemoteFTRLWorker(spec, table_id, self._channel)
         raise KeyError(f"unknown remote table kind {kind!r}")
 
     def tables(self) -> List[WorkerTable]:
@@ -1921,6 +1938,36 @@ class _RemoteMatrixWorker(MatrixWorker):
     def get_device(self):
         raise RuntimeError("get_device() needs mesh residency; remote "
                            "clients are off-mesh — use get()")
+
+    def get_state_device(self, name):
+        raise RuntimeError("get_state_device() needs mesh residency; "
+                           "remote clients are off-mesh")
+
+
+class _RemoteFTRLWorker(FTRLWorker):
+    """The keyed FTRL table's host forms over the wire (``get(keys)``,
+    ``add(keys, grads)``, ``get_async`` / ``add_async`` + ``wait``): the
+    worker's own shaping (keys int32 and checked against the table's size
+    before anything is sent), the server's own serving (``SERVE_HANDLE``,
+    the dispatcher, ``FTRLServer.process_add`` / ``launch_get``,
+    ``finish_reply``). ``last_ordinal`` (``FTRLWorker``) reads the Add
+    ordinal off the reply. Device IO is in-process only."""
+
+    supports_device_io = False
+
+    def __init__(self, spec, table_id: int, channel: RemoteChannel) -> None:
+        WorkerTable.__init__(self, channel=channel)
+        self._waited = threading.local()
+        self.table_id = table_id
+        self.size = int(spec["size"])
+
+    def get_device_async(self, keys):
+        log.fatal("device IO is in-process only; remote tables use "
+                  "get/get_async (host arrays)")
+
+    def add_device_async(self, grads, keys):
+        log.fatal("device IO is in-process only; remote tables use "
+                  "add/add_async (host arrays)")
 
     def get_state_device(self, name):
         raise RuntimeError("get_state_device() needs mesh residency; "

@@ -199,6 +199,10 @@ class Server:
         self.admission = AdmissionGate.from_flags()
         self._lane_sort = (self.reorders_lanes
                            and bool(config.get_flag("priority_lanes")))
+        # table_id -> Adds applied, of the tables whose Adds are ordered
+        # (``ServerTable.orders_adds``): the Add ordinal (``_stamp``).
+        # Written on the dispatcher thread alone.
+        self._adds_applied: Dict[int, int] = {}
 
     def _on_batch_cap_change(self, _name: str, value) -> None:
         self._apply_batch_cap = max(0, int(value))
@@ -522,22 +526,44 @@ class Server:
             log.error("server: unhandled message type %s", msg.type)
 
     @dispatcher_only
+    def _stamp(self, table_id: int, completion, served, add: bool) -> None:
+        """The Add ordinal of an op on a table whose Adds are ordered, on
+        its completion (which replies with it) and on its service record
+        (``served``): an Add that has just been applied takes the table's
+        next place, 1, 2, ...; a Get about to launch takes the number of
+        Adds applied so far, so that it returns the state after exactly
+        that many. One count a table, on this thread, which applies and
+        launches in one order: the ordinals are that order."""
+        k = self._adds_applied.get(table_id, 0)
+        if add:
+            k = self._adds_applied[table_id] = k + 1
+        served.ordinal = k
+        if getattr(completion, "takes_ordinal", False):
+            completion.ordinal = k
+
+    @dispatcher_only
     def _process_add(self, msg: Message) -> None:
-        with monitor("SERVER_PROCESS_ADD_MSG", n=1):
+        with monitor("SERVER_PROCESS_ADD_MSG", n=1) as served:
             request, completion = msg.data
             self._wal_append(msg)
             hop(msg.req_id, "apply_add")
+            table = self._tables[msg.table_id]
             # process_add may return a fused-get payload (ArrayTable's
             # add+get sync path); plain adds return None as before
-            completion.done(self._tables[msg.table_id].process_add(request))
+            result = table.process_add(request)
+            if table.orders_adds:
+                self._stamp(msg.table_id, completion, served, add=True)
+            completion.done(result)
 
     @dispatcher_only
     def _process_get(self, msg: Message) -> None:
-        with monitor("SERVER_PROCESS_GET_MSG"):
+        with monitor("SERVER_PROCESS_GET_MSG") as served:
             request, completion = msg.data
             hop(msg.req_id, "serve_get")
-            complete_get(completion,
-                         self._tables[msg.table_id].launch_get(request))
+            table = self._tables[msg.table_id]
+            if table.orders_adds:
+                self._stamp(msg.table_id, completion, served, add=False)
+            complete_get(completion, table.launch_get(request))
 
     @dispatcher_only
     def _process_query(self, msg: Message) -> None:

@@ -39,6 +39,18 @@ from multiverso_tpu.utils.quantization import (QuantizedDelta, sparse_encode,
 _COMPRESS_MIN_SIZE = 64
 
 
+class Ordered:
+    """A reply's payload ``value`` with the Add ``ordinal`` its op was
+    stamped with (``Server._stamp``: a table whose Adds are ordered). It
+    rides in the structure tree, ``{"t": "ord", "k": ordinal, "v": ...}``,
+    no blob of its own; ``RemoteClient._pump`` takes it apart."""
+
+    __slots__ = ("value", "ordinal")
+
+    def __init__(self, value: Any, ordinal: int) -> None:
+        self.value, self.ordinal = value, int(ordinal)
+
+
 def encode(obj: Any, compress: bool = False) -> List[np.ndarray]:
     """Structure -> [json-tree blob, ndarray blobs...]. Timed under the
     WIRE_ENCODE monitor (the reference instrumented exactly its serialize
@@ -70,6 +82,8 @@ def _encode(obj: Any, compress: bool) -> List[np.ndarray]:
                           o.rho, o.lambda_]}
         if isinstance(o, GetOption):
             return {"t": "getopt", "v": o.worker_id}
+        if isinstance(o, Ordered):
+            return {"t": "ord", "k": o.ordinal, "v": enc(o.value)}
         if isinstance(o, QuantizedDelta):
             # pre-encoded by the client's ErrorFeedback (the OneBits-slot
             # codec); rides as one uint8 blob, decoded server-side to
@@ -142,6 +156,8 @@ def _decode(blobs: List[np.ndarray]) -> Any:
             return GetOption(int(node["v"]))
         if t == "arr":
             return data[node["i"]]
+        if t == "ord":
+            return Ordered(dec(node["v"]), node["k"])
         if t == "sparse":
             from multiverso_tpu.utils.quantization import sparse_decode
             shape = tuple(node["shape"])

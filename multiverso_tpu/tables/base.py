@@ -160,16 +160,23 @@ class Completion:
     A keyed host Get is done with its ``PendingHostRead``
     (``takes_pending``): the waiter's own thread fetches the rows in
     ``wait``, not the dispatcher, which has gone on to the next
-    message."""
+    message.
 
-    __slots__ = ("_waiter", "result", "error", "done_ns", "wake_ns")
-    takes_pending = True
+    ``ordinal``: the table's Add ordinal the op was stamped with, where
+    the table orders its Adds (``ServerTable.orders_adds``; the async
+    dispatcher writes it before ``done``, a remote client's pump from the
+    reply), else None."""
+
+    __slots__ = ("_waiter", "result", "error", "done_ns", "wake_ns",
+                 "ordinal")
+    takes_pending = takes_ordinal = True
 
     def __init__(self) -> None:
         self._waiter = Waiter(1)
         self.result: Any = None
         self.error: Optional[BaseException] = None
         self.done_ns = self.wake_ns = 0
+        self.ordinal: Optional[int] = None
 
     def done(self, result: Any) -> None:
         self.result = result
@@ -364,6 +371,14 @@ class ServerTable:
         self._opt_cache_lock = threading.Lock()
 
     _OPT_CACHE_MAX = 256
+
+    # True on a table whose Adds do not commute (each reads the state the
+    # one before it left: an optimizer step), so that their order is part
+    # of the result: its dispatcher never merges them, and the async
+    # server stamps every reply to an op on it with the table's Add
+    # ordinal (``Server._stamp``). A table with a row plan says what its
+    # plan says (``DeviceIdsServer.orders_adds``).
+    orders_adds = False
 
     def _option_consts(self, option):
         """Device constants (worker index, scalars envelope) for an

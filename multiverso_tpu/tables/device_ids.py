@@ -136,6 +136,14 @@ class DeviceIdsServer:
     own ``launch_form``), and calls ``_init_device_ids`` once it knows its sentinel (the id of the
     scratch slot padding aims at) and whether it is on one device."""
 
+    @property
+    def orders_adds(self) -> bool:
+        """``ServerTable.orders_adds`` as the table's row plan says it: a
+        rule that is not linear (``RowPlan.merge`` declined) makes an Add
+        an optimizer step, the dispatcher never merges them and the async
+        server stamps their order on the replies (``Server._stamp``)."""
+        return not self.plan.merge
+
     def _init_device_ids(self, sentinel: int, one_device: bool) -> None:
         self._pad_id = int(sentinel)
         # of the launches on ids their caller sent up, those on the ids the

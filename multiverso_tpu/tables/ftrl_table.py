@@ -60,19 +60,23 @@ The whole-array ops this table had (``get()``, ``add(grad)``) are the keyed
 ops over every key.
 
 Not served, refused by name: a key outside ``[0, size)`` (on the caller's
-thread); a gradient shorter than its keys; a remote client's ``table()``
-(``runtime/remote.py``). Served and stated: a mesh of several devices takes
+thread); a gradient shorter than its keys; a remote client's device IO (its
+host forms are served: ``runtime/remote.py``, ``_RemoteFTRLWorker``).
+Served and stated: a mesh of several devices takes
 XLA's partitioned gather and scatter (the dispatcher sends the ids up; a
 device Get's result is committed to the mesh's first device); the ``sync`` /
 SSP / deterministic servers serve the ops message by message as the async
 server does. An FTRL step is not linear, so Adds never fuse
 (``merge_add_requests`` is the base class's: per message) and they do not
-commute: the order of acknowledgement is part of the result.
+commute: the order of acknowledgement is part of the result. The async
+server makes that order visible: every reply to an op on this table carries
+the table's Add ordinal (``Server._stamp``; ``FTRLWorker.last_ordinal``).
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -235,6 +239,10 @@ class FTRLServer(DeviceIdsServer, ServerTable):
         self._init_device_ids(self.scratch_key, num_shards == 1)
         self._keys_get = Dashboard.counter("FTRL_KEYS_GET")
         self._keys_add = Dashboard.counter("FTRL_KEYS_ADD")
+        # the ops that came from a remote client (`RemoteServer._handle`)
+        self.served_over_wire = {
+            MsgType.Request_Get: Dashboard.counter("FTRL_SERVED_GET"),
+            MsgType.Request_Add: Dashboard.counter("FTRL_SERVED_ADD")}
         log.info("FTRLTable %d keys (z, n: %d B) on %d %s device(s): keyed "
                  "Get and Add, a Get by XLA gather, %s", self.size,
                  8 * self.padded, num_shards,
@@ -295,12 +303,19 @@ class FTRLServer(DeviceIdsServer, ServerTable):
                 log.fatal("FTRLTable.add: %d keys but %d gradient values",
                           n, grad.shape[0])
             took, ids_from = self.plan.took_ids(self, keys, "add", took)
-            grad = (self.plan.device_delta(grad, took.bucket)
-                    if isinstance(grad, jax.Array)
-                    else async_upload(grad[:n]))
+            live = live_slots(n, took.bucket)
+            if isinstance(grad, jax.Array):
+                grad = self.plan.device_delta(grad, took.bucket)
+            else:
+                # a host gradient goes up at the slots its program works
+                # on, zeros past its keys: ONE program for every count of
+                # keys under those slots, as for a Get, not one a count
+                # (a served trainer's minibatches each name another count)
+                padded = np.zeros(live, np.float32)
+                padded[:n] = grad[:n]
+                grad = async_upload(padded)
             self.z, self.n = self.plan.launch_add(
-                (self.z, self.n), took, grad, live_slots(n, took.bucket),
-                ids_from)
+                (self.z, self.n), took, grad, live, ids_from)
             self._keys_add.add(n)
 
     def process_get(self, request):
@@ -352,6 +367,7 @@ class FTRLWorker(DeviceIdsWorker, WorkerTable):
                  init: Optional[Callable] = None,
                  server: Optional[FTRLServer] = None) -> None:
         super().__init__()
+        self._waited = threading.local()
         self.size = int(size)
         self._server_table = server or FTRLServer(size, alpha, beta,
                                                   lambda1, lambda2, init)
@@ -363,6 +379,30 @@ class FTRLWorker(DeviceIdsWorker, WorkerTable):
     @property
     def scratch_key(self) -> int:
         return self._server_table.scratch_key
+
+    # -- the order of the Adds ----------------------------------------------
+    def wait(self, msg_id: int) -> Any:
+        """``WorkerTable.wait``; the op's Add ordinal is then
+        ``last_ordinal``."""
+        completion = self._pending.get(msg_id)
+        result = super().wait(msg_id)
+        self._waited.ordinal = completion.ordinal
+        return result
+
+    @property
+    def last_ordinal(self) -> Optional[int]:
+        """The table's Add ordinal that the async server stamped on the op
+        the calling thread last waited for on this proxy (``wait``, or the
+        ``get`` / ``add`` that wait themselves). An Add's: its own place,
+        1, 2, ..., in the ONE order in which the server applied the Adds of
+        every worker to this table; a retried Add keeps the place of its
+        one application. A Get's: how many Adds had been applied when it
+        was launched; the weights it returns are the state after exactly
+        those. None before any op, and under a server that stamps nothing
+        (the round-gated and the deterministic servers: their rounds are
+        the order). It orders the Adds of one table of one serving
+        process, for as long as that process lives."""
+        return getattr(self._waited, "ordinal", None)
 
     def _keys(self, keys, submit=None) -> Optional[np.ndarray]:
         """A request's keys: int32, inside the table; None is every key."""

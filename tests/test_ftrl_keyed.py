@@ -403,14 +403,24 @@ def test_the_records_of_a_keyed_op_carry_the_matrix_ops_fields(
         "layers", "pallas_row_share.ftrlctr").read(Run()) == 100.0
 
 
-def test_a_remote_client_is_refused_by_name(ref):
+def test_a_remote_client_is_served_the_host_forms(ref):
+    """`RemoteClient.table` gives the kind's proxy (until PR 50 it refused
+    the kind by name); `tests/test_ftrl_served.py` has the served path."""
     mv.init(mesh_shape="1", remote_workers=1)
     table = _table(ref)
     endpoint = mv.serve("127.0.0.1:0")
     client = mv.remote_connect(endpoint)
     try:
-        with pytest.raises(KeyError, match="ftrl.*not served to remote"):
-            client.table(table.table_id)
+        remote = client.table(table.table_id)
+        replay = ref.Replay(np.arange(SIZE), SEED, OPT)
+        rng = np.random.default_rng(11)
+        keys = _keys(rng, 200)
+        grad = ref.to_float(ref.grad_k(rng, len(keys)))
+        remote.add(keys, grad)
+        replay.add(replay.plan(keys), grad)
+        z, _, want, steps = replay.state(keys)
+        assert ref.w_error(remote.get(keys), want, z, steps, OPT) <= 1
+        _check(ref, table, replay)
     finally:
         client.close()
 

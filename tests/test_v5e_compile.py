@@ -405,6 +405,33 @@ def test_group_slab_programs_compile_at_19_million_rows(one_chip, program):
         bucket * 128 * 4 + (1 << 20))
 
 
+def test_a_served_keyed_ftrl_add_compiles_at_its_gradients_length(one_chip):
+    """The keyed FTRL Add of the served cell (`ftrlctr8.remote-steps`:
+    20,100-20,600 keys of a 2,048-sample minibatch in a bucket of 32,768):
+    the keys go up at the bucket and a host gradient at the slots the
+    program works on (PR 50), so the program's shape follows the bucket
+    and those slots alone; the row kernel still serves it."""
+    from multiverso_tpu.tables import ftrl_table as ft
+    from multiverso_tpu.tables.device_ids import live_slots
+
+    size, bucket = 882_774_573, 32_768
+    padded = -(-(size + 1) // 1024) * 1024
+    live = live_slots(20_370, bucket)
+    assert live == live_slots(19_500, bucket) == 20_488
+    _, add = ft._make_programs(0.1, 1.0, 1.0, 1.0, size)
+    state = jax.ShapeDtypeStruct((padded,), jnp.float32, sharding=one_chip)
+    compiled = add.lower(
+        state, state,
+        jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((live,), jnp.float32, sharding=one_chip),
+        live=live, rows=False).compile()
+    text, mem = _hlo_text(compiled), compiled.memory_analysis()
+    assert text.splitlines()[0].startswith("HloModule jit__ftrl_keyed_add")
+    entry = text[text.index("ENTRY"):].splitlines()
+    assert len([line for line in entry if "tpu_custom_call" in line]) == 1
+    assert mem.alias_size_in_bytes >= 2 * 4 * padded
+
+
 def test_keyed_ftrl_add_compiles_with_the_row_kernel_in_place(one_chip):
     """The keyed FTRL Add of the benchmark's cell (882,774,573 keys, 111,300
     named in a 131,072 bucket) on the path one chip takes (PR 42): ONE
