@@ -273,8 +273,8 @@ def phase_keyed(size, n_keys, adds, expect_kernel=(True, False), seed=0):
         bucket = max(next_pow2(len(keys) + 1), 128)
         ids = np.full(bucket, size, np.int32)
         ids[:len(keys)] = keys
-        z, n = add(z, n, jnp.asarray(ids), jnp.asarray(grad),
-                   live=live_slots(len(keys), bucket), rows=None)
+        z, n, _ = add(z, n, jnp.asarray(ids), jnp.asarray(grad),
+                      live=live_slots(len(keys), bucket), rows=None)
     want = [np.asarray(take(s, rows)) for s in (z, n)]
     changed_xla = changed(z, n)
     del z, n

@@ -49,32 +49,34 @@ Contracts (enforced by the caller, `tables.matrix_table.MatrixServer`):
   there in HBM either way).
 
 * ``add_at_lanes`` (PR 42; PR 41 built it and was refused for what it cost
-  a process's set-up; since PR 49 it works a caller's rule out itself) is
-  the update of SINGLE float32 values of lane-dense 1-D states (the keyed
-  FTRL table's ``z`` and ``n``) by the same row descriptors: a state is
-  read as rows of 128, a key lives in row ``key >> 7``, lane ``key & 127``,
-  and the rule (``step``, plain ``jax.numpy``: the FTRL table hands it
-  ``ftrl_table.ftrl_step``; without one, ``state[key] += delta``) is traced
-  into the kernel and computed on the ``(LANE_GROUP, 128)`` blocks the rows
-  landed in, so that the caller's program gathers no state and writes no
-  block a slot for the kernel: the keys and the deltas go in lane-dense and
-  a group's row of 128 slots is turned onto sublanes in VMEM. This module
-  knows nothing of FTRL. Its contract is not ``scatter_add_rows``': the
-  keys are SORTED ascending (their rows are the scalar prefetch); keys
-  that share a row are expected (a row's slots are adjacent, each takes
-  what all of them bring before it steps and writes, a run that straddles
-  two grid steps is written in both, each stepping the lanes its own slots
-  name, the first's write-backs awaited); a key several slots name is
-  stepped once, by its first slot, wherever a grid step's boundary falls
-  among them; pad slots may aim at a row that live keys share (the FTRL
-  table's scratch key does) and are part of its run; a lane no stepping
+  a process's set-up; since PR 49 it works a caller's rule out itself;
+  since PR 51 it walks ROWS, not slots) is the update of SINGLE float32
+  values of lane-dense 1-D states (the keyed FTRL table's ``z`` and ``n``)
+  by the same row descriptors: a state is read as rows of 128, a key lives
+  in row ``key >> 7``, lane ``key & 127``, and the rule (``step``, plain
+  ``jax.numpy``: the FTRL table hands it ``ftrl_table.ftrl_step``; without
+  one, ``state[key] += delta``) is traced into the kernel and computed on
+  the ``(LANE_GROUP, 128)`` blocks the rows landed in, so that the
+  caller's program gathers no state and writes no block a slot for the
+  kernel. This module knows nothing of FTRL. Its contract is not
+  ``scatter_add_rows``': the keys are SORTED ascending; keys that share a
+  row are expected, and the row is read ONCE, stepped once and written
+  once: the distinct rows are compacted on the device in front of the
+  kernel (their list, their count ``U`` and, for each group of 128 of
+  them, the chunks of 128 sorted slots that hold its keys: the scalar
+  prefetch), a grid step walks ``LANE_GROUP`` ROWS and ``ceil(U /
+  LANE_GROUP)`` steps are live (the grid is the slots', the bound; a step
+  past ``U`` does nothing), and what a step's slots bring is folded onto
+  its rows in VMEM by the matrix unit, the delta's four bytes as int8
+  planes between two one-hots, summed in int32, exactly; a key several slots name is stepped once,
+  by its first slot; pad slots may aim at a row that live keys share (the
+  FTRL table's scratch key does) and bring nothing; a lane no stepping
   slot names is written back as read, by a select (the rule is computed on
   it and dropped; adding ``-0.0`` would do for every float32 but a
   denormal, which the vector unit flushes: PR 41's first build's test
   found it); the slots, filled to whole groups here, are at most
-  ``PREFETCH_SLOTS``; a grid step walks ``LANE_GROUP`` slots, a group of
-  the kernel's own (the standing kernels keep ``ROW_GROUP``). Its docstring
-  has the rest.
+  ``PREFETCH_SLOTS``. ``U`` is what adapts: the program reads it from its
+  input and returns it. Its docstring has the rest.
 * nothing that ``import multiverso_tpu`` reaches imports this module at its
   top: it brings ``jax.experimental.pallas``, a second of module code. A
   table imports it inside the functions that need it; the keyed FTRL table
@@ -317,6 +319,90 @@ current machine):
   4.004 ms for the whole program, is the sort (0.14) and 459,264
   descriptors at 8.4 ns. What is left is the descriptors: (a) and (b) of
   ROADMAP Queue 1 item 2.
+* rows, not slots: `add_at_lanes` walks the keys' distinct rows (PR 51,
+  2026-10-04, one v5e chip, `TPU v5 lite`; the cell's own generator at
+  another seed: 111,312 Zipf keys in 67,245 rows, 114,696 live slots of a
+  131,072 bucket, `z` and `n` of 882,775,040 float32; the table's whole
+  program, ms a launch, 30 launches back to back after a warm one, wall
+  clock over the count, three rounds in one chip call, the order reversed
+  in the second; seconds to `.lower()` on the chip's host, a fresh `jit`;
+  every variant's first launch held to XLA's gathers, the same rule and
+  XLA's scatters on the rows it names, as a compact state of 8.6M
+  entries: 0 entries of `z` and 0 of `n` differ in any bit, every variant;
+  my chip runs, PR 51, call 1. `all-distinct`: the same count of keys, each
+  in a row of its own, 111,312 rows).
+    the standing program (PR 49's: a read and a write-back a SLOT and a
+      state, the slots of a row merged by `_run_and`)
+                                    4.158 / 4.162 / 4.154   (0.29-0.33 s)
+      on all-distinct rows          4.141 / 4.141 / 4.139
+    rows compacted by a second sort, the fold's planes bfloat16 with
+      float32 sums, the slots' grid with dead steps under ONE `pl.when`,
+      the first chunk of a step folded in line with the reads' issue and
+      the rest in a loop       (A)  2.761 / 2.761 / 2.763   0.32 s
+      on all-distinct rows          4.201 / 4.201 / 4.204
+    (A) with the row list by a scatter at each slot's row index, sorted
+      indices                       3.328 / 3.327 / 3.328   0.31 s
+    (A) with the planes int8 and int32 sums (bytes less 128, put back
+      under the count plane)        2.687 / 2.693 / 2.686   0.32 s
+    (A) with the grid sized from `U` on the device and no `pl.when`
+                                    2.768 / 2.769 / 2.768   0.38 s
+      on all-distinct rows          4.220 / 4.220 / 4.221
+    (A) with every chunk in the loop, the accumulator zeroed first
+                                    2.840 / 2.840 / 2.840   0.32 s
+      on all-distinct rows          4.148 / 4.146 / 4.149
+    (A) with two chunks in line (the second masked where a step has one)
+      and a loop for the rest       2.798 / 2.803 / 2.799   0.38 s
+    calls 3 and 4, the kernel as kept: (A) with int8 planes of the SIGNED
+      bytes (two shifts a plane; `& 255` puts them back), the fold's
+      sublane iota and row index built INSIDE the step's `pl.when`
+                                    2.682 / 2.690 / 2.681   0.34 s
+      on all-distinct rows          4.139 / 4.138 / 4.134
+      (the standing program beside it 4.155 / 4.156 / 4.159 and 4.148 /
+      4.144 / 4.143)
+    the same with those two values hoisted over the `pl.when`
+                                    2.754 / 2.756 / 2.751   0.37 s
+      (so read the tree of call 2: 2.746 / 2.750 / 2.756; the five planes
+      cast to int8 whole after the concatenate or piece by piece before
+      it: 2.757 / 2.754 / 2.755, no difference)
+  What Mosaic said (each form compiled here for the described v5e first,
+  libtpu 0.0.34, then run): nothing against any of them. The one-hot
+  product with the contraction on the LAST dimension of both sides
+  (`dot_general(((1,), (1,)))`, the slots on lanes on both: no transpose
+  is written) lowers in bfloat16 and in int8; `(640, 128)` int32 and
+  float32 values cast to int8 / bfloat16 after a concatenate of five
+  `(128, 128)` pieces lower; a `fori_loop` between two scalars read from
+  SMEM lowers; a grid bound that is a traced scalar lowers and runs; the
+  slots and the gradient's bits whole in VMEM (`(1024, 128)` int32 each)
+  with a `(1, 128)` row read at a dynamic sublane lower.
+  What it says. (a) **1.40 ms a launch gone, a third of the program**
+  (predicted 0.7-1.2; 1.47 as kept): 526 live steps issue 269,312
+  descriptors where 897 issued 459,264, 1.63 ms at 8.6 ns, and what was
+  feared against it is small: the program at 2.76 ms is those descriptors
+  (2.32), the first sort (0.14) and 0.30 for the second sort, the
+  cumulative sum, the chunk ranges, the fold and the dead steps together
+  (as kept, in the cell's traced run: the kernel 2.378, of it 0.05 not
+  descriptors; the second sort 0.115; the rest of the compaction 0.01). The fold costs little
+  because most of it stands in line with the reads' issue: a step has 2.7
+  chunks on average, the first is in line and the scalar core issues 256
+  descriptors (2.2 us) over it; only the chunks after it wait in a loop
+  ((A) against every chunk in the loop: 0.08 ms for the first chunks of
+  526 steps). Two in line lose 0.04: the masked second chunk is computed
+  in every step and hides nothing more. (b) **The row list by a second
+  sort, not a scatter**: XLA's scatter of 114,816 single values into a
+  512 KB array costs 0.57 ms more than sorting them, sorted indices or
+  not. (c) **int8 planes save 0.07 ms on bfloat16** (half the bytes
+  through the casts and the matrix unit's int8 rate) and state their
+  exactness without an argument about mantissas: kept. **Two `(128, 128)`
+  int32 values defined outside the step's `pl.when` and used inside it
+  cost 0.066 ms a launch** (0.12 us a live step: they cross the branch
+  through memory); inside it they cost nothing. (d) **The dead
+  steps cost nothing that can be read**: the grid sized from `U` is
+  0.007 ms SLOWER than 371 dead steps under one `pl.when`, and lowers
+  0.06 s slower; the static grid stays. (e) **Where walking rows saves
+  nothing** (every key a row of its own) the program as kept is level with
+  the standing one (0.009 ms UNDER it; (A) was 0.06 over): the compaction
+  and the fold cost what `_run_and` cost, and no knob is made for it. (f) `.lower()` is what it
+  was: still ONE unrolled group of 512 descriptors.
 """
 
 from __future__ import annotations
@@ -657,41 +743,23 @@ def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
 # ids a kernel's scalar prefetch may hold: 512 KB of the chip's 1 MB of SMEM
 # (an op's bucket is a power of two; the next one would take all of it)
 PREFETCH_SLOTS = 131_072
-# the bits of a delta that steps nothing (`add_at_lanes`): all ones, the
-# identity of AND; as a float32 a NaN no arithmetic produces
-NO_DELTA = -1
-# slots a grid step of `add_at_lanes` walks: its own group, because its
-# descriptors are four a slot (a grid step's fixed cost is a twentieth of
+# rows a grid step of `add_at_lanes` walks: its own group, because its
+# descriptors are four a row (a grid step's fixed cost is a twentieth of
 # their issue time at 128 as at 256) and a program lowers in the time its
-# unrolled slots take (the optimization record, PR 42, has both sweeps). It
-# is the lanes of a row: the kernel takes a group's keys and deltas as ONE
-# lane-dense row and turns it square (PR 49)
+# unrolled descriptors take (the optimization record, PR 42, has both
+# sweeps). It is the lanes of a row: the slots come to the kernel 128 to a
+# lane-dense row, a chunk, and a chunk is folded onto a group's rows by one
+# square matrix product (PR 51)
 LANE_GROUP = LANES
-# slots whose rule is worked out between two batches of write-backs: the
+# rows whose rule is worked out between two batches of write-backs: the
 # vector unit steps the next chunk while the scalar core issues the last
 # one's descriptors (whole sublane tiles; the record, PR 49, prices 16, 32,
 # 64 and the whole group)
 STEP_SLOTS = 32
-
-
-def _run_and(run, blocks):
-    """``blocks`` (int32, each ``(LANE_GROUP, 128)``, a slot a sublane) with
-    every slot's row replaced by the bitwise AND of the rows of its RUN:
-    the adjacent slots that hold its value of ``run`` (same shape, a slot's
-    value on all its lanes; the values ascend). By doubling, backwards and
-    forwards: a slot ``d`` away is in the run exactly where ``run`` says so
-    there, because the values ascend, and then so is every slot between.
-    AND takes a slot twice as once, so the windows may overlap, and a roll
-    that wraps round the group brings a slot of the run or none."""
-    ones = jnp.int32(NO_DELTA)
-    for p in range(LANE_GROUP.bit_length() - 1):
-        # backwards and forwards; the last distance is half the group
-        # either way
-        for shift in sorted({1 << p, LANE_GROUP - (1 << p)}):
-            joins = pltpu.roll(run, shift, 0) == run
-            blocks = [b & jnp.where(joins, pltpu.roll(b, shift, 0), ones)
-                      for b in blocks]
-    return blocks
+# a delta's 32 bits travel through the matrix unit as its four bytes, signed:
+# int8 planes, int32 sums
+_PLANES, _PLANE_BITS = 4, 8
+_LANE_BITS = LANES.bit_length() - 1
 
 
 def _add_brought(olds, brought):
@@ -699,22 +767,23 @@ def _add_brought(olds, brought):
     return [old + delta for old, delta in zip(olds, brought)]
 
 
-def _lane_add_kernel(rows_ref, keys_ref, *refs, states, brings, step,
-                     interpret):
+def _lane_add_kernel(rows_ref, walked_ref, chunks_ref, slots_ref, *refs,
+                     states, brings, step, interpret):
     deltas, refs = refs[:brings], refs[brings:]
     # the inputs are aliased with the outputs; all access goes through out
-    tables, blocks, sems = (refs[states:2 * states],
-                            refs[2 * states:3 * states], refs[3 * states])
+    tables, blocks = refs[states:2 * states], refs[2 * states:3 * states]
+    folded, sems = refs[3 * states], refs[3 * states + 1]
     group = pl.program_id(0)
     base = group * LANE_GROUP
+    walked = walked_ref[0]
     read_sem, write_sem = sems.at[0], sems.at[1]
 
-    def each(slot, lo=0, count=LANE_GROUP):
+    def each(row, lo=0, count=LANE_GROUP):
         # compiled, the loop is unrolled as it is lowered (from a rolled
         # one, 64 slots a pass, the launch takes 6.67 ms for 4.34: the
         # optimization record, PR 41); interpreted it stays rolled (XLA's
         # CPU compiler takes half a minute a shape over 1,024 copies)
-        jax.lax.fori_loop(lo, lo + count, lambda k, _: slot(k), None,
+        jax.lax.fori_loop(lo, lo + count, lambda k, _: row(k), None,
                           unroll=not interpret)
 
     def read(k):
@@ -729,91 +798,176 @@ def _lane_add_kernel(rows_ref, keys_ref, *refs, states, brings, step,
             pltpu.make_async_copy(block.at[k], table.at[rid],
                                   write_sem).start()
 
-    each(read)
-    # under the landing reads, what does not depend on them. The group's
-    # slots come lane-dense, 128 on one row of an (8, 128) block: slot k's
-    # key and deltas turned onto every lane of sublane k
-    mine = pl.ds(group % SUBLANES, 1)
+    def walk():
+        each(read)
+        # under the landing reads, the fold. Built here, inside the step's
+        # one branch: hoisted over the `pl.when` these two cost 0.07 ms a
+        # launch (the record, PR 51)
+        sublane = jax.lax.broadcasted_iota(jnp.int32, (LANE_GROUP, LANES), 0)
+        # the sublanes past the last row walked are more copies of it
+        # (`rows_ref` names it there): they take what its slots bring too
+        row = jnp.minimum(base + sublane, walked - 1)
 
-    def turned(ref):
-        return jnp.broadcast_to(ref[mine, :], (LANE_GROUP, LANES)).T
+        def fold(chunk):
+            """What a chunk's 128 slots bring to this group's rows:
+            ``(planes x LANE_GROUP, 128)`` int32, byte ``p`` of delta ``i``
+            (signed) at the rows from ``(4 i + p) x LANE_GROUP``, the count
+            of stepping slots that name a lane last. A slot is a lane of
+            the chunk's row here: its row of the group is a one-hot over
+            the sublanes on one side of the product, its lane of that row
+            a one-hot on the other, and the matrix unit multiplies a byte
+            by one and adds zeros, in integers: exact, whatever bits the
+            delta holds."""
+            slots = slots_ref[pl.ds(chunk, 1), :]
+            # a slot that steps nothing holds -1: no row's
+            lands = jnp.broadcast_to(slots >> _LANE_BITS, row.shape) == row
+            names = jnp.broadcast_to(slots & (LANES - 1),
+                                     row.shape) == sublane
+            planes = []
+            for delta in deltas:
+                bits = delta[pl.ds(chunk, 1), :]
+                for p in range(_PLANES):
+                    # the byte, its sign spread (an arithmetic shift)
+                    plane = bits << (32 - _PLANE_BITS * (p + 1)) >> (
+                        32 - _PLANE_BITS)
+                    planes.append(jnp.where(
+                        lands, jnp.broadcast_to(plane, row.shape),
+                        0).astype(jnp.int8))
+            planes.append(jnp.where(lands, 1, 0).astype(jnp.int8))
+            return jax.lax.dot_general(
+                jnp.concatenate(planes, axis=0),
+                jnp.where(names, 1, 0).astype(jnp.int8),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
 
-    keys = turned(keys_ref)
-    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
-    names = lane == (keys & (LANES - 1))
-    # slots of one row each read it; each then takes what ALL of them
-    # bring, so that whichever write-back lands last writes the row they
-    # all wrote
-    brought = _run_and(keys >> (LANES.bit_length() - 1),
-                       [jnp.where(names, turned(d), NO_DELTA) for d in deltas])
-    stepped = brought[0] != NO_DELTA
-    for block in blocks:  # a semaphore counts bytes: a block's a wait
-        pltpu.make_async_copy(block, block, read_sem).wait()
-    brought = [jax.lax.bitcast_convert_type(b, blocks[0].dtype)
-               for b in brought]
-    # the rule a chunk of slots at a time, each chunk's write-backs issued
-    # before the next chunk is stepped: the vector unit works under the
-    # scalar core's issue of the descriptors
-    for lo in range(0, LANE_GROUP, STEP_SLOTS):
-        part = slice(lo, lo + STEP_SLOTS)
-        olds = [block[part, :] for block in blocks]
-        news = step(olds, [b[part, :] for b in brought])
-        for block, old, new in zip(blocks, olds, news):
-            # a lane nobody names is written back as read, whatever the
-            # rule made of it: a select, because the vector unit flushes a
-            # denormal that it adds anything to
-            block[part, :] = jnp.where(stepped[part, :], new, old)
-        each(write, lo, STEP_SLOTS)
-    # the next grid step may read these rows: a run may straddle two steps
-    for block in blocks:
-        pltpu.make_async_copy(block, block, write_sem).wait()
+        # the slots of the group's rows are the sorted slots of a run of
+        # chunks, at least one. The first in line with the reads' issue,
+        # the rest in a loop of vector work alone
+        groups = chunks_ref.shape[0] // 2
+        first, end = chunks_ref[group], chunks_ref[groups + group]
+        folded[...] = fold(first)
+
+        def more(chunk, _):
+            folded[...] += fold(chunk)
+
+        jax.lax.fori_loop(first + 1, end, more, None)
+        # every (row, lane) is named by one stepping slot at most, so the
+        # planes added: each holds that slot's byte, or nothing
+        summed = folded[...]
+        stepped = summed[_PLANES * brings * LANE_GROUP:, :] > 0
+        brought = []
+        for i in range(brings):
+            bits = 0
+            for p in range(_PLANES):
+                at = (_PLANES * i + p) * LANE_GROUP
+                bits |= ((summed[at:at + LANE_GROUP, :]
+                          & ((1 << _PLANE_BITS) - 1)) << (_PLANE_BITS * p))
+            brought.append(jax.lax.bitcast_convert_type(bits,
+                                                        blocks[0].dtype))
+        for block in blocks:  # a semaphore counts bytes: a block's a wait
+            pltpu.make_async_copy(block, block, read_sem).wait()
+        # the rule a chunk of rows at a time, each chunk's write-backs
+        # issued before the next chunk is stepped: the vector unit works
+        # under the scalar core's issue of the descriptors
+        for lo in range(0, LANE_GROUP, STEP_SLOTS):
+            part = slice(lo, lo + STEP_SLOTS)
+            olds = [block[part, :] for block in blocks]
+            news = step(olds, [b[part, :] for b in brought])
+            for block, old, new in zip(blocks, olds, news):
+                # a lane nobody names is written back as read, whatever the
+                # rule made of it: a select, because the vector unit flushes
+                # a denormal that it adds anything to
+                block[part, :] = jnp.where(stepped[part, :], new, old)
+            each(write, lo, STEP_SLOTS)
+        # the next grid step reads other rows into these blocks
+        for block in blocks:
+            pltpu.make_async_copy(block, block, write_sem).wait()
+
+    # the grid is the slots': a step past the rows walked does nothing
+    pl.when(base < walked)(walk)
+
+
+def _rows_walked(keys, steps):
+    """The walk of sorted ``keys`` (whole groups of slots), made on the
+    device: the distinct rows they live in, ascending, then the last one
+    again for every slot left (``rows``: the kernel's descriptors' scalar
+    prefetch); their count (``walked``, one element); for each group of
+    ``LANE_GROUP`` of those rows the chunks of 128 slots that hold its
+    keys, first and end (``chunks``: the firsts, then the ends; a chunk may
+    serve two groups); and the slots themselves, 128 to a lane-dense row:
+    ``index of its row << 7 | its lane``, or -1 for a slot that steps
+    nothing."""
+    rows = keys >> _LANE_BITS
+    first = jnp.concatenate([jnp.ones(1, bool), rows[1:] != rows[:-1]])
+    index = jnp.cumsum(first.astype(jnp.int32)) - 1
+    # a second sort, not a scatter at `index` (the record, PR 51): the
+    # firsts ascend already and every other slot sorts behind them
+    distinct = jnp.minimum(
+        jnp.sort(jnp.where(first, rows, jnp.iinfo(jnp.int32).max)), rows[-1])
+    slots = jnp.where(steps, index << _LANE_BITS | keys & (LANES - 1), -1)
+    by_chunk = index.reshape(-1, LANES)
+    groups = keys.shape[0] // LANE_GROUP
+    base = LANE_GROUP * jnp.arange(groups, dtype=jnp.int32)[:, None]
+    # the indexes ascend: the chunks wholly before a group, and those that
+    # begin before its end
+    chunks = jnp.concatenate([
+        jnp.sum(by_chunk[None, :, -1] < base, axis=1, dtype=jnp.int32),
+        jnp.sum(by_chunk[None, :, 0] < base + LANE_GROUP, axis=1,
+                dtype=jnp.int32)])
+    return distinct, index[-1:] + 1, chunks, slots.reshape(-1, LANES)
 
 
 def add_at_lanes(states, keys, deltas, steps, *, step=None,
                  interpret: bool):
     """``states = step(states at keys, deltas)`` at the slots ``steps``
-    marks, in place, by row descriptors: traceable, for a caller's jitted
-    program that donates the states. A state is a lane-dense float32
-    ``(n x 128,)`` array, read here as rows of 128 (a bitcast); ``keys``
-    are int32 in ``[0, n x 128)``, ASCENDING, at least one and at most
+    marks, in place, by row descriptors, and the count of rows walked
+    (int32, on the device): traceable, for a caller's jitted program that
+    donates the states. A state is a lane-dense float32 ``(n x 128,)``
+    array, read here as rows of 128 (a bitcast); ``keys`` are int32 in
+    ``[0, n x 128)``, ASCENDING, at least one and at most
     ``PREFETCH_SLOTS`` with the slots that fill their last group (those
     repeat the last key and step nothing); a delta is a float32 a slot,
     ``steps`` a bool a slot. ``step(olds, brought) -> news`` is the
     caller's rule, plain ``jax.numpy``, elementwise: ``olds`` the states'
     values and ``brought`` the deltas' (as many as the caller gave, not
     one a state), all of one shape, and it returns a new value a state;
-    without one every state takes the sum of its own delta, ``state[key]
-    += delta``. A grid step reads the rows its ``LANE_GROUP`` keys live in
-    (one descriptor a key and a state), applies the rule to the
-    ``(LANE_GROUP, 128)`` blocks where they landed, in VMEM, and writes
-    the rows back: the caller's program neither gathers a state nor
-    spreads anything over the lanes of a row (the keys and the deltas go
-    in lane-dense, ``slots / 128`` rows of 128, and a group's row is
-    turned onto sublanes in the kernel).
+    without one every state takes its own delta, ``state[key] += delta``.
 
-    * **Keys that share a row** are served: a row's slots are adjacent,
-      each takes what ALL of them bring (``_run_and``), steps the copy of
-      the row it read, and all write the same bytes back. A run that
-      straddles two grid steps is written in both, each stepping the lanes
-      ITS slots name: the first one's write-backs are awaited before the
-      second one reads. The rule is applied lane by lane, so this holds
-      for any rule.
+    The kernel walks ROWS, not slots (PR 51). In front of it, in the
+    caller's program and with no word to the host, the keys' distinct rows
+    are compacted (``_rows_walked``): ``U`` of them, a number the program
+    reads from its input and returns. A grid step reads ``LANE_GROUP`` of
+    those rows of every state (one descriptor a row and a state), folds
+    onto them what the slots of their keys bring, applies the rule to the
+    ``(LANE_GROUP, 128)`` blocks where the rows landed, in VMEM, and writes
+    them back; ``ceil(U / LANE_GROUP)`` steps do that, the rest of the
+    slots' grid nothing. No row is named by two steps. The caller's
+    program neither gathers a state nor spreads anything over the lanes of
+    a row.
+
+    * **The slots of a step's rows** are a run of the sorted slots, 128 to
+      a lane-dense chunk. A chunk is folded onto the rows by the matrix
+      unit: slot ``s`` to the sublane of its row and the lane of its key,
+      two one-hots around the delta's four bytes, int8 planes summed in
+      int32, which the product carries exactly. The bits arrive as they were given: a
+      NaN (its payload too), an infinity, ``-0.0`` and a denormal reach
+      their own key's lane and no other.
     * **A slot that steps nothing** (a pad, wherever it aims: the scratch
-      key may share its row with live keys) is part of its row's run and
-      writes back what the run's other slots make of the row, or the row
-      as read.
+      key may share its row with live keys) brings nothing. Its row is
+      walked like any other and written back as its other slots make it,
+      or as read.
     * **A key named by several slots** (they are adjacent) is stepped once,
-      by what its FIRST slot brings; the others' deltas are not read. The
-      slots may lie either side of a grid step's boundary: those of the
-      second step bring nothing and write back what the first step wrote.
+      by what its FIRST slot brings; the others' deltas are not read,
+      whichever chunks they lie in.
     * **A lane no stepping slot names** is written back as read, by a
       select: its bits stand, a denormal's too. The rule IS computed on it,
-      from its value and a NaN delta (``NO_DELTA``'s bits), and the result
-      dropped: a rule may make of that what it likes, it cannot trap.
+      from its value and a delta of 0.0, and the result dropped: a rule may
+      make of that what it likes, it cannot trap.
+    * **The sublanes of the last step past ``U``** name the last distinct
+      row again and take all that its slots bring: every copy writes the
+      same bytes, whichever lands last.
     A stepped lane takes the rule's float32 operations on the numbers it
     would take on gathered values (without a rule, the one addition
-    ``state.at[key].add(delta)`` makes). A delta that is NaN goes in as
-    the canonical NaN (``NO_DELTA`` is a NaN's bit pattern)."""
+    ``state.at[key].add(delta)`` makes)."""
     slots = launched_slots(keys.shape[0], LANE_GROUP)
     if not 0 < slots <= PREFETCH_SLOTS:
         raise ValueError(
@@ -826,47 +980,42 @@ def add_at_lanes(states, keys, deltas, steps, *, step=None,
                 f"states and no rule")
         step = _add_brought
     # a key several slots name steps at its first: the others bring nothing
-    # (inside a grid step they take the first's from the run; in the next
-    # one, where a run straddles two, they must not step it again)
     steps = steps & jnp.concatenate(
         [jnp.ones(1, bool), keys[1:] != keys[:-1]])
     tail = slots - keys.shape[0]
-    if tail:  # whole groups: more slots of the last key's run
+    if tail:  # whole groups: more slots of the last key, stepping nothing
         keys = jnp.concatenate([keys, jnp.broadcast_to(keys[-1:], (tail,))])
         steps = jnp.concatenate([steps, jnp.zeros(tail, bool)])
         deltas = [jnp.concatenate([d, jnp.zeros(tail, d.dtype)])
                   for d in deltas]
     count = len(states)
-
-    def brought(delta):
-        bits = jax.lax.bitcast_convert_type(
-            jnp.where(delta != delta, jnp.nan, delta), jnp.int32)
-        return jnp.where(steps, bits, NO_DELTA).reshape(-1, LANES)
-
+    rows, walked, chunks, lane_slots = _rows_walked(keys, steps)
     views = [s.reshape(-1, LANES) for s in states]
-    # a grid step's 128 slots are one row of these; the pipeline brings a
-    # tile of eight rows every eighth step
-    block = pl.BlockSpec((SUBLANES, LANES),
-                         lambda g, rows: (g // SUBLANES, 0),
-                         memory_space=pltpu.VMEM)
+    # the slots and the deltas' bits whole in VMEM (512 KB each at the
+    # largest bucket): a step's chunks are wherever its rows' keys sorted to
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(slots // LANE_GROUP,),
-        in_specs=[block] * (1 + len(deltas))
+        in_specs=[whole] * (1 + len(deltas))
         + [pl.BlockSpec(memory_space=pl.ANY)] * count,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * count,
         scratch_shapes=[pltpu.VMEM((LANE_GROUP, LANES), v.dtype)
                         for v in views]
-        + [pltpu.SemaphoreType.DMA((2,))],  # the reads', the write-backs'
+        + [pltpu.VMEM(((_PLANES * len(deltas) + 1) * LANE_GROUP, LANES),
+                      jnp.int32),
+           pltpu.SemaphoreType.DMA((2,))],  # the reads', the write-backs'
     )
     out = pl.pallas_call(
         functools.partial(_lane_add_kernel, states=count,
                           brings=len(deltas), step=step, interpret=interpret),
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype) for v in views],
         grid_spec=grid_spec,
-        # operand order: the rows, the keys, the deltas, the states
-        input_output_aliases={2 + len(deltas) + i: i for i in range(count)},
+        # operand order: the rows, their count, the chunks, the slots, the
+        # deltas, the states
+        input_output_aliases={4 + len(deltas) + i: i for i in range(count)},
         interpret=interpret,
-    )(keys >> (LANES.bit_length() - 1), keys.reshape(-1, LANES),
-      *[brought(d) for d in deltas], *views)
-    return tuple(o.reshape(-1) for o in out)
+    )(rows, walked, chunks, lane_slots,
+      *[jax.lax.bitcast_convert_type(d, jnp.int32).reshape(-1, LANES)
+        for d in deltas], *views)
+    return tuple(o.reshape(-1) for o in out), walked[0]

@@ -26,7 +26,9 @@ the lane kernel (``ops/pallas_rows.add_at_lanes``, the one custom call of
 ``jit__ftrl_keyed_add``, handed :func:`ftrl_step` as its rule: it reads the
 rows of 128 the keys live in, computes the step on them where they landed
 in VMEM and writes them back; since PR 49 that program gathers no state and
-spreads nothing over a row's lanes), up to a bucket of
+spreads nothing over a row's lanes; since PR 51 a row several keys share is
+read, stepped and written ONCE, the distinct rows compacted on the device
+in the same program, which returns their count), up to a bucket of
 ``pallas_rows.PREFETCH_SLOTS`` keys; elsewhere XLA gathers the keys' ``z``
 and ``n``, the same function steps them a slot, and XLA's two scatters of
 single floats write them, which a 3.53 GB operand prices at 11 ms each.
@@ -116,7 +118,7 @@ def ftrl_step(z: jax.Array, n: jax.Array, g: jax.Array, alpha: float,
     (McMahan et al., Algorithm 1), elementwise, float32: the new ``(z,
     n)``. THE rule, written once: XLA's path calls it on the values it
     gathered a slot, the row kernel traces it on the ``(LANE_GROUP, 128)``
-    blocks of ``z`` and ``n`` it has read into VMEM."""
+    blocks of ``z`` and ``n`` it has read into VMEM, a distinct row each."""
     grown = n + g * g
     sigma = (jnp.sqrt(grown) - jnp.sqrt(n)) / alpha
     w = ftrl_weights(z, n, alpha, beta, lambda1, lambda2)
@@ -148,7 +150,9 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
     works on (static). An Add's ``rows`` (static): None where XLA gathers,
     steps and scatters, else the row kernel reads, steps and writes the
     keys' rows (``pallas_rows.add_at_lanes`` under :func:`ftrl_step`),
-    interpreted (True) or compiled."""
+    interpreted (True) or compiled. An Add returns ``(z, n, rows walked)``:
+    the count of distinct rows the kernel read and wrote, an int32 on the
+    device, None from XLA's path."""
 
     step = functools.partial(ftrl_step, alpha=alpha, beta=beta,
                              lambda1=lambda1, lambda2=lambda2)
@@ -181,14 +185,16 @@ def _make_programs(alpha: float, beta: float, lambda1: float,
             z_new, n_new = step(state_of_slots(z, at), state_of_slots(n, at),
                                 g)
             return (z.at[at].set(z_new, indices_are_sorted=True),
-                    n.at[at].set(n_new, indices_are_sorted=True))
-        # the rows of 128 the keys live in, read, stepped where they landed
-        # in VMEM and written back by the row kernel: each key's lane takes
-        # the rule, once (the kernel steps a repeated key at its first
-        # slot), and this program gathers no state
-        return _row_kernel().add_at_lanes(
+                    n.at[at].set(n_new, indices_are_sorted=True), None)
+        # the distinct rows of 128 the keys live in, read once each,
+        # stepped where they landed in VMEM and written back by the row
+        # kernel: each key's lane takes the rule, once (the kernel steps a
+        # repeated key at its first slot), and this program gathers no
+        # state. How many rows that was is its third result
+        (z, n), walked = _row_kernel().add_at_lanes(
             (z, n), at, (g,), at != scratch, interpret=rows,
             step=lambda state, brought: step(*state, *brought))
+        return z, n, walked
 
     # named so that the compiled modules are `jit__ftrl_keyed_get` and
     # `jit__ftrl_keyed_add` in a trace

@@ -17,6 +17,7 @@ This module reaches ``ops/pallas_rows`` inside functions only (`_row_kernel`).
 
 from __future__ import annotations
 
+import collections
 from concurrent.futures import ThreadPoolExecutor
 import functools
 from typing import Any, Callable, Optional, Tuple
@@ -25,13 +26,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from multiverso_tpu.dashboard import Dashboard, span
+from multiverso_tpu.dashboard import Dashboard, current_span, span
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.tables.array_table import _make_whole_update
 from multiverso_tpu.tables.device_ids import (IDS_FROM, LaunchIds, live_slots,
                                               state_of_slots)
 from multiverso_tpu.updaters import SGDUpdater, Updater
 from multiverso_tpu.utils import async_upload
+
+
+# ``(id of its TABLE_ROW_LAUNCH record, distinct rows of 128 its lane kernel
+# walked)`` of the keyed FTRL Adds launched while the op trace recorded: the
+# count is the program's third result, an int32 LEFT ON THE DEVICE. Nothing
+# here fetches it (a launch only appends the pair: no copy is started and
+# the dispatcher waits for nothing, traced or not); whoever reads the trace
+# joins the pairs to the records by id and fetches the counts in one go
+# (``benchmark/layers/ftrl_rows_share.py``). The newest 4,096 launches.
+ROWS_WALKED: collections.deque = collections.deque(maxlen=1 << 12)
 
 
 def _row_kernel():
@@ -409,9 +420,17 @@ def row_plan(mesh, spans_processes: bool, *, dtype: Any = np.float32,
         plan.slot_bytes = plan.state_slot_bytes = 8
         plan.arrays = 2
         plan.updater, plan.state_ops = "ftrl", ("add", "get")
-        plan.add = lambda state, took, grad, slots, path: add(
-            *state, took.ids, grad, live=slots,
-            rows=plan.interpret if path == "pallas" else None)
+
+        def keyed_add(state, took, grad, slots, path):
+            *state, walked = add(
+                *state, took.ids, grad, live=slots,
+                rows=plan.interpret if path == "pallas" else None)
+            launch = current_span()
+            if launch and walked is not None:
+                ROWS_WALKED.append((launch, walked))
+            return tuple(state)
+
+        plan.add = keyed_add
         plan.get = lambda state, took, live: get(*state, took.ids, live=live)
         if shards > 1:
             # a worker's gradient is committed to one device

@@ -401,6 +401,24 @@ def test_the_records_of_a_keyed_op_carry_the_matrix_ops_fields(
     # the Add's launch is the kernel's: the cell's counter of the mechanism
     assert common.load_module(
         "layers", "pallas_row_share.ftrlctr").read(Run()) == 100.0
+    # the kernel walks the keys' distinct rows (PR 51), fewer than the slots
+    # its record's descriptors bound. Only the device knows how many: a
+    # traced launch leaves the count there beside its record's id
+    # (`row_plan.ROWS_WALKED`) and fetches nothing; an untraced one keeps
+    # nothing; the reader joins them to the window's records
+    from multiverso_tpu.tables.row_plan import ROWS_WALKED
+    rows = len(np.unique(np.append(keys, table.scratch_key) >> 7))
+    assert [(launch, int(count)) for launch, count in ROWS_WALKED
+            if launch in (get.id, add.id)] == [(add.id, rows)]
+    rows_share = common.load_module("layers", "ftrl_rows_share").read
+    assert rows_share(Run()) == pytest.approx(100.0 * rows / live)
+    kept = len(ROWS_WALKED)
+    t2 = time.perf_counter()
+    table.wait(table.add_device_async(jnp.ones(1024, jnp.float32), keys))
+    Zoo.instance().server.run_serialized(lambda: None)
+    Run.window = (t2, time.perf_counter())
+    assert len(ROWS_WALKED) == kept
+    assert rows_share(Run()) is None
 
 
 def test_a_remote_client_is_served_the_host_forms(ref):
@@ -857,8 +875,11 @@ def test_the_row_kernel_writes_back_what_the_scatter_writes(ref, case):
             # n + g*g rounds here: a contracted multiply-add would differ
             grad[:count] = rng.standard_normal(count).astype(np.float32) * 3
         for state, rows in zip(states, (None, True)):
-            state[:] = add(*state, jnp.asarray(ids), jnp.asarray(grad),
-                           live=live, rows=rows)
+            # the third result is the rows the kernel walked (PR 51)
+            *state[:], walked = add(*state, jnp.asarray(ids),
+                                    jnp.asarray(grad), live=live, rows=rows)
+            assert walked is None if rows is None else int(walked) == len(
+                np.unique(np.append(keys, _KSIZE) >> 7))
     for want, got, was in zip(states[0], states[1], (z0, n0)):
         want, got = (np.asarray(s).view(np.uint32) for s in (want, got))
         np.testing.assert_array_equal(got, want)

@@ -273,15 +273,24 @@ def test_wide_table_needs_whole_tiles_of_rows():
 
 
 # -- `add_at_lanes`: single floats into lane-dense states, by row descriptors --
-def _lane_case(rng):
-    """Two states of 80 rows of 128 and 300 sorted slots over them: keys
-    that share a row, a key three slots name (its slots either side of slot
-    `LANE_GROUP`), pads that repeat the last key, a slot that steps
+_LANE_ROWS = 300    # rows of 128 a state of the lane cases has
+
+
+def _lane_states(rng):
+    """Two states; the second is never negative (a rule may take its
+    root)."""
+    return [rng.integers(-99, 99, _LANE_ROWS * 128).astype(np.float32),
+            rng.integers(0, 99, _LANE_ROWS * 128).astype(np.float32)]
+
+
+def _lane_shared(rng):
+    """300 sorted slots over 80 rows: keys that share a row, a key three
+    slots name (the first the last slot of a chunk, the second and third
+    in the next chunk), pads that repeat the last key, a slot that steps
     nothing among its row's live slots, a denormal, `-0.0` and `inf` in
     lanes nobody names."""
     group = pallas_rows.LANE_GROUP
-    states = [rng.integers(-99, 99, 80 * 128).astype(np.float32)
-              for _ in range(2)]
+    states = _lane_states(rng)
     keys = np.sort(rng.choice(9_000, 296, replace=False)).astype(np.int32)
     keys = np.sort(np.concatenate([keys, [keys[group - 1]] * 2,
                                    [keys[-1]] * 2])).astype(np.int32)
@@ -291,57 +300,139 @@ def _lane_case(rng):
     steps[40] = False           # and one slot among live ones
     quiet = np.setdiff1d(np.arange(80 * 128), keys)[:3]
     states[0][quiet] = 1e-42, -0.0, np.inf
+    return states, keys, steps
+
+
+def _lane_whole_row(rng):
+    """A row named by all 128 of its keys, among 60 keys elsewhere."""
+    keys = np.sort(np.concatenate([
+        5 * 128 + np.arange(128),
+        rng.choice(np.setdiff1d(np.arange(4_000), 5 * 128 + np.arange(128)),
+                   60, replace=False)])).astype(np.int32)
+    return _lane_states(rng), keys, np.ones(len(keys), bool)
+
+
+def _lane_chunks(rng):
+    """One step's rows (100) hold 600 slots: five chunks fold onto one
+    group, and rows have slots either side of a chunk's boundary."""
+    keys = np.sort(rng.choice(100 * 128, 600, replace=False)).astype(np.int32)
+    rows = keys >> 7
+    assert len(np.unique(rows)) <= 128
+    assert sum(rows[edge - 1] == rows[edge] for edge in range(128, 600, 128)) >= 2
+    return _lane_states(rng), keys, np.ones(len(keys), bool)
+
+
+def _lane_distinct(rng):
+    """Every slot a row of its own: 256 keys, 256 rows walked, two steps
+    of one chunk each."""
+    keys = (np.sort(rng.choice(_LANE_ROWS, 256, replace=False)) * 128
+            + rng.integers(0, 128, 256)).astype(np.int32)
+    return _lane_states(rng), keys, np.ones(len(keys), bool)
+
+
+def _lane_pads(rng):
+    """400 slots (four steps of the grid) in 150 rows: a whole step, a
+    last live step of 22 rows whose other sublanes are copies of the last
+    row, two dead steps."""
+    rows = np.sort(rng.choice(_LANE_ROWS, 150, replace=False))
+    keys = np.concatenate([rows * 128 + 3, rng.choice(rows, 250) * 128
+                           + rng.integers(4, 128, 250)])
+    keys = np.sort(keys).astype(np.int32)
+    assert len(np.unique(keys >> 7)) == 150
+    return _lane_states(rng), keys, np.ones(len(keys), bool)
+
+
+def _lane_one_row(rng):
+    """One row walked: 130 slots (two chunks) of 90 keys of row 7."""
+    keys = 7 * 128 + rng.choice(128, 90, replace=False)
+    keys = np.sort(np.concatenate([keys, rng.choice(keys, 40)]))
+    return _lane_states(rng), keys.astype(np.int32), np.ones(130, bool)
+
+
+def _lane_scratch(rng):
+    """A table's pads: slots aimed at a scratch key (the largest) that
+    step nothing, in a row that live keys share."""
+    scratch = 50 * 128 + 77
+    live = np.concatenate([rng.choice(50 * 128, 150, replace=False),
+                           50 * 128 + np.array([0, 76, 78, 127])])
+    keys = np.sort(np.concatenate([live, [scratch] * 46])).astype(np.int32)
+    return _lane_states(rng), keys, keys != scratch
+
+
+def _lane_specials(rng):
+    """Deltas that are NaN, +-inf, -0.0 and denormal, in one row beside an
+    unnamed lane that holds a denormal; each reaches its own key's lane
+    and no other."""
+    states = _lane_states(rng)
+    keys = np.sort(np.concatenate([
+        9 * 128 + np.array([1, 2, 3, 4, 5, 6]),
+        rng.choice(8 * 128, 40, replace=False)])).astype(np.int32)
+    states[0][9 * 128 + 7] = 1e-42
+    states[1][9 * 128 + 7] = 1e-42
+    return states, keys, np.ones(len(keys), bool)
+
+
+_SPECIALS = np.array([np.nan, np.inf, -np.inf, -0.0, 1e-42, -3e-40],
+                     np.float32)
+_LANE_CASES = {
+    "shared_rows": _lane_shared, "whole_row": _lane_whole_row,
+    "chunks": _lane_chunks, "distinct_rows": _lane_distinct,
+    "pads": _lane_pads, "one_row": _lane_one_row,
+    "scratch_row": _lane_scratch, "specials": _lane_specials}
+
+
+def _callers_rule(olds, brought):
+    """ONE delta for two states: `a += d`, `b = max(b, a_old * d)`."""
+    (a, b), (d,) = olds, brought
+    return a + d, jnp.maximum(b, a * d)
+
+
+def _ftrl_rule(olds, brought):
+    from multiverso_tpu.tables.ftrl_table import ftrl_step
+
+    return ftrl_step(*olds, *brought, 0.1, 1.0, 1.0, 1.0)
+
+
+@pytest.mark.parametrize("rule", [None, _callers_rule, _ftrl_rule],
+                         ids=["no_rule", "callers_rule", "ftrl_rule"])
+@pytest.mark.parametrize("case", _LANE_CASES)
+def test_add_at_lanes_writes_what_xlas_path_writes(case, rule, rng):
+    """`add_at_lanes`, interpreted, against XLA's path (its gathers, the
+    rule a slot, its scatters at the first stepping slot of every key) bit
+    for bit in every entry of both states, with no rule (every state takes
+    its own delta, one float32 addition), under a caller's rule traced on
+    the blocks the kernel read (one delta for two states) and under the
+    FTRL table's; the count of rows walked is the keys' distinct rows.
+    The cases are `_LANE_CASES`' docstrings."""
+    import jax
+
+    states, keys, steps = _LANE_CASES[case](rng)
     deltas = [rng.integers(1, 9, len(keys)).astype(np.float32)
-              for _ in range(2)]
-    # a key takes what its FIRST slot brings, if that slot steps
-    first = np.concatenate([[True], keys[1:] != keys[:-1]]) & steps
-    return states, keys, deltas, steps, first, quiet
+              for _ in range(1 if rule else 2)]
+    if case == "specials":
+        for delta in deltas:
+            delta[keys >> 7 == 9] = _SPECIALS
 
+    @jax.jit
+    def xla(states, keys, deltas, steps):
+        first = steps & jnp.concatenate(
+            [jnp.ones(1, bool), keys[1:] != keys[:-1]])
+        olds = [s[keys] for s in states]
+        news = (rule or pallas_rows._add_brought)(olds, deltas)
+        at = jnp.where(first, keys, states[0].shape[0])
+        return [s.at[at].set(new, mode="drop")
+                for s, new in zip(states, news)]
 
-def test_add_at_lanes_without_a_rule_adds(rng):
-    """`add_at_lanes` with no `step`: every state takes its own delta at
-    the keys whose first slot steps, one float32 addition; every other
-    entry keeps its bits (300 slots: three groups, the last filled with
-    slots of the last key's run)."""
-    import jax
-
-    states, keys, deltas, steps, first, quiet = _lane_case(rng)
-    expect = [s.copy() for s in states]
-    for want, delta in zip(expect, deltas):
-        want[keys[first]] += delta[first]
-    out = jax.jit(lambda s, k, d, m: pallas_rows.add_at_lanes(
-        s, k, d, m, interpret=True))(
+    want = xla(tuple(states), keys, tuple(deltas), steps)
+    got, walked = jax.jit(lambda s, k, d, m: pallas_rows.add_at_lanes(
+        s, k, d, m, step=rule, interpret=True))(
             tuple(states), keys, tuple(deltas), steps)
-    for want, got in zip(expect, out):
-        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
-                                      want.view(np.uint32))
-    assert not np.array_equal(expect[0], states[0])
-    assert (expect[0][quiet].view(np.uint32)
-            == states[0][quiet].view(np.uint32)).all()
-
-
-def test_add_at_lanes_applies_a_callers_rule_in_the_kernel(rng):
-    """A rule of the caller's, traced on the blocks the kernel read: ONE
-    delta for two states (`a += d`, `b = max(b, a_old * d)`), computed on
-    every lane of a row and written at the lanes named alone."""
-    import jax
-
-    states, keys, deltas, steps, first, quiet = _lane_case(rng)
-    a, b = (s.copy() for s in states)
-    d = deltas[0]
-    b[keys[first]] = np.maximum(b[keys[first]], a[keys[first]] * d[first])
-    a[keys[first]] += d[first]
-
-    def rule(olds, brought):
-        (a, b), (d,) = olds, brought
-        return a + d, jnp.maximum(b, a * d)
-
-    out = jax.jit(lambda s, k, d, m: pallas_rows.add_at_lanes(
-        s, k, (d,), m, step=rule, interpret=True))(
-            tuple(states), keys, d, steps)
-    for want, got in zip((a, b), out):
-        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
-                                      want.view(np.uint32))
+    assert int(walked) == len(np.unique(keys >> 7))
+    for before, expect, out in zip(states, want, got):
+        np.testing.assert_array_equal(np.asarray(out).view(np.int32),
+                                      np.asarray(expect).view(np.int32))
+        assert not np.array_equal(np.asarray(out).view(np.int32),
+                                  before.view(np.int32))
 
 
 def test_add_at_lanes_refuses_what_it_cannot_serve():

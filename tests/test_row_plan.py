@@ -148,12 +148,14 @@ def test_the_ftrl_tables_plan(case, devices, platform, want, monkeypatch):
     served = []
     plan = row_plan(_mesh(devices), False, fill=fill, platform=platform,
                     keyed=(len, lambda z, n, ids, grad, live, rows:
-                           served.append(rows) or (z, n)))
+                           served.append(rows) or (z, n, rows)))
     counts = {}
     for bucket in (pallas_rows.PREFETCH_SLOTS, 2 * pallas_rows.PREFETCH_SLOTS):
         before = {path: Dashboard.counter_value("ROW_LAUNCH_%s_ADD" % path)
                   for path in ("PALLAS", "XLA")}
         took = LaunchIds(None, bucket, None, 0, 0, np.zeros(5, np.int32))
+        # the program's third result, the rows its kernel walked, is not
+        # the table's state
         assert plan.launch_add((1, 2), took, np.zeros(5, np.float32), 128,
                                "dispatcher") == (1, 2)
         counts[bucket] = [

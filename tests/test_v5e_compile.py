@@ -443,8 +443,12 @@ def test_keyed_ftrl_add_compiles_with_the_row_kernel_in_place(one_chip):
     of 128), and it writes no block a slot for the kernel: the keys and
     the gradient go in lane-dense, so the temporaries are the sort's (a
     few arrays of the slots, under 8 MB where the three blocks a slot were
-    177). The kernel refuses more keys than its scalar prefetch holds (the
-    table keeps XLA's program there: `RowPlan.largest_bucket`)."""
+    177). Since PR 51 the kernel walks the keys' distinct rows, compacted
+    in this program (a second sort, a cumulative sum, two comparisons of
+    1,024 chunks with 1,024 groups: still no scatter into a state and under
+    8 MB), and the program's third result is their count. The kernel
+    refuses more keys than its scalar prefetch holds (the table keeps XLA's
+    program there: `RowPlan.largest_bucket`)."""
     from multiverso_tpu.tables import ftrl_table as ft
     from multiverso_tpu.tables.device_ids import live_slots
 
@@ -479,6 +483,9 @@ def test_keyed_ftrl_add_compiles_with_the_row_kernel_in_place(one_chip):
                 if " gather(" in line and "slice_sizes={1,128}" in line]
     assert mem.alias_size_in_bytes >= 2 * 4 * padded
     assert mem.temp_size_in_bytes <= 8 << 20
+    # the third result: the rows the kernel walked, a scalar (PR 51)
+    assert [(o.shape, str(o.dtype)) for o in jax.tree.leaves(
+        compiled.out_info)] == [((padded,), "float32")] * 2 + [((), "int32")]
     # the cell's bucket is the largest the kernel's scalar prefetch holds
     assert bucket == pallas_rows.PREFETCH_SLOTS
     with pytest.raises(ValueError, match="add_at_lanes"):
