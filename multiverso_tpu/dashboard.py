@@ -464,6 +464,7 @@ class _OpFields(NamedTuple):
     ids_from: str = ""
     ids_ready: int = 0
     ordinal: int = 0
+    worker: int = -1
 
 
 _OP_DEFAULTS = tuple(_OpFields._field_defaults.get(f)
@@ -497,7 +498,10 @@ class OpRecord(_OpFields):
     says who uploaded its ids (``ids_from``: ``caller``, an in-process
     device-path op's own thread at submit, or ``dispatcher``, in the op's
     ``TABLE_ROW_PREP``) and whether they had landed when the launch began
-    (``ids_ready``: the id array's ``is_ready()``, 1 or 0).
+    (``ids_ready``: the id array's ``is_ready()``, 1 or 0). The six
+    ``CLIENT_*`` records of a served op's client half say which worker's
+    client stamped them (``worker``: its ``worker_id``; -1 on every other
+    record), in its own ring or, carried there, in its server's.
 
     ``_make`` also takes a row shorter than the fields, from a cut
     recorded before the last of them existed: they read their defaults."""
@@ -527,13 +531,19 @@ class OpRing:
         self._slots: List[Optional[tuple]] = [None] * (self._mask + 1)
         self._seq = itertools.count()
 
+    @property
+    def size(self) -> int:
+        """How many records the ring holds before it overwrites."""
+        return self._mask + 1
+
     def append(self, span_id: int, parent: int, stage: str, start_ns: int,
                dur_ns: int, cpu_ns: int, op: int, n: int, path: str = "",
                descriptors: int = 0, bytes: int = 0, shards: int = 0,
                max_shard_n: int = 0, exchange_bytes: int = 0,
                dups: int = 0, updater: str = "", state_rows: int = 0,
                state_bytes: int = 0, waits: int = 0, ids_from: str = "",
-               ids_ready: int = 0, ordinal: int = 0) -> None:
+               ids_ready: int = 0, ordinal: int = 0,
+               worker: int = -1) -> None:
         seq = next(self._seq)
         self._slots[seq & self._mask] = (seq, span_id, parent, stage,
                                          start_ns, dur_ns, cpu_ns, op, n,
@@ -541,7 +551,7 @@ class OpRing:
                                          max_shard_n, exchange_bytes, dups,
                                          updater, state_rows, state_bytes,
                                          waits, ids_from, ids_ready,
-                                         ordinal)
+                                         ordinal, worker)
 
     def point(self, stage: str, op: int) -> None:
         """A point of an op's passage (``hop``), caused by the span the

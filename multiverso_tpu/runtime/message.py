@@ -155,6 +155,13 @@ class MsgType(enum.IntEnum):
     # dispatch ladders treat it as a data request.
     Request_Query = 49
     Reply_Query = -49
+    # the client's half of its served ops, posted to the serving process
+    # that asked for it with the header's profile bit (runtime/remote.py,
+    # docs/observability.md 2.3): one int64 array a batch, a row an op,
+    # and the poster's clock pair. Slot-free and fire-and-forget by
+    # design: the records are a by-product of the ops they describe, and
+    # a post that waited for an answer would be an op of its own
+    Control_Client_Spans = 50  # mvlint: ignore[msg-pairs]
 
     @property
     def is_server_bound(self) -> bool:
@@ -207,6 +214,13 @@ class Message:
     # req_id != 0; the flag's job is propagation and the read tier's
     # primary watermark-confirm leg.
     trace: bool = False
+    # Profile flag: the second ride-along bit of the channel byte (bit 6,
+    # beside the trace flag's bit 7; no version bump). A serving process
+    # sets it on every reply to a correlated request while its
+    # ``Dashboard.profile_annotations`` is on: the client that reads it
+    # records its own half of its ops and posts it back
+    # (``Control_Client_Spans``; docs/observability.md 2.3).
+    profile: bool = False
     # Absolute deadline in LOCAL time.monotonic() seconds (0.0 = none).
     # Never crosses a process boundary as an absolute instant — the wire
     # header (runtime/net.py v5) carries the REMAINING budget in
@@ -228,6 +242,10 @@ class Message:
     # the switch is off): the parent of the message's SERVER_QUEUE_WAIT
     # record. Like enq_ns local to the process and never on the wire.
     enq_span: int = 0
+    # time.perf_counter_ns() at which the receiving thread had this
+    # frame's header (net.py ``_read_frame``, every frame); 0 on a message
+    # that crossed no wire. Local like enq_ns, never on the wire.
+    recv_ns: int = 0
     data: List[Any] = field(default_factory=list)
 
     def create_reply(self) -> "Message":

@@ -423,6 +423,9 @@ class TcpNet:
         # (shm rings) inherit it for free
         wire_channel = channel | (0x80 if getattr(msg, "trace", False)
                                   else 0)
+        if getattr(msg, "profile", False):
+            # the profile flag rides bit 6 the same way
+            wire_channel |= 0x40
         # deadline rides as REMAINING budget (µs): measured against this
         # sender's clock at encode time, so queueing spent here is already
         # subtracted. An expired-at-encode deadline ships as the 1 µs
@@ -742,13 +745,16 @@ class TcpNet:
         is discarded and retransmit recovers it); raises
         :class:`_WireDesync` on an unparsable header."""
         head = read(_HEADER.size)
+        recv_ns = time.perf_counter_ns()
         (magic, version, channel, src, dst, mtype, table_id, msg_id,
          req_id, watermark, deadline_us, nblobs, payload_len,
          crc) = _HEADER.unpack(head)
-        # the channel byte's high bit is the trace flag — mask it off
-        # before routing (the raw channel's == 1 check must still hold)
+        # the channel byte's two high bits are the trace and the profile
+        # flag — mask them off before routing (the raw channel's == 1
+        # check must still hold)
         trace = bool(channel & 0x80)
-        channel &= 0x7F
+        profile = bool(channel & 0x40)
+        channel &= 0x3F
         if magic != _MAGIC:
             log.error("net: bad frame magic %x", magic)
             raise _WireDesync("bad frame magic")
@@ -793,7 +799,7 @@ class TcpNet:
         msg = Message(src=src, dst=dst, type=MsgType(mtype),
                       table_id=table_id, msg_id=msg_id,
                       req_id=req_id, watermark=watermark, trace=trace,
-                      data=blobs)
+                      profile=profile, recv_ns=recv_ns, data=blobs)
         if deadline_us > 0:
             # re-anchor the remaining budget on THIS process's monotonic
             # clock — absolute instants never cross the wire

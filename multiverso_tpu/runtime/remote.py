@@ -49,6 +49,15 @@ replies waiting are finished by the dispatcher as before. Replies may
 therefore leave a connection out of service order; ``RemoteClient._pump``
 settles each by its ``msg_id``. A reply's ``watermark`` is the append
 watermark at its request's service on the dispatcher, whoever sends it.
+
+The client's half of a served op (``docs/observability.md`` 2.3): while a
+serving process's ``Dashboard.profile_annotations`` is on, its replies to
+correlated requests carry the header's profile bit; the ``RemoteClient``
+that reads it stamps seven instants of every op its proxies send from then
+on (``_ClientOp``), and posts them back in batches
+(``Control_Client_Spans``), where they become the six ``CLIENT_*`` records
+of the serving process's op trace (``client_span_records``), on the one
+``perf_counter_ns`` clock the processes of a host share.
 """
 
 from __future__ import annotations
@@ -197,6 +206,7 @@ class _NetCompletion(_WireCompletion):
             msg = Message(src=t.dst, dst=t.src, type=msg_type,
                           table_id=t.table_id, msg_id=t.msg_id,
                           req_id=t.req_id, watermark=watermark,
+                          profile=Dashboard.profile_annotations,
                           data=wire.encode(payload, compress=self._compress))
             self._server._dedup_store(t.req_id, msg)
             hop(t.req_id, "reply_sent")
@@ -229,6 +239,7 @@ class _ReadCompletion(_WireCompletion):
         msg = Message(src=t.dst, dst=t.src, type=msg_type,
                       table_id=t.table_id, msg_id=t.msg_id, req_id=t.req_id,
                       watermark=watermark,
+                      profile=Dashboard.profile_annotations,
                       data=wire.encode(payload, compress=self._compress))
         hop(t.req_id, "read_reply_sent")
         try:
@@ -712,6 +723,9 @@ class RemoteServer:
         if msg.type == MsgType.Control_Cut:
             self._handle_cut(msg)
             return
+        if msg.type == MsgType.Control_Client_Spans:
+            self._take_client_spans(msg)
+            return
         if msg.type == MsgType.Request_Read:
             self._serve_read(msg, compress)
             return
@@ -761,7 +775,7 @@ class RemoteServer:
             self._net.send_via(msg._conn, Message(
                 src=0, dst=msg.src, type=MsgType.Reply_WrongShard,
                 table_id=msg.table_id, msg_id=msg.msg_id, req_id=msg.req_id,
-                trace=msg.trace,
+                trace=msg.trace, profile=Dashboard.profile_annotations,
                 data=wire.encode({"layout_version": self.layout_version,
                                   "manifest": self.layout})))
             return
@@ -950,6 +964,43 @@ class RemoteServer:
             data=wire.encode(Dashboard.snapshot())))
 
     @slot_free
+    def _take_client_spans(self, msg: Message) -> None:
+        """Control_Client_Spans: a client's half of its served ops, a row
+        an op (``_ClientOp.row``), with the client's clock pair at the
+        post. Appended to THIS process's op trace as the six ``CLIENT_*``
+        records a row (``client_span_records``), where the reader of a
+        served op and an operator who cannot reach the trainers' hosts
+        find them beside the server's own records of the same ``req_id``.
+        ``perf_counter_ns`` is one clock for the processes of one boot of
+        one host and nothing anywhere else: both sides' wall clocks say
+        which it is, and a batch whose clock's distance to the wall clock
+        differs from ours by more than ``_CLOCK_GUARD_NS`` is counted and
+        dropped. Nothing is answered."""
+        if not Dashboard.profile_annotations:
+            return  # asked for while the switch was on: nobody reads it now
+        here = time.time_ns() - time.perf_counter_ns()
+        try:
+            rows, clock = (np.asarray(blob) for blob in msg.data)
+            if (rows.dtype != np.int64 or rows.ndim != 2
+                    or rows.shape[1] != len(_ClientOp.ROW)
+                    or clock.dtype != np.int64 or clock.shape != (2,)):
+                raise ValueError(f"{rows.dtype}{rows.shape}, "
+                                 f"{clock.dtype}{clock.shape}")
+            kinds = rows[:, _ClientOp.ROW.index("kind")]
+            if ((kinds < 0) | (kinds >= len(_OP_KINDS))).any():
+                raise ValueError(f"op kinds {sorted(set(kinds.tolist()))}")
+        except ValueError as exc:
+            log.error("remote: malformed client spans from worker %d "
+                      "dropped: %s", msg.src, exc)
+            return
+        if abs(int(clock[1] - clock[0]) - here) > _CLOCK_GUARD_NS:
+            count("CLIENT_SPANS_FOREIGN_CLOCK")
+            return
+        for row in rows.tolist():
+            client_span_records(row, msg.src)
+        count("CLIENT_SPANS_RECEIVED", len(rows))
+
+    @slot_free
     def _reply_layout(self, msg: Message) -> None:
         """Control_Layout: ship the shard group's layout manifest. Like
         the stats probe: no worker slot, no lease, no dedup entry — a
@@ -1042,6 +1093,7 @@ class RemoteServer:
         reply = Message(src=msg.dst, dst=msg.src,
                         type=MsgType.Control_Reply_Register,
                         msg_id=msg.msg_id, req_id=msg.req_id,
+                        profile=Dashboard.profile_annotations,
                         data=wire.encode(payload))
         self._dedup_store(msg.req_id, reply)
         self._net.send_via(msg._conn, reply)
@@ -1225,8 +1277,14 @@ class RemoteChannel:
     def worker_id(self) -> int:
         return self._client.worker_id
 
+    def begin_op(self) -> Optional["_ClientOp"]:
+        return self._client._begin_op()
+
     def submit(self, table_id: int, msg_type: MsgType, request: Any,
                msg_id: int, completion: Completion) -> None:
+        # the waiter stamps its instants on the record of the public op
+        # the calling thread is in, where one is kept
+        completion.client_op = getattr(self._client._op_tls, "op", None)
         self._client._send(table_id, msg_type, request, msg_id, completion)
 
     def post(self, table_id: int, msg_type: MsgType) -> None:
@@ -1299,13 +1357,109 @@ class _Inflight:
     the request-latency histogram measures from here, so retransmits
     lengthen (never reset) the observed latency."""
 
-    __slots__ = ("msg", "sent", "first", "attempts")
+    __slots__ = ("msg", "sent", "first", "attempts", "op")
 
-    def __init__(self, msg: Message, sent: float) -> None:
+    def __init__(self, msg: Message, sent: float,
+                 op: Optional["_ClientOp"] = None) -> None:
         self.msg = msg
         self.sent = sent
         self.first = sent
         self.attempts = 0
+        self.op = op  # the record of the op's client half, if one is kept
+
+
+# a client posts what it recorded after this many ops (and from its
+# maintenance thread, and at close)
+_SPANS_A_POST = 16
+# how far two processes' perf_counter-to-wall-clock distances may differ
+# and still be one host's one clock (NTP steps and the two reads apart stay
+# far under it; another host or another boot is seconds to years away)
+_CLOCK_GUARD_NS = 50_000_000
+_OP_KINDS = ("add", "get", "query")
+# the replies a serving process marks with the profile bit while it records
+# (an error built outside a completion, a dedup entry seeded at a failover
+# and the control replies are not marked)
+_MARKED_REPLIES = frozenset((
+    MsgType.Reply_Get, MsgType.Reply_Add, MsgType.Reply_Read,
+    MsgType.Reply_Query, MsgType.Reply_WrongShard,
+    MsgType.Control_Reply_Register))
+_KIND_OF = {MsgType.Request_Add: 0, MsgType.Request_Get: 1,
+            MsgType.Request_Query: 2}
+
+
+class _ClientOp:
+    """The client's half of one served op while it is recorded: seven
+    ``time.perf_counter_ns()`` instants, each stamped by the thread on
+    which it happens. ``call``: the proxy's public op begins (this object
+    is what ``WorkerTable._public_op`` runs it inside); ``sent``:
+    ``RemoteClient._send`` is back from the transport's send;
+    ``reply_header``: the receive thread has
+    the reply's header (``Message.recv_ns``); ``reply_msg``: the pump has
+    the message; ``done``: the pump has decoded it and is about to settle
+    the completion; ``woken``: the caller's thread is back from
+    ``Completion.wait``; ``ret``: the public op returns (an async op: its
+    ``wait`` returns). It rides on the op's ``_Inflight`` (the pump's
+    three) and ``Completion`` (the waiter's one); no lock: every field has
+    one writer, and the caller's thread reads them after it was woken.
+
+    An op that never reached the wire (refused before the send, served by
+    the read tier) leaves no record; one that failed leaves ``CLIENT_OP``
+    alone (its ``woken`` stays 0)."""
+
+    ROW = ("req_id", "call", "sent", "reply_header", "reply_msg", "done",
+           "woken", "ret", "nbytes", "kind")
+    __slots__ = ROW + ("_client", "open", "retried")
+
+    def __init__(self, client: "RemoteClient") -> None:
+        self._client = client
+        self.open = True  # inside the public op that began it
+        self.retried = False
+        self.req_id = self.nbytes = self.kind = 0
+        self.sent = self.reply_header = self.reply_msg = 0
+        self.done = self.woken = self.ret = 0
+        self.call = time.perf_counter_ns()
+
+    def __enter__(self) -> "_ClientOp":
+        return self
+
+    def __exit__(self, exc_type, *_) -> bool:
+        self.open = False
+        self._client._op_tls.op = None
+        if self.req_id and (self.woken or exc_type is not None):
+            self.close()
+        # else nothing was sent, or an async op's wait is still to come
+        return False
+
+    def close(self) -> None:
+        self.ret = time.perf_counter_ns()
+        self._client._op_recorded(self)
+
+    def row(self) -> List[int]:
+        return [getattr(self, name) for name in self.ROW]
+
+
+def client_span_records(row, worker: int) -> None:
+    """Append the records of one op's client half (a ``_ClientOp.row``) to
+    this process's ring: ``CLIENT_OP`` [``call``, ``ret``] (``n`` = bytes of
+    the request's blobs, ``path`` = the op's kind) and, for an op that was
+    answered, ``CLIENT_SUBMIT`` [``call``, ``sent``], ``CLIENT_REPLY_READ``
+    [``reply_header``, ``reply_msg``], ``CLIENT_REPLY_DECODE``
+    [``reply_msg``, ``done``], ``CLIENT_WAKE`` [``done``, ``woken``] and
+    ``CLIENT_RETURN`` [``woken``, ``ret``]; ids and parents 0 (they join by ``op``, the request's ``req_id``), each
+    with the ``worker`` whose client stamped it."""
+    (req_id, call, sent, reply_header, reply_msg, done, woken, ret, nbytes,
+     kind) = row
+    RING.append(0, 0, "CLIENT_OP", call, ret - call, 0, req_id, nbytes,
+                path=_OP_KINDS[kind], worker=worker)
+    if not (sent and done and woken):
+        return
+    for stage, start, end in (("CLIENT_SUBMIT", call, sent),
+                              ("CLIENT_REPLY_READ", reply_header, reply_msg),
+                              ("CLIENT_REPLY_DECODE", reply_msg, done),
+                              ("CLIENT_WAKE", done, woken),
+                              ("CLIENT_RETURN", woken, ret)):
+        RING.append(0, 0, stage, start, end - start, 0, req_id, 0,
+                    worker=worker)
 
 
 class RemoteClient:
@@ -1364,6 +1518,15 @@ class RemoteClient:
         # through it); the router itself is built after registration
         self._read_router = None
         self._read_ok: Dict[int, bool] = {}
+        # the client's half of its ops (``_ClientOp``): whether the last
+        # correlated reply carried the server's profile bit, the op each
+        # thread's public op is kept in, and the rows recorded since the
+        # last post with the time of the oldest
+        self._server_records = False
+        self._op_tls = threading.local()
+        self._spans: List[List[int]] = []
+        self._spans_lock = threading.Lock()
+        self._spans_since = 0.0
         self._pump_thread = threading.Thread(
             target=self._pump, daemon=True, name="mv-remote-client")
         self._pump_thread.start()
@@ -1409,6 +1572,7 @@ class RemoteClient:
         self._stop_maint.set()
         if self._read_router is not None:
             self._read_router.close()
+        self._post_spans()
         try:
             self._net.send(Message(src=self.worker_id, dst=0,
                                    type=MsgType.Control_Deregister,
@@ -1539,6 +1703,12 @@ class RemoteClient:
         data = [] if request is None and msg_type not in (
             MsgType.Request_Get, MsgType.Request_Add) else wire.encode(
                 request, compress=self._compress)
+        # the record of the calling thread's public op, if one is kept and
+        # this is the request it sends
+        op = getattr(self._op_tls, "op", None)
+        if op is not None and (op.req_id or completion is None
+                               or msg_type not in _KIND_OF):
+            op = None
         msg = Message(src=self.worker_id, dst=0, type=msg_type,
                       table_id=table_id, msg_id=msg_id,
                       deadline=deadline if deadline is not None else 0.0,
@@ -1554,7 +1724,8 @@ class RemoteClient:
         with self._lock:
             if completion is not None:
                 self._pending[msg_id] = completion
-                self._inflight[msg_id] = _Inflight(msg, time.monotonic())
+                self._inflight[msg_id] = _Inflight(msg, time.monotonic(),
+                                                   op)
                 gauge_set("CLIENT_INFLIGHT", len(self._inflight))
                 hop(msg.req_id, "client_send")
                 if msg_type in (MsgType.Request_Get, MsgType.Request_Add,
@@ -1566,18 +1737,88 @@ class RemoteClient:
                     count(f"TENANT_{tenant}_BYTES",
                           sum(int(getattr(b, "nbytes", 0) or len(b))
                               for b in data))
+            if op is not None:
+                op.req_id, op.kind = msg.req_id, _KIND_OF[msg_type]
+                op.nbytes = sum(int(getattr(b, "nbytes", 0) or len(b))
+                                for b in data)
             if self._recovering:
                 # recovery retransmits the whole inflight set (in req_id
                 # order) once re-registered; sending now would race it
                 return msg.req_id
         try:
             self._net.send(msg)
+            if op is not None:
+                op.sent = time.perf_counter_ns()
         except OSError:
             if completion is None:
                 raise  # fire-and-forget posts keep the fail-loud contract
             self._start_recovery()  # the request stays inflight; recovery
             # (or its deadline) settles the completion
         return msg.req_id
+
+    # -- the client's half of an op, recorded --------------------------------
+    def _begin_op(self) -> Optional[_ClientOp]:
+        """A proxy's public op begins on the calling thread: its record,
+        ``call`` stamped, while the server this client talks to records (its
+        last correlated reply carried the profile bit) or this process's
+        own switch is on, and no op that encloses this one has begun it."""
+        if not (self._server_records or Dashboard.profile_annotations):
+            return None
+        tls = self._op_tls
+        if getattr(tls, "op", None) is not None:
+            return None
+        op = tls.op = _ClientOp(self)
+        return op
+
+    @staticmethod
+    def _resent(flight: _Inflight) -> None:
+        """A recorded op's request goes out again: it keeps its first
+        ``call`` and ``sent`` (one the recovery sends for the first time
+        gets its ``sent`` here) and is counted when it ends."""
+        op = flight.op
+        if op is not None:
+            op.retried = bool(op.sent)
+            op.sent = op.sent or time.perf_counter_ns()
+
+    def _op_recorded(self, op: _ClientOp) -> None:
+        """An op's record is complete (the caller's thread, after ``ret``):
+        into this process's ring where its own switch is on, and into the
+        batch for the server that asked, posted once ``_SPANS_A_POST`` ops
+        are in it. After the op, never inside it."""
+        row = op.row()
+        if op.retried:
+            count("CLIENT_SPANS_RETRIED")
+        if Dashboard.profile_annotations:
+            client_span_records(row, self.worker_id)
+        if not self._server_records:
+            return
+        with self._spans_lock:
+            if not self._spans:
+                self._spans_since = time.monotonic()
+            self._spans.append(row)
+            full = len(self._spans) >= _SPANS_A_POST
+        if full:
+            self._post_spans()
+
+    def _post_spans(self) -> None:
+        """Post what was recorded since the last post to the server, fire
+        and forget, as two raw int64 blobs (no payload codec): a row an op
+        (``_ClientOp.ROW``), and this process's ``(perf_counter_ns,
+        time_ns)`` at the post, by which the server tells whether the rows
+        are on its clock. A post that fails is dropped: the records are a
+        by-product of the ops."""
+        with self._spans_lock:
+            batch, self._spans = self._spans, []
+        if not batch:
+            return
+        clock = np.array([time.perf_counter_ns(), time.time_ns()], np.int64)
+        try:
+            self._net.send(Message(
+                src=self.worker_id, dst=0,
+                type=MsgType.Control_Client_Spans, msg_id=next_msg_id(),
+                data=[np.array(batch, np.int64), clock]))
+        except OSError:
+            pass
 
     def _pump(self) -> None:
         while True:
@@ -1608,6 +1849,16 @@ class RemoteClient:
                 gauge_set("CLIENT_INFLIGHT", len(self._inflight))
             if completion is None:
                 continue  # duplicate reply (retransmit + dedup): settled
+            op = flight.op if flight is not None else None
+            if op is not None:
+                op.reply_header = msg.recv_ns
+                op.reply_msg = time.perf_counter_ns()
+            if msg.profile != self._server_records and (
+                    msg.profile or msg.type in _MARKED_REPLIES):
+                # the serving process began, or stopped, recording: so do
+                # this client's ops from the next one on. A reply of a
+                # kind the server does not mark says nothing of its switch
+                self._server_records = msg.profile
             # ANY correlated reply — success or server-side error — proves
             # the connection lives: refill the retry budget, feed the
             # breaker (its failure signal is silence, not error payloads)
@@ -1620,36 +1871,47 @@ class RemoteClient:
                         time.monotonic() - flight.first)
             hop(msg.req_id, "client_reply")
             try:
-                if msg.type == MsgType.Reply_Error:
-                    text = wire.decode(msg.data)
-                    if (isinstance(text, str) and text.startswith("shed:")
-                            and flight is not None
-                            and flight.msg.type == MsgType.Request_Add):
-                        # admission-shed training write: the graceful-
-                        # degradation contract — the delta is DROPPED (a
-                        # lost async gradient, Downpour-tolerated), the
-                        # caller is not errored, the shed is counted
-                        count("CLIENT_ADDS_SHED")
-                        completion.done(None)
-                    else:
-                        completion.fail(RuntimeError(
-                            f"server-side failure: {text}"))
-                elif msg.type == MsgType.Reply_WrongShard:
-                    refusal = wire.decode(msg.data)
-                    completion.fail(WrongShardError(
-                        refusal.get("layout_version", 0),
-                        refusal.get("manifest")))
-                else:
-                    result = wire.decode(msg.data)
-                    if isinstance(result, wire.Ordered):
-                        completion.ordinal = result.ordinal
-                        result = result.value
-                    completion.done(None if msg.type == MsgType.Reply_Add
-                                    else result)
+                result, error = self._outcome(msg, flight, completion), None
             except Exception as exc:  # noqa: BLE001 — a malformed reply must
                 # fail its waiter, not kill the pump (which would hang every
                 # later request forever)
-                completion.fail(exc)
+                result, error = None, exc
+            if op is not None:
+                op.done = time.perf_counter_ns()
+            if error is None:
+                try:
+                    completion.done(result)
+                    continue
+                except Exception as exc:  # noqa: BLE001 — as above
+                    error = exc
+            completion.fail(error)
+
+    @staticmethod
+    def _outcome(msg: Message, flight: Optional[_Inflight],
+                 completion: Completion) -> Any:
+        """What a correlated reply settles its completion with, decoded:
+        the result, or the error raised."""
+        if msg.type == MsgType.Reply_Error:
+            text = wire.decode(msg.data)
+            if (isinstance(text, str) and text.startswith("shed:")
+                    and flight is not None
+                    and flight.msg.type == MsgType.Request_Add):
+                # admission-shed training write: the graceful-degradation
+                # contract — the delta is DROPPED (a lost async gradient,
+                # Downpour-tolerated), the caller is not errored, the shed
+                # is counted
+                count("CLIENT_ADDS_SHED")
+                return None
+            raise RuntimeError(f"server-side failure: {text}")
+        if msg.type == MsgType.Reply_WrongShard:
+            refusal = wire.decode(msg.data)
+            raise WrongShardError(refusal.get("layout_version", 0),
+                                  refusal.get("manifest"))
+        result = wire.decode(msg.data)
+        if isinstance(result, wire.Ordered):
+            completion.ordinal = result.ordinal
+            result = result.value
+        return None if msg.type == MsgType.Reply_Add else result
 
     # -- fault recovery ------------------------------------------------------
     def _start_recovery(self) -> None:
@@ -1698,6 +1960,7 @@ class RemoteClient:
                     for flight in backlog:
                         flight.attempts += 1
                         flight.sent = now
+                        self._resent(flight)
                         hop(flight.msg.req_id, "client_resume_retransmit")
                         try:
                             self._net.send(flight.msg)
@@ -1750,6 +2013,8 @@ class RemoteClient:
                     continue
             if self._rto > 0:
                 self._retransmit_stale(now)
+            if self._spans and now - self._spans_since >= tick:
+                self._post_spans()  # a batch that no 16th op completes
 
     def _retransmit_stale(self, now: float) -> None:
         """Re-send correlated requests whose reply is overdue (per-request
@@ -1776,6 +2041,7 @@ class RemoteClient:
                 stale.append(f)
         for flight in stale:
             count("CLIENT_RETRIES")
+            self._resent(flight)
             hop(flight.msg.req_id, "client_retransmit")
             log.debug("remote client %d: retransmitting %s (attempt %d)",
                       self.worker_id, flight.msg.type, flight.attempts)

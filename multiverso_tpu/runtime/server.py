@@ -62,6 +62,11 @@ def _apply_metrics():
     return _apply_metrics_cache
 
 
+# the interpreter probe's sleep (``Server._probe_interpreter``): fifty wakes a
+# second, while the op trace is on
+_PROBE_PERIOD_NS = 20_000_000
+
+
 def complete_get(completion, result) -> None:
     """Complete a Get with what its table's ``launch_get`` returned, served
     at once or released from a round gate later. A keyed host Get comes
@@ -168,6 +173,10 @@ class Server:
         self._tables: Dict[int, "object"] = {}  # table_id -> ServerTable
         self._queue: MtQueue[Message] = MtQueue()
         self._thread: Optional[threading.Thread] = None
+        # the interpreter probe: a thread only while the op trace is on
+        # (``_main`` starts it, the switch going off ends it)
+        self._probe: Optional[threading.Thread] = None
+        self._probe_stop = threading.Event()
         self._started = threading.Event()
         # Heartbeat/lease tracker for remote workers, attached by the
         # RemoteServer when it starts serving (fault/detector.py); None
@@ -226,6 +235,7 @@ class Server:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> None:
+        self._probe_stop.clear()  # before the thread that starts the probe
         self._thread = threading.Thread(target=self._main, name="mv-server", daemon=True)
         self._thread.start()
         self._started.wait()
@@ -235,9 +245,38 @@ class Server:
             self._flag_unsub()
             self._flag_unsub = None
         self._queue.exit()
+        self._probe_stop.set()
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        probe = self._probe
+        if probe is not None:
+            probe.join(timeout=10)
+
+    def _probe_interpreter(self) -> None:
+        """How long after its timer a sleeping thread of THIS process runs
+        Python again, fifty times a second while the op trace is on: the
+        kernel's timer and scheduler, then the wait for the interpreter
+        lock, which the dispatcher, the serve thread, the finishing thread
+        and every receive thread of a serving process share (a taker waits
+        at most ``sys.getswitchinterval()`` a holder). An idle process
+        reads the first alone. One ring record a wake, start the instant
+        the sleep was due to end, ``dur_ns`` how late it ended; no section
+        (nothing runs inside it) and no monitor: with the switch off there
+        is no thread, because a thread that wakes fifty times a second
+        beside a saturated dispatcher cost it 1.3-2.6% of its rate
+        (PERF.md, PR 52). The dispatcher starts it with the first drain
+        it makes under the switch (``_main``); it ends when it
+        wakes to the switch off, or with the dispatcher (the sleep is a
+        timed wait for the stop, so ``stop`` does not wait a period
+        out)."""
+        while Dashboard.profile_annotations:
+            due = time.perf_counter_ns() + _PROBE_PERIOD_NS
+            if self._probe_stop.wait(_PROBE_PERIOD_NS * 1e-9):
+                break
+            late = max(0, time.perf_counter_ns() - due)
+            RING.append(0, 0, "INTERP_WAKE_DELAY", due, late, 0, 0, 0)
+        self._probe = None
 
     def run_serialized(self, fn: Callable,
                        timeout: Optional[float] = 300.0):
@@ -301,6 +340,12 @@ class Server:
                 clear_wait(_prev_wait)
             if msgs is None:
                 return
+            if Dashboard.profile_annotations and self._probe is None:
+                # this thread alone starts the probe
+                self._probe = threading.Thread(
+                    target=self._probe_interpreter, name="mv-interp-probe",
+                    daemon=True)
+                self._probe.start()
             with span("DISPATCHER_DRAIN", n=len(msgs), cpu=True):
                 # depth AFTER the drain = requests that arrived behind
                 # this wakeup's batch; sampled once per drain, not once

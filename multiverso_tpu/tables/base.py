@@ -16,6 +16,7 @@ callers written against the reference's API port 1:1.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -165,10 +166,14 @@ class Completion:
     ``ordinal``: the table's Add ordinal the op was stamped with, where
     the table orders its Adds (``ServerTable.orders_adds``; the async
     dispatcher writes it before ``done``, a remote client's pump from the
-    reply), else None."""
+    reply), else None.
+
+    ``client_op``: the record of the op's client half, where a remote
+    client keeps one (``RemoteChannel.submit`` sets it), else None:
+    ``wait`` stamps the waiter's ``woken`` on it."""
 
     __slots__ = ("_waiter", "result", "error", "done_ns", "wake_ns",
-                 "ordinal")
+                 "ordinal", "client_op")
     takes_pending = takes_ordinal = True
 
     def __init__(self) -> None:
@@ -177,6 +182,7 @@ class Completion:
         self.error: Optional[BaseException] = None
         self.done_ns = self.wake_ns = 0
         self.ordinal: Optional[int] = None
+        self.client_op: Any = None
 
     def done(self, result: Any) -> None:
         self.result = result
@@ -194,7 +200,9 @@ class Completion:
         if self.done_ns:
             self.wake_ns = time.perf_counter_ns() - self.done_ns
         if self.error is not None:
-            raise self.error
+            raise self.error  # a failed op's record keeps ``woken`` 0
+        if self.client_op is not None:
+            self.client_op.woken = time.perf_counter_ns()
         # kept: a second wait finds the rows
         self.result = PendingHostRead.fetched(self.result)
         return self.result
@@ -225,6 +233,10 @@ class LocalChannel:
         self._zoo.server.send(msg)
 
 
+# what ``WorkerTable._public_op`` hands out where no record is kept
+_NOT_RECORDED = contextlib.nullcontext()
+
+
 class WorkerTable:
     """Client proxy: issues Get/Add messages, tracks outstanding replies."""
 
@@ -235,6 +247,19 @@ class WorkerTable:
         self._pending: Dict[int, Completion] = {}
         self._pending_request: Dict[int, Any] = {}
         self._lock = threading.Lock()
+        # a remote client's channel records the client's half of an op
+        # while its server, or its own process, traces (runtime/remote.py)
+        self._begin_op = getattr(self._channel, "begin_op", None)
+
+    def _public_op(self):
+        """What a public op runs inside, from its first line to its
+        return: the record of the op's client half (its ``call`` stamped
+        here, its ``ret`` where the ``with`` ends), where the channel keeps
+        one and no op that encloses this one on the calling thread has
+        begun it; else a context that does nothing. An async op's record
+        stays open past the ``with`` and ends where its ``wait`` returns."""
+        op = self._begin_op() if self._begin_op is not None else None
+        return _NOT_RECORDED if op is None else op
 
     # -- wiring ------------------------------------------------------------
     def _register(self, server_table: "ServerTable") -> None:
@@ -265,10 +290,12 @@ class WorkerTable:
         return msg_id
 
     def get_async(self, request: Any) -> int:
-        return self._submit(MsgType.Request_Get, request)
+        with self._public_op():
+            return self._submit(MsgType.Request_Get, request)
 
     def add_async(self, request: Any) -> int:
-        return self._submit(MsgType.Request_Add, request)
+        with self._public_op():
+            return self._submit(MsgType.Request_Add, request)
 
     def wait(self, msg_id: int) -> Any:
         with self._lock:
@@ -276,16 +303,22 @@ class WorkerTable:
             request = self._pending_request.pop(msg_id, None)
         if completion is None:
             log.fatal("wait: unknown msg_id %d on table %d", msg_id, self.table_id)
-        with span("WORKER_WAIT", op=msg_id) as waited:
-            raw = completion.wait()
-            if waited.id and completion.done_ns > waited.start_ns:
-                # n: how long after done() the thread that slept here ran
-                # again (0 where the result was there before the wait);
-                # a host Get's fetch, made in the wait, is not in it
-                waited.n = completion.wake_ns
-        if raw is None:
-            return None
-        return self.process_reply_get(raw, request)
+        try:
+            with span("WORKER_WAIT", op=msg_id) as waited:
+                raw = completion.wait()
+                if waited.id and completion.done_ns > waited.start_ns:
+                    # n: how long after done() the thread that slept here
+                    # ran again (0 where the result was there before the
+                    # wait); a host Get's fetch, made in the wait, is not
+                    # in it
+                    waited.n = completion.wake_ns
+            if raw is None:
+                return None
+            return self.process_reply_get(raw, request)
+        finally:
+            op = completion.client_op
+            if op is not None and not op.open:
+                op.close()  # an async op: it ends where its wait returns
 
     def process_reply_get(self, raw: Any, request: Any) -> Any:
         """Post-process a Get reply (reference: ``ProcessReplyGet`` writes
@@ -304,11 +337,11 @@ class WorkerTable:
     # NOTE: these call _submit directly (not self.get_async) so subclasses can
     # override the async methods with their own signatures safely.
     def get(self, request: Any) -> Any:
-        with monitor("WORKER_TABLE_SYNC_GET"):
+        with self._public_op(), monitor("WORKER_TABLE_SYNC_GET"):
             return self.wait(self._submit(MsgType.Request_Get, request))
 
     def add(self, request: Any) -> Any:
-        with monitor("WORKER_TABLE_SYNC_ADD"):
+        with self._public_op(), monitor("WORKER_TABLE_SYNC_ADD"):
             return self.wait(self._submit(MsgType.Request_Add, request))
 
     def query(self, vecs: Any, k: int, metric: str = "dot") -> Any:
@@ -324,12 +357,13 @@ class WorkerTable:
         final (ids, scores) pair — per-kind Get post-processing (e.g.
         MatrixWorker's buffer fill) must not touch it."""
         from multiverso_tpu.query.engine import check_request
-        request = check_request((vecs, k, metric))
-        with monitor("WORKER_TABLE_SYNC_QUERY"):
-            completion = Completion()
-            self._channel.submit(self.table_id, MsgType.Request_Query,
-                                 request, next_msg_id(), completion)
-            return completion.wait()
+        with self._public_op():
+            request = check_request((vecs, k, metric))
+            with monitor("WORKER_TABLE_SYNC_QUERY"):
+                completion = Completion()
+                self._channel.submit(self.table_id, MsgType.Request_Query,
+                                     request, next_msg_id(), completion)
+                return completion.wait()
 
     def finish_train(self) -> None:
         """Signal end-of-training so BSP clocks release peers

@@ -424,10 +424,11 @@ class FTRLWorker(DeviceIdsWorker, WorkerTable):
     # -- host forms: numpy in, numpy out, the same two programs -----------------
     def get(self, keys: Optional[np.ndarray] = None) -> np.ndarray:
         """The weights of ``keys`` (every key's without)."""
-        return super().get((self._keys(keys), False))
+        with self._public_op():
+            return super().get((self._keys(keys), False))
 
     def get_async(self, keys: Optional[np.ndarray] = None) -> int:
-        with span("WORKER_SUBMIT") as submit:
+        with self._public_op(), span("WORKER_SUBMIT") as submit:
             return self._submit(MsgType.Request_Get,
                                 (self._keys(keys, submit), False), submit)
 
@@ -441,11 +442,12 @@ class FTRLWorker(DeviceIdsWorker, WorkerTable):
         """One FTRL step of ``keys`` from their raw gradients ``grads``;
         ``add(grad)``, a gradient for every key, steps the whole table. A
         key named twice takes ONE step from the sum of its gradients."""
-        keys, grads = self._host_add(keys, grads)
-        super().add((self._keys(keys), grads))
+        with self._public_op():
+            keys, grads = self._host_add(keys, grads)
+            super().add((self._keys(keys), grads))
 
     def add_async(self, keys, grads: Optional[np.ndarray] = None) -> int:
-        with span("WORKER_SUBMIT") as submit:
+        with self._public_op(), span("WORKER_SUBMIT") as submit:
             keys, grads = self._host_add(keys, grads)
             return self._submit(MsgType.Request_Add,
                                 (self._keys(keys, submit), grads), submit)

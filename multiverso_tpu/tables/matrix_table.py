@@ -695,13 +695,14 @@ class MatrixWorker(DeviceIdsWorker, WorkerTable):
     # -- get ---------------------------------------------------------------
     def get(self, row_ids: Optional[np.ndarray] = None,
             option: Optional[GetOption] = None) -> np.ndarray:
-        option, phase = self._prep_get_option(option, row_ids)
-        raw = super().get((self._norm_ids(row_ids), option))
-        return self._finish_get(raw, row_ids, phase)
+        with self._public_op():
+            option, phase = self._prep_get_option(option, row_ids)
+            raw = super().get((self._norm_ids(row_ids), option))
+            return self._finish_get(raw, row_ids, phase)
 
     def get_async(self, row_ids: Optional[np.ndarray] = None,
                   option: Optional[GetOption] = None) -> int:
-        with span("WORKER_SUBMIT") as submit:
+        with self._public_op(), span("WORKER_SUBMIT") as submit:
             option, phase = self._prep_get_option(option, row_ids)
             ids = self._named_ids(row_ids, submit)
             msg_id = self._submit(MsgType.Request_Get, (ids, option), submit)
@@ -931,13 +932,14 @@ class MatrixWorker(DeviceIdsWorker, WorkerTable):
 
     def add(self, values: np.ndarray, row_ids: Optional[np.ndarray] = None,
             option: Optional[AddOption] = None) -> None:
-        row_ids, values = self._auto_sparse_rows(values, row_ids)
-        option = self._default_add_option(option)
-        super().add((self._norm_ids(row_ids), values, option))
+        with self._public_op():
+            row_ids, values = self._auto_sparse_rows(values, row_ids)
+            option = self._default_add_option(option)
+            super().add((self._norm_ids(row_ids), values, option))
 
     def add_async(self, values: np.ndarray, row_ids: Optional[np.ndarray] = None,
                   option: Optional[AddOption] = None) -> int:
-        with span("WORKER_SUBMIT") as submit:
+        with self._public_op(), span("WORKER_SUBMIT") as submit:
             row_ids, values = self._auto_sparse_rows(values, row_ids)
             option = self._default_add_option(option)
             ids = self._named_ids(row_ids, submit)
