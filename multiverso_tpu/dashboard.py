@@ -488,7 +488,11 @@ class OpRecord(_OpFields):
     whose rows are sharded over chips it also carries the ``shards`` that
     launched (``n`` is then the slots of all of them), the fullest shard's
     slots (``max_shard_n``) and the bytes of table rows that crossed chips
-    (``exchange_bytes``). Every other stage leaves the seven empty. The
+    (``exchange_bytes``). Every other stage leaves the seven empty, but
+    for the ``bytes`` of ids a section sent up (``WORKER_ROW_IDS``, the
+    caller at submit; the ``TABLE_ROW_PREP`` of a routed op on a table
+    sharded over chips: 0 where the op launched on ids kept from the op
+    before). The
     ``TABLE_ROW_PREP`` of a host row Add says how many of its value rows
     were summed into an earlier row of the same id (``dups``; its ``n`` is
     the distinct rows that went up). The launch of an Add under a stateful

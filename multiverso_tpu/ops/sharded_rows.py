@@ -11,8 +11,11 @@ is named, not left to the partitioner.
 
 **Routing** happens on the chip that holds the op, the mesh's first (a
 device delta is committed there, a device Get's contract (``RowPlan._out_device``), and a Get's
-rows are wanted there). The host uploads the ids as they came, once, to that
-chip alone, and counts how many fall into each shard's range
+rows are wanted there). The host uploads the ids once, to that chip alone,
+an Add's and a Get's in one form (the slots a Get gathers: the ids as they
+came, then ids past the table, the sentinel last; ``RowPlan.launch_ids``
+keeps the array, and an op that names the same rows launches on it and
+uploads nothing), and counts how many fall into each shard's range
 (:func:`shard_counts`, in ``TABLE_ROW_ROUTE``) to pick the static size of a
 shard's segment (:func:`shard_capacity`: the fullest shard's count, rounded
 up in steps, so traffic whose ids spread evenly compiles once and an op
@@ -26,9 +29,10 @@ the host (a radix sort on the owner, ten numpy passes, three sharded
 uploads) cost 4.9 ms an Add on the dispatcher thread; the sort on the chip
 costs 0.11 ms of device time and the host's count 0.13.
 
-**The Add** (one device program). On the first chip each shard's piece of
-the delta is gathered in the order of its segment and sent to its owner,
-with the segment's shard-local ids and its live count, by
+**The Add** (one device program). The ids named are the slots the delta
+has rows for, a static slice of the array that came. On the first chip each
+shard's piece of the delta is gathered in the order of its segment and
+sent to its owner, with the segment's shard-local ids and its live count, by
 ``collective-permute`` with the single pair ``(0, s)``: every row crosses
 the interconnect at most once and nobody receives a row it does not own.
 Then every shard runs the row kernel (``pallas_rows._scatter_add``) on its
@@ -140,7 +144,8 @@ class ShardedRows:
         # named, like their parameters, for the module and operand names a
         # trace is read by. `add(data, ids, delta, capacity=...)`: the table
         # after `delta`'s rows were added to the rows `ids` names; `data` is
-        # donated; `ids` and `delta` as `on_first` gives them
+        # donated; `ids` and `delta` as `on_first` gives them, the ids in
+        # a Get's form: the first `delta.shape[0]` name the rows
         self.add = jax.jit(sharded_row_add, donate_argnums=(0,),
                            static_argnames=("capacity",))
         self._get = jax.jit(sharded_row_get,
@@ -187,7 +192,11 @@ class ShardedRows:
 
     def _add_from_first(self, block, ids, delta, *, capacity):
         with jax.named_scope("shard_route"):
-            _, _, segments = self._segments(ids, block.shape[0], capacity)
+            # the ids come as a Get's do (`RowPlan.launch_ids`: one form,
+            # so that either op can launch on the other's): the slots the
+            # delta has rows for name the rows, a static slice
+            _, _, segments = self._segments(ids[: delta.shape[0]],
+                                            block.shape[0], capacity)
             # the delta's rows in the order of each shard's segment
             pieces = [(meta, delta[positions].astype(block.dtype))
                       for meta, positions in segments]

@@ -4,7 +4,10 @@ every table kind whose device ops launch on a padded id array shares.
 A table on one device has its caller send an op's ids up itself, at submit,
 so that the upload rides under the queue wait (PR 36), and the proxy keeps
 what its last op sent up, so that the next op that names the same ids
-launches on the array that is already there (PR 39). The matrix and the
+launches on the array that is already there (PR 39). On a table sharded
+over the chips of one process the dispatcher sends the ids up, and there
+the table's row plan keeps what its last routed op sent up, by the same
+rule (``KeptIds``; ``RowPlan.launch_ids``, PR 53). The matrix and the
 keyed FTRL table share both, as two mixins:
 
 * ``DeviceIdsServer`` (beside ``ServerTable``): the form of the array an op's
@@ -69,18 +72,21 @@ class LaunchIds(NamedTuple):
     """The ids of one row op as its launch takes them, on their way to the
     device (``DeviceIdsServer.launch_ids``). ``ids``: on a table one program
     serves, the ids padded to ``bucket`` with sentinel-aimed slots, an
-    Add's and a Get's alike; a Get's on a table sharded over chips, the
-    first shard's piece of the array the routed program takes
+    Add's and a Get's alike; a routed op's on a table sharded over chips
+    (``RowPlan.launch_ids``), an Add's and a Get's alike, the slots a Get
+    gathers (the ids, ids past the table, the sentinel last) as the first
+    shard's piece of the array the routed program takes
     (``ShardedRows.on_first``). ``bucket``: the op's power of two (the
     shape of a Get's result, and of a delta XLA's programs take).
     ``counts`` and ``capacity``: the host's part of routing an op over the
-    chips (``RowPlan.launch_ids``), None and 0 where nothing is routed.
+    chips, the op's own, None and 0 where nothing is routed.
     ``nbytes`` went up. ``host``: the ids named as they went up, a view of
     the uploaded host array (to read, never to write). ``counted``: the
     bucket's last slot holds the count of ids and not the sentinel (an Add
     whose delta outnumbers its ids). ``host``, ``bucket`` and ``counted``
     decide the array: an op whose own would have the same three can launch
-    on this one (``DeviceIdsWorker._ids_at_submit`` keeps the last)."""
+    on this one (``DeviceIdsWorker._ids_at_submit`` keeps the last; on a
+    mesh ``RowPlan.launch_ids`` does)."""
 
     ids: jax.Array
     bucket: int
@@ -103,7 +109,9 @@ class SentIds(np.ndarray):
 
 class KeptIds(NamedTuple):
     """What a proxy keeps of the last device-path op it sent up
-    (``DeviceIdsWorker._ids_at_submit``): ``took``, the array on the device,
+    (``DeviceIdsWorker._ids_at_submit``), and a row plan of the last routed
+    op it sent up (``RowPlan.launch_ids``: ``took`` with the counts by shard
+    of the ids named, an Add's): ``took``, the array on the device,
     and ``named``, a private host copy of the ids as the caller named them
     (before a group's bases; ``took.host`` itself where nothing is
     added)."""
@@ -155,7 +163,8 @@ class DeviceIdsServer:
         # (the routed ids' `on_first` and a launch call on several devices
         # outlast the landing: `launch_to_device_ms` 0.37 either way in
         # `emb128x4.bulk-rows`, where the move cost 0.14-0.23 ms an op,
-        # PERF.md, PR 36), so the dispatcher keeps them
+        # PERF.md, PR 36), so the dispatcher sends them up, and the row plan
+        # keeps the routed ones there (`RowPlan.launch_ids`, PR 53)
         self.ids_at_submit = bool(one_device)
 
     def launch_form(self, n: int, op: str, ensure_pad: bool = False,

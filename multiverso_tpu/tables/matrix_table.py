@@ -397,7 +397,7 @@ class MatrixServer(DeviceIdsServer, ServerTable):
                 slot.ids[:n] = row_ids
                 slot.ids[n:bucket] = self.sentinel_row
                 took, delta = self.plan.host_operands(
-                    self, slot.ids, slot.vals, n, bucket)
+                    self, prep, slot.ids, slot.vals, n, bucket)
             # the whole bucket went up, and the program walks it
             self.data, self.states = self.plan.launch_add(
                 (self.data, self.states), took, delta, bucket, "dispatcher",
@@ -782,8 +782,12 @@ class MatrixWorker(DeviceIdsWorker, WorkerTable):
         the same rows, the same pull again) sends nothing up and launches
         on it; which it is, is decided by comparing ``row_ids`` with the
         proxy's copy, never by the array's identity. On a mesh the request
-        holds ``row_ids`` itself and the dispatcher sends the ids up, as
-        before: leave the array alone until ``wait_device`` returns."""
+        holds ``row_ids`` itself and the dispatcher sends the ids up: leave
+        the array alone until ``wait_device`` returns. There the table's
+        row plan keeps what it sent up by the same rule
+        (``RowPlan.launch_ids``): a routed Get that names the rows of the
+        routed op before it launches on that op's ids, compared with the
+        plan's own copy."""
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()
@@ -830,10 +834,14 @@ class MatrixWorker(DeviceIdsWorker, WorkerTable):
         another count. A delta longer than its ids takes a Get's array
         only from an earlier such Add (its last slot holds the count). On
         a mesh the request holds
-        ``row_ids`` itself and the dispatcher sends the ids up, as before:
-        leave the array alone until ``wait`` returns. A count of ids that
-        differs from the value rows' fails the op at its ``wait``, as on
-        every Add path."""
+        ``row_ids`` itself and the dispatcher sends the ids up: leave the
+        array alone until ``wait`` returns. Where the Add is routed to the
+        chips' row kernels (``default`` / ``sgd``) its ids go up in a Get's
+        form and the table's row plan keeps them, so the push of the rows
+        just pulled, and the pull of the rows just pushed, launch on the
+        ids already on the first chip (``RowPlan.launch_ids``). A count of
+        ids that differs from the value rows' fails the op at its ``wait``,
+        as on every Add path."""
         if self.is_sparse:
             log.fatal("device IO is not available on is_sparse tables")
         self._require_device_io()

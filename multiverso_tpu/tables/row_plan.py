@@ -29,8 +29,8 @@ import numpy as np
 from multiverso_tpu.dashboard import Dashboard, current_span, span
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.tables.array_table import _make_whole_update
-from multiverso_tpu.tables.device_ids import (IDS_FROM, LaunchIds, live_slots,
-                                              state_of_slots)
+from multiverso_tpu.tables.device_ids import (IDS_FROM, KeptIds, LaunchIds,
+                                              live_slots, state_of_slots)
 from multiverso_tpu.updaters import SGDUpdater, Updater
 from multiverso_tpu.utils import async_upload
 
@@ -224,6 +224,9 @@ class RowPlan:
     _out_device = None
     # the routed programs of a table sharded over chips (`ops/sharded_rows`)
     _shards = None
+    # the last routed op's ids as they went up, with an Add's counts and
+    # capacity (`launch_ids`)
+    _kept: Optional[KeptIds] = None
 
     def __init__(self) -> None:
         # always on: which program served each row launch, by op
@@ -242,31 +245,69 @@ class RowPlan:
         groups: a device Add's rows named, a host Add's uploaded bucket."""
         return -(-rows // self.group) * self.group
 
-    def launch_ids(self, table, row_ids: np.ndarray, op: str,
+    def launch_ids(self, table, row_ids: np.ndarray, op: str, prep,
                    **form) -> LaunchIds:
         """``row_ids`` (int32) as ``op``'s program takes them, their upload
-        begun: ``table.launch_ids`` (the bucket's one form), or, where the
-        op is routed, on the mesh's first chip with the host's count of
-        them by shard and a segment's capacity (TABLE_ROW_ROUTE): an Add's
-        ids as they came; a Get's the ``live_slots`` it gathers alone,
-        padded with ids past the table, which no shard owns, and the
-        sentinel last (the result's tail is its row, wherever it lives)."""
+        begun, inside the op's TABLE_ROW_PREP (``prep``):
+        ``table.launch_ids`` (the bucket's one form), or, where the op is
+        routed, on the mesh's first chip with the host's count of them by
+        shard and a segment's capacity. A routed Add's and a routed Get's
+        go up in ONE form, the Get's: the ``live_slots`` it gathers alone,
+        the ids named, then ids past the table, which no shard owns, and
+        the sentinel last (the result's tail is its row, wherever it
+        lives); the Add's program walks the slots its delta has rows for.
+
+        The plan keeps what its last routed op sent up (``KeptIds``, depth
+        one; only the dispatcher routes, so no lock), and a routed op that
+        names the same rows launches on it: a trainer's Get names the rows
+        of its Add. Nothing is filled, counted (no TABLE_ROW_ROUTE), put or
+        assembled; ``ROW_IDS_KEPT`` counts it, and a routed op's ``prep``
+        says the ``bytes`` of ids that went up: 0. The same rows:
+        ``row_ids`` equal, element for element, to the plan's own copy of
+        what the last op named (never the caller's array, which it may
+        write again once its op has returned), in an array of the same
+        form (``KeptIds.serves``). Anything else is a miss and replaces
+        what is kept. Hit or miss, the op's own ``counts`` (a Get's: the
+        named ids' and one at the sentinel's owner) and ``capacity`` are
+        worked out from the named ids' counts alike, so a hit launches the
+        program a miss would have."""
         if op not in self.routed:
             return table.launch_ids(row_ids, op, **form)
         from multiverso_tpu.ops import sharded_rows
-        n, bucket, ids, shards = len(row_ids), 0, row_ids, self._shards.shards
+        n, shards = len(row_ids), self._shards.shards
+        form = table.launch_form(n, op, **form)
+        slots = live_slots(n, form[0])
+        block = table.padded_rows // shards
+        kept = self._kept
+        if kept is not None and kept.serves(row_ids, op, form) \
+                and np.array_equal(row_ids, kept.named):
+            table.ids_kept.add()
+            took = kept.took
+        else:
+            ids = table._padded_ids(row_ids, slots, None, table.padded_rows,
+                                    table.sentinel_row)
+            with span("TABLE_ROW_ROUTE") as routing:
+                routing.n = n
+                counts = sharded_rows.shard_counts(ids[:n], block, shards)
+            # as an Add takes them. What goes up is a fresh array nobody
+            # writes again: its first slots are the plan's own copy of the
+            # ids named
+            took = LaunchIds(
+                self._shards.on_first(ids), form[0], counts,
+                sharded_rows.shard_capacity(int(counts.max()), n, shards),
+                ids.nbytes, ids[:n])
+            self._kept = KeptIds(took, took.host)
+            prep.bytes = took.nbytes
         if op == "get":
-            bucket, _ = table.launch_form(n, op, **form)
-            ids = table._padded_ids(row_ids, live_slots(n, bucket), None,
-                                    table.padded_rows, table.sentinel_row)
-        with span("TABLE_ROW_ROUTE") as routing:
-            routing.n = len(ids)
-            counts = sharded_rows.shard_counts(
-                ids, table.padded_rows // shards, shards)
-            capacity = sharded_rows.shard_capacity(
-                int(counts.max()), len(ids), shards)
-        return LaunchIds(self._shards.on_first(ids), bucket, counts,
-                         capacity, ids.nbytes, ids[:n])
+            counts = took.counts
+            if slots > n:
+                # the sentinel, the last slot, is its owner's to gather
+                counts = counts.copy()
+                counts[table.sentinel_row // block] += 1
+            took = took._replace(
+                counts=counts, capacity=sharded_rows.shard_capacity(
+                    int(counts.max()), slots, shards))
+        return took
 
     def took_ids(self, table, row_ids: np.ndarray, op: str,
                  took: Optional[LaunchIds], **form):
@@ -276,18 +317,20 @@ class RowPlan:
         with span("TABLE_ROW_PREP") as prep:
             prep.n = len(row_ids)
             if took is None:
-                took = self.launch_ids(table, row_ids, op, **form)
+                took = self.launch_ids(table, row_ids, op, prep, **form)
         return took, ids_from
 
-    def host_operands(self, table, ids: np.ndarray, vals: np.ndarray,
+    def host_operands(self, table, prep, ids: np.ndarray, vals: np.ndarray,
                       n: int, bucket: int) -> Tuple[LaunchIds, jax.Array]:
-        """A host Add's operands on their way up, from the table's staging
-        arrays (``n`` distinct ids then sentinel slots, their summed rows
-        then zeros): ``bucket`` slots of both in ONE call (a call costs the
-        host a quarter of a millisecond); a routed Add's ``n`` ids and rows
+        """A host Add's operands on their way up, inside its TABLE_ROW_PREP
+        (``prep``), from the table's staging arrays (``n`` distinct ids
+        then sentinel slots, their summed rows then zeros): ``bucket``
+        slots of both in ONE call (a call costs the host a quarter of a
+        millisecond); a routed Add's ``n`` ids, in the routed ops' one form
+        (``launch_ids``: the Get of them launches on that array), and rows
         at the table's columns go to the first chip, as a device delta's."""
         if "add" in self.routed:
-            return (self.launch_ids(table, ids[:n], "add"),
+            return (self.launch_ids(table, ids[:n], "add", prep),
                     self._shards.on_first(vals[:n, : table.num_col]))
         ids_up, vals_up = async_upload((ids[:bucket], vals[:bucket]))
         return LaunchIds(ids_up, bucket, None, 0, ids_up.nbytes,

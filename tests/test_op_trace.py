@@ -581,14 +581,17 @@ def test_wide_row_readers_count_the_rows_named_at_300_columns():
 # -- a table sharded over chips: routing, and what a launch record carries ----
 
 def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
-    """On a table sharded over four devices every row op counts its ids by
-    shard in a TABLE_ROW_ROUTE inside its TABLE_ROW_PREP (`n` = ids routed:
-    a Get's are padded to its step of the bucket, the sentinel last; the
-    chip does the rest of the routing): the dispatcher sends the ids up
-    there, a device-path op's too (no WORKER_ROW_IDS on a mesh), and the
-    TABLE_ROW_PREP keeps `n` = rows named; its TABLE_ROW_LAUNCH says so
-    (`ids_from`) and carries the shards, the slots launched over all of
-    them, the fullest shard's and the bytes of rows that crossed chips
+    """On a table sharded over four devices a row op that sends ids up
+    counts them by shard in a TABLE_ROW_ROUTE inside its TABLE_ROW_PREP
+    (`n` = the ids named; the chip does the rest of the routing): the
+    dispatcher sends the ids up there, a device-path op's too (no
+    WORKER_ROW_IDS on a mesh), padded to a Get's step of the bucket, the
+    sentinel last, and the row plan keeps them: an op that names the rows
+    of the op before it routes nothing and its TABLE_ROW_PREP says `bytes`
+    0. Every TABLE_ROW_PREP keeps `n` = rows named; its TABLE_ROW_LAUNCH
+    says who sent the ids (`ids_from`) and carries the shards, the slots
+    launched over all of them, the fullest shard's and the bytes of rows
+    that crossed chips, hit or miss
     (on one device the caller sends them up: WORKER_ROW_IDS, `caller`).
     `shard_slots_share`,
     `shard_exchange_bytes_share` and `shard_row_imbalance` read them; a
@@ -634,11 +637,13 @@ def test_sharded_launch_records_and_their_readers(tracing, monkeypatch):
                 assert _metric(name, run) is None
             mv.shutdown()
             continue
-        assert [r.n for r in routes] == [n, _live_slots(n, 2048)] * 2
+        assert [r.n for r in routes] == [n]
         preps = {r.id: r for r in trace.spans("TABLE_ROW_PREP")}
         assert all(r.parent in preps for r in routes)
         assert not trace.spans("WORKER_ROW_IDS")
-        assert [r.n for r in preps.values()] == [n] * 4
+        assert [(r.n, r.bytes) for r in preps.values()] == [
+            (n, 4 * _live_slots(n, 2048))] + [(n, 0)] * 3
+        assert _metric("shard_ids_kept_share", run) == 75.0
         assert all(trace._by_id[r.parent].stage in (
             "TABLE_PROCESS_ADD", "TABLE_PROCESS_GET")
             for r in preps.values())

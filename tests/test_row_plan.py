@@ -3,6 +3,7 @@ serves a table's row Add and row Get, decided from the platform, the mesh,
 the lane width and the table's rule. No table is built: a case a program."""
 
 import threading
+from types import SimpleNamespace
 
 import jax
 import numpy as np
@@ -164,3 +165,76 @@ def test_the_ftrl_tables_plan(case, devices, platform, want, monkeypatch):
     assert served == [want[1], None]
     assert list(counts.values()) == [
         [1, 0] if want[0] == "pallas" else [0, 1], [0, 1]]
+
+
+class _RoutedTable(matrix_table.DeviceIdsServer):
+    """What a routed plan asks of its table: the form of an op's id array
+    and the rows and sentinel its padding aims at."""
+
+    padded_rows, sentinel_row = 1000, 999
+
+    def __init__(self):
+        self._init_device_ids(self.sentinel_row, one_device=False)
+
+    def _get_bucket(self, n, ensure_pad):
+        return max(matrix_table._next_pow2(n + 1 if ensure_pad else n), 8)
+
+
+@pytest.mark.parametrize("n", [100, 64])
+@pytest.mark.parametrize("order", [("add", "get"), ("get", "add")])
+def test_the_routed_ops_ids_go_up_in_one_form(order, n, monkeypatch):
+    """The operands a routed plan hands its programs: an Add's ids and a
+    Get's in ONE form, the slots the Get gathers (the ids named, ids past
+    the table, the sentinel last) on the mesh's first device; the counts by
+    shard and a segment's capacity are the op's own (a Get's with the
+    sentinel at its owner, over its slots; an Add's of the ids named), and
+    the second op of a pair launches on the first's array with the counts
+    a plan that kept nothing works out. 64 ids: the device Get's bucket
+    keeps a sentinel slot, 128 against the Add's 64, and both go up."""
+    from multiverso_tpu.ops import sharded_rows
+    from multiverso_tpu.tables.device_ids import live_slots
+
+    monkeypatch.setattr(matrix_table, "_use_pallas_scatter",
+                        lambda platform, num_shards, *width: True)
+    plans = [row_plan(_mesh(4), False, dtype=np.float32, lanes=128,
+                      updater=get_updater(np.float32, "default"), cols=100,
+                      padded_rows=1000, sentinel=999, platform="tpu")
+             for _ in range(2)]
+    assert plans[0]._shards is plans[1]._shards and plans[0]._kept is None
+    table = _RoutedTable()
+    ids = np.random.default_rng(n).choice(990, n, replace=False).astype(
+        np.int32)
+    form = {"add": {"rows": n}, "get": {"ensure_pad": True}}
+    kept = Dashboard.counter_value("ROW_IDS_KEPT")
+    # each op's TABLE_ROW_PREP section, which is told the bytes that went up
+    preps = [SimpleNamespace(bytes=0) for _ in range(3)]
+    first, second = (
+        plans[0].launch_ids(table, ids, op, prep, **form[op])
+        for op, prep in zip(order, preps))
+    alone = plans[1].launch_ids(table, ids, order[1], preps[2],
+                                **form[order[1]])
+    hit = n == 100
+    assert Dashboard.counter_value("ROW_IDS_KEPT") == kept + hit
+    assert (second.ids is first.ids) == hit
+    assert [prep.bytes for prep in preps] == [
+        first.nbytes, 0 if hit else second.nbytes, alone.nbytes]
+    named = np.bincount(ids // 250, minlength=4)
+    for took, op in ((first, order[0]), (second, order[1]),
+                     (alone, order[1])):
+        bucket = (128 if op == "get" else 64) if n == 64 else 128
+        slots = live_slots(n, bucket)
+        assert took.bucket == bucket and not took.counted
+        assert took.ids.shape == (4 * slots,)
+        up = np.asarray(took.ids.addressable_shards[0].data)
+        np.testing.assert_array_equal(up[:n], ids)
+        # (64 ids fill an Add's bucket of 64: no pad, and no sentinel)
+        assert slots == n or ((up[n:-1] == 1000).all() and up[-1] == 999)
+        np.testing.assert_array_equal(took.host, ids)
+        assert not np.shares_memory(took.host, ids)
+        counts = named + (np.arange(4) == 3) * (op == "get" and slots > n)
+        np.testing.assert_array_equal(took.counts, counts)
+        assert took.capacity == sharded_rows.shard_capacity(
+            counts.max(), slots if op == "get" else n, 4)
+    assert second.capacity == alone.capacity
+    # what is kept holds an Add's counts, whichever op sent it up
+    np.testing.assert_array_equal(plans[0]._kept.took.counts, named)

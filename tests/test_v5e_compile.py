@@ -176,7 +176,10 @@ SHARDED_TABLES = [(40_000_004, 128, 128), (3_000_032, 384, 300)]
 def test_sharded_row_add_compiles_with_the_kernel_on_every_shard(
         four_chips, rows, lanes, width):
     """A device Add of 100,000 rows into a table row-sharded over a v5e
-    2x2, at `emb128x4.bulk-rows`' shapes: the ids are sorted on the chip,
+    2x2, at `emb128x4.bulk-rows`' shapes: the ids come in the routed ops'
+    one form, the 102,408 slots a Get of them gathers
+    (`RowPlan.launch_ids`), the 100,000 the delta has rows for are sorted on
+    the chip (one sort, of the ids named and not of the longer array),
     Mosaic takes the kernel with its live count on a shard's block, named
     `shard_scatter` (how `benchmark/shard_trace.py` finds it in a trace),
     the table's blocks are aliased, the delta's rows leave the first chip
@@ -187,8 +190,12 @@ def test_sharded_row_add_compiles_with_the_kernel_on_every_shard(
 
     from multiverso_tpu.ops import sharded_rows
 
+    from multiverso_tpu.tables.matrix_table import _live_slots
+
     programs = sharded_rows.ShardedRows(four_chips, False, -1.0)
     named, shards = 100_000, 4
+    slots = _live_slots(named, 131_072)
+    assert slots == 102_408
     capacity = sharded_rows.shard_capacity(25_137, named, shards)
     assert shards * capacity <= 1.15 * named and capacity % 1024
 
@@ -196,7 +203,7 @@ def test_sharded_row_add_compiles_with_the_kernel_on_every_shard(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=programs.by_rows)
 
     compiled = programs.add.lower(
-        spec((rows, lanes), jnp.float32), spec((shards * named,), jnp.int32),
+        spec((rows, lanes), jnp.float32), spec((shards * slots,), jnp.int32),
         spec((shards * named, width), jnp.float32),
         capacity=capacity).compile()
     text, mem = _hlo_text(compiled), compiled.memory_analysis()
@@ -219,7 +226,9 @@ def test_sharded_row_add_compiles_with_the_kernel_on_every_shard(
     metas = re.findall(r"collective-permute-start\(s32\[(\d+)\].*?"
                        r"source_target_pairs=\{\{0,(\d)\}\}", entry)
     assert sorted(metas) == [(str(capacity + 1), str(s)) for s in (1, 2, 3)]
-    assert len(re.findall(r" sort\(", entry)) == 1
+    sorts = [line for line in entry.splitlines() if " sort(" in line]
+    assert len(sorts) == 1 and f"s32[{named}]" in sorts[0], sorts
+    assert f"s32[{slots}]" not in sorts[0]
     for collective in ("all-reduce", "all-gather", "all-to-all"):
         assert collective not in entry
     # beside the table: the pieces on their way, never the bucket (8.05
